@@ -3,6 +3,7 @@
 #
 #   make native          build tokend/pmgr/client/shim into native/build
 #   make test            run the test suite (CPU mesh)
+#   make chip-smoke      start the whole system once on the TPU (fails without one)
 #   make serve-smoke     continuous-batching serving bench, fast CPU path
 #   make serve-prefix-smoke  prefix-cache on/off serving bench, fast CPU path
 #   make serve-qos-smoke multi-tenant QoS serving bench, fast CPU path
@@ -24,7 +25,7 @@
 IMAGE ?= kubeshare-tpu:latest
 DOCKER ?= $(shell command -v docker || command -v podman)
 
-.PHONY: all native test serve-smoke serve-prefix-smoke serve-qos-smoke serve-mixed-smoke serve-tier-smoke serve-spec-smoke serve-disagg-smoke serve-sharded-smoke serve-loop-smoke serve-loop-v2-smoke serve-fleet-smoke serve-autotune-smoke serve-chaos-smoke serve-fabric-smoke images image-check e2e-kind tsan clean
+.PHONY: all native test chip-smoke serve-smoke serve-prefix-smoke serve-qos-smoke serve-mixed-smoke serve-tier-smoke serve-spec-smoke serve-disagg-smoke serve-sharded-smoke serve-loop-smoke serve-loop-v2-smoke serve-fleet-smoke serve-autotune-smoke serve-chaos-smoke serve-fabric-smoke images image-check e2e-kind tsan clean
 
 all: native
 
@@ -36,6 +37,11 @@ tsan:
 
 test:
 	python3 -m pytest tests/ -x -q
+
+# needs a TPU; one process holds the chip (see the script's docstring for
+# --chips 4 and --interposer)
+chip-smoke:
+	python3 chip_smoke.py
 
 serve-smoke:
 	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --smoke

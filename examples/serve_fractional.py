@@ -44,9 +44,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 
-_requested = os.environ.get("JAX_PLATFORMS", "")
-if _requested:
-    jax.config.update("jax_platforms", _requested)
+from kubeshare_tpu.utils.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 import jax.numpy as jnp
 import numpy as np

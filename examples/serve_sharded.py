@@ -49,9 +49,9 @@ if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
 
 import jax
 
-_requested = os.environ.get("JAX_PLATFORMS", "")
-if _requested:
-    jax.config.update("jax_platforms", _requested)
+from kubeshare_tpu.utils.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 import jax.numpy as jnp
 import numpy as np

@@ -73,8 +73,6 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-# jax 0.4.x: shard_map lives in jax.experimental (jax.shard_map is 0.5+)
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.decoding import _attend_cached, speculative_acceptance
@@ -468,7 +466,7 @@ class ShardedServingContext:
             return _local_prefill(w, cfg, dec, lct, pk, pv, tables,
                                   starts, active, tokens, last_rows)
 
-        # check_rep=False: the replicated outputs (logits, picks) are
+        # check_vma=False: the replicated outputs (logits, picks) are
         # produced by all_gathers, which shard_map's replication checker
         # can't prove replicated — they are, by construction
         self.prefill = self._smap(
@@ -485,8 +483,8 @@ class ShardedServingContext:
             paged_upload_block, (kv, kv, r, slab, slab), (kv, kv))
 
     def _smap(self, fn, in_specs, out_specs):
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def place_params(self, params):
         """Device_put the param tree under the serving rules (sharded

@@ -26,3 +26,10 @@ def wait_listening(
         except OSError:
             time.sleep(poll_s)
     raise TimeoutError(f"nothing listening on {host}:{port}")
+
+
+def free_port(host: str = "127.0.0.1") -> int:
+    """A TCP port nothing listens on right now (bind to 0, read, release)."""
+    with socket.socket() as s:
+        s.bind((host, 0))
+        return s.getsockname()[1]

@@ -984,6 +984,16 @@ const PJRT_Api* WrapApi(const PJRT_Api* real) {
                  "tpushim: additional PJRT plugin detected, not gating it\n");
     return real;
   }
+  // one line per process: which PJRT C API this shim was compiled against
+  // and which one the runtime it wraps reports (a size or version gap here
+  // is the first thing to look at when gating misbehaves)
+  std::fprintf(stderr,
+               "tpushim: header PJRT %d.%d (api struct %zu), runtime PJRT "
+               "%d.%d (api struct %zu)\n",
+               PJRT_API_MAJOR, PJRT_API_MINOR,
+               static_cast<size_t>(PJRT_Api_STRUCT_SIZE),
+               real->pjrt_api_version.major_version,
+               real->pjrt_api_version.minor_version, real->struct_size);
   if (real->struct_size < PJRT_Api_STRUCT_SIZE) {
     // runtime older than our header: pass through unhooked
     std::fprintf(stderr,

@@ -1,0 +1,163 @@
+"""The main path's programs compile for the real chip — without the chip.
+
+The TPU compiler is installed wherever libtpu is, and compiles for a chip
+that is described and not attached (``jax.experimental.topologies``).  Each
+case below compiles one program of ``chip_smoke.py``'s main path at the
+flagship width for one v5e device and checks what only the real compiler
+can say: the Pallas kernel survived lowering (``tpu_custom_call`` — a
+silent demotion to the XLA reference fails here), and the program fits the
+chip's memory.  Nothing runs, so none of this is a chip result.
+
+Skipped where the topology cannot be described (no libtpu).  The persistent
+compilation cache is off around the cases: a compile for a described device
+is written to it but cannot be read back without the chip.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from kubeshare_tpu.models.transformer import (  # noqa: E402
+    TransformerConfig, transformer_init)
+from kubeshare_tpu.ops.attention import (  # noqa: E402
+    _flash_attention, _flash_forward, default_blocks)
+from kubeshare_tpu.serving.paged import (  # noqa: E402
+    paged_decode_loop, paged_decode_step, paged_prefill_step)
+
+V5E_HBM_BYTES = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e device, compile cache off."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # describing a topology takes libtpu's one-process lockfile; xdist
+    # workers (and any other test that loads libtpu) would abort each
+    # other.  Nothing here opens a chip, so the lock guards nothing.
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compiler on this host
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, args, sharding):
+    shaped = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    return jax.jit(fn).lower(*shaped).compile()
+
+
+def _flash_args(b, h, h_kv, s, d):
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, h_kv, s, d), jnp.bfloat16)
+    return q, kv, kv
+
+
+FLASH_SHAPES = chip_smoke.FULL.kernel_shapes  # MHA and GQA, s=2048, d=128
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_forward_compiles_as_kernel(one_chip, shape):
+    block_q, block_k = default_blocks(shape[3])
+    compiled = _compile(
+        lambda q, k, v: _flash_forward(q, k, v, True, block_q, False,
+                                       block_k=block_k),
+        _flash_args(*shape), one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_backward_compiles_as_kernel(one_chip, shape):
+    block_q, block_k = default_blocks(shape[3])
+
+    def loss(q, k, v):
+        out = _flash_attention(q, k, v, True, block_q, False, None, block_k)
+        return out.astype(jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        _flash_args(*shape), one_chip)
+    # forward + dkv + dq kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _serving_shapes():
+    """The serving model and pool of chip_smoke.FULL, as shapes only."""
+    config = TransformerConfig(dtype=jnp.bfloat16, **chip_smoke.FULL.model)
+    params = jax.eval_shape(
+        lambda: transformer_init(jax.random.PRNGKey(0), config))
+    e = chip_smoke.FULL.engine
+    pool = jax.ShapeDtypeStruct(
+        (config.n_layers, e["num_blocks"], config.kv_heads, e["block_size"],
+         config.head_dim), config.dtype)
+    lanes, width = e["num_slots"], e["max_request_len"] // e["block_size"]
+    return config, params, pool, lanes, width, e["prefill_chunk"]
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _decode_step_case():
+    config, params, pool, s, t, _ = _serving_shapes()
+    fn = lambda w, pk, pv, tables, lengths, active, tokens: \
+        paged_decode_step(w, config, pk, pv, tables, lengths, active, tokens)
+    return fn, (params, pool, pool, _i32(s, t), _i32(s),
+                jax.ShapeDtypeStruct((s,), bool), _i32(s))
+
+
+def _prefill_case():
+    config, params, pool, _, t, chunk = _serving_shapes()
+    fn = lambda w, pk, pv, tables, starts, active, tokens, last: \
+        paged_prefill_step(w, config, pk, pv, tables, starts, active,
+                           tokens, last)
+    return fn, (params, pool, pool, _i32(1, t), _i32(1),
+                jax.ShapeDtypeStruct((1,), bool), _i32(1, chunk), _i32(1))
+
+
+def _decode_loop_case():
+    config, params, pool, s, t, _ = _serving_shapes()
+    span, k_units = 4, 4
+
+    def pick(logits, temps, keys):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    fn = lambda w, pk, pv, tables, lengths, active, tokens, temps, keys, \
+        budgets: paged_decode_loop(
+            w, config, pick, span, k_units, None, pk, pv, tables, lengths,
+            active, tokens, temps, keys, budgets)
+    return fn, (params, pool, pool, _i32(s, t), _i32(s),
+                jax.ShapeDtypeStruct((s,), bool), _i32(s),
+                jax.ShapeDtypeStruct((s,), jnp.float32),
+                jax.ShapeDtypeStruct((s, span * k_units, 2), jnp.uint32),
+                _i32(s))
+
+
+@pytest.mark.parametrize("case", [_decode_step_case, _prefill_case,
+                                  _decode_loop_case],
+                         ids=["paged_decode_step", "paged_prefill_step",
+                              "paged_decode_loop"])
+def test_serving_program_compiles_and_fits(one_chip, case):
+    fn, args = case()
+    memory = _compile(fn, args, one_chip).memory_analysis()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes)
+    assert resident < V5E_HBM_BYTES, memory
