@@ -20,6 +20,7 @@ import zlib
 from typing import Optional, Tuple
 
 from .. import constants
+from ..utils.profiling import span
 
 
 class TokenClient:
@@ -123,10 +124,14 @@ class TokenClient:
         costs the co-run bench on a serial-core host; tokend.cc protocol
         notes).  Falls back to ``REQ`` polling against an older daemon
         that answers ``ERR`` for REQB."""
-        import time
+        with span("kubeshare.client.acquire", pod=self.pod_name) as asked:
+            return self._acquire(est_ms, asked)
 
+    def _acquire(self, est_ms: float, asked: span) -> float:
+        round_trips = 0
         while True:
             start = time.monotonic()
+            round_trips += 1
             if self._blocking_ok:
                 reply = self._round_trip(
                     f"REQB {self.pod_name} {est_ms:.3f} "
@@ -137,6 +142,7 @@ class TokenClient:
             else:
                 reply = self._round_trip(f"REQ {self.pod_name} {est_ms:.3f}\n")
             if reply.startswith("TOK "):
+                asked.set(round_trips=round_trips)
                 return float(reply[4:])
             if reply.startswith("WAIT "):
                 # A WAIT that came back well before the park window means

@@ -28,10 +28,11 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Any, Callable, Optional, TypeVar
+from typing import Any, Callable, Optional, Tuple, TypeVar
 
 from .. import constants
 from ..utils.logger import get_logger
+from ..utils.profiling import span
 from .client import TokenClient, connect_from_env
 
 F = TypeVar("F", bound=Callable)
@@ -85,8 +86,18 @@ class ExecutionGuard:
         self._idle_release_ms = idle_release_ms
         self._in_flight = False  # between acquire() and charge(): a step runs
         self._monitor: Optional[threading.Thread] = None
+        # the pod's name on this guard's spans (kubeshare.guard.*)
+        self._pod = getattr(client, "pod_name", "")
+        self._gated_span: Optional[span] = None  # acquire() .. charge()
         self.tokens_acquired = 0
         self.total_gated_ms = 0.0
+        # every acquire(), and those of them that went to the token client
+        # (the others found the held token's budget enough): calls and the
+        # seconds they took, fed by the kubeshare.guard.acquire span
+        self.acquire_calls = 0
+        self.acquire_wait_s = 0.0
+        self.broker_calls = 0
+        self.broker_wait_s = 0.0
 
     @property
     def gated(self) -> bool:
@@ -122,6 +133,27 @@ class ExecutionGuard:
         """
         if self.client is None:
             return 0.0
+        with span("kubeshare.guard.acquire", pod=self._pod) as waited:
+            quota, broker = self._acquire_locked()
+            waited.set(broker=int(broker))
+        self.acquire_calls += 1
+        self.acquire_wait_s += waited.seconds
+        if broker:
+            self.broker_calls += 1
+            self.broker_wait_s += waited.seconds
+        # what tokend is charged for runs from here to charge()'s entry
+        self._end_gated()  # left open by an acquire() never charged
+        self._gated_span = span("kubeshare.guard.gated", pod=self._pod)
+        self._gated_span.__enter__()
+        return quota
+
+    def _end_gated(self) -> None:
+        if self._gated_span is not None:
+            self._gated_span.__exit__(None, None, None)
+            self._gated_span = None
+
+    def _acquire_locked(self) -> Tuple[float, bool]:
+        """(the held token's budget, whether the token client was asked)."""
         with self._lock:
             self._last_activity = time.monotonic()
             self._in_flight = True  # a step follows; idle monitor backs off
@@ -132,7 +164,7 @@ class ExecutionGuard:
             # whole extra turn from a parked peer (measured ~25% of the
             # co-run bench's aggregate before this check)
             if self._held and self._budget_ms >= 0.5 * self._estimate_ms:
-                return self._budget_ms
+                return self._budget_ms, False
             if self._held:
                 self._release_held()
             quota = self.client.acquire(self._estimate_ms)
@@ -141,12 +173,13 @@ class ExecutionGuard:
             self._budget_ms = quota
             self._held_used_ms = 0.0
             self._ensure_monitor()
-            return quota
+            return quota, True
 
     def charge(self, elapsed_ms: float) -> None:
         """Consume budget for one step; release the token when exhausted."""
         if self.client is None:
             return
+        self._end_gated()
         with self._lock:
             self._last_activity = time.monotonic()
             self._in_flight = False

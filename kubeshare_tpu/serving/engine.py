@@ -95,6 +95,8 @@ free: the only recomputed work is the sliding bucketed tail chunk.
 
 from __future__ import annotations
 
+import functools
+import threading
 import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
@@ -106,6 +108,8 @@ import numpy as np
 from ..models.decoding import _filter_logits, bucket_width
 from ..models.transformer import TransformerConfig
 from ..parallel.mesh import MeshSpec
+from ..utils.logger import get_logger
+from ..utils import profiling
 from ..utils.promtext import (MetricFamily, MetricServer, Sample,
                               _format_value)
 from .autotune import AnalyticPolicy, AutoTuner
@@ -153,6 +157,19 @@ SPEC_ACCEPT_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
 # single cold lane cannot end the launch for the whole batch.
 SPEC_LOOP_HIST = 64
 SPEC_LOOP_REDRAFT = 0.25
+
+# A gated dispatch is SLOW when it lasts longer than both of these: the
+# engine then says where the seconds went (guard.acquire, launch or
+# device_wait) on one WARNING line and counts it in
+# kubeshare_serving_slow_dispatches_total{phase}.  The factor is over the
+# running estimate of a dispatch's length (the guard's own 0.8/0.2 average
+# of the same elapsed times, kept here because a guard may be a proxy).
+SLOW_DISPATCH_S = 1.0
+SLOW_DISPATCH_FACTOR = 8.0
+# device arrays at the head of each kind of in-flight record — what
+# _consume_inflight fetches before its bookkeeping
+_INFLIGHT_DEVICE_ARRAYS = {"span": 1, "verify": 2, "loop": 2,
+                           "spec_loop": 5}
 
 
 def _pow2_ceil(n: int) -> int:
@@ -586,6 +603,12 @@ class RequestResult:
     tokens: List[int] = field(default_factory=list)
     submitted_at: float = 0.0
     admitted_at: Optional[float] = None
+    # the launch of the first dispatch that carried a prefill chunk of
+    # this request (a prompt the prefix cache covers but for its tail has
+    # that one), and how many dispatches carried one; None / 0 for a
+    # request a decode pool admitted already prefilled
+    first_dispatch_at: Optional[float] = None
+    prefill_chunks: int = 0
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
 
@@ -665,6 +688,19 @@ class _Slot:
         self.drafter: Optional[NGramDrafter] = None
         self.draft_width = 0
         self.accept_rate = 0.5
+
+
+def _step_program(kind: str, fn, donate_argnums):
+    """``fn`` jitted as ``kubeshare_<kind>_step``: the XLA module is then
+    ``jit_kubeshare_<kind>_step``, and the device plane's ``XLA Modules``
+    line tells the engine's programs from everything else on the chip (a
+    user's ``jit_step``, pod B's).  The name is in the compile cache's key."""
+    @functools.wraps(fn)  # keeps the argument names in the HLO
+    def step(*args):
+        return fn(*args)
+
+    step.__name__ = step.__qualname__ = f"kubeshare_{kind}_step"
+    return jax.jit(step, donate_argnums=donate_argnums)
 
 
 class ServingEngine:
@@ -870,6 +906,17 @@ class ServingEngine:
             "admit": 0.0, "plan": 0.0, "dispatch": 0.0, "consume": 0.0,
             "tune": 0.0}
         self.host_planner_invocations = 0
+        self.steps = 0  # step() calls: `i` on the kubeshare.engine.step span
+        # between _begin_launch and _dispatch: the plan being launched and
+        # its open kubeshare.engine.marshal span
+        self._launching: Optional[_StepPlan] = None
+        self._marshal: Optional[profiling.span] = None
+        # gated dispatches over SLOW_DISPATCH_S and SLOW_DISPATCH_FACTOR x
+        # the running estimate, by the phase that took most of them
+        self.slow_dispatches: Dict[str, int] = {
+            "acquire": 0, "launch": 0, "device_wait": 0}
+        self._dispatch_estimate_ms = 1.0
+        self.log = get_logger("serving")
         # speculation counters, per tenant: proposals scored by verify
         # dispatches, drafts actually emitted, and the per-round
         # acceptance-ratio histogram ([bucket counts, ratio sum] —
@@ -978,7 +1025,7 @@ class ServingEngine:
         # the pool buffers are DONATED: each step updates the cache in
         # place device-side instead of materializing a second pool (on a
         # fractional-HBM pod a transient 2x cache would blow the cap)
-        self._prefill_step = jax.jit(prefill, donate_argnums=(1, 2))
+        self._prefill_step = _step_program("prefill", prefill, (1, 2))
 
         span = ec.decode_span
         eos = ec.eos_token
@@ -997,7 +1044,7 @@ class ServingEngine:
 
         if sharded is not None:
             decode = sharded.decode_span(pick_rows, span, eos)
-        self._decode_step = jax.jit(decode, donate_argnums=(1, 2))
+        self._decode_step = _step_program("decode", decode, (1, 2))
 
         def make_loop(k_units):
             # the device-resident multi-step loop: up to K span-units
@@ -1015,7 +1062,7 @@ class ServingEngine:
 
             if sharded is not None:
                 loop = sharded.decode_loop(pick_rows, span, k_units, eos)
-            return jax.jit(loop, donate_argnums=(1, 2))
+            return _step_program("loop", loop, (1, 2))
 
         # one jitted loop program per depth: just the configured K
         # normally; under autotune, EVERY power-of-two depth up to the
@@ -1056,7 +1103,7 @@ class ServingEngine:
                 spec_loop = sharded.spec_loop(
                     pick_rows, k_units, eos, max_order,
                     SPEC_LOOP_REDRAFT, spec_w)
-            return jax.jit(spec_loop, donate_argnums=(1, 2))
+            return _step_program("spec_loop", spec_loop, (1, 2))
 
         # one speculative loop program per warmed depth — exactly the
         # plain loop's depth set, armed only when speculation is on
@@ -1081,7 +1128,7 @@ class ServingEngine:
 
         if sharded is not None:
             mixed = sharded.mixed_step(pick_rows, span, eos)
-        self._mixed_step = jax.jit(mixed, donate_argnums=(1, 2))
+        self._mixed_step = _step_program("mixed", mixed, (1, 2))
 
         def verify(w, pk, pv, tables, lengths, active, tokens, widths,
                    temps, keys):
@@ -1096,7 +1143,7 @@ class ServingEngine:
 
         if sharded is not None:
             verify = sharded.verify_span(pick_rows)
-        self._verify_step = jax.jit(verify, donate_argnums=(1, 2))
+        self._verify_step = _step_program("verify", verify, (1, 2))
 
         def mixed_verify(w, pk, pv, p_table, p_start, p_tokens,
                          p_last_row, p_temp, p_key, d_tables, d_lengths,
@@ -1112,8 +1159,8 @@ class ServingEngine:
 
         if sharded is not None:
             mixed_verify = sharded.mixed_verify_step(pick_rows)
-        self._mixed_verify_step = jax.jit(mixed_verify,
-                                          donate_argnums=(1, 2))
+        self._mixed_verify_step = _step_program(
+            "mixed_verify", mixed_verify, (1, 2))
         # the copy-on-write primitive: one block, all layers, K and V —
         # a single static shape, so the cache adds exactly ONE compile.
         # Wrapped per-engine (like prefill/decode above): jitting the
@@ -1124,7 +1171,7 @@ class ServingEngine:
 
         if sharded is not None:
             copy = sharded.copy_block
-        self._copy_step = jax.jit(copy, donate_argnums=(0, 1))
+        self._copy_step = _step_program("copy", copy, (0, 1))
 
         # the KV tier's promotion primitive: one block's host payload
         # into a fresh pool block — like the CoW copy, a single static
@@ -1138,7 +1185,7 @@ class ServingEngine:
             # pool's head sharding, so tier promotion and migration
             # unpack are sharding-agnostic host-side
             upload = sharded.upload_block
-        self._upload_step = jax.jit(upload, donate_argnums=(0, 1))
+        self._upload_step = _step_program("upload", upload, (0, 1))
 
         # the online autotuner (serving/autotune.py): ticked by step()
         # between consume and plan, so _plan_step always reads
@@ -1344,10 +1391,13 @@ class ServingEngine:
         state — the drafter reads ``generated``) and the next step
         dispatched.  Returns False when the engine is fully idle.
 
-        Every phase is wall-timed into ``host_seconds`` (exported as
-        ``kubeshare_serving_host_seconds_total{phase}``) — the raw
-        material for proving, not asserting, that the device-resident
-        loop removes host overhead from the decode hot path."""
+        Every phase is a ``kubeshare.engine.<phase>`` span
+        (utils/profiling.py) whose seconds feed ``host_seconds``
+        (exported as ``kubeshare_serving_host_seconds_total{phase}``)
+        — the raw material for proving, not asserting, that the
+        device-resident loop removes host overhead from the decode hot
+        path; under a profiler session the same spans are in the trace
+        on the device's clock."""
         if self.fault_clock is not None:
             # chaos seam: a planned replica kill raises ReplicaKilled
             # HERE, before any host state mutates this step — the
@@ -1355,32 +1405,33 @@ class ServingEngine:
             # the fleet's recovery walk
             self.fault_clock.on_engine_step(self)
         hs = self.host_seconds
-        t0 = time.monotonic()
-        self._admit()
-        t1 = time.monotonic()
-        consumed = self._consume_inflight()
-        t2 = time.monotonic()
-        # the tuner ticks BETWEEN consume and plan: it reads the
-        # fully-consumed counters and retunes its knobs before
-        # _plan_step consults them — and its wall time lands in the
-        # "tune" phase, never in "plan" (tuner overhead is first-class
-        # observable, and the planner/host counters exclude it)
-        if self._tuner is not None:
-            self._tuner.tick()
-            t2t = time.monotonic()
-        else:
-            t2t = t2  # no tuner: the "tune" phase stays exactly zero
-        plan = self._plan_step()
-        t3 = time.monotonic()
-        hs["admit"] += t1 - t0
-        hs["consume"] += t2 - t1
-        hs["tune"] += t2t - t2
-        hs["plan"] += t3 - t2t
-        if plan is None:
-            return consumed
-        self._dispatch_plan(plan)
-        hs["dispatch"] += time.monotonic() - t3
-        return True
+        with profiling.span("kubeshare.engine.step", i=self.steps):
+            self.steps += 1
+            with profiling.span("kubeshare.engine.admit") as phase:
+                self._admit()
+            hs["admit"] += phase.seconds
+            with profiling.span("kubeshare.engine.consume") as phase:
+                consumed = self._consume_inflight()
+            hs["consume"] += phase.seconds
+            # the tuner ticks BETWEEN consume and plan: it reads the
+            # fully-consumed counters and retunes its knobs before
+            # _plan_step consults them — and its wall time lands in the
+            # "tune" phase, never in "plan" (tuner overhead is
+            # first-class observable, and the planner/host counters
+            # exclude it; no tuner: the phase stays exactly zero)
+            if self._tuner is not None:
+                with profiling.span("kubeshare.engine.tune") as phase:
+                    self._tuner.tick()
+                hs["tune"] += phase.seconds
+            with profiling.span("kubeshare.engine.plan") as phase:
+                plan = self._plan_step()
+            hs["plan"] += phase.seconds
+            if plan is None:
+                return consumed
+            with profiling.span("kubeshare.engine.dispatch") as phase:
+                self._dispatch_plan(plan)
+            hs["dispatch"] += phase.seconds
+            return True
 
     def _plan_step(self) -> Optional[_StepPlan]:
         """The scheduling decision, free of dispatch mechanics (the
@@ -1488,6 +1539,7 @@ class ServingEngine:
         """Launch one planned step — device-argument marshaling and
         dispatch only; every scheduling decision was made in
         :meth:`_plan_step`."""
+        self._begin_launch(plan)
         # the fleet watchdog's hang budget scales by the units this
         # launch may legitimately cover — a deep loop is slower than a
         # span WITHOUT being hung
@@ -1851,6 +1903,35 @@ class ServingEngine:
         for phase in sorted(self.host_seconds):
             host_s.add({"phase": phase, **plabel},
                        self.host_seconds[phase])
+        # the engine's own guard, where it has the counters (a wrapped or
+        # stand-in guard may not): every acquire() is either covered by
+        # the held token's budget or goes to the token broker
+        guard_wait = MetricFamily(
+            "kubeshare_serving_guard_wait_seconds_total",
+            "Seconds inside the execution guard's acquire(), by whether "
+            "the held token's budget covered the dispatch (held) or the "
+            "token broker was asked (broker).", "counter")
+        guard_calls = MetricFamily(
+            "kubeshare_serving_guard_calls_total",
+            "Calls of the execution guard's acquire(), by kind.",
+            "counter")
+        if hasattr(self.guard, "broker_calls"):
+            g = self.guard
+            for kind, calls, wait in (
+                    ("held", g.acquire_calls - g.broker_calls,
+                     g.acquire_wait_s - g.broker_wait_s),
+                    ("broker", g.broker_calls, g.broker_wait_s)):
+                guard_wait.add({"kind": kind, **plabel}, wait)
+                guard_calls.add({"kind": kind, **plabel}, calls)
+        slow = MetricFamily(
+            "kubeshare_serving_slow_dispatches_total",
+            "Gated dispatches that lasted over a second and over eight "
+            "times the running estimate, by the phase that took most of "
+            "it (acquire / launch / device_wait); each is also one "
+            "WARNING line.", "counter")
+        for phase in sorted(self.slow_dispatches):
+            slow.add({"phase": phase, **plabel},
+                     self.slow_dispatches[phase])
         planner = MetricFamily(
             "kubeshare_serving_host_planner_invocations_total",
             "Scheduler planner invocations (_plan_step calls).  With "
@@ -2059,7 +2140,8 @@ class ServingEngine:
                            **plabel}, n)
         return [req, blocks, tokens, dispatches, loop_units,
                 spec_loop_units, exit_reason, depth_summary, host_s,
-                planner, prefix, hit_tokens, evicted, tier_blocks,
+                guard_wait, guard_calls, slow, planner, prefix,
+                hit_tokens, evicted, tier_blocks,
                 tier_req, tier_tokens, tier_bytes, tier_stall,
                 tier_corrupt, tier_origin, disk_bytes, disk_blocks, ttft,
                 t_depth, t_blocks, t_tokens, preempt, cls_ttft, tbt,
@@ -2818,20 +2900,88 @@ class ServingEngine:
         asynchronous, so host-side work (admission, the caller's
         arrival loop) overlaps device execution, and emitted tokens
         are read one step later in :meth:`_consume_inflight`."""
+        plan, self._launching = self._launching, None
+        if self._marshal is not None:
+            self._marshal.__exit__(None, None, None)
+            self._marshal = None
         if self.fault_clock is not None:
             # chaos seam: an injected slow/hung dispatch advances the
             # fault clock's virtual time here, where the fleet's
             # dispatch watchdog measures
             self.fault_clock.on_dispatch(self)
         if self.guard is None:
-            return fn(*args)
+            return self._launch(plan, fn, args)[0]
+        entered = time.monotonic()
         self.guard.acquire()
         start = time.monotonic()
         try:
-            out = jax.block_until_ready(fn(*args))
+            out, launch = self._launch(plan, fn, args)
+            with profiling.span("kubeshare.engine.device_wait") as wait:
+                out = jax.block_until_ready(out)
         finally:
-            self.guard.charge((time.monotonic() - start) * 1e3)
+            elapsed_ms = (time.monotonic() - start) * 1e3
+            self.guard.charge(elapsed_ms)
+        total = wait.end - entered
+        if total > SLOW_DISPATCH_S and \
+                total * 1e3 > SLOW_DISPATCH_FACTOR * self._dispatch_estimate_ms:
+            self._report_slow_dispatch(entered, start, launch, wait)
+        self._dispatch_estimate_ms = (0.8 * self._dispatch_estimate_ms
+                                      + 0.2 * elapsed_ms)
         return out
+
+    def _begin_launch(self, plan: _StepPlan) -> None:
+        """Argument marshalling for ``plan`` starts: the
+        ``kubeshare.engine.marshal`` span runs from here to
+        :meth:`_dispatch`'s entry, which also takes the plan for the
+        launch span's attributes and the request's prefill stamps."""
+        self._launching = plan
+        self._marshal = profiling.span("kubeshare.engine.marshal")
+        self._marshal.__enter__()
+
+    def _launch(self, plan: Optional[_StepPlan], fn, args):
+        """The call of one step program until it returns (the enqueue;
+        on an unguarded engine nothing waits for the device here);
+        returns its result and the launch's span.  A dispatch outside
+        any plan is a single-block pool write: a tier promotion's
+        upload or a copy-on-write."""
+        if plan is None:
+            attrs = {"kind": "upload" if fn is self._upload_step else "copy",
+                     "lanes": 0, "rows": 0, "chunk": 0}
+        else:
+            attrs = {"kind": plan.kind, "lanes": len(plan.decode_slots),
+                     "rows": sum(s.length for s in plan.decode_slots),
+                     "chunk": plan.chunk[1] if plan.chunk else 0}
+        with profiling.span("kubeshare.engine.launch", **attrs) as launch:
+            out = fn(*args)
+        if plan is not None and plan.prefill_slot is not None:
+            result = plan.prefill_slot.result
+            result.prefill_chunks += 1
+            if result.first_dispatch_at is None:
+                result.first_dispatch_at = launch.start
+        return out, launch
+
+    def _report_slow_dispatch(self, entered: float, start: float,
+                              launch: profiling.span,
+                              wait: profiling.span) -> None:
+        """One WARNING for a gated dispatch that lasted seconds: what it
+        carried and where the time went (whether the guard went to the
+        broker is on its own span, in the ring)."""
+        me = threading.current_thread().name
+        acquired = [r for r in profiling.spans(
+            since=entered, name="kubeshare.guard.acquire") if r[3] == me]
+        seconds = {"acquire": start - entered, "launch": launch.seconds,
+                   "device_wait": wait.seconds}
+        self.slow_dispatches[max(seconds, key=seconds.get)] += 1
+        self.log.warning(
+            "slow dispatch: %.3f s against a running estimate of %.1f ms; "
+            "kind=%s lanes=%d chunk=%d guard.acquire=%.3f s (%s) "
+            "launch=%.3f s device_wait=%.3f s",
+            wait.end - entered, self._dispatch_estimate_ms,
+            launch.attrs["kind"], launch.attrs["lanes"],
+            launch.attrs["chunk"], seconds["acquire"],
+            "unnamed guard" if not acquired else
+            "broker" if acquired[-1][4].get("broker") else "held",
+            seconds["launch"], seconds["device_wait"])
 
     def _next_prefill_slot(self, prefill: List[_Slot]) -> _Slot:
         """Round-robin over filling slots: the prefill slot at or
@@ -3124,6 +3274,8 @@ class ServingEngine:
                 return
             while staged.plan:
                 chunk = staged.plan.pop(0)
+                self._begin_launch(_StepPlan(
+                    "prefill", prefill_slot=staged, chunk=chunk))
                 final, table, start, segment, last_row, temp, key = \
                     self._prefill_lane(staged, chunk)
                 picked, pk, pv = self._dispatch(
@@ -3318,22 +3470,31 @@ class ServingEngine:
             return False
         kind, decode_part, prefill_part = self._inflight
         self._inflight = None
+        # every device result the bookkeeping below needs, read in one
+        # place (on an unguarded engine the first read waits for the
+        # device; on a guarded one the dispatch already has)
+        with profiling.span("kubeshare.engine.fetch"):
+            first = (None if prefill_part is None
+                     else int(np.asarray(prefill_part[1])[0]))
+            fetched = ([] if decode_part is None else
+                       [np.asarray(x) for x in
+                        decode_part[:_INFLIGHT_DEVICE_ARRAYS[kind]]])
         if prefill_part is not None:
-            slot, picked = prefill_part
-            self._finish_prefill(slot, int(np.asarray(picked)[0]))
+            self._finish_prefill(prefill_part[0], first)
         if decode_part is not None:
             if kind == "verify":
-                picked, accepts, slots, k_lanes, budgets = decode_part
-                self._accept_verify(slots, np.asarray(picked),
-                                    np.asarray(accepts), k_lanes, budgets)
+                picked, accepts = fetched
+                _, _, slots, k_lanes, budgets = decode_part
+                self._accept_verify(slots, picked, accepts, k_lanes,
+                                    budgets)
             elif kind == "loop":
                 # the device loop's epilogue drain: only NOW (the one
                 # device sync) is it known how many span-units actually
                 # ran, so the unit-proportional counters land here —
                 # each unit is one decode_span of work, charged exactly
                 # as K=1 span dispatches would have charged it
-                ring, units_dev, slots, budgets = decode_part
-                units = int(np.asarray(units_dev))
+                ring, units = fetched[0], int(fetched[1])
+                _, _, slots, budgets = decode_part
                 span = self.engine_config.decode_span
                 self.decode_steps += units
                 self.loop_units += units
@@ -3341,7 +3502,7 @@ class ServingEngine:
                     "decode_span", "decode",
                     lanes=self.engine_config.num_slots,
                     span=units * span)
-                emitted = np.asarray(ring)[: units * span]
+                emitted = ring[: units * span]
                 # exit reason + realized depth BEFORE acceptance (the
                 # acceptance walk retires slots, destroying the lane
                 # state the derivation reads)
@@ -3350,16 +3511,13 @@ class ServingEngine:
                 self._accept_decode(slots, emitted, budgets,
                                     n_steps=units * span)
             elif kind == "spec_loop":
-                (out_p, out_a, out_d, units_dev, head_dev, slots,
-                 staged) = decode_part
-                self._accept_spec_loop(
-                    slots, staged, np.asarray(out_p),
-                    np.asarray(out_a), np.asarray(out_d),
-                    int(np.asarray(units_dev)),
-                    int(np.asarray(head_dev)))
+                out_p, out_a, out_d, units, head = fetched
+                slots, staged = decode_part[5:]
+                self._accept_spec_loop(slots, staged, out_p, out_a, out_d,
+                                       int(units), int(head))
             else:
-                emitted, slots, budgets = decode_part
-                self._accept_decode(slots, np.asarray(emitted), budgets)
+                _, slots, budgets = decode_part
+                self._accept_decode(slots, fetched[0], budgets)
         return True
 
     def _finish_prefill(self, slot: _Slot, first: int) -> None:

@@ -67,6 +67,7 @@ from ..ops.rope import apply_rope
 from .drafter import ngram_propose_rows
 
 
+@jax.named_scope("kv_view")
 def paged_gather_kv(pool_k, pool_v, block_table):
     """Materialize one slot's virtual K/V view.
 
@@ -122,6 +123,7 @@ def paged_upload_block(pool_k, pool_v, dst, k_slab, v_slab):
             pool_v.at[:, dst].set(v_slab))
 
 
+@jax.named_scope("kv_view")
 def _layer_views(pk_layer, pv_layer, tables, config: TransformerConfig):
     """Per-lane virtual K/V views for ONE layer: pool [B, h_kv, bs, d]
     gathered through lane tables [P, T] -> [P, h_kv, T*bs, d].  The one
@@ -137,6 +139,7 @@ def _layer_views(pk_layer, pv_layer, tables, config: TransformerConfig):
     return view(pk_layer), view(pv_layer)
 
 
+@jax.named_scope("mlp")
 def _moe_or_mlp(layer, config: TransformerConfig, y):
     """The post-attention feed-forward shared by both paged steps —
     identical contract to the dense step: MoE capacity pinned to the
@@ -208,29 +211,42 @@ def paged_prefill_step(
     new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
-        q = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wq"].astype(dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wv"].astype(dtype))
-        if use_rope:
-            q = apply_rope(q, positions)  # [P, C]: per-lane positions
-            k = apply_rope(k, positions)
-        # rows (blk[p,i], :, off[p,i], :) <- k[p, :, i, :]
-        pk = pool_k[layer_idx].at[blk, :, off, :].set(k.transpose(0, 2, 1, 3))
-        pv = pool_v[layer_idx].at[blk, :, off, :].set(v.transpose(0, 2, 1, 3))
+        with jax.named_scope("attention"):
+            q = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wq"].astype(dtype))
+            k = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wk"].astype(dtype))
+            v = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wv"].astype(dtype))
+            if use_rope:
+                q = apply_rope(q, positions)  # [P, C]: per-lane positions
+                k = apply_rope(k, positions)
+        with jax.named_scope("kv_write"):
+            # rows (blk[p,i], :, off[p,i], :) <- k[p, :, i, :]
+            pk = pool_k[layer_idx].at[blk, :, off, :].set(
+                k.transpose(0, 2, 1, 3))
+            pv = pool_v[layer_idx].at[blk, :, off, :].set(
+                v.transpose(0, 2, 1, 3))
         new_k.append(pk)
         new_v.append(pv)
         view_k, view_v = _layer_views(pk, pv, tables, config)
-        o = _attend_cached(
-            q, view_k, view_v, positions, window=config.attention_window
-        ).astype(dtype)
-        x = x + jnp.einsum("bhsk,hkd->bsd", o, layer["attn"]["wo"].astype(dtype))
+        with jax.named_scope("attention"):
+            o = _attend_cached(
+                q, view_k, view_v, positions, window=config.attention_window
+            ).astype(dtype)
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _moe_or_mlp(layer, config, y)
 
-    x = _rms_norm(x, params["final_norm"]["scale"])
-    head_in = jnp.take_along_axis(x, last_rows[:, None, None], axis=1)  # [P,1,d]
-    logits = (head_in @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"]["scale"])
+        head_in = jnp.take_along_axis(
+            x, last_rows[:, None, None], axis=1)  # [P,1,d]
+        logits = (head_in
+                  @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
 
 
 def paged_decode_step(
@@ -268,30 +284,39 @@ def paged_decode_step(
     new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
-        q = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wq"].astype(dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wv"].astype(dtype))
-        if use_rope:
-            # [S, 1]: every slot rotates by its own position
-            q = apply_rope(q, positions[:, None])
-            k = apply_rope(k, positions[:, None])
-        pk = pool_k[layer_idx].at[blk, :, off, :].set(k[:, :, 0, :])
-        pv = pool_v[layer_idx].at[blk, :, off, :].set(v[:, :, 0, :])
+        with jax.named_scope("attention"):
+            q = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wq"].astype(dtype))
+            k = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wk"].astype(dtype))
+            v = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wv"].astype(dtype))
+            if use_rope:
+                # [S, 1]: every slot rotates by its own position
+                q = apply_rope(q, positions[:, None])
+                k = apply_rope(k, positions[:, None])
+        with jax.named_scope("kv_write"):
+            pk = pool_k[layer_idx].at[blk, :, off, :].set(k[:, :, 0, :])
+            pv = pool_v[layer_idx].at[blk, :, off, :].set(v[:, :, 0, :])
         new_k.append(pk)
         new_v.append(pv)
         # gather every slot's block list into its virtual view [S,h_kv,V,d]
         view_k, view_v = _layer_views(pk, pv, block_tables, config)
-        o = _attend_cached(
-            q, view_k, view_v, positions[:, None],
-            window=config.attention_window,
-        ).astype(dtype)
-        x = x + jnp.einsum("bhsk,hkd->bsd", o, layer["attn"]["wo"].astype(dtype))
+        with jax.named_scope("attention"):
+            o = _attend_cached(
+                q, view_k, view_v, positions[:, None],
+                window=config.attention_window,
+            ).astype(dtype)
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _moe_or_mlp(layer, config, y)
 
-    x = _rms_norm(x, params["final_norm"]["scale"])
-    logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"]["scale"])
+        logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
 
 
 def paged_decode_span(
@@ -326,7 +351,8 @@ def paged_decode_span(
         pk, pv, lens, toks, alive = carry
         logits, pk, pv = paged_decode_step(
             params, config, pk, pv, tables, lens, alive, toks)
-        nxt = pick_fn(logits, temps, keys[:, i])
+        with jax.named_scope("sample"):
+            nxt = pick_fn(logits, temps, keys[:, i])
         lens = lens + alive.astype(jnp.int32)
         cont = alive & (i + 1 < budgets)
         if eos is not None:
@@ -388,7 +414,8 @@ def _decode_loop_impl(
         u, pk, pv, lens, toks, alive = carry
         logits, pk, pv = step_fn(pk, pv, tables, lens, alive, toks)
         i = u * span + j
-        nxt = pick_fn(logits, temps, jnp.take(keys, i, axis=1))
+        with jax.named_scope("sample"):
+            nxt = pick_fn(logits, temps, jnp.take(keys, i, axis=1))
         lens = lens + alive.astype(jnp.int32)
         cont = alive & (i + 1 < budgets)
         if eos is not None:
@@ -518,33 +545,45 @@ def paged_verify_span(
     new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
-        q = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wq"].astype(dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", y, layer["attn"]["wv"].astype(dtype))
-        if use_rope:
-            q = apply_rope(q, positions)  # [S, W]: per-lane positions
-            k = apply_rope(k, positions)
-        pk = pool_k[layer_idx].at[blk, :, off, :].set(k.transpose(0, 2, 1, 3))
-        pv = pool_v[layer_idx].at[blk, :, off, :].set(v.transpose(0, 2, 1, 3))
+        with jax.named_scope("attention"):
+            q = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wq"].astype(dtype))
+            k = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wk"].astype(dtype))
+            v = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wv"].astype(dtype))
+            if use_rope:
+                q = apply_rope(q, positions)  # [S, W]: per-lane positions
+                k = apply_rope(k, positions)
+        with jax.named_scope("kv_write"):
+            pk = pool_k[layer_idx].at[blk, :, off, :].set(
+                k.transpose(0, 2, 1, 3))
+            pv = pool_v[layer_idx].at[blk, :, off, :].set(
+                v.transpose(0, 2, 1, 3))
         new_k.append(pk)
         new_v.append(pv)
         view_k, view_v = _layer_views(pk, pv, tables, config)
-        o = _attend_cached(
-            q, view_k, view_v, positions, window=config.attention_window
-        ).astype(dtype)
-        x = x + jnp.einsum("bhsk,hkd->bsd", o, layer["attn"]["wo"].astype(dtype))
+        with jax.named_scope("attention"):
+            o = _attend_cached(
+                q, view_k, view_v, positions, window=config.attention_window
+            ).astype(dtype)
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _moe_or_mlp(layer, config, y)
 
-    x = _rms_norm(x, params["final_norm"]["scale"])
-    logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"]["scale"])
+        logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
     # column i's pick is emission-number-identical to a width-1 decode
     # step at that position, so it consumes that emission's key
-    picked = jnp.stack(
-        [pick_fn(logits[:, i], temps, keys[:, i]) for i in range(w)],
-        axis=1)  # [S, W]
-    accepts = speculative_acceptance(tokens[:, 1:], picked)
-    return picked, accepts, jnp.stack(new_k), jnp.stack(new_v)
+    with jax.named_scope("sample"):
+        picked = jnp.stack(
+            [pick_fn(logits[:, i], temps, keys[:, i]) for i in range(w)],
+            axis=1)  # [S, W]
+        accepts = speculative_acceptance(tokens[:, 1:], picked)
+    with jax.named_scope("kv_write"):
+        return picked, accepts, jnp.stack(new_k), jnp.stack(new_v)
 
 
 def _spec_loop_impl(
@@ -855,7 +894,8 @@ def paged_mixed_verify_step(
     p_logits, pk, pv = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row)
-    p_picked = pick_fn(p_logits, p_temp, p_key)
+    with jax.named_scope("sample"):
+        p_picked = pick_fn(p_logits, p_temp, p_key)
     picked, accepts, pk, pv = paged_verify_span(
         params, config, pick_fn, pk, pv, d_tables, d_lengths, d_active,
         d_tokens, d_widths, d_temps, d_keys)
@@ -910,7 +950,8 @@ def paged_mixed_step(
     p_logits, pk, pv = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row)
-    p_picked = pick_fn(p_logits, p_temp, p_key)
+    with jax.named_scope("sample"):
+        p_picked = pick_fn(p_logits, p_temp, p_key)
     emitted, pk, pv = paged_decode_span(
         params, config, pick_fn, span, eos, pk, pv,
         d_tables, d_lengths, d_active, d_tokens, d_temps, d_keys,
