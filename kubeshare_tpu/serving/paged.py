@@ -7,7 +7,17 @@ property: each slot sits at its OWN length, and its cache rows live
 scattered across pool blocks (kv_blocks.py).  The two entry points here
 keep the dense step's exact math — same projections, same rope, same
 per-query causal band through the SAME :func:`_attend_cached` — and swap
-only the cache plumbing:
+only the cache plumbing.  That plumbing is two functions over the stacked
+pool ``[n_layers, num_blocks, h_kv, block_size, d]``, which every step
+program receives as its donated arguments: :func:`_write_rows` scatters a
+layer's new K/V rows into that buffer itself, and :func:`_layer_views`
+gathers a layer's per-lane views from that layer's window of it through
+the block tables.  No step scatters into a slab cut out of the pool or
+builds a new pool from per-layer pieces, so no program holds a second
+pool: a step moves the rows it writes and what its views read
+(``tests/test_serving.py::TestPoolWrittenInPlace`` pins the structure,
+``tests/test_chip_compile.py`` what the TPU compiler makes of it).  The
+entry points:
 
 - :func:`paged_prefill_step`: a width-C prompt chunk writing its K/V
   straight into a slot's blocks (no dense staging cache to copy from);
@@ -123,20 +133,53 @@ def paged_upload_block(pool_k, pool_v, dst, k_slab, v_slab):
             pool_v.at[:, dst].set(v_slab))
 
 
+@jax.named_scope("kv_write")
+def _write_rows(pool_k, pool_v, layer_idx, blk, off, k, v):
+    """Scatter one layer's new K/V rows into the stacked pool itself:
+    rows ``[layer_idx, blk[...], :, off[...], :] <- k[..., :, :]``
+    (``blk``/``off`` [...] int32, ``k``/``v`` [..., h_kv, d]).  The one
+    pool write every paged step goes through.  The pool is the step
+    program's donated argument, so the scatter updates that buffer in
+    place and the rows are the only bytes of the pool a step writes —
+    no slab is written apart from the pool, and nothing is restacked.
+
+    The KV head is an index of the scatter, not a slice of it: the
+    update window is one row of ``d``.  With the ``[h_kv, d]`` window
+    that ``[layer_idx, blk, :, off, :]`` gives, the TPU compiler re-tiles
+    the whole pool so that heads sit next to rows whenever ``h_kv > 1``
+    — two pool-sized copies in and two out, every program (PERF.md,
+    PR 25)."""
+    heads = jnp.arange(pool_k.shape[2])
+    blk, off = blk[..., None], off[..., None]
+    return (pool_k.at[layer_idx, blk, heads, off, :].set(k),
+            pool_v.at[layer_idx, blk, heads, off, :].set(v))
+
+
 @jax.named_scope("kv_view")
-def _layer_views(pk_layer, pv_layer, tables, config: TransformerConfig):
-    """Per-lane virtual K/V views for ONE layer: pool [B, h_kv, bs, d]
-    gathered through lane tables [P, T] -> [P, h_kv, T*bs, d].  The one
-    view construction both paged steps attend through — a change here is
-    a change to the paged read path, full stop."""
+def _layer_views(pool_k, pool_v, layer_idx, tables):
+    """Per-lane virtual K/V views of ONE layer of the stacked pool
+    [L, B, h_kv, bs, d], gathered through lane tables [P, T] ->
+    [P, h_kv, T*bs, d]; the head count is the pool's own, so a
+    head-sharded pool shard (serving/sharded.py) reads through the same
+    function.  The one view construction every paged step attends
+    through — a change here is a change to the paged read path, full
+    stop.
+
+    ``pool[layer_idx]`` is a window at a fixed offset of the donated
+    buffer, not a copy in HBM: the TPU compiler stages that layer's
+    slab in the chip's fast memory when it fits there and gathers the
+    blocks from the staged copy.  Indexing layer and table in ONE
+    gather (``pool[layer_idx, tables]``) reads the same blocks straight
+    from HBM, 4 KB at a time, and is 1.6 times slower a decode step on a
+    v5e (PERF.md, PR 25)."""
     p, t = tables.shape
-    bs = pk_layer.shape[2]
+    _, _, h_kv, bs, d = pool_k.shape
 
     def view(pool):
-        return pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            p, config.kv_heads, t * bs, config.head_dim)
+        return pool[layer_idx][tables].transpose(0, 2, 1, 3, 4).reshape(
+            p, h_kv, t * bs, d)
 
-    return view(pk_layer), view(pv_layer)
+    return view(pool_k), view(pool_v)
 
 
 @jax.named_scope("mlp")
@@ -208,7 +251,6 @@ def paged_prefill_step(
     if not use_rope:
         x = x + params["pos_embed"][positions].astype(dtype)
 
-    new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
         with jax.named_scope("attention"):
@@ -221,15 +263,11 @@ def paged_prefill_step(
             if use_rope:
                 q = apply_rope(q, positions)  # [P, C]: per-lane positions
                 k = apply_rope(k, positions)
-        with jax.named_scope("kv_write"):
-            # rows (blk[p,i], :, off[p,i], :) <- k[p, :, i, :]
-            pk = pool_k[layer_idx].at[blk, :, off, :].set(
-                k.transpose(0, 2, 1, 3))
-            pv = pool_v[layer_idx].at[blk, :, off, :].set(
-                v.transpose(0, 2, 1, 3))
-        new_k.append(pk)
-        new_v.append(pv)
-        view_k, view_v = _layer_views(pk, pv, tables, config)
+        # rows (layer, blk[p,i], :, off[p,i], :) <- k[p, :, i, :]
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         with jax.named_scope("attention"):
             o = _attend_cached(
                 q, view_k, view_v, positions, window=config.attention_window
@@ -245,8 +283,7 @@ def paged_prefill_step(
             x, last_rows[:, None, None], axis=1)  # [P,1,d]
         logits = (head_in
                   @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    with jax.named_scope("kv_write"):
-        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+    return logits[:, 0], pool_k, pool_v
 
 
 def paged_decode_step(
@@ -281,7 +318,6 @@ def paged_decode_step(
     if not use_rope:
         x = x + params["pos_embed"][positions].astype(dtype)[:, None, :]
 
-    new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
         with jax.named_scope("attention"):
@@ -295,13 +331,11 @@ def paged_decode_step(
                 # [S, 1]: every slot rotates by its own position
                 q = apply_rope(q, positions[:, None])
                 k = apply_rope(k, positions[:, None])
-        with jax.named_scope("kv_write"):
-            pk = pool_k[layer_idx].at[blk, :, off, :].set(k[:, :, 0, :])
-            pv = pool_v[layer_idx].at[blk, :, off, :].set(v[:, :, 0, :])
-        new_k.append(pk)
-        new_v.append(pv)
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off, k[:, :, 0, :], v[:, :, 0, :])
         # gather every slot's block list into its virtual view [S,h_kv,V,d]
-        view_k, view_v = _layer_views(pk, pv, block_tables, config)
+        view_k, view_v = _layer_views(
+            pool_k, pool_v, layer_idx, block_tables)
         with jax.named_scope("attention"):
             o = _attend_cached(
                 q, view_k, view_v, positions[:, None],
@@ -315,8 +349,7 @@ def paged_decode_step(
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"])
         logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    with jax.named_scope("kv_write"):
-        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+    return logits[:, 0], pool_k, pool_v
 
 
 def paged_decode_span(
@@ -542,7 +575,6 @@ def paged_verify_span(
     if not use_rope:
         x = x + params["pos_embed"][positions].astype(dtype)
 
-    new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
         with jax.named_scope("attention"):
@@ -555,14 +587,10 @@ def paged_verify_span(
             if use_rope:
                 q = apply_rope(q, positions)  # [S, W]: per-lane positions
                 k = apply_rope(k, positions)
-        with jax.named_scope("kv_write"):
-            pk = pool_k[layer_idx].at[blk, :, off, :].set(
-                k.transpose(0, 2, 1, 3))
-            pv = pool_v[layer_idx].at[blk, :, off, :].set(
-                v.transpose(0, 2, 1, 3))
-        new_k.append(pk)
-        new_v.append(pv)
-        view_k, view_v = _layer_views(pk, pv, tables, config)
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         with jax.named_scope("attention"):
             o = _attend_cached(
                 q, view_k, view_v, positions, window=config.attention_window
@@ -582,8 +610,7 @@ def paged_verify_span(
             [pick_fn(logits[:, i], temps, keys[:, i]) for i in range(w)],
             axis=1)  # [S, W]
         accepts = speculative_acceptance(tokens[:, 1:], picked)
-    with jax.named_scope("kv_write"):
-        return picked, accepts, jnp.stack(new_k), jnp.stack(new_v)
+    return picked, accepts, pool_k, pool_v
 
 
 def _spec_loop_impl(

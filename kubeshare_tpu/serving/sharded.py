@@ -79,8 +79,9 @@ from ..models.decoding import _attend_cached, speculative_acceptance
 from ..models.transformer import TransformerConfig, _rms_norm
 from ..ops.rope import apply_rope
 from ..parallel.mesh import MeshSpec, make_mesh, param_spec_tree, shard_params
-from .paged import (_decode_loop_impl, _moe_or_mlp, _spec_loop_impl,
-                    paged_copy_block, paged_upload_block)
+from .paged import (_decode_loop_impl, _layer_views, _moe_or_mlp,
+                    _spec_loop_impl, _write_rows, paged_copy_block,
+                    paged_upload_block)
 
 # the paged pool is [n_layers, num_blocks, kv_heads, block_size, head_dim];
 # head-sharding splits axis 2, so every block's rows for a device's KV
@@ -174,22 +175,9 @@ def serving_sharding_rules(decision: ShardDecision) -> Dict[str, P]:
 # local (per-device) bodies — the math paged.py runs, on one shard
 # ---------------------------------------------------------------------------
 
-def _local_views(pk_layer, pv_layer, tables, head_dim: int):
-    """paged._layer_views on the LOCAL pool shard: the head axis is the
-    shard's own (``pool.shape[1]``), not ``config.kv_heads`` — under
-    the replicated fallback they coincide."""
-    p, t = tables.shape
-    h_local, bs = pk_layer.shape[1], pk_layer.shape[2]
-
-    def view(pool):
-        return pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            p, h_local, t * bs, head_dim)
-
-    return view(pk_layer), view(pv_layer)
-
-
 def _chunk_attend(cfg: TransformerConfig, dec: ShardDecision,
-                  lct: Optional[int], q, pk, pv, tables, positions):
+                  lct: Optional[int], q, pool_k, pool_v, layer_idx, tables,
+                  positions):
     """One layer's attention for a [P, C] chunk on this device's shard.
 
     Head-sharded: q carries the local query-head group, the views carry
@@ -199,7 +187,7 @@ def _chunk_attend(cfg: TransformerConfig, dec: ShardDecision,
     heads for sequence: all_to_all q to [P, H, C/tp, d], gather the KV
     views, attend this device's query rows, and swap back — every step
     data movement or per-query-row math, so still exact."""
-    view_k, view_v = _local_views(pk, pv, tables, cfg.head_dim)
+    view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
     c = q.shape[2]
     if (dec.attn_sharded and lct is not None and c >= lct
             and c % dec.tp == 0):
@@ -254,7 +242,6 @@ def _chunk_stack(params, cfg: TransformerConfig, dec: ShardDecision,
     if not use_rope:
         x = x + params["pos_embed"][positions].astype(dtype)
 
-    new_k, new_v = [], []
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
         # column-parallel: sharded weights project the LOCAL head group
@@ -265,11 +252,11 @@ def _chunk_stack(params, cfg: TransformerConfig, dec: ShardDecision,
             q = apply_rope(q, positions)
             k = apply_rope(k, positions)
         # local KV heads land in the local pool shard (no collective)
-        pk = pool_k[layer_idx].at[blk, :, off, :].set(k.transpose(0, 2, 1, 3))
-        pv = pool_v[layer_idx].at[blk, :, off, :].set(v.transpose(0, 2, 1, 3))
-        new_k.append(pk)
-        new_v.append(pv)
-        o = _chunk_attend(cfg, dec, lct, q, pk, pv, tables, positions)
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        o = _chunk_attend(cfg, dec, lct, q, pool_k, pool_v, layer_idx,
+                          tables, positions)
         wo = layer["attn"]["wo"].astype(dtype)
         if dec.attn_sharded:
             # gather the head-sharded activations AND the row-sharded
@@ -281,8 +268,7 @@ def _chunk_stack(params, cfg: TransformerConfig, dec: ShardDecision,
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _ffn(layer, cfg, dec, y)
 
-    return _rms_norm(x, params["final_norm"]["scale"]), \
-        jnp.stack(new_k), jnp.stack(new_v)
+    return _rms_norm(x, params["final_norm"]["scale"]), pool_k, pool_v
 
 
 def _project_rows(params, cfg: TransformerConfig, dec: ShardDecision, x):

@@ -29,7 +29,8 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
 from kubeshare_tpu.serving.paged import (  # noqa: E402
-    paged_decode_loop, paged_decode_step, paged_prefill_step)
+    paged_decode_loop, paged_decode_step, paged_mixed_step,
+    paged_prefill_step)
 
 V5E_HBM_BYTES = 16 << 30
 
@@ -59,11 +60,12 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, args, sharding):
+def _compile(fn, args, sharding, donate_argnums=()):
     shaped = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         args)
-    return jax.jit(fn).lower(*shaped).compile()
+    return jax.jit(fn, donate_argnums=donate_argnums).lower(
+        *shaped).compile()
 
 
 def _flash_args(b, h, h_kv, s, d):
@@ -102,8 +104,14 @@ def test_flash_backward_compiles_as_kernel(one_chip, shape):
 def _serving_shapes():
     """The serving model and pool of chip_smoke.FULL, as shapes only."""
     config = TransformerConfig(dtype=jnp.bfloat16, **chip_smoke.FULL.model)
-    params = jax.eval_shape(
-        lambda: transformer_init(jax.random.PRNGKey(0), config))
+    # weights in the model's dtype, as chipbench/weights.py serves them:
+    # over float32 masters a loop program hoists a bf16 copy of every
+    # weight into its temporaries (ROADMAP 1.11), which would hide what
+    # the pool costs there
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, config.dtype),
+        jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), config)))
     e = chip_smoke.FULL.engine
     pool = jax.ShapeDtypeStruct(
         (config.n_layers, e["num_blocks"], config.kv_heads, e["block_size"],
@@ -133,17 +141,17 @@ def _prefill_case():
                 jax.ShapeDtypeStruct((1,), bool), _i32(1, chunk), _i32(1))
 
 
+def _greedy_pick(logits, temps, keys):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _decode_loop_case():
     config, params, pool, s, t, _ = _serving_shapes()
     span, k_units = 4, 4
-
-    def pick(logits, temps, keys):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     fn = lambda w, pk, pv, tables, lengths, active, tokens, temps, keys, \
         budgets: paged_decode_loop(
-            w, config, pick, span, k_units, None, pk, pv, tables, lengths,
-            active, tokens, temps, keys, budgets)
+            w, config, _greedy_pick, span, k_units, None, pk, pv, tables,
+            lengths, active, tokens, temps, keys, budgets)
     return fn, (params, pool, pool, _i32(s, t), _i32(s),
                 jax.ShapeDtypeStruct((s,), bool), _i32(s),
                 jax.ShapeDtypeStruct((s,), jnp.float32),
@@ -151,13 +159,39 @@ def _decode_loop_case():
                 _i32(s))
 
 
+def _mixed_step_case():
+    config, params, pool, s, t, chunk = _serving_shapes()
+    span = 4
+    fn = lambda w, pk, pv, *rest: paged_mixed_step(
+        w, config, _greedy_pick, span, None, pk, pv, *rest)
+    return fn, (params, pool, pool,
+                # the one filling lane: table, start, chunk, last row,
+                # temperature, key
+                _i32(1, t), _i32(1), _i32(1, chunk), _i32(1),
+                jax.ShapeDtypeStruct((1,), jnp.float32),
+                jax.ShapeDtypeStruct((1, 2), jnp.uint32),
+                # the decode lanes: tables, lengths, active, tokens,
+                # temperatures, keys, budgets
+                _i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool),
+                _i32(s), jax.ShapeDtypeStruct((s,), jnp.float32),
+                jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
+
+
 @pytest.mark.parametrize("case", [_decode_step_case, _prefill_case,
-                                  _decode_loop_case],
+                                  _decode_loop_case, _mixed_step_case],
                          ids=["paged_decode_step", "paged_prefill_step",
-                              "paged_decode_loop"])
+                              "paged_decode_loop", "paged_mixed_step"])
 def test_serving_program_compiles_and_fits(one_chip, case):
+    """Compiled as the engine compiles it (``engine._step_program``: the
+    pool halves donated), the program fits the chip, and its temporaries
+    hold no second pool: the K/V rows are scattered into the donated
+    buffers.  A step that restacks the pool or cuts layer slabs out of it
+    needs 1.1-1.3 x BOTH halves of temporaries (PR 25)."""
     fn, args = case()
-    memory = _compile(fn, args, one_chip).memory_analysis()
+    memory = _compile(fn, args, one_chip,
+                      donate_argnums=(1, 2)).memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes)
     assert resident < V5E_HBM_BYTES, memory
+    pool_half = args[1].size * args[1].dtype.itemsize
+    assert memory.temp_size_in_bytes < pool_half // 2, memory

@@ -151,6 +151,94 @@ class TestPagedEquivalence:
             rtol=1e-6, atol=1e-6)
 
 
+def _all_eqns(jaxpr):
+    """Every equation of ``jaxpr``, sub-jaxprs (jit, scan, shard_map)
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+def _pool_step_case(name):
+    """(fn(params, pool_k, pool_v), params, pool shape, pool shape on one
+    device) for one of the four layer loops that write the pool, at a tiny
+    GQA + rope config with every lane live."""
+    from kubeshare_tpu.serving.paged import (
+        paged_decode_step, paged_prefill_step, paged_verify_span)
+
+    config = _small_config(n_kv_heads=2, positional="rope")
+    params = transformer_init(jax.random.PRNGKey(0), config)
+    lanes, width, chunk, bs, blocks = 3, 5, 4, 4, 17
+    shape = (config.n_layers, blocks, config.kv_heads, bs, config.head_dim)
+    tables = jnp.arange(1, 1 + lanes * width, dtype=jnp.int32).reshape(
+        lanes, width)
+    lengths = jnp.asarray([3, 6, 9], jnp.int32)
+    active = jnp.ones((lanes,), bool)
+    chunk_tokens = jnp.ones((lanes, chunk), jnp.int32)
+    last_rows = jnp.zeros((lanes,), jnp.int32)
+
+    if name == "paged_prefill_step":
+        return (lambda w, pk, pv: paged_prefill_step(
+            w, config, pk, pv, tables, lengths, active, chunk_tokens,
+            last_rows), params, shape, shape)
+    if name == "paged_decode_step":
+        return (lambda w, pk, pv: paged_decode_step(
+            w, config, pk, pv, tables, lengths, active,
+            jnp.ones((lanes,), jnp.int32)), params, shape, shape)
+    if name == "paged_verify_span":
+        def pick(logits, temps, keys):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        return (lambda w, pk, pv: paged_verify_span(
+            w, config, pick, pk, pv, tables, lengths, active, chunk_tokens,
+            jnp.full((lanes,), chunk, jnp.int32),
+            jnp.zeros((lanes,), jnp.float32),
+            jnp.zeros((lanes, chunk, 2), jnp.uint32)), params, shape, shape)
+    # sharded._chunk_stack, through the shard_map twin of the prefill
+    # step: each of two devices holds one of the two KV heads
+    from kubeshare_tpu.parallel.mesh import MeshSpec
+    from kubeshare_tpu.serving.sharded import ShardedServingContext
+
+    assert name == "sharded_prefill"
+    ctx = ShardedServingContext(config, MeshSpec(dp=1, tp=2, sp=1), params)
+    assert ctx.decision.attn_sharded
+    return (lambda w, pk, pv: ctx.prefill(
+        w, pk, pv, tables, lengths, active, chunk_tokens, last_rows),
+        ctx.place_params(params), shape, shape[:2] + (1,) + shape[3:])
+
+
+class TestPoolWrittenInPlace:
+    """The K/V rows of a step are scattered into the pool buffer itself:
+    no step builds a second pool (a restack of per-layer slabs) and none
+    writes a layer's slab apart from it.  With the dense-equivalence tests
+    above this pins "same rows, no restack"; what the TPU compiler makes
+    of it is tests/test_chip_compile.py's to say."""
+
+    @pytest.mark.parametrize("name", [
+        "paged_prefill_step", "paged_decode_step", "paged_verify_span",
+        "sharded_prefill"])
+    def test_only_row_scatters_produce_a_pool(self, name):
+        fn, params, shape, local = _pool_step_case(name)
+        pool = jnp.zeros(shape, jnp.float32)
+        eqns = list(_all_eqns(jax.make_jaxpr(fn)(params, pool, pool).jaxpr))
+
+        def producers(shape):
+            return [e.primitive.name for e in eqns
+                    if any(getattr(v.aval, "shape", None) == shape
+                           for v in e.outvars)]
+
+        # K and V, once a layer, and nothing else makes a pool
+        assert producers(local) == ["scatter"] * (2 * shape[0])
+        # a layer's slab [B, h_kv, bs, d] is only ever read (the view's
+        # window into the pool), never written and restacked
+        assert "scatter" not in producers(local[1:])
+
+
 class TestContinuousBatching:
     def test_mixed_lengths_match_solo_references(self):
         """The killer property: 10 mixed-length requests squeezed
