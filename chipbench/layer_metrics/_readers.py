@@ -5,7 +5,9 @@ move (``.rate`` / ``.backlog``) call the same function here.
 A reader gets ``run``: ``record`` (the window's counter and tokend ``STAT``
 deltas, the benchmark's own step records), ``trace`` (the reduced profiler
 trace, see ``chipbench/trace.py``), ``tc`` (the configuration's
-``transformer_config``), ``device_kind``, ``pod_a`` (pod A's name) and
+``transformer_config``), ``roofline`` (the configuration's byte-count module:
+the least bytes of a step are its count, ``chipbench.roofline`` where the
+file names none), ``device_kind``, ``pod_a`` (pod A's name) and
 ``notes`` (what ``run.end_to_end`` works out besides the metrics).  It returns a number, or None
 where it finds nothing to read.
 """
@@ -71,8 +73,8 @@ def mixed_hbm_roofline(run: Dict) -> Optional[float]:
         return None
     span = run["record"]["decode_span"]
     peak = roofline.peaks(run["device_kind"])["hbm_bytes_per_s"]
-    least = sum(span * roofline.decode_step_min_bytes(run["tc"],
-                                                      sum(s["rows"]))
+    least = sum(span * run["roofline"].decode_step_min_bytes(
+        run["tc"], sum(s["rows"]))
                 for s in steps) / peak
     busy = sum(run["trace"].step_busy_s[s["i"]] for s in steps)
     return least / busy * 100.0 if busy > 0 else None
@@ -98,8 +100,8 @@ def decode_hbm_roofline(run: Dict) -> Optional[float]:
         return None
     span = run["record"]["decode_span"]
     peak = roofline.peaks(run["device_kind"])["hbm_bytes_per_s"]
-    least = sum(span * roofline.decode_step_min_bytes(run["tc"],
-                                                      sum(s["rows"]))
+    least = sum(span * run["roofline"].decode_step_min_bytes(
+        run["tc"], sum(s["rows"]))
                 for s in steps) / peak
     busy = sum(run["trace"].step_busy_s[s["i"]] for s in steps)
     return least / busy * 100.0 if busy > 0 else None

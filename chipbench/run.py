@@ -33,6 +33,10 @@ if REPO not in sys.path:
 from chipbench import metrics, roofline, system, traffic  # noqa: E402
 
 STATE_DIR = os.path.join(HERE, ".state")  # git-ignored: control-plane files, traces
+# what a configuration's file may name, and what stands where it names none:
+# its plain reference, the maker of its weights, its count of bytes
+MODULES = {"reference": "chipbench.reference", "weights": "chipbench.weights",
+           "roofline": "chipbench.roofline"}
 TRACE_SECONDS = 5.0  # the tail of the window that a --trace 1 run traces
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -128,6 +132,17 @@ def load_json(*parts: str) -> Dict:
         return json.load(f)
 
 
+def config_modules(config_file: Dict) -> Dict[str, str]:
+    """The names of a configuration's three modules (README.md says what
+    each must expose); a file that names none gets the repository's block."""
+    return {kind: config_file.get(kind, default)
+            for kind, default in MODULES.items()}
+
+
+def cell_module(cell: Dict, kind: str):
+    return importlib.import_module(cell["modules"][kind])
+
+
 def load_cell(name: str, benchmark: Optional[Dict] = None,
               root: str = REPO) -> Dict:
     """A cell, from ``BENCHMARK.json`` and the files its names point to."""
@@ -139,6 +154,7 @@ def load_cell(name: str, benchmark: Optional[Dict] = None,
     cell = dict(cells[name])
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cell["config_file"] = load_json(root, config["file"])
+    cell["modules"] = config_modules(cell["config_file"])
     base = os.path.dirname(os.path.dirname(os.path.join(root, config["file"])))
     cell["mix"] = traffic.load_mix(cell["traffic"],
                                    os.path.join(base, "traffic"))
@@ -239,6 +255,20 @@ class Session:
         shutil.rmtree(STATE_DIR, ignore_errors=True)
         os.makedirs(STATE_DIR)
         self.plane = system.ControlPlane(inventory, pods, STATE_DIR)
+        self.cotenant = None
+        self.seed = seed
+        try:
+            self._stand_up(lap)
+        except BaseException:
+            # a set-up that fails (a pool that is not what its count says, a
+            # pod placed wrong) leaves no native child behind
+            self.close()
+            raise
+
+    def _stand_up(self, lap) -> None:
+        """Pod B, the weights, pod A's engine, warm: the rest of set-up."""
+        import jax
+
         a = self.cfg["pod"]
         injected = self.plane.pods[a["name"]]["mem_fraction"]
         if abs(injected - a["gpu_mem"]) > 1e-3:
@@ -246,7 +276,6 @@ class Session:
                                f"for pod A, its file says {a['gpu_mem']}")
         lap("control_plane_s")
 
-        self.cotenant = None
         if self.mix.get("cotenant"):
             # pod B runs from here on, so that tokend's decayed share of
             # it has settled at its limit before the window opens
@@ -256,17 +285,17 @@ class Session:
             self.cotenant.start()
             lap("cotenant_start_s")
 
-        from chipbench.weights import make_weights
-
-        self.seed = seed
-        self.params = make_weights(seed, self.tc)
+        self.params = cell_module(self.cell, "weights").make_weights(
+            self.seed, self.tc)
         jax.block_until_ready(self.params)
         lap("weights_s")
 
         self.annotate = jax.profiler.TraceAnnotation
         self.guard = system.GuardProxy(self.plane.guard(a["name"]),
                                        self.annotate)
-        self.engine = system.build_engine(self.cfg, self.params, self.guard)
+        self.counts = cell_module(self.cell, "roofline")
+        self.engine = system.build_engine(self.cfg, self.params, self.guard,
+                                          self.counts)
         self.engine.warmup()
         lap("engine_warmup_s")
         self._warm_paths()
@@ -648,7 +677,7 @@ def judge(session: Session, record: Dict) -> Dict:
         check("pod_b_share_of_window", share, cap, share <= cap)
 
     # the plain reference, after the engine's pool is freed
-    reference = importlib.import_module(session.cfg["reference"])
+    reference = cell_module(cell, "reference")
     limits = session.cfg["correct"]
     session.release_engine()
     t0 = time.monotonic()
@@ -656,25 +685,31 @@ def judge(session: Session, record: Dict) -> Dict:
     gaps = [reference.served_gaps(session.params, session.tc,
                                   e["request"].prompt, e["result"].tokens)
             for e in sample]
+    # the numbers compared are the ones the configuration states a limit
+    # for: "<name>_limit" in its correct block, <name> in what its
+    # reference's summarize() returns
+    compared = {key[:-len("_limit")]: limit for key, limit in limits.items()
+                if key.endswith("_limit")}
+    widest = compared.get("widest_gap", float("inf"))
     for e, g in zip(sample, gaps):
         # request by request, so that a run that fails says where
-        far = [int(i) for i in (g > limits["widest_gap_limit"]).nonzero()[0]]
+        far = [int(i) for i in (g > widest).nonzero()[0]]
         say(reference_request={
             "rid": e["request"].rid, "prompt": len(e["request"].prompt),
             "served": len(g), "widest_gap": float(g.max()),
             "mean_gap": float(g.mean()), "over_limit": len(far),
             "over_limit_at": far[:12]})
-    if gaps:
+    if gaps and compared:
         summary = reference.summarize(gaps)
-        for name in ("widest_gap", "mean_gap"):
-            limit = limits[f"{name}_limit"]
+        for name, limit in compared.items():
             check(f"served_vs_reference.{name}", summary[name], limit,
                   summary[name] <= limit)
         summary["reference_s"] = round(time.monotonic() - t0, 2)
         summary["requests"] = len(gaps)
         say(reference=summary)
     else:
-        check("served_vs_reference.sample", 0, ">= 1 request", False)
+        check("served_vs_reference.sample", len(gaps),
+              ">= 1 request, >= 1 limit", False)
     for c in checks:
         say(**c)
     return {"correct": all(c["ok"] for c in checks), "attempted": attempted,
@@ -691,6 +726,8 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
         setup_s = process_age_s()
         say(setup=session.timeline, setup_s=setup_s,
             compile_cache=session.cache_dir, pods=session.plane.pods,
+            pool={"num_blocks": session.engine.engine_config.num_blocks,
+                  "bytes": system.pool_bytes(session.engine.pool)},
             share_table=session.plane.share_table)
         record = session.measure(seconds, cell["params"].get("rate_rps"),
                                  trace=trace)
@@ -712,7 +749,8 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
         if trace:
             summary = record["trace"]
             run = {"cell": cell, "record": record, "trace": summary,
-                   "tc": session.tc, "device_kind": session.device["kind"],
+                   "tc": session.tc, "roofline": session.counts,
+                   "device_kind": session.device["kind"],
                    "pod_a": session.cfg["pod"]["name"],
                    "notes": e2e["notes"]}
             result["metrics"] = per_layer(cell, run)
@@ -721,10 +759,18 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
             result["breakdown"] = {"device_ops": summary.device_ops,
                                    "idle_gaps": summary.idle_gaps}
         verdict = judge(session, record)
-        result = {"correct": verdict["correct"],
-                  "attempted": verdict["attempted"],
-                  "failed": verdict["failed"], **result}
-        return result
+        # each number compared beside its limit: the last lines on standard
+        # error, and the result line's last key
+        for c in verdict["checks"]:
+            print(f"chipbench: {c['check']} = {c['value']} (limit "
+                  f"{c['limit']}): {'ok' if c['ok'] else 'NOT OK'}",
+                  file=sys.stderr, flush=True)
+        return {"correct": verdict["correct"],
+                "attempted": verdict["attempted"],
+                "failed": verdict["failed"], **result,
+                "checks": {c["check"]: {"value": c["value"],
+                                        "limit": c["limit"]}
+                           for c in verdict["checks"]}}
     finally:
         session.close()
 

@@ -235,10 +235,21 @@ class Cotenant:
             raise RuntimeError(f"pod B's loop failed: {self.error!r}")
 
 
-def build_engine(cfg: Dict, params, guard):
+def pool_bytes(pool) -> int:
+    """The bytes of the device arrays an engine's pool holds."""
+    import jax
+
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(vars(pool))
+               if isinstance(x, jax.Array))
+
+
+def build_engine(cfg: Dict, params, guard, counts):
     """Pod A's engine: the configuration file fixes the model, the memory
     geometry and ``prefill_chunk``; every other scheduling field stays at
-    the program's default."""
+    the program's default.  ``counts`` is the configuration's byte-count
+    module: its ``kv_bytes_per_row`` sizes the pool, and is held against
+    what the program then allocates, so that a count that is wrong can
+    neither mis-size a pool nor flatter a roofline share unseen."""
     import jax.numpy as jnp
 
     from kubeshare_tpu.models.transformer import TransformerConfig
@@ -248,11 +259,18 @@ def build_engine(cfg: Dict, params, guard):
     tc["dtype"] = jnp.dtype(tc["dtype"])
     config = TransformerConfig(**tc)
     e = cfg["engine"]
-    per_block = (2 * config.n_layers * config.kv_heads * e["block_size"]
-                 * config.head_dim * jnp.dtype(config.dtype).itemsize)
+    per_block = counts.kv_bytes_per_row(cfg["transformer_config"]) \
+        * e["block_size"]
     ec = EngineConfig(
         num_slots=e["num_slots"], block_size=e["block_size"],
         num_blocks=e["pool_bytes"] // per_block + 1,  # + scratch block 0
         max_request_len=e["max_request_len"],
         prefill_chunk=e["prefill_chunk"])
-    return ServingEngine(params, config, ec, guard=guard)
+    engine = ServingEngine(params, config, ec, guard=guard)
+    counted, held = ec.num_blocks * per_block, pool_bytes(engine.pool)
+    if counted != held:
+        raise RuntimeError(
+            f"{counts.__name__}.kv_bytes_per_row says {per_block} B a block "
+            f"of {e['block_size']} rows, {counted} B for {ec.num_blocks} "
+            f"blocks; the program's pool holds {held} B")
+    return engine

@@ -6,7 +6,8 @@ a device number.  (``shared`` reads not correct here: over a 4 s window
 tokend's 10 s ledger lets pod B burst past its limit; over the cells' 50 s it
 reads 0.498-0.4995.)
 
-    JAX_PLATFORMS=cpu python3 -m chipbench.tests.rehearse [rate|shared|backlog]
+    JAX_PLATFORMS=cpu python3 -m chipbench.tests.rehearse \
+        [rate|shared|backlog] [tiny|tiny_moe|<another twin>]
 """
 
 from __future__ import annotations
@@ -27,11 +28,15 @@ E2E = [{"name": "ttft_tail_ms", "unit": "ms"},
        {"name": "setup_s", "unit": "s"}]
 
 
-def tiny_cell(kind: str) -> dict:
-    with open(os.path.join(HERE, "configs", "tiny.json")) as f:
-        config = json.load(f)
-    return {"name": f"tiny.{kind}", "config": "tiny", "traffic": f"tiny.{kind}",
-            "chips": 1, "config_file": config,
+def tiny_cell(kind: str, config: str = "tiny") -> dict:
+    """A cell of ``configs/<config>.json`` here (a configuration's small
+    twin) under the tiny mix of that kind."""
+    with open(os.path.join(HERE, "configs", f"{config}.json")) as f:
+        config_file = json.load(f)
+    return {"name": f"{config}.{kind}", "config": config,
+            "traffic": f"tiny.{kind}", "chips": 1,
+            "config_file": config_file,
+            "modules": run.config_modules(config_file),
             "mix": traffic.load_mix(f"tiny.{kind}",
                                     os.path.join(HERE, "traffic")),
             "params": {"rate_rps": 6.0}, "end_to_end": E2E, "per_layer": [],
@@ -49,10 +54,12 @@ def fake_inventory():
                      model="TPU-v5e", index=0, coords=None)]
 
 
-def rehearse(kind: str, seed: int = 3, seconds: float = 4.0) -> dict:
-    result = run.run_cell(tiny_cell(kind), seed, seconds, trace=False,
+def rehearse(kind: str, config: str = "tiny", seed: int = 3,
+             seconds: float = 4.0) -> dict:
+    result = run.run_cell(tiny_cell(kind, config), seed, seconds, trace=False,
                           require_tpu=False, inventory=fake_inventory())
-    return {"rehearsal": kind, "platform": result["device"]["platform"],
+    return {"rehearsal": f"{config}.{kind}",
+            "platform": result["device"]["platform"],
             "correct": result["correct"], "attempted": result["attempted"],
             "failed": result["failed"]}
 
@@ -61,4 +68,4 @@ if __name__ == "__main__":
     if os.environ.get("JAX_PLATFORMS") != "cpu":
         raise SystemExit("rehearse: set JAX_PLATFORMS=cpu; the chip's runs "
                          "go through python3 -m chipbench.run")
-    print(json.dumps(rehearse(sys.argv[1] if len(sys.argv) > 1 else "rate")))
+    print(json.dumps(rehearse(*(sys.argv[1:3] or ["rate"]))))

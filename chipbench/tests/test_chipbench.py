@@ -96,6 +96,43 @@ def test_backlog_is_lazy_and_seeded():
     assert all(x.due == 0.0 for x in first)
 
 
+def test_a_token_law_is_data(tmp_path):
+    """``"tokens"`` as an object: ids by Zipf rank under one of ``topics``
+    seeded orders of the vocabulary.  The same seed gives the same ids; the
+    hottest 1% of a topic's ranks carry the share the exponent says; two
+    topics have other hot ids; the lengths and arrivals are untouched."""
+    vocab, s, law = 4096, 1.2, {"dist": "zipf", "exponent": 1.2, "topics": 2}
+    plain = traffic.load_mix("gen.rate")
+    (tmp_path / "zipf.json").write_text(json.dumps(dict(plain, tokens=law)))
+    mix = traffic.load_mix("zipf", str(tmp_path))
+    a, b, u = (_first(traffic.requests(m, 1.6, 50, vocab, seed), 80)
+               for m, seed in ((mix, 9), (mix, 9), (plain, 9)))
+    assert all((x.prompt == y.prompt).all() for x, y in zip(a, b))
+    assert all(len(x.prompt) == len(y.prompt) and x.due == y.due
+               and x.max_new == y.max_new for x, y in zip(a, u))
+    assert all(x.prompt.dtype == np.int32 and 0 <= x.prompt.min()
+               and x.prompt.max() < vocab for x in a)
+    # a request's topic shows in its most frequent id: two topics, two ids
+    hottest = [np.bincount(x.prompt, minlength=vocab).argmax() for x in a
+               if len(x.prompt) >= 256]
+    assert len(set(hottest)) == 2
+    weights = np.arange(1, vocab + 1) ** -s
+    said = weights[:vocab // 100].sum() / weights.sum()
+    for top in set(hottest):
+        ids = np.concatenate([x.prompt for x in a if len(x.prompt) >= 256
+                              and np.bincount(x.prompt).argmax() == top])
+        counts = np.sort(np.bincount(ids, minlength=vocab))[::-1]
+        share = counts[:vocab // 100].sum() / counts.sum()
+        assert abs(share - said) < 0.03, (share, said)
+    flat = np.concatenate([x.prompt for x in u])
+    assert np.sort(np.bincount(flat, minlength=vocab))[::-1][
+        :vocab // 100].sum() / flat.size < 0.03  # uniform: about 1%
+    (tmp_path / "odd.json").write_text(json.dumps(dict(
+        plain, tokens={"dist": "pareto"})))
+    with pytest.raises(ValueError, match="token law"):
+        traffic.load_mix("odd", str(tmp_path))
+
+
 # -- end-to-end arithmetic --------------------------------------------------
 
 def test_percentile_matches_numpy_and_hand():

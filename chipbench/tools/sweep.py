@@ -25,7 +25,6 @@ weights.  Every line also goes to
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import os
 import time
@@ -84,7 +83,7 @@ def main(argv=None) -> None:
         log.flush()
 
     session = run.Session(cell, args.seed)
-    reference = importlib.import_module(session.cfg["reference"])
+    reference = run.cell_module(cell, "reference")
     try:
         say(setup=session.timeline, setup_s=run.process_age_s())
         rate = args.rate or cell["params"].get("rate_rps")

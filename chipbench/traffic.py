@@ -24,12 +24,13 @@ import math
 import os
 from dataclasses import dataclass
 from statistics import NormalDist
-from typing import Dict, Iterator, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional
 
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ARRIVALS = ("exponential", "backlog")
+TOKEN_LAWS = ("zipf",)
 BACKLOG_ORDER_SEED = 1  # orders a backlog block's lengths, for every seed
 # irrational steps of the even orders of prompts, outputs and gaps: far from
 # each other, so that the three are paired without a pattern
@@ -53,6 +54,11 @@ def load_mix(name: str, directory: str = os.path.join(HERE, "traffic")) -> Dict:
         raise ValueError(f"mix {name!r}: arrivals {mix['arrivals']!r} is not "
                          f"one of {ARRIVALS}; another arrival process is a "
                          f"change to this generator (README.md)")
+    law = mix.get("tokens")
+    if isinstance(law, dict) and law.get("dist") not in TOKEN_LAWS:
+        raise ValueError(f"mix {name!r}: token law {law.get('dist')!r} is "
+                         f"not one of {TOKEN_LAWS}; another law is a change "
+                         f"to this generator (README.md)")
     return mix
 
 
@@ -85,12 +91,36 @@ def _rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed), stream])
 
 
-def _requests(prompts, outputs, due, vocab: int, seed: int, block: int,
+def token_law(mix: Dict, vocab: int, seed: int
+              ) -> Callable[[np.random.Generator, int], np.ndarray]:
+    """How a request's ids are drawn.  ``"tokens"`` as a string (a note)
+    means uniform over the vocabulary.  ``{"dist": "zipf", "exponent": s,
+    "topics": k}``: the seed makes ``k`` orders of the vocabulary; a request
+    draws one of them, then its ids by Zipf rank under it (rank ``r`` with
+    weight ``r ** -s``), so requests of a topic share their hot ids — and a
+    router that follows the ids is loaded unevenly."""
+    law = mix.get("tokens")
+    if not isinstance(law, dict):
+        return lambda rng, n: rng.integers(0, vocab, n, dtype=np.int32)
+    cdf = np.cumsum(np.arange(1, vocab + 1) ** -float(law["exponent"]))
+    cdf /= cdf[-1]
+    topics = [np.random.default_rng([int(seed), topic, 0])
+              .permutation(vocab).astype(np.int32)
+              for topic in range(int(law["topics"]))]
+
+    def draw(rng: np.random.Generator, n: int) -> np.ndarray:
+        order = topics[rng.integers(len(topics))]
+        ranks = np.searchsorted(cdf, rng.random(n), side="right")
+        return order[np.minimum(ranks, vocab - 1)]
+
+    return draw
+
+
+def _requests(prompts, outputs, due, draw, seed: int, block: int,
               first: int) -> List[TrafficRequest]:
-    """One block's requests; the seed draws the tokens."""
+    """One block's requests; the seed draws the tokens, by ``draw``."""
     rng = _rng(seed, block)
-    return [TrafficRequest(f"r{first + i:06d}",
-                           rng.integers(0, vocab, int(p), dtype=np.int32),
+    return [TrafficRequest(f"r{first + i:06d}", draw(rng, int(p)),
                            int(o), float(t))
             for i, (p, o, t) in enumerate(zip(prompts, outputs, due))]
 
@@ -106,9 +136,10 @@ def open_loop_requests(mix: Dict, rate_rps: float, seconds: float,
     outputs = np.minimum(outputs, mix["max_total"] - prompts)
     gaps = exponential_gaps(n, seconds)[even_order(n, _GAP_STEP)]
     due = np.concatenate([[0.0], np.cumsum(gaps[:-1])])
+    draw = token_law(mix, vocab, seed)
     block = 0
     while True:
-        yield from _requests(prompts, outputs, due + block * seconds, vocab,
+        yield from _requests(prompts, outputs, due + block * seconds, draw,
                              seed, block, block * n)
         block += 1
 
@@ -118,13 +149,14 @@ def backlog_requests(mix: Dict, vocab: int, seed: int
     """Every request due at 0, made lazily in stratified blocks, without
     end: the window's cut decides how many are used."""
     n = int(mix["backlog_block"])
+    draw = token_law(mix, vocab, seed)
     block = 0
     while True:
         order = _rng(BACKLOG_ORDER_SEED, block)
         prompts = order.permutation(lognormal_lengths(mix["prompt"], n))
         outputs = order.permutation(lognormal_lengths(mix["output"], n))
         outputs = np.minimum(outputs, mix["max_total"] - prompts)
-        yield from _requests(prompts, outputs, np.zeros(n), vocab, seed,
+        yield from _requests(prompts, outputs, np.zeros(n), draw, seed,
                              block, block * n)
         block += 1
 
