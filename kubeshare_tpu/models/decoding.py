@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.rope import apply_rope
-from .transformer import TransformerConfig, _rms_norm
+from .transformer import (TransformerConfig, _rms_norm, latent_attend,
+                          latent_layer, latent_qkv)
 
 
 def _check_moe_decodable(config: TransformerConfig) -> None:
@@ -82,7 +83,17 @@ def init_kv_cache(config: TransformerConfig, batch: int) -> Dict:
     """Static [layers x batch x kv_heads x max_seq x head_dim] cache.
 
     Under GQA (``n_kv_heads < n_heads``) the cache — decode's dominant
-    HBM cost — shrinks by the query-group factor."""
+    HBM cost — shrinks by the query-group factor.  The
+    'latent_shortcut' block caches one headless row an attention
+    sub-layer instead: ``k`` its latent values, ``v`` its rotary key."""
+    if config.latent:
+        # the head axis stays (1): every cached path reads capacity there
+        shape = (config.attn_sublayers, batch, 1, config.max_seq_len)
+        return {
+            "k": jnp.zeros((*shape, config.kv_lora_rank), config.dtype),
+            "v": jnp.zeros((*shape, config.qk_rope_head_dim), config.dtype),
+            "length": jnp.zeros((), jnp.int32),
+        }
     shape = (batch, config.kv_heads, config.max_seq_len, config.head_dim)
     return {
         "k": jnp.zeros((config.n_layers, *shape), config.dtype),
@@ -132,6 +143,49 @@ def _attend_cached(q, cache_k, cache_v, q_positions, window=None):
     return out.reshape(b, h, cq, d)
 
 
+def _latent_chunk_layers(params, config: TransformerConfig, cache: Dict,
+                         x, positions):
+    """The 'latent_shortcut' block's layers of a width-C cached step:
+    each sub-layer writes its chunk's latent rows at ``positions`` and
+    attends the whole dense cache in the absorbed form — the paged
+    steps' math (serving/paged.py) over a lockstep cache."""
+    batch, chunk = x.shape[:2]
+    start = positions[0]
+    positions = jnp.broadcast_to(positions[None, :], (batch, chunk))
+    cache_k, cache_v = cache["k"], cache["v"]
+
+    for layer_idx, layer in enumerate(params["layers"]):
+
+        def attend(j, attn, y):
+            nonlocal cache_k, cache_v
+            sub = 2 * layer_idx + j
+            q_nope, q_rope, c_kv, k_rope = latent_qkv(
+                attn, y, positions, config)
+            cache_k = jax.lax.dynamic_update_slice(
+                cache_k, c_kv[None, :, None], (sub, 0, 0, start, 0))
+            cache_v = jax.lax.dynamic_update_slice(
+                cache_v, k_rope[None, :, None], (sub, 0, 0, start, 0))
+            return latent_attend(attn, q_nope, q_rope, cache_k[sub, :, 0],
+                                 cache_v[sub, :, 0], positions, config,
+                                 absorbed=True)
+
+        x, _ = latent_layer(layer, x, config, attend)
+    return x, {"k": cache_k, "v": cache_v,
+               "length": cache["length"] + chunk}
+
+
+def _chunk_head(params, config: TransformerConfig, x, head_last_only,
+                head_row):
+    """Final norm and ``lm_head`` over every row of a chunk, its last
+    row, or the one row ``head_row``."""
+    x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
+    if head_last_only:
+        x = x[:, -1:]
+    elif head_row is not None:
+        x = x[:, head_row: head_row + 1]
+    return (x @ params["lm_head"].astype(config.dtype)).astype(jnp.float32)
+
+
 def _decode_chunk(params, config: TransformerConfig, cache: Dict,
                   tokens: jax.Array, head_last_only: bool = False,
                   head_row: Optional[int] = None):
@@ -154,6 +208,9 @@ def _decode_chunk(params, config: TransformerConfig, cache: Dict,
     chunk = tokens.shape[1]
     positions = position + jnp.arange(chunk)  # global positions [C]
     x = params["embed"][tokens].astype(dtype)  # [b,C,d]
+    if config.latent:
+        x, cache = _latent_chunk_layers(params, config, cache, x, positions)
+        return _chunk_head(params, config, x, head_last_only, head_row), cache
     use_rope = config.positional == "rope"
     if not use_rope:
         pos_embed = jax.lax.dynamic_slice_in_dim(
@@ -205,14 +262,7 @@ def _decode_chunk(params, config: TransformerConfig, cache: Dict,
             y = jax.nn.gelu(y @ layer["mlp"]["w_in"].astype(dtype))
             x = x + y @ layer["mlp"]["w_out"].astype(dtype)
 
-    x = _rms_norm(x, params["final_norm"]["scale"])
-    if head_last_only:
-        head_in = x[:, -1:]
-    elif head_row is not None:
-        head_in = x[:, head_row: head_row + 1]
-    else:
-        head_in = x
-    logits = (head_in @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    logits = _chunk_head(params, config, x, head_last_only, head_row)
     cache = {
         "k": jnp.stack(new_k),
         "v": jnp.stack(new_v),
@@ -247,6 +297,12 @@ def prefill(params, config: TransformerConfig, prompt: jax.Array) -> Tuple[Dict,
     _check_moe_decodable(config)
     if config.attention in ("ring", "ulysses"):
         return prefill_incremental(params, config, prompt)
+    if config.latent:
+        # no bulk forward collects latent rows: one cached chunk does
+        logits, cache = _decode_chunk(
+            params, config, init_kv_cache(config, batch), prompt,
+            head_last_only=True)
+        return cache, logits[:, 0]
     kv_sink: list = []
     hidden, _ = _forward(params, prompt, config, _select_attention(config),
                          0, apply_head=False, kv_sink=kv_sink)

@@ -55,6 +55,55 @@ class TransformerConfig:
     # "scatter" (permutation dispatch, no dispatch FLOPs) or "einsum"
     # (dense one-hot dispatch); see ops.moe
     moe_dispatch: str = "scatter"
+    # rotary base and RMSNorm epsilon (the dense block's constants)
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    # block kind.  "dense": norm -> MHA/MQA/GQA -> norm -> two-matrix
+    # GELU MLP (or a moe_every mixture).  "latent_shortcut": a DOUBLE
+    # layer — two latent (MLA) attentions, two dense gated (SwiGLU)
+    # FFNs of width d_ff, and ONE routed expert layer that reads the
+    # first sub-layer's post-attention norm and is added after the
+    # second sub-layer's FFN (the shortcut).  The fields below describe
+    # it and mean nothing to the dense block.
+    block: str = "dense"
+    q_lora_rank: int = 0  # query down-projection rank
+    kv_lora_rank: int = 0  # cached latent width
+    qk_nope_head_dim: int = 0  # per-head no-position q.k width
+    qk_rope_head_dim: int = 0  # rotary q.k width (one key for all heads)
+    v_head_dim: int = 0
+    # the expert layer: a router over n_routed_experts + n_zero_experts
+    # outputs (zero-compute experts return their input), router_top_k
+    # choices a token, softmax scores x routed_scaling_factor, no
+    # capacity, nothing dropped.  This device holds experts_held of the
+    # routed experts from first_expert_held on (None = all) and leaves
+    # out what the others would add (ops/moe.py shortcut_experts_apply)
+    n_routed_experts: int = 0
+    n_zero_experts: int = 0
+    router_top_k: int = 0
+    routed_scaling_factor: float = 1.0
+    expert_d_ff: int = 0
+    experts_held: Optional[int] = None
+    first_expert_held: int = 0
+
+    def __post_init__(self) -> None:
+        _check_block(self)
+
+    @property
+    def latent(self) -> bool:
+        """The block caches one latent row a sub-layer, not a K and a V
+        a head."""
+        return self.block == "latent_shortcut"
+
+    @property
+    def attn_sublayers(self) -> int:
+        """Attention sub-layers, each with a cache row of its own: the
+        layers of the KV pool."""
+        return 2 * self.n_layers if self.latent else self.n_layers
+
+    @property
+    def held_experts(self) -> int:
+        return (self.n_routed_experts if self.experts_held is None
+                else self.experts_held)
 
     def layer_is_moe(self, layer_idx: int) -> bool:
         return (self.moe_every is not None
@@ -70,7 +119,99 @@ class TransformerConfig:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
 
+def _check_block(config: TransformerConfig) -> None:
+    if config.block not in ("dense", "latent_shortcut"):
+        raise ValueError(
+            f"block must be 'dense' or 'latent_shortcut', got "
+            f"{config.block!r}")
+    if not config.latent:
+        if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
+            raise ValueError(
+                "the dense block's rotary base (10000) and norm epsilon "
+                "(1e-6) are fixed; rope_theta and norm_eps are the "
+                "'latent_shortcut' block's")
+        return
+    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
+                 "router_top_k", "expert_d_ff"):
+        if getattr(config, name) < 1:
+            raise ValueError(
+                f"block 'latent_shortcut' needs {name} >= 1, got "
+                f"{getattr(config, name)}")
+    if config.qk_rope_head_dim % 2:
+        raise ValueError("qk_rope_head_dim must be even")
+    if config.moe_every is not None or config.attention_window is not None \
+            or config.positional != "rope":
+        raise ValueError(
+            "block 'latent_shortcut' takes neither moe_every nor "
+            "attention_window, and positional='rope' (it has no learned "
+            "positions)")
+    total = config.n_routed_experts + config.n_zero_experts
+    if not 1 <= config.router_top_k <= total:
+        raise ValueError(
+            f"router_top_k must be in [1, {total}], got "
+            f"{config.router_top_k}")
+    last = config.first_expert_held + config.held_experts
+    if config.first_expert_held < 0 or config.held_experts < 1 \
+            or last > config.n_routed_experts:
+        raise ValueError(
+            f"experts held [{config.first_expert_held}, {last}) are not "
+            f"among the {config.n_routed_experts} routed experts")
+
+
+def _latent_layer_init(keys, config: TransformerConfig, dense) -> Dict:
+    """One double layer of the 'latent_shortcut' block."""
+    d, h, f = config.d_model, config.n_heads, config.d_ff
+    qr, kr = config.q_lora_rank, config.kv_lora_rank
+    nope, rope, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                      config.v_head_dim)
+    e, fe = config.held_experts, config.expert_d_ff
+    outputs = config.n_routed_experts + config.n_zero_experts
+
+    def attn():
+        return {
+            "wdq": dense(next(keys), (d, qr), d),
+            "q_norm": {"scale": jnp.ones((qr,))},
+            "wuq": dense(next(keys), (qr, h, nope + rope), qr),
+            "wdkv": dense(next(keys), (d, kr + rope), d),
+            "kv_norm": {"scale": jnp.ones((kr,))},
+            # per head [k_nope | v]
+            "wukv": dense(next(keys), (kr, h, nope + vd), kr),
+            "wo": dense(next(keys), (h, vd, d), h * vd),
+        }
+
+    def ffn():
+        return {"w_gate": dense(next(keys), (d, f), d),
+                "w_up": dense(next(keys), (d, f), d),
+                "w_down": dense(next(keys), (f, d), f)}
+
+    return {
+        "attn": [attn(), attn()],
+        "norm_attn": [{"scale": jnp.ones((d,))} for _ in range(2)],
+        "norm_ffn": [{"scale": jnp.ones((d,))} for _ in range(2)],
+        "ffn": [ffn(), ffn()],
+        "moe": {"router": dense(next(keys), (d, outputs), d),
+                "w_gate": dense(next(keys), (e, d, fe), d),
+                "w_up": dense(next(keys), (e, d, fe), d),
+                "w_down": dense(next(keys), (e, fe, d), fe)},
+    }
+
+
 def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
+    if config.latent:
+        def dense(key, shape, fan_in):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    * (1.0 / fan_in) ** 0.5)
+
+        keys = iter(jax.random.split(rng, 2 + 20 * config.n_layers))
+        d = config.d_model
+        return {
+            "embed": dense(next(keys), (config.vocab_size, d), d),
+            "layers": [_latent_layer_init(keys, config, dense)
+                       for _ in range(config.n_layers)],
+            "final_norm": {"scale": jnp.ones((d,))},
+            "lm_head": dense(next(keys), (d, config.vocab_size), d),
+        }
     if config.moe_every is not None and config.moe_every < 1:
         raise ValueError(f"moe_every must be >= 1, got {config.moe_every}")
     if config.kv_heads < 1:
@@ -134,9 +275,214 @@ def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
     return params
 
 
-def _rms_norm(x, scale):
-    norm = jax.lax.rsqrt(jnp.mean(x.astype(jnp.float32) ** 2, -1, keepdims=True) + 1e-6)
+def _rms_norm(x, scale, eps: float = 1e-6):
+    norm = jax.lax.rsqrt(jnp.mean(x.astype(jnp.float32) ** 2, -1, keepdims=True) + eps)
     return (x * norm.astype(x.dtype)) * scale.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the 'latent_shortcut' block: latent (MLA) attention, gated FFNs and one
+# shortcut-connected routed expert layer a double layer.  These pieces are
+# shared by the unpaged forward below and the paged step programs
+# (serving/paged.py); only the cache plumbing differs.
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("mla")
+def latent_qkv(attn, y, positions, config: TransformerConfig):
+    """One sub-layer's projections of ``y`` [B, C, d] at ``positions``
+    [B, C]: ``q_nope`` [B, H, C, nope], ``q_rope`` [B, H, C, rope]
+    (rotated), and the two parts of the row the sub-layer caches —
+    ``c_kv`` [B, C, kv_lora_rank] (normed and scaled) and ``k_rope``
+    [B, C, rope] (rotated; ONE key for all heads)."""
+    dtype = config.dtype
+    d, eps = config.d_model, config.norm_eps
+    kr, nope = config.kv_lora_rank, config.qk_nope_head_dim
+    c_q = _rms_norm(y @ attn["wdq"].astype(dtype),
+                    attn["q_norm"]["scale"], eps)
+    q = jnp.einsum("bcr,rhk->bhck", c_q, attn["wuq"].astype(dtype))
+    q = q * jnp.asarray((d / config.q_lora_rank) ** 0.5, dtype)
+    a = y @ attn["wdkv"].astype(dtype)
+    c_kv = _rms_norm(a[..., :kr], attn["kv_norm"]["scale"], eps) \
+        * jnp.asarray((d / kr) ** 0.5, dtype)
+    rope = lambda x: apply_rope(x, positions, theta=config.rope_theta,
+                                interleaved=True)
+    k_rope = rope(a[:, None, :, kr:])[:, 0]
+    return q[..., :nope], rope(q[..., nope:]), c_kv, k_rope
+
+
+def _latent_softmax(scores, positions, dtype):
+    """Causal softmax of ``scores`` [B, H, C, V] (float32): query i of
+    lane b sees view rows ``<= positions[b, i]``.
+
+    The row maximum goes through an optimization barrier: fused with its
+    broadcast, the TPU compiler turns it into a reduce-window as wide as
+    the view (16383 for 8192 rows), which took 19 of the 25 ms a block
+    of 128 queries cost on a v5e (PERF.md, PR 27)."""
+    k_pos = jnp.arange(scores.shape[-1])
+    valid = k_pos[None, None, :] <= positions[:, :, None]  # [B, C, V]
+    scores = jnp.where(valid[:, None], scores, -jnp.inf)
+    top = jax.lax.optimization_barrier(
+        jnp.max(scores, axis=-1, keepdims=True))
+    weights = jnp.exp(scores - top)
+    return (weights / jnp.sum(weights, axis=-1, keepdims=True)).astype(dtype)
+
+
+@jax.named_scope("mla")
+def latent_attend(attn, q_nope, q_rope, view_c, view_r, positions,
+                  config: TransformerConfig, absorbed: bool):
+    """Attention of one sub-layer over latent rows ``view_c`` [B, V,
+    kv_lora_rank] / ``view_r`` [B, V, rope] -> [B, C, d].
+
+    ``absorbed``: the scores and the context are taken over the latent
+    rows themselves (the key up-projection folded into the query, the
+    value up-projection applied to the attended latent) — nothing of
+    ``V x heads`` is ever built, which is what a decode step over a
+    long view needs.  Otherwise every view row is expanded to a key and
+    a value a head first: fewer FLOPs a query when there are many
+    queries (a prefill chunk).  Both are the same numbers."""
+    dtype = config.dtype
+    nope = config.qk_nope_head_dim
+    scale = (nope + config.qk_rope_head_dim) ** -0.5
+    wukv = attn["wukv"].astype(dtype)
+    wuk, wuv = wukv[..., :nope], wukv[..., nope:]
+    if absorbed:
+        q_nope = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
+    else:
+        k_nope = jnp.einsum("bvr,rhn->bhvn", view_c, wuk)
+        v = jnp.einsum("bvr,rhm->bhvm", view_c, wuv)
+
+    f32 = jnp.float32
+    scores = jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                        preferred_element_type=f32)
+    if absorbed:
+        scores += jnp.einsum("bhcr,bvr->bhcv", q_nope, view_c,
+                             preferred_element_type=f32)
+    else:
+        scores += jnp.einsum("bhcn,bhvn->bhcv", q_nope, k_nope,
+                             preferred_element_type=f32)
+    probs = _latent_softmax(scores * scale, positions, dtype)
+    if absorbed:
+        ctx = jnp.einsum("bhcv,bvr->bhcr", probs, view_c)
+        o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
+    else:
+        o = jnp.einsum("bhcv,bhvm->bhcm", probs, v)
+    return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
+
+
+@jax.named_scope("mla")
+def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
+                         positions, config: TransformerConfig):
+    """:func:`latent_attend` in the absorbed form over a view that is
+    handed over a block of K = ``block_rows`` rows at a time:
+    ``view_block(i)`` gives rows ``[i * K, (i + 1) * K)`` of every lane's
+    view as (``view_c`` [B, K, kv_lora_rank], ``view_r`` [B, K, rope]).  Only the blocks that hold
+    a row some query may see are asked for — up to the largest of
+    ``positions`` — and the softmax is carried across them (running
+    maximum, running sum, rescaled context), so a step's attention costs
+    what its lanes hold, not what a lane may hold.  Same numbers as the
+    whole view at once, up to the order of the sums."""
+    dtype = config.dtype
+    nope = config.qk_nope_head_dim
+    scale = (nope + config.qk_rope_head_dim) ** -0.5
+    wukv = attn["wukv"].astype(dtype)
+    wuk, wuv = wukv[..., :nope], wukv[..., nope:]
+    q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
+    b, h, c, r = q_abs.shape
+    f32 = jnp.float32
+
+    def step(i, carry):
+        top, total, ctx = carry
+        view_c, view_r = view_block(i)
+        scores = (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
+                             preferred_element_type=f32)
+                  + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                               preferred_element_type=f32)) * scale
+        k_pos = i * block_rows + jnp.arange(block_rows)
+        valid = k_pos[None, None, :] <= positions[:, :, None]  # [B, C, K]
+        scores = jnp.where(valid[:, None], scores, -jnp.inf)
+        # block 0 holds row 0, which every query sees: new_top is finite
+        # from the first step on (the barrier: see _latent_softmax)
+        new_top = jax.lax.optimization_barrier(
+            jnp.maximum(top, jnp.max(scores, axis=-1)))
+        weights = jnp.exp(scores - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        total = total * keep + jnp.sum(weights, axis=-1)
+        ctx = ctx * keep[..., None] + jnp.einsum(
+            "bhcv,bvr->bhcr", weights.astype(dtype), view_c,
+            preferred_element_type=f32)
+        return new_top, total, ctx
+
+    _, total, ctx = jax.lax.fori_loop(
+        0, jnp.max(positions) // block_rows + 1, step,
+        (jnp.full((b, h, c), -jnp.inf, f32), jnp.zeros((b, h, c), f32),
+         jnp.zeros((b, h, c, r), f32)))
+    ctx = (ctx / total[..., None]).astype(dtype)
+    o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
+    return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
+
+
+@jax.named_scope("ffn")
+def gated_ffn(ffn, y, dtype):
+    """SwiGLU: ``(silu(y Wg) * (y Wu)) Wd``."""
+    hidden = jax.nn.silu(y @ ffn["w_gate"].astype(dtype)) \
+        * (y @ ffn["w_up"].astype(dtype))
+    return hidden @ ffn["w_down"].astype(dtype)
+
+
+def shortcut_experts(layer, config: TransformerConfig, y):
+    """The double layer's expert layer over ``y`` [B, C, d] ->
+    (out [B, C, d], routing counts int32[4]: see ops/moe.py)."""
+    from ..ops.moe import shortcut_experts_apply
+
+    b, c, d = y.shape
+    out, counts = shortcut_experts_apply(
+        layer["moe"], y.reshape(b * c, d),
+        n_routed=config.n_routed_experts, top_k=config.router_top_k,
+        scale=config.routed_scaling_factor,
+        first_held=config.first_expert_held)
+    return out.reshape(b, c, d), counts
+
+
+def latent_layer(layer, x, config: TransformerConfig, attend):
+    """One double layer.  ``attend(j, attn_weights, y)`` is sub-layer
+    j's attention of the normed input ``y`` — where the callers differ
+    (the unpaged forward attends its own rows, a paged step writes the
+    cache row and attends the lane's view).  Returns (x, counts)."""
+    dtype, eps = config.dtype, config.norm_eps
+    expert_out = counts = None
+    for j in range(2):
+        y = _rms_norm(x, layer["norm_attn"][j]["scale"], eps)
+        x = x + attend(j, layer["attn"][j], y)
+        y = _rms_norm(x, layer["norm_ffn"][j]["scale"], eps)
+        if j == 0:
+            # the shortcut: routed on the first sub-layer's normed
+            # hidden state, added after the second sub-layer's FFN
+            expert_out, counts = shortcut_experts(layer, config, y)
+        x = x + gated_ffn(layer["ffn"][j], y, dtype)
+    return x + expert_out, counts
+
+
+def _latent_forward(params, tokens, config: TransformerConfig,
+                    apply_head: bool = True):
+    """The unpaged forward of the 'latent_shortcut' block: every
+    sub-layer attends its own rows in the expanded form."""
+    dtype = config.dtype
+    b, seq = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
+    x = params["embed"][tokens].astype(dtype)
+
+    def attend(_, attn, y):
+        q_nope, q_rope, c_kv, k_rope = latent_qkv(attn, y, positions, config)
+        return latent_attend(attn, q_nope, q_rope, c_kv, k_rope, positions,
+                             config, absorbed=False)
+
+    for layer in params["layers"]:
+        x, _ = latent_layer(layer, x, config, attend)
+    x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
+    if not apply_head:
+        return x, jnp.float32(0.0)
+    return ((x @ params["lm_head"].astype(dtype)).astype(jnp.float32),
+            jnp.float32(0.0))
 
 
 def _select_attention(config: TransformerConfig):
@@ -164,6 +510,13 @@ def _forward(params, tokens, config, attention_fn, pos_offset,
     ``kv_sink`` (a list) collects each layer's (k, v) projections —
     the bulk-prefill path fills the decode cache from them; remat is
     bypassed there (inference has no backward to rematerialize for)."""
+    if config.latent:
+        if kv_sink is not None or jnp.ndim(pos_offset) != 0:
+            raise ValueError(
+                "block 'latent_shortcut' has no dense-cache or "
+                "sequence-sharded forward: its cache is the paged latent "
+                "pool (serving/paged.py)")
+        return _latent_forward(params, tokens, config, apply_head)
     dtype = config.dtype
     seq = tokens.shape[1]
     x = params["embed"][tokens].astype(dtype)

@@ -232,6 +232,127 @@ def _experts_choose(params, x, tokens, probs, config, capacity):
     return combined.reshape(b, s, d), jnp.float32(0.0)
 
 
+# ---------------------------------------------------------------------------
+# one expert-parallel rank's share of a shortcut-connected expert layer
+# ---------------------------------------------------------------------------
+
+EXPERT_TILE = 128  # rows of one grouped-matmul tile, at most
+
+
+def expert_tile_rows(n: int) -> int:
+    """Rows a tile for an ``n``-row pass: a power of two, 8 to
+    ``EXPERT_TILE``.  A tile is one expert's: its three matrices are read
+    once a tile, so a large tile costs a pass of few rows (a decode
+    step) padding FLOPs, and a small one costs a pass of many rows (a
+    prefill chunk) re-reads of the weights."""
+    return min(EXPERT_TILE, max(8, 1 << (max(n, 1) - 1).bit_length()))
+
+
+@jax.named_scope("experts")
+def shortcut_experts_apply(
+    moe: Dict,
+    y: jax.Array,
+    *,
+    n_routed: int,
+    top_k: int,
+    scale: float,
+    first_held: int = 0,
+) -> Tuple[jax.Array, jax.Array]:
+    """The part of a routed expert layer that THIS device computes.
+
+    ``y`` [n, d].  The router (``moe["router"]`` [d, n_routed + n_zero])
+    keeps every output: a token's scores are the float32 softmax over all
+    of them, it chooses the ``top_k`` largest, and choice ``e`` weighs
+    ``scale * P_e`` (not renormalised).  Experts ``>= n_routed`` are
+    zero-compute: they return their input, so all of a token's identity
+    choices are ONE weighted add.  Of the routed experts this device
+    holds ``moe["w_gate"].shape[0]`` from ``first_held`` on (SwiGLU,
+    ``w_gate``/``w_up`` [e, d, f], ``w_down`` [e, f, d]); a choice of
+    an expert held elsewhere adds nothing here — its owner adds it, and
+    no code stands in for that exchange.
+
+    Nothing is dropped and nothing is padded to a capacity: the
+    assignments to held experts are grouped by expert into tiles of
+    ``expert_tile_rows(n)`` rows (an expert's last tile is padded), and
+    a loop runs as many tiles as the routing made, each gathering its
+    rows, running its expert, and adding the weighted result to its
+    rows.  The work follows the routing — an expert that no row chose
+    is not read.  A row's result depends on that row alone: routing is
+    per row, and a row's choices are added in expert order.
+
+    Returns (out [n, d] in ``y``'s dtype, counts int32[4]): assignments
+    to held, zero-compute and absent experts (they add up to
+    ``n * top_k``) and the held experts that got at least one row.
+    Forward only (the loop's length is data).
+    """
+    n, d = y.shape
+    e_held = moe["w_gate"].shape[0]
+    with jax.named_scope("router"):
+        # bf16 x bf16 products are exact in float32, so this IS the
+        # float32 product of the values the block holds
+        logits = jnp.dot(y, moe["router"].astype(y.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, index = jax.lax.top_k(probs, top_k)  # [n, k]
+        gate = gate * scale
+    local = index - first_held
+    held = (local >= 0) & (local < e_held) & (index < n_routed)
+    zero = index >= n_routed
+    y32 = y.astype(jnp.float32)
+    out = jnp.sum(jnp.where(zero, gate, 0.0), -1, keepdims=True) * y32
+
+    # group the held assignments by expert, in tiles
+    tile = expert_tile_rows(n)
+    a = n * top_k
+    flat_local = jnp.where(held, local, e_held).reshape(a)
+    flat_token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), top_k)
+    onehot = flat_local[:, None] == jnp.arange(e_held)[None, :]  # [a, e]
+    rank = jnp.sum(jnp.where(onehot, jnp.cumsum(onehot, axis=0) - 1, 0), -1)
+    counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)  # [e]
+    tiles = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    # a token chooses an expert at most once: counts <= n, their sum
+    # <= n * min(top_k, e_held)
+    max_tiles = min(e_held * -(-n // tile),
+                    n * min(top_k, e_held) // tile + e_held)
+    slots = max_tiles * tile
+    dest = jnp.where(
+        flat_local < e_held,
+        (tile_end - tiles)[jnp.minimum(flat_local, e_held - 1)] * tile + rank,
+        slots)
+    slot_token = jnp.full((slots,), n, jnp.int32).at[dest].set(
+        flat_token, mode="drop")
+    slot_gate = jnp.zeros((slots,), jnp.float32).at[dest].set(
+        gate.reshape(a), mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(max_tiles), side="right"),
+        e_held - 1)
+
+    dtype = y.dtype
+    rows_of = jnp.concatenate([y, jnp.zeros((1, d), dtype)])  # row n: pad
+
+    def run_tile(i, acc):
+        token = jax.lax.dynamic_slice(slot_token, (i * tile,), (tile,))
+        weight = jax.lax.dynamic_slice(slot_gate, (i * tile,), (tile,))
+        expert = tile_expert[i]
+        rows = rows_of[token]  # [tile, d]; pad slots read the zero row
+        pick = lambda w: jax.lax.dynamic_index_in_dim(
+            w, expert, keepdims=False).astype(dtype)
+        hidden = jax.nn.silu(rows @ pick(moe["w_gate"])) \
+            * (rows @ pick(moe["w_up"]))
+        result = jnp.dot(hidden, pick(moe["w_down"]),
+                         preferred_element_type=jnp.float32)
+        return acc.at[token].add(weight[:, None] * result)
+
+    acc = jnp.concatenate([out, jnp.zeros((1, d), jnp.float32)])
+    acc = jax.lax.fori_loop(0, tile_end[-1], run_tile, acc)
+    n_held = jnp.sum(held, dtype=jnp.int32)
+    n_zero = jnp.sum(zero, dtype=jnp.int32)
+    stats = jnp.stack([n_held, n_zero, a - n_held - n_zero,
+                       jnp.sum(counts > 0, dtype=jnp.int32)])
+    return acc[:n].astype(dtype), stats
+
+
 def moe_sharding_rules(ep_axis: str = "dp") -> Dict[str, P]:
     """Expert weights sharded over the expert-parallel axis (conventionally
     laid over dp); router replicated."""

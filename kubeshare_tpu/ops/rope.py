@@ -23,6 +23,7 @@ def apply_rope(
     x: jax.Array,
     positions: jax.Array,
     theta: float = 10000.0,
+    interleaved: bool = False,
 ) -> jax.Array:
     """Rotate [batch, heads, seq, head_dim] by per-token positions.
 
@@ -30,7 +31,10 @@ def apply_rope(
     rotates every batch row by its OWN positions — the paged serving
     pool, where each slot sits at its own decode length
     (serving/paged.py).  Split-half convention: pairs
-    (x[..., :d/2], x[..., d/2:]).
+    (x[..., :d/2], x[..., d/2:]).  ``interleaved``: the pairs are
+    (x[..., 2i], x[..., 2i+1]) instead; the result comes back
+    de-interleaved (evens' half, then odds'), which leaves every dot
+    product between two vectors rotated this way what it is.
     """
     d = x.shape[-1]
     inv_freq = rope_frequencies(d, theta)
@@ -41,8 +45,12 @@ def apply_rope(
     else:
         cos = jnp.cos(angles)[:, None, :, :]  # [b, 1, s, d/2]
         sin = jnp.sin(angles)[:, None, :, :]
-    x1 = x[..., : d // 2].astype(jnp.float32)
-    x2 = x[..., d // 2 :].astype(jnp.float32)
+    if interleaved:
+        x1 = x[..., 0::2].astype(jnp.float32)
+        x2 = x[..., 1::2].astype(jnp.float32)
+    else:
+        x1 = x[..., : d // 2].astype(jnp.float32)
+        x2 = x[..., d // 2 :].astype(jnp.float32)
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     )

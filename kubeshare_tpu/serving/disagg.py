@@ -81,7 +81,8 @@ from .fabric import (FabricEndpoint, FabricTransport, K_TICKET,
 from .engine import (EngineConfig, Request, RequestResult, ServingEngine,
                      _Pending, _histogram_samples, _bucket_observe,
                      plan_prefill_chunks)
-from .kv_blocks import BlockExhausted, QuotaExceeded, chain_token_runs
+from .kv_blocks import (BlockExhausted, QuotaExceeded, chain_token_runs,
+                        require_kv_heads)
 from .kv_tier import (HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, pack_chain,
                       unpack_chain)
@@ -468,6 +469,8 @@ class DisaggRouter:
         fabric: Optional[FabricTransport] = None,
         fabric_ttl_ticks: int = 16,
     ) -> None:
+        require_kv_heads(config, "DisaggRouter (a handoff migrates K/V "
+                         "head slabs between the pools)")
         if handoff_ttl_steps is not None and handoff_ttl_steps < 1:
             raise ValueError(
                 f"handoff_ttl_steps must be >= 1, got {handoff_ttl_steps}")

@@ -115,7 +115,7 @@ from ..utils.promtext import (MetricFamily, MetricServer, Sample,
 from .autotune import AnalyticPolicy, AutoTuner
 from .drafter import NGramDrafter
 from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
-                        init_paged_pool)
+                        init_paged_pool, kv_row_layout)
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
@@ -427,7 +427,27 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
         ec.block_size, config.head_dim,
         jnp.dtype(config.dtype).itemsize)
         if ec.host_tier_bytes is not None else None)
+    layout = kv_row_layout(config)
+    # what still assumes a K and a V [kv_heads, head_dim] pair a layer
+    not_served = [name for name, asked in (
+        ("speculative=True (the draft-verify programs)", ec.speculative),
+        ("steps_per_launch > 1 (the device-resident loops)",
+         ec.steps_per_launch > 1),
+        ("mesh_spec (serving/sharded.py shards the KV-head axis)",
+         ec.mesh_spec is not None),
+        ("host_tier_bytes (serving/kv_tier.py packs K/V head slabs)",
+         ec.host_tier_bytes is not None),
+        ("a shared host tier (serving/kv_tier.py, serving/fabric.py)",
+         shared_host_tier is not None),
+        (f"pool_role={ec.pool_role!r} (serving/disagg.py migrates K/V "
+         f"head slabs)", ec.pool_role != "both"),
+    ) if asked]
     return [
+        (layout.kind != "kv_heads" and bool(not_served),
+         f"block {config.block!r} caches the {layout.kind!r} row layout "
+         f"(one row of {layout.k_row[1]} + {layout.v_row[1]} values a "
+         f"sub-layer, no heads), which is not served yet by: "
+         f"{'; '.join(not_served)}"),
         (mesh_devices is not None and ec.mesh_spec is None,
          "mesh_devices requires mesh_spec — an unsharded engine "
          "has no mesh to pin onto a device group; pin it with "
@@ -805,6 +825,9 @@ class ServingEngine:
                               else ec.prefill_chunk)
         self._prefill_rr = 0
         self._inflight = None
+        # beside it, a routed block's dispatch: (its counts array in a
+        # list, the rows and the expert-layer passes it carried)
+        self._routing_inflight = ([], 0, 0)
         # the warmed prefill-chunk bucket universe — warmup compiles
         # exactly this set, and the autotuner's fused-budget envelope
         # is confined to it (a tuned budget can only select among
@@ -926,6 +949,16 @@ class ServingEngine:
         self.spec_accepted: Dict[str, int] = {}
         self._spec_accept: Dict[str, list] = {}
         self.tokens_generated = 0
+        # a routed block's step programs return their routing counts
+        # (ops/moe.py shortcut_experts_apply), read with the tokens in
+        # _consume_inflight: assignments by where the chosen expert
+        # lives, held experts that got a row (summed over passes and
+        # layers), and expert-layer passes (one a chunk, one a decode
+        # step of a span)
+        self.moe_assignments: Dict[str, int] = {
+            "held": 0, "zero": 0, "absent": 0}
+        self.moe_experts_touched = 0
+        self.moe_passes = 0
         self.peak_blocks_in_use = 0
         self.requests_admitted = 0
         self.requests_finished = 0
@@ -1011,6 +1044,7 @@ class ServingEngine:
         # dispatch for its first token.
         sharded = self._sharded
         sharded_prefill = sharded.prefill if sharded is not None else None
+        routed = config.latent
 
         def prefill(w, pk, pv, tables, starts, active, tokens, last_rows,
                     temps, keys):
@@ -1018,9 +1052,10 @@ class ServingEngine:
                 logits, pk, pv = sharded_prefill(
                     w, pk, pv, tables, starts, active, tokens, last_rows)
                 return pick_rows(logits, temps, keys), pk, pv
-            logits, pk, pv = paged_prefill_step(
-                w, cfg, pk, pv, tables, starts, active, tokens, last_rows)
-            return pick_rows(logits, temps, keys), pk, pv
+            logits, pk, pv, *counts = paged_prefill_step(
+                w, cfg, pk, pv, tables, starts, active, tokens, last_rows,
+                routing=routed)
+            return (pick_rows(logits, temps, keys), pk, pv, *counts)
 
         # the pool buffers are DONATED: each step updates the cache in
         # place device-side instead of materializing a second pool (on a
@@ -1040,7 +1075,7 @@ class ServingEngine:
             # collectives live inside the program.
             return paged_decode_span(
                 w, cfg, pick_rows, span, eos, pk, pv, tables, lengths,
-                active, tokens, temps, keys, budgets)
+                active, tokens, temps, keys, budgets, routing=routed)
 
         if sharded is not None:
             decode = sharded.decode_span(pick_rows, span, eos)
@@ -1124,7 +1159,7 @@ class ServingEngine:
                 w, cfg, pick_rows, span, eos, pk, pv, p_table, p_start,
                 p_tokens, p_last_row, p_temp, p_key, d_tables,
                 d_lengths, d_active, d_tokens, d_temps, d_keys,
-                d_budgets)
+                d_budgets, routing=routed)
 
         if sharded is not None:
             mixed = sharded.mixed_step(pick_rows, span, eos)
@@ -1649,7 +1684,7 @@ class ServingEngine:
         for width in sorted(widths):
             # the pool rides through every warmup call (its buffers are
             # donated); the only writes land in the scratch block
-            _, pk, pv = self._prefill_step(
+            _, pk, pv, *_ = self._prefill_step(
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((1, self._table_width), jnp.int32),
                 one, jnp.zeros((1,), bool),
@@ -1664,7 +1699,7 @@ class ServingEngine:
             # EVERY width warms — the tuned budget may move up to any
             # warmed bucket, and a budget change must never compile
             if ec.mixed and (ec.autotune or width <= self._mixed_budget):
-                _, _, pk, pv = self._mixed_step(
+                _, _, pk, pv, *_ = self._mixed_step(
                     self.params, self.pool.k, self.pool.v,
                     jnp.zeros((1, self._table_width), jnp.int32), one,
                     jnp.zeros((1, width), jnp.int32), one,
@@ -1694,7 +1729,7 @@ class ServingEngine:
                             jnp.zeros((s, 1 + k, 2), jnp.uint32))
                         self.pool = replace(self.pool, k=pk, v=pv)
         if ec.pool_role != "prefill":
-            _, pk, pv = self._decode_step(
+            _, pk, pv, *_ = self._decode_step(
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((s, self._table_width), jnp.int32),
                 zeros_s, jnp.zeros((s,), bool), zeros_s,
@@ -1770,11 +1805,12 @@ class ServingEngine:
             # off): a zero slab into the scratch block (whose rows are
             # dead by construction)
             cfg2 = self.model_config
-            slab = jnp.zeros((cfg2.n_layers, cfg2.kv_heads, ec.block_size,
-                              cfg2.head_dim), cfg2.dtype)
+            k_slab, v_slab = (
+                jnp.zeros(shape, cfg2.dtype) for shape in
+                kv_row_layout(cfg2).block_shapes(ec.block_size))
             pk, pv = self._upload_step(
                 self.pool.k, self.pool.v, jnp.zeros((), jnp.int32),
-                slab, slab)
+                k_slab, v_slab)
             self.pool = replace(self.pool, k=pk, v=pv)
         jax.block_until_ready(self.pool.k)
 
@@ -2138,7 +2174,25 @@ class ServingEngine:
                     self._tuner.decisions.items()):
                 tuner.add({"knob": knob, "direction": direction,
                            **plabel}, n)
+        moe_assign = MetricFamily(
+            "kubeshare_serving_moe_assignments_total",
+            "Router choices of a routed block's step programs, by where "
+            "the chosen expert lives: held (a routed expert this device "
+            "holds and computes), zero (a zero-compute identity expert) "
+            "or absent (a routed expert another device holds: adds "
+            "nothing here).  Padded and inactive rows choose too.",
+            "counter")
+        for kind in sorted(self.moe_assignments):
+            moe_assign.add({"kind": kind, **plabel},
+                           self.moe_assignments[kind])
+        moe_touched = MetricFamily(
+            "kubeshare_serving_moe_experts_touched_total",
+            "Held experts that got at least one row, summed over "
+            "expert-layer passes and layers: the experts whose weights a "
+            "pass had to read.", "counter")
+        moe_touched.add(dict(plabel), self.moe_experts_touched)
         return [req, blocks, tokens, dispatches, loop_units,
+                moe_assign, moe_touched,
                 spec_loop_units, exit_reason, depth_summary, host_s,
                 guard_wait, guard_calls, slow, planner, prefix,
                 hit_tokens, evicted, tier_blocks,
@@ -3097,11 +3151,12 @@ class ServingEngine:
             chunk = slot.plan.pop(0)
         final, table, start, segment, last_row, temp, key = \
             self._prefill_lane(slot, chunk)
-        picked, pk, pv = self._dispatch(
+        picked, pk, pv, *counts = self._dispatch(
             self._prefill_step, self.params, self.pool.k, self.pool.v,
             table, start, jnp.ones((1,), bool), segment, last_row,
             temp, key)
         self.pool = replace(self.pool, k=pk, v=pv)
+        self._routing_inflight = (counts, segment.shape[1], 1)
         self.prefill_chunks += 1
         self._charge_collectives("prefill_chunk", "prefill", lanes=1,
                                  chunk=segment.shape[1])
@@ -3109,20 +3164,24 @@ class ServingEngine:
         # prefix-cache hit charges only its uncached suffix — tokend's
         # charge-measured-work principle)
         self._queue.charge(slot.tenant, chunk[1])
-        if final:
-            # the fused pick at the final chunk's last-real-row logits
-            # IS the first token; read when consumed (one step later)
-            self._inflight = ("span", None, (slot, picked))
+        # the fused pick at the final chunk's last-real-row logits IS
+        # the first token; read when consumed (one step later), with
+        # a routed block's counts
+        if final or counts:
+            self._inflight = ("span", None,
+                              (slot, picked) if final else None)
 
     def _run_decode_step(self, decode_slots: List[_Slot]) -> None:
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
-        emitted, pk, pv = self._dispatch(
+        emitted, pk, pv, *counts = self._dispatch(
             self._decode_step, self.params, self.pool.k, self.pool.v,
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
             jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
             jnp.asarray(budgets))
         self.pool = replace(self.pool, k=pk, v=pv)
+        span = self.engine_config.decode_span
+        self._routing_inflight = (counts, len(tokens) * span, span)
         self.decode_steps += 1
         self._charge_collectives(
             "decode_span", "decode", lanes=self.engine_config.num_slots,
@@ -3344,13 +3403,16 @@ class ServingEngine:
             self._prefill_lane(p_slot, chunk)
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
-        picked, emitted, pk, pv = self._dispatch(
+        picked, emitted, pk, pv, *counts = self._dispatch(
             self._mixed_step, self.params, self.pool.k, self.pool.v,
             table, start, segment, last_row, temp, key,
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
             jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
             jnp.asarray(budgets))
         self.pool = replace(self.pool, k=pk, v=pv)
+        span = self.engine_config.decode_span
+        self._routing_inflight = (
+            counts, segment.shape[1] + len(tokens) * span, 1 + span)
         self.prefill_chunks += 1
         self.decode_steps += 1
         self.mixed_steps += 1
@@ -3479,6 +3541,11 @@ class ServingEngine:
             fetched = ([] if decode_part is None else
                        [np.asarray(x) for x in
                         decode_part[:_INFLIGHT_DEVICE_ARRAYS[kind]]])
+            routing, rows, passes = self._routing_inflight
+            routing = [np.asarray(x) for x in routing]
+        self._routing_inflight = ([], 0, 0)
+        if routing:
+            self._observe_routing(routing[0], rows, passes)
         if prefill_part is not None:
             self._finish_prefill(prefill_part[0], first)
         if decode_part is not None:
@@ -3519,6 +3586,22 @@ class ServingEngine:
                 _, slots, budgets = decode_part
                 self._accept_decode(slots, fetched[0], budgets)
         return True
+
+    def _observe_routing(self, counts, rows: int, passes: int) -> None:
+        """One routed dispatch's counts into the counters and a
+        ``kubeshare.engine.routing`` span (its attributes are what a
+        trace's reader can reach; its length is this bookkeeping's).
+        ``rows`` are the rows the dispatch's passes carried, padded and
+        inactive ones too — each went through the router and chose."""
+        held, zero, absent, touched = (int(c) for c in counts)
+        with profiling.span("kubeshare.engine.routing", rows=rows,
+                            passes=passes, held=held, zero=zero,
+                            absent=absent, touched=touched):
+            self.moe_assignments["held"] += held
+            self.moe_assignments["zero"] += zero
+            self.moe_assignments["absent"] += absent
+            self.moe_experts_touched += touched
+            self.moe_passes += passes
 
     def _finish_prefill(self, slot: _Slot, first: int) -> None:
         # prompt fully cached: join the decode pool with the fused

@@ -508,6 +508,11 @@ class ReplicaFleet:
 
         self.shared_tier: Optional[HostTier] = None
         if shared_tier_bytes is not None:
+            from .kv_blocks import require_kv_heads
+
+            require_kv_heads(config, "ReplicaFleet's shared host tier and "
+                             "KV fabric (kv_tier packs K/V head slabs; "
+                             "fabric carries them)")
             if tier_policy not in ("lru", "qos"):
                 raise ValueError(
                     f"tier_policy must be 'lru' or 'qos', got "

@@ -53,10 +53,75 @@ class QuotaExceeded(BlockExhausted):
 
 
 @dataclass(frozen=True)
+class KVRowLayout:
+    """What ONE token caches in ONE pool layer, as a value: the pool's
+    two arrays are ``[layers, num_blocks, *k_row[:1], block_size,
+    *k_row[1:]]`` and the same with ``v_row``.
+
+    - ``"kv_heads"``: a K and a V ``[kv_heads, head_dim]`` pair a model
+      layer (MHA / MQA / GQA).
+    - ``"latent"``: one latent row an attention SUB-layer, shared by all
+      query heads — ``k`` holds its ``kv_lora_rank`` latent values, ``v``
+      its ``qk_rope_head_dim`` rotary-key values, the head axis 1.  The
+      V rows of ``v_packed`` consecutive layers lie side by side in one
+      row of the V array (``[layers / v_packed, ..., v_packed x
+      width]``): a row of 64 values is half a TPU vector register, and
+      the compiler keeps an array that narrow blocks-minor — it would
+      transpose the whole array into and out of every program.
+
+    Every step program writes rows through ``paged._write_rows`` and
+    reads them through ``paged._layer_views`` whatever the layout; what
+    packs a block for another tier or another pool (``kv_tier``,
+    ``fabric``, ``disagg``) or shards the head axis (``sharded``) still
+    assumes ``"kv_heads"`` and says so (:func:`require_kv_heads`).
+    """
+
+    kind: str
+    layers: int
+    k_row: Tuple[int, int]  # (heads, width)
+    v_row: Tuple[int, int]
+    v_packed: int = 1
+
+    def values_per_row(self) -> int:
+        """Cached values a token, over all pool layers."""
+        return self.layers * (self.k_row[0] * self.k_row[1]
+                              + self.v_row[0] * self.v_row[1])
+
+    def block_shapes(self, block_size: int):
+        """One block's K and V slabs, all layers."""
+        return ((self.layers, self.k_row[0], block_size, self.k_row[1]),
+                (self.layers // self.v_packed, self.v_row[0], block_size,
+                 self.v_row[1] * self.v_packed))
+
+
+def kv_row_layout(config: TransformerConfig) -> KVRowLayout:
+    if config.latent:
+        return KVRowLayout("latent", config.attn_sublayers,
+                           (1, config.kv_lora_rank),
+                           (1, config.qk_rope_head_dim), v_packed=2)
+    row = (config.kv_heads, config.head_dim)
+    return KVRowLayout("kv_heads", config.n_layers, row, row)
+
+
+def require_kv_heads(config: TransformerConfig, who: str) -> None:
+    """Raise for a cache row ``who`` cannot serve yet."""
+    layout = kv_row_layout(config)
+    if layout.kind != "kv_heads":
+        raise ValueError(
+            f"{who} serves the 'kv_heads' row layout only (a K and a V "
+            f"[kv_heads, head_dim] pair a layer); block {config.block!r} "
+            f"caches the {layout.kind!r} layout (one row of "
+            f"{layout.k_row[1]} + {layout.v_row[1]} values a sub-layer, "
+            f"no heads)")
+
+
+@dataclass(frozen=True)
 class PagedKVPool:
     """The static device-side block pool.
 
-    ``k``/``v``: [n_layers, num_blocks, kv_heads, block_size, head_dim]
+    ``k``/``v``: [layers, num_blocks, heads, block_size, width] as the
+    model's :class:`KVRowLayout` says (``[n_layers, num_blocks,
+    kv_heads, block_size, head_dim]`` both, for the dense block)
     — one cache row per (block, offset) pair; a slot's virtual position
     ``p`` lives at block ``table[p // block_size]``, offset
     ``p % block_size``.
@@ -73,8 +138,8 @@ class PagedKVPool:
     def bytes_per_block(self) -> int:
         """HBM cost of one block (K and V, all layers) — the allocation
         granularity the serving docs size against."""
-        n_layers, _, kv_heads, block_size, head_dim = self.k.shape
-        return 2 * n_layers * kv_heads * block_size * head_dim * self.k.dtype.itemsize
+        return sum(x.size // x.shape[1] * x.dtype.itemsize
+                   for x in (self.k, self.v))
 
     def read_block(self, block: int) -> Tuple[np.ndarray, np.ndarray]:
         """Host snapshot of one block's K and V slabs, each
@@ -141,10 +206,8 @@ def init_paged_pool(
             f"num_blocks must be >= 2 (block 0 is reserved scratch), "
             f"got {num_blocks}"
         )
-    shape = (config.n_layers, num_blocks, config.kv_heads, block_size,
-             config.head_dim)
-    k = jnp.zeros(shape, config.dtype)
-    v = jnp.zeros(shape, config.dtype)
+    k, v = (jnp.zeros(shape[:1] + (num_blocks,) + shape[1:], config.dtype)
+            for shape in kv_row_layout(config).block_shapes(block_size))
     if kv_sharding is not None:
         k = jax.device_put(k, kv_sharding)
         v = jax.device_put(v, kv_sharding)

@@ -8,8 +8,9 @@ scattered across pool blocks (kv_blocks.py).  The two entry points here
 keep the dense step's exact math — same projections, same rope, same
 per-query causal band through the SAME :func:`_attend_cached` — and swap
 only the cache plumbing.  That plumbing is two functions over the stacked
-pool ``[n_layers, num_blocks, h_kv, block_size, d]``, which every step
-program receives as its donated arguments: :func:`_write_rows` scatters a
+pool ``[n_layers, num_blocks, h_kv, block_size, d]`` (or, for a block that
+caches a latent row, the layout ``kv_blocks.KVRowLayout`` gives), which
+every step program receives as its donated arguments: :func:`_write_rows` scatters a
 layer's new K/V rows into that buffer itself, and :func:`_layer_views`
 gathers a layer's per-lane views from that layer's window of it through
 the block tables.  No step scatters into a slab cut out of the pool or
@@ -17,7 +18,9 @@ builds a new pool from per-layer pieces, so no program holds a second
 pool: a step moves the rows it writes and what its views read
 (``tests/test_serving.py::TestPoolWrittenInPlace`` pins the structure,
 ``tests/test_chip_compile.py`` what the TPU compiler makes of it).  The
-entry points:
+layers themselves are written once a block kind (:func:`_dense_layers`,
+:func:`_latent_layers`) and run by every step through
+:func:`_run_layers`.  The entry points:
 
 - :func:`paged_prefill_step`: a width-C prompt chunk writing its K/V
   straight into a slot's blocks (no dense staging cache to copy from);
@@ -72,7 +75,9 @@ from ..models.decoding import (
     _check_moe_decodable,
     speculative_acceptance,
 )
-from ..models.transformer import TransformerConfig, _rms_norm
+from ..models.transformer import (TransformerConfig, _rms_norm,
+                                  latent_attend_blocks, latent_layer,
+                                  latent_qkv)
 from ..ops.rope import apply_rope
 from .drafter import ngram_propose_rows
 
@@ -149,10 +154,38 @@ def _write_rows(pool_k, pool_v, layer_idx, blk, off, k, v):
     the whole pool so that heads sit next to rows whenever ``h_kv > 1``
     — two pool-sized copies in and two out, every program (PERF.md,
     PR 25)."""
-    heads = jnp.arange(pool_k.shape[2])
+    if blk.ndim == 2 and blk.shape[1] == 1:
+        # a decode step's one row a lane: the scatter it always was
+        blk, off, k, v = blk[:, 0], off[:, 0], k[:, 0], v[:, 0]
     blk, off = blk[..., None], off[..., None]
-    return (pool_k.at[layer_idx, blk, heads, off, :].set(k),
-            pool_v.at[layer_idx, blk, heads, off, :].set(v))
+    v_layer, lanes = _v_part(pool_k, pool_v, layer_idx)
+    return (pool_k.at[layer_idx, blk, jnp.arange(pool_k.shape[2]), off,
+                      :].set(k),
+            pool_v.at[v_layer, blk, jnp.arange(pool_v.shape[2]), off,
+                      lanes].set(v))
+
+
+def _v_part(pool_k, pool_v, layer_idx):
+    """Where pool layer ``layer_idx``'s V row lies: the V array's layer
+    and the lanes of its row.  The V rows of ``packed`` consecutive
+    layers share one array row (kv_blocks.KVRowLayout ``v_packed``; 1,
+    the whole row, for a K and a V a head)."""
+    packed = pool_k.shape[0] // pool_v.shape[0]
+    if packed == 1:
+        return layer_idx, slice(None)
+    width = pool_v.shape[-1] // packed
+    lo = layer_idx % packed * width
+    return layer_idx // packed, slice(lo, lo + width)
+
+
+# A layer's slab larger than this is not windowed out of the pool before
+# the gather (see _layer_views): it cannot be staged in fast memory, and
+# the window becomes a copy of the whole slab in HBM.  Measured on a v5e
+# (PERF.md, PR 27): 179 MB of latent rows, a decode span of 4 steps 134.7
+# ms with the window and 117.4 without; 44.7 MB of rotary keys, gathered
+# a key block at a time, 75.2 ms with and 66.3 without.  The dense cells'
+# slabs, 33.5 and 17.9 MB, are staged (PR 25).
+STAGED_SLAB_MAX_BYTES = 40 << 20
 
 
 @jax.named_scope("kv_view")
@@ -171,15 +204,20 @@ def _layer_views(pool_k, pool_v, layer_idx, tables):
     blocks from the staged copy.  Indexing layer and table in ONE
     gather (``pool[layer_idx, tables]``) reads the same blocks straight
     from HBM, 4 KB at a time, and is 1.6 times slower a decode step on a
-    v5e (PERF.md, PR 25)."""
+    v5e (PERF.md, PR 25) — but a slab over ``STAGED_SLAB_MAX_BYTES``
+    cannot be staged, its window IS a copy in HBM, and there the one
+    gather (of 16 KB blocks) is the faster."""
     p, t = tables.shape
-    _, _, h_kv, bs, d = pool_k.shape
 
-    def view(pool):
-        return pool[layer_idx][tables].transpose(0, 2, 1, 3, 4).reshape(
-            p, h_kv, t * bs, d)
+    def view(pool, layer):
+        _, blocks, h_kv, bs, d = pool.shape  # K's and V's rows may differ
+        slab_bytes = blocks * h_kv * bs * d * pool.dtype.itemsize
+        gathered = (pool[layer][tables] if slab_bytes <= STAGED_SLAB_MAX_BYTES
+                    else pool[layer, tables])
+        return gathered.transpose(0, 2, 1, 3, 4).reshape(p, h_kv, t * bs, d)
 
-    return view(pool_k), view(pool_v)
+    v_layer, lanes = _v_part(pool_k, pool_v, layer_idx)
+    return view(pool_k, layer_idx), view(pool_v, v_layer)[..., lanes]
 
 
 @jax.named_scope("mlp")
@@ -207,6 +245,110 @@ def _moe_or_mlp(layer, config: TransformerConfig, y):
     return hidden @ layer["mlp"]["w_out"].astype(config.dtype)
 
 
+def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
+                  tables, positions, blk, off, x):
+    """The dense block's layers over ``x`` [B, C, d]: lane b's C rows sit
+    at virtual positions ``positions[b]`` of ``tables[b]`` and are
+    written at ``(blk, off)`` [B, C] first, then attend the lane's whole
+    view under the per-query causal band."""
+    dtype = config.dtype
+    use_rope = config.positional == "rope"
+    for layer_idx, layer in enumerate(params["layers"]):
+        y = _rms_norm(x, layer["norm1"]["scale"])
+        with jax.named_scope("attention"):
+            q = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wq"].astype(dtype))
+            k = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wk"].astype(dtype))
+            v = jnp.einsum("bsd,dhk->bhsk", y,
+                           layer["attn"]["wv"].astype(dtype))
+            if use_rope:
+                q = apply_rope(q, positions)  # [B, C]: per-lane positions
+                k = apply_rope(k, positions)
+        # rows (layer, blk[b,i], :, off[b,i], :) <- k[b, :, i, :]
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
+        with jax.named_scope("attention"):
+            o = _attend_cached(
+                q, view_k, view_v, positions, window=config.attention_window
+            ).astype(dtype)
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
+        y = _rms_norm(x, layer["norm2"]["scale"])
+        x = x + _moe_or_mlp(layer, config, y)
+    return x, pool_k, pool_v, None
+
+
+LATENT_KEY_BLOCK = 512  # view rows a step of the latent attention takes
+
+
+def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
+                   tables, positions, blk, off, x):
+    """The 'latent_shortcut' block's double layers, same contract as
+    :func:`_dense_layers`.  Pool layer ``2 * l + j`` is sub-layer j of
+    layer l; its row is the latent ``c_kv`` (``pool_k``) and the one
+    rotary key (``pool_v``), written and viewed through the same two
+    functions as a K and a V.  Every step attends in the absorbed form,
+    over the latent rows themselves: a decode step could not expand a
+    view (32 lanes x 8192 rows x 64 heads), and a 512-row prefill chunk
+    against an 8192-row view measured 8.75 ms absorbed, 11.26 ms
+    expanded on a v5e (PERF.md, PR 27) — and a key block of the view at
+    a time, through ``_layer_views`` of that part of the table, only as
+    far as the lanes reach, not the whole ``max_request_len`` view at
+    once: a decode span of 4 steps over 32 lanes of 600-3000 rows 66.3
+    against 125.0 ms, a mixed dispatch 117.2 against 237.9.  Also returns
+    the routing counts int32[4] summed over the layers (ops/moe.py
+    shortcut_experts_apply)."""
+    counts = jnp.zeros((4,), jnp.int32)
+    block_size = pool_k.shape[3]
+    # table entries a key block: the most that divide the table's width
+    # and hold no more than LATENT_KEY_BLOCK rows
+    entries = max(e for e in range(1, tables.shape[1] + 1)
+                  if tables.shape[1] % e == 0
+                  and e * block_size <= max(LATENT_KEY_BLOCK, block_size))
+    for layer_idx, layer in enumerate(params["layers"]):
+
+        def attend(j, attn, y):
+            nonlocal pool_k, pool_v
+            sub = 2 * layer_idx + j
+            q_nope, q_rope, c_kv, k_rope = latent_qkv(
+                attn, y, positions, config)
+            pool_k, pool_v = _write_rows(
+                pool_k, pool_v, sub, blk, off,
+                c_kv[:, :, None, :], k_rope[:, :, None, :])
+
+            def view_block(i):
+                part = jax.lax.dynamic_slice_in_dim(
+                    tables, i * entries, entries, axis=1)
+                view_c, view_r = _layer_views(pool_k, pool_v, sub, part)
+                return view_c[:, 0], view_r[:, 0]
+
+            return latent_attend_blocks(
+                attn, q_nope, q_rope, view_block, entries * block_size,
+                positions, config)
+
+        x, layer_counts = latent_layer(layer, x, config, attend)
+        counts = counts + layer_counts
+    return x, pool_k, pool_v, counts
+
+
+_LAYERS = {"dense": _dense_layers, "latent_shortcut": _latent_layers}
+
+
+def _run_layers(params, config: TransformerConfig, *args):
+    """The block's layer loop: the one place a step program's layers
+    run, whatever the step (prefill chunk, decode step, verify chunk)."""
+    return _LAYERS[config.block](params, config, *args)
+
+
+def _with_routing(routing: bool, counts, *outputs):
+    """A step's outputs, with a routed block's routing counts last when
+    the caller asked for them."""
+    return outputs + (counts,) if routing else outputs
+
+
 def paged_prefill_step(
     params,
     config: TransformerConfig,
@@ -217,7 +359,8 @@ def paged_prefill_step(
     active,
     tokens,
     last_rows,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One width-C prefill chunk for P slot lanes at once.
 
     ``tokens`` [P, C] are each lane's chunk at virtual positions
@@ -247,43 +390,18 @@ def paged_prefill_step(
     blk = jnp.where(active[:, None], blk, 0)
     off = positions % bs
     x = params["embed"][tokens].astype(dtype)  # [P, C, d]
-    use_rope = config.positional == "rope"
-    if not use_rope:
+    if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
-
-    for layer_idx, layer in enumerate(params["layers"]):
-        y = _rms_norm(x, layer["norm1"]["scale"])
-        with jax.named_scope("attention"):
-            q = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wq"].astype(dtype))
-            k = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wk"].astype(dtype))
-            v = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wv"].astype(dtype))
-            if use_rope:
-                q = apply_rope(q, positions)  # [P, C]: per-lane positions
-                k = apply_rope(k, positions)
-        # rows (layer, blk[p,i], :, off[p,i], :) <- k[p, :, i, :]
-        pool_k, pool_v = _write_rows(
-            pool_k, pool_v, layer_idx, blk, off,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
-        with jax.named_scope("attention"):
-            o = _attend_cached(
-                q, view_k, view_v, positions, window=config.attention_window
-            ).astype(dtype)
-            x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                               layer["attn"]["wo"].astype(dtype))
-        y = _rms_norm(x, layer["norm2"]["scale"])
-        x = x + _moe_or_mlp(layer, config, y)
+    x, pool_k, pool_v, counts = _run_layers(
+        params, config, pool_k, pool_v, tables, positions, blk, off, x)
 
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["final_norm"]["scale"])
+        x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
         head_in = jnp.take_along_axis(
             x, last_rows[:, None, None], axis=1)  # [P,1,d]
         logits = (head_in
                   @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    return logits[:, 0], pool_k, pool_v
+    return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
 
 
 def paged_decode_step(
@@ -295,7 +413,8 @@ def paged_decode_step(
     lengths,
     active,
     tokens,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One decode token for every slot in the pool at once.
 
     ``tokens`` [S] (this step's input token per slot, 0 for inactive
@@ -314,42 +433,17 @@ def paged_decode_step(
     blk = jnp.where(active, blk, 0)
     off = positions % bs
     x = params["embed"][tokens].astype(dtype)[:, None, :]  # [S, 1, d]
-    use_rope = config.positional == "rope"
-    if not use_rope:
+    if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)[:, None, :]
-
-    for layer_idx, layer in enumerate(params["layers"]):
-        y = _rms_norm(x, layer["norm1"]["scale"])
-        with jax.named_scope("attention"):
-            q = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wq"].astype(dtype))
-            k = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wk"].astype(dtype))
-            v = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wv"].astype(dtype))
-            if use_rope:
-                # [S, 1]: every slot rotates by its own position
-                q = apply_rope(q, positions[:, None])
-                k = apply_rope(k, positions[:, None])
-        pool_k, pool_v = _write_rows(
-            pool_k, pool_v, layer_idx, blk, off, k[:, :, 0, :], v[:, :, 0, :])
-        # gather every slot's block list into its virtual view [S,h_kv,V,d]
-        view_k, view_v = _layer_views(
-            pool_k, pool_v, layer_idx, block_tables)
-        with jax.named_scope("attention"):
-            o = _attend_cached(
-                q, view_k, view_v, positions[:, None],
-                window=config.attention_window,
-            ).astype(dtype)
-            x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                               layer["attn"]["wo"].astype(dtype))
-        y = _rms_norm(x, layer["norm2"]["scale"])
-        x = x + _moe_or_mlp(layer, config, y)
+    # every slot a one-row chunk at its own position: the same layer loop
+    x, pool_k, pool_v, counts = _run_layers(
+        params, config, pool_k, pool_v, block_tables, positions[:, None],
+        blk[:, None], off[:, None], x)
 
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["final_norm"]["scale"])
+        x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
         logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    return logits[:, 0], pool_k, pool_v
+    return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
 
 
 def paged_decode_span(
@@ -367,7 +461,8 @@ def paged_decode_span(
     temps,
     keys,
     budgets,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Advance every active lane up to ``span`` tokens in ONE dispatch.
 
     The scan body is EXACTLY :func:`paged_decode_step` plus the
@@ -376,26 +471,31 @@ def paged_decode_span(
     mid-span (budget spent, or EOS sampled) deactivates itself — its
     remaining iterations write to the scratch block and its surplus
     emissions are ignored host-side.  Returns
-    (emitted [span, S], pool_k, pool_v).  ``pick_fn``/``span``/``eos``
-    are trace-time constants (the engine closes over them under jit).
+    (emitted [span, S], pool_k, pool_v), and with ``routing`` the
+    routing counts summed over the span's steps last.
+    ``pick_fn``/``span``/``eos`` are trace-time constants (the engine
+    closes over them under jit).
     """
 
     def body(carry, i):
-        pk, pv, lens, toks, alive = carry
-        logits, pk, pv = paged_decode_step(
-            params, config, pk, pv, tables, lens, alive, toks)
+        pk, pv, lens, toks, alive, *counts = carry
+        logits, pk, pv, *step_counts = paged_decode_step(
+            params, config, pk, pv, tables, lens, alive, toks,
+            routing=routing)
         with jax.named_scope("sample"):
             nxt = pick_fn(logits, temps, keys[:, i])
         lens = lens + alive.astype(jnp.int32)
         cont = alive & (i + 1 < budgets)
         if eos is not None:
             cont = cont & (nxt != eos)
-        return (pk, pv, lens, nxt, cont), nxt
+        counts = [c + s for c, s in zip(counts, step_counts)]
+        return (pk, pv, lens, nxt, cont, *counts), nxt
 
-    carry = (pool_k, pool_v, lengths, tokens, active)
-    (pk, pv, _, _, _), emitted = jax.lax.scan(
+    carry = (pool_k, pool_v, lengths, tokens, active,
+             *([jnp.zeros((4,), jnp.int32)] if routing else []))
+    (pk, pv, _, _, _, *counts), emitted = jax.lax.scan(
         body, carry, jnp.arange(span))
-    return emitted, pk, pv
+    return (emitted, pk, pv, *counts)
 
 
 def _decode_loop_impl(
@@ -571,37 +671,13 @@ def paged_verify_span(
     # match them); clamp the embed gather only — `tokens` itself keeps
     # the -1 sentinel for the acceptance comparison below
     x = params["embed"][jnp.maximum(tokens, 0)].astype(dtype)  # [S, W, d]
-    use_rope = config.positional == "rope"
-    if not use_rope:
+    if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
-
-    for layer_idx, layer in enumerate(params["layers"]):
-        y = _rms_norm(x, layer["norm1"]["scale"])
-        with jax.named_scope("attention"):
-            q = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wq"].astype(dtype))
-            k = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wk"].astype(dtype))
-            v = jnp.einsum("bsd,dhk->bhsk", y,
-                           layer["attn"]["wv"].astype(dtype))
-            if use_rope:
-                q = apply_rope(q, positions)  # [S, W]: per-lane positions
-                k = apply_rope(k, positions)
-        pool_k, pool_v = _write_rows(
-            pool_k, pool_v, layer_idx, blk, off,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
-        with jax.named_scope("attention"):
-            o = _attend_cached(
-                q, view_k, view_v, positions, window=config.attention_window
-            ).astype(dtype)
-            x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                               layer["attn"]["wo"].astype(dtype))
-        y = _rms_norm(x, layer["norm2"]["scale"])
-        x = x + _moe_or_mlp(layer, config, y)
+    x, pool_k, pool_v, _ = _run_layers(
+        params, config, pool_k, pool_v, tables, positions, blk, off, x)
 
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["final_norm"]["scale"])
+        x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
         logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
     # column i's pick is emission-number-identical to a width-1 decode
     # step at that position, so it consumes that emission's key
@@ -950,7 +1026,8 @@ def paged_mixed_step(
     d_temps,
     d_keys,
     d_budgets,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One fused mixed dispatch: a bounded prefill chunk for ONE
     filling slot + a full decode span for every active decode lane.
 
@@ -972,15 +1049,19 @@ def paged_mixed_step(
     scheduler's dispatch order.  Returns
     (p_picked [1], emitted [span, S], pool_k, pool_v); ``p_picked`` is
     meaningful only when the chunk is the prompt's final one (the
-    fused first-token pick, same as the standalone prefill step).
+    fused first-token pick, same as the standalone prefill step); with
+    ``routing`` the routing counts of the chunk and the span, summed,
+    come last.
     """
-    p_logits, pk, pv = paged_prefill_step(
+    p_logits, pk, pv, *p_counts = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
-        jnp.ones_like(p_start, bool), p_tokens, p_last_row)
+        jnp.ones_like(p_start, bool), p_tokens, p_last_row,
+        routing=routing)
     with jax.named_scope("sample"):
         p_picked = pick_fn(p_logits, p_temp, p_key)
-    emitted, pk, pv = paged_decode_span(
+    emitted, pk, pv, *d_counts = paged_decode_span(
         params, config, pick_fn, span, eos, pk, pv,
         d_tables, d_lengths, d_active, d_tokens, d_temps, d_keys,
-        d_budgets)
-    return p_picked, emitted, pk, pv
+        d_budgets, routing=routing)
+    return (p_picked, emitted, pk, pv,
+            *[p + d for p, d in zip(p_counts, d_counts)])

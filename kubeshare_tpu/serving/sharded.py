@@ -410,6 +410,10 @@ class ShardedServingContext:
         long_context_threshold: Optional[int] = None,
         devices=None,
     ) -> None:
+        from .kv_blocks import require_kv_heads
+
+        require_kv_heads(config, "ShardedServingContext (the pool is "
+                         "sharded over its KV-head axis)")
         if mesh_spec.dp != 1 or mesh_spec.ep != 1 or mesh_spec.sp != 1:
             raise ValueError(
                 f"a SINGLE engine shards tensor-parallel only: "

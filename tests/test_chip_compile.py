@@ -29,8 +29,8 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
 from kubeshare_tpu.serving.paged import (  # noqa: E402
-    paged_decode_loop, paged_decode_step, paged_mixed_step,
-    paged_prefill_step)
+    paged_decode_loop, paged_decode_span, paged_decode_step,
+    paged_mixed_step, paged_prefill_step)
 
 V5E_HBM_BYTES = 16 << 30
 
@@ -195,3 +195,77 @@ def test_serving_program_compiles_and_fits(one_chip, case):
     assert resident < V5E_HBM_BYTES, memory
     pool_half = args[1].size * args[1].dtype.itemsize
     assert memory.temp_size_in_bytes < pool_half // 2, memory
+
+
+def _latent_case(kind):
+    """The 'latent_shortcut' block's decode and mixed programs at the
+    published widths of ``chipbench/configs/longcat-flash-chat.json`` (one
+    expert-parallel rank: 10.35 GB of bf16 weights, a 1.5 GiB latent
+    pool), as shapes only, compiled as the engine compiles them."""
+    import json
+
+    from kubeshare_tpu.serving.kv_blocks import kv_row_layout
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "longcat-flash-chat.json")) as f:
+        config_file = json.load(f)
+    tc = dict(config_file["transformer_config"])
+    tc["dtype"] = jnp.dtype(tc["dtype"])
+    config = TransformerConfig(**tc)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, config.dtype),
+        jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), config)))
+    e = config_file["engine"]
+    layout = kv_row_layout(config)
+    num_blocks = e["pool_bytes"] // (
+        layout.values_per_row() * 2 * e["block_size"]) + 1
+    pool_k, pool_v = (
+        jax.ShapeDtypeStruct(shape[:1] + (num_blocks,) + shape[1:],
+                             config.dtype)
+        for shape in layout.block_shapes(e["block_size"]))
+    s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
+    span = 4
+    lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
+             jax.ShapeDtypeStruct((s,), jnp.float32),
+             jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
+    if kind == "decode":
+        fn = lambda w, pk, pv, *rest: paged_decode_span(
+            w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+        return config, fn, (params, pool_k, pool_v, *lanes)
+    fn = lambda w, pk, pv, *rest: paged_mixed_step(
+        w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+    return config, fn, (
+        params, pool_k, pool_v, _i32(1, t), _i32(1),
+        _i32(1, e["prefill_chunk"]), _i32(1),
+        jax.ShapeDtypeStruct((1,), jnp.float32),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_latent_block_program_compiles_and_fits(one_chip, kind):
+    """One expert-parallel rank at the published widths fits the chip with
+    its pool; the pool is written in place (no copy of a pool-shaped
+    array); and the expert layer's work follows the routing: nothing in
+    the program has a row of every lane or chunk row for each of the 16
+    held experts (``rows x 16`` expert rows a layer is what a
+    capacity-pinned dispatch would multiply)."""
+    import re
+
+    config, fn, args = _latent_case(kind)
+    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2))
+    memory = compiled.memory_analysis()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert resident < 15 * 10 ** 9, memory
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in args[1:3])
+    assert memory.alias_size_in_bytes >= pool_bytes, memory
+    text = compiled.as_text()
+    pool_shapes = {",".join(map(str, a.shape)) for a in args[1:3]} \
+        | {",".join(map(str, a.shape[:2] + a.shape[3:])) for a in args[1:3]}
+    for shape in pool_shapes:
+        assert not re.search(rf"bf16\[{shape}\][^ ]* copy\(", text), shape
+    held, d, f = config.held_experts, config.d_model, config.expert_d_ff
+    rows = "|".join(str(a.shape[0] * a.shape[-1]) for a in (args[3], args[5])
+                    if len(a.shape) == 2) + "|32|512|544"
+    assert not re.search(rf"\[{held},({rows}),({d}|{f})\]", text)
