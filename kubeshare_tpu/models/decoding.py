@@ -14,8 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.rope import apply_rope
-from .transformer import (TransformerConfig, _rms_norm, latent_attend,
-                          latent_layer, latent_qkv)
+from .transformer import (TransformerConfig, _rms_norm, attend_key_blocks,
+                          latent_attend, latent_layer, latent_qkv)
 
 
 def _check_moe_decodable(config: TransformerConfig) -> None:
@@ -140,6 +140,36 @@ def _attend_cached(q, cache_k, cache_v, q_positions, window=None):
     scores = jnp.where(valid, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(cache_v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, cache_v)
+    return out.reshape(b, h, cq, d)
+
+
+def _attend_blocks(q, view_block, block_rows: int, kv_heads: int,
+                   q_positions, window=None):
+    """:func:`_attend_cached` over a view that is handed over a block of
+    K = ``block_rows`` rows at a time: ``view_block(i)`` gives rows
+    ``[i * K, (i + 1) * K)`` of every lane's view as (k, v), each
+    [b, ``kv_heads``, K, d]; ``q_positions`` [b, Cq] are per lane.  The
+    blocks are attended through :func:`attend_key_blocks`, only as far as
+    the furthest query reaches, so a step costs what its lanes hold.
+    The query heads are grouped over the shared KV heads without
+    repeating K/V and the probabilities meet V in the served dtype, as
+    in :func:`_attend_cached`: the same numbers, up to the order of the
+    softmax's sums."""
+    b, h, cq, d = q.shape
+    group = h // kv_heads
+    scale = d ** -0.5
+    qg = q.reshape(b, kv_heads, group, cq, d)
+
+    def scores_of(k, _):
+        return jnp.einsum(
+            "bhgqd,bhkd->bhgqk", qg, k).astype(jnp.float32) * scale
+
+    def context_of(weights, _, v):
+        return jnp.einsum("bhgqk,bhkd->bhgqd", weights.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    out = attend_key_blocks(view_block, block_rows, scores_of, context_of,
+                            q_positions, (kv_heads, group), d, window)
     return out.reshape(b, h, cq, d)
 
 

@@ -369,54 +369,99 @@ def latent_attend(attn, q_nope, q_rope, view_c, view_r, positions,
     return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
 
 
+def attend_key_blocks(view_block, block_rows: int, scores_of, context_of,
+                      positions, heads, width: int, window=None):
+    """Causal softmax attention over a view that is handed over a block
+    of K = ``block_rows`` rows at a time — the one running softmax of the
+    paged steps (:func:`latent_attend_blocks`, ``decoding._attend_blocks``).
+
+    ``view_block(i)`` gives rows ``[i * K, (i + 1) * K)`` of every lane's
+    view, as whatever tuple the two closures take: ``scores_of(*view)``
+    the scaled float32 scores [B, *heads, C, K] and
+    ``context_of(weights, *view)`` the float32 product [B, *heads, C,
+    width] of the float32 weights (which it casts to the served dtype
+    first) with the block's values.  Query i of lane b sits at
+    ``positions[b, i]`` and sees rows at or before it (and, with a
+    ``window``, fewer than ``window`` rows back).  Only the blocks that
+    hold a row some query may see are asked for — up to the largest of
+    ``positions`` — and the softmax is carried across them in float32
+    (running maximum, running sum, rescaled context), so a step's
+    attention costs what its lanes hold, not what a lane may hold.
+    Returns the normalised context, float32: the numbers of the whole
+    view at once, up to the order of the sums.
+
+    A block past a lane's reach is an exact no-op for that lane (scores
+    ``-inf``, so the maximum stays, ``keep = exp(0) = 1`` and the weights
+    are 0): a lane's numbers do not depend on how far its neighbours
+    reach.  The running maximum goes through an optimization barrier
+    (see :func:`_latent_softmax`: fused with its broadcast it became a
+    reduce-window as wide as the view, 817 ms a dispatch; PERF.md, PR 27)."""
+    b, c = positions.shape
+    lead = (b, *heads, c)
+    f32 = jnp.float32
+
+    def step(i, carry):
+        top, total, ctx = carry
+        view = view_block(i)
+        scores = scores_of(*view)
+        k_pos = i * block_rows + jnp.arange(block_rows)
+        valid = k_pos[None, None, :] <= positions[:, :, None]  # [B, C, K]
+        if window is not None:
+            valid = valid & (
+                positions[:, :, None] - k_pos[None, None, :] < window)
+        valid = jnp.expand_dims(valid, tuple(range(1, 1 + len(heads))))
+        scores = jnp.where(valid, scores, -jnp.inf)
+        new_top = jax.lax.optimization_barrier(
+            jnp.maximum(top, jnp.max(scores, axis=-1)))
+        weights = jnp.exp(scores - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        total = total * keep + jnp.sum(weights, axis=-1)
+        ctx = ctx * keep[..., None] + context_of(weights, *view)
+        return new_top, total, ctx
+
+    # Without a window block 0 holds row 0, which every query sees: the
+    # maximum is finite from the first step on.  Under a window a leading
+    # block can be wholly masked for a query, and -inf - (-inf) is NaN:
+    # there the maximum starts from a finite floor no score reaches.
+    floor = -jnp.inf if window is None else jnp.finfo(f32).min / 2
+    _, total, ctx = jax.lax.fori_loop(
+        0, jnp.max(positions) // block_rows + 1, step,
+        (jnp.full(lead, floor, f32), jnp.zeros(lead, f32),
+         jnp.zeros((*lead, width), f32)))
+    return ctx / total[..., None]
+
+
 @jax.named_scope("mla")
 def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
                          positions, config: TransformerConfig):
     """:func:`latent_attend` in the absorbed form over a view that is
     handed over a block of K = ``block_rows`` rows at a time:
     ``view_block(i)`` gives rows ``[i * K, (i + 1) * K)`` of every lane's
-    view as (``view_c`` [B, K, kv_lora_rank], ``view_r`` [B, K, rope]).  Only the blocks that hold
-    a row some query may see are asked for — up to the largest of
-    ``positions`` — and the softmax is carried across them (running
-    maximum, running sum, rescaled context), so a step's attention costs
-    what its lanes hold, not what a lane may hold.  Same numbers as the
-    whole view at once, up to the order of the sums."""
+    view as (``view_c`` [B, K, kv_lora_rank], ``view_r`` [B, K, rope]),
+    attended through :func:`attend_key_blocks` as far as the lanes
+    reach.  Same numbers as the whole view at once, up to the order of
+    the sums."""
     dtype = config.dtype
     nope = config.qk_nope_head_dim
     scale = (nope + config.qk_rope_head_dim) ** -0.5
     wukv = attn["wukv"].astype(dtype)
     wuk, wuv = wukv[..., :nope], wukv[..., nope:]
     q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
-    b, h, c, r = q_abs.shape
     f32 = jnp.float32
 
-    def step(i, carry):
-        top, total, ctx = carry
-        view_c, view_r = view_block(i)
-        scores = (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
-                             preferred_element_type=f32)
-                  + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
-                               preferred_element_type=f32)) * scale
-        k_pos = i * block_rows + jnp.arange(block_rows)
-        valid = k_pos[None, None, :] <= positions[:, :, None]  # [B, C, K]
-        scores = jnp.where(valid[:, None], scores, -jnp.inf)
-        # block 0 holds row 0, which every query sees: new_top is finite
-        # from the first step on (the barrier: see _latent_softmax)
-        new_top = jax.lax.optimization_barrier(
-            jnp.maximum(top, jnp.max(scores, axis=-1)))
-        weights = jnp.exp(scores - new_top[..., None])
-        keep = jnp.exp(top - new_top)
-        total = total * keep + jnp.sum(weights, axis=-1)
-        ctx = ctx * keep[..., None] + jnp.einsum(
-            "bhcv,bvr->bhcr", weights.astype(dtype), view_c,
-            preferred_element_type=f32)
-        return new_top, total, ctx
+    def scores_of(view_c, view_r):
+        return (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
+                           preferred_element_type=f32)
+                + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                             preferred_element_type=f32)) * scale
 
-    _, total, ctx = jax.lax.fori_loop(
-        0, jnp.max(positions) // block_rows + 1, step,
-        (jnp.full((b, h, c), -jnp.inf, f32), jnp.zeros((b, h, c), f32),
-         jnp.zeros((b, h, c, r), f32)))
-    ctx = (ctx / total[..., None]).astype(dtype)
+    def context_of(weights, view_c, _):
+        return jnp.einsum("bhcv,bvr->bhcr", weights.astype(dtype), view_c,
+                          preferred_element_type=f32)
+
+    ctx = attend_key_blocks(
+        view_block, block_rows, scores_of, context_of, positions,
+        q_abs.shape[1:2], q_abs.shape[3]).astype(dtype)
     o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
     return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
 

@@ -119,7 +119,7 @@ from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
-from .paged import (paged_copy_block, paged_decode_loop,
+from .paged import (key_block_entries, paged_copy_block, paged_decode_loop,
                     paged_decode_span, paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
                     paged_spec_loop, paged_upload_block,
@@ -813,6 +813,9 @@ class ServingEngine:
             evictor=(self._evict_blocks if self.prefix_index is not None
                      else None))
         self._table_width = -(-ec.max_request_len // ec.block_size)
+        # view rows a step of the blockwise attention takes (paged.py)
+        self._key_block_rows = ec.block_size * key_block_entries(
+            self._table_width, ec.block_size)
         self._slots = [_Slot(i, self._table_width)
                        for i in range(ec.num_slots)]
         # mixed-batching scheduler state: the effective fused-chunk
@@ -959,6 +962,12 @@ class ServingEngine:
             "held": 0, "zero": 0, "absent": 0}
         self.moe_experts_touched = 0
         self.moe_passes = 0
+        # how far the step programs' attention had to go: summed over
+        # planned dispatches, the furthest lane's rows rounded up to key
+        # blocks (what the key-block loop runs over) and the view's
+        # whole width (what a lane may hold)
+        self.view_rows_reached = 0
+        self.view_rows_configured = 0
         self.peak_blocks_in_use = 0
         self.requests_admitted = 0
         self.requests_finished = 0
@@ -2191,8 +2200,18 @@ class ServingEngine:
             "expert-layer passes and layers: the experts whose weights a "
             "pass had to read.", "counter")
         moe_touched.add(dict(plabel), self.moe_experts_touched)
+        view_rows = MetricFamily(
+            "kubeshare_serving_view_rows_total",
+            "View rows a lane of the step programs' attention, summed "
+            "over planned dispatches: reached (the furthest lane's rows "
+            "at launch, rounded up to key blocks: what the attention ran "
+            "over) and configured (max_request_len: what it would run "
+            "over whatever the lanes hold).", "counter")
+        view_rows.add({"kind": "reached", **plabel}, self.view_rows_reached)
+        view_rows.add({"kind": "configured", **plabel},
+                      self.view_rows_configured)
         return [req, blocks, tokens, dispatches, loop_units,
-                moe_assign, moe_touched,
+                moe_assign, moe_touched, view_rows,
                 spec_loop_units, exit_reason, depth_summary, host_s,
                 guard_wait, guard_calls, slow, planner, prefix,
                 hit_tokens, evicted, tier_blocks,
@@ -3000,11 +3019,22 @@ class ServingEngine:
         upload or a copy-on-write."""
         if plan is None:
             attrs = {"kind": "upload" if fn is self._upload_step else "copy",
-                     "lanes": 0, "rows": 0, "chunk": 0}
+                     "lanes": 0, "rows": 0, "chunk": 0, "reach": 0}
         else:
+            # the furthest row any lane holds once the dispatch's first
+            # rows are written: a decode lane's length and its new row,
+            # the chunk's end
+            reach = max([s.length + 1 for s in plan.decode_slots]
+                        + [sum(plan.chunk[:2]) if plan.chunk else 0])
             attrs = {"kind": plan.kind, "lanes": len(plan.decode_slots),
                      "rows": sum(s.length for s in plan.decode_slots),
-                     "chunk": plan.chunk[1] if plan.chunk else 0}
+                     "chunk": plan.chunk[1] if plan.chunk else 0,
+                     "reach": reach}
+            # whole key blocks, which divide the view
+            self.view_rows_reached += (
+                -(-reach // self._key_block_rows) * self._key_block_rows)
+            self.view_rows_configured += (
+                self._table_width * self.engine_config.block_size)
         with profiling.span("kubeshare.engine.launch", **attrs) as launch:
             out = fn(*args)
         if plan is not None and plan.prefill_slot is not None:

@@ -6,19 +6,32 @@
 property: each slot sits at its OWN length, and its cache rows live
 scattered across pool blocks (kv_blocks.py).  The two entry points here
 keep the dense step's exact math — same projections, same rope, same
-per-query causal band through the SAME :func:`_attend_cached` — and swap
-only the cache plumbing.  That plumbing is two functions over the stacked
+per-query causal band (through the SAME :func:`_attend_cached`, or its
+blockwise twin over a long view) — and swap only the cache plumbing.
+That plumbing is two functions over the stacked
 pool ``[n_layers, num_blocks, h_kv, block_size, d]`` (or, for a block that
 caches a latent row, the layout ``kv_blocks.KVRowLayout`` gives), which
 every step program receives as its donated arguments: :func:`_write_rows` scatters a
-layer's new K/V rows into that buffer itself, and :func:`_layer_views`
+layer's new K/V rows into that buffer itself, and :func:`_layer_reader`
 gathers a layer's per-lane views from that layer's window of it through
 the block tables.  No step scatters into a slab cut out of the pool or
 builds a new pool from per-layer pieces, so no program holds a second
 pool: a step moves the rows it writes and what its views read
 (``tests/test_serving.py::TestPoolWrittenInPlace`` pins the structure,
-``tests/test_chip_compile.py`` what the TPU compiler makes of it).  The
-layers themselves are written once a block kind (:func:`_dense_layers`,
+``tests/test_chip_compile.py`` what the TPU compiler makes of it).
+
+What the views read follows what the lanes HOLD, not what a lane may
+hold: a view longer than one key block (``KEY_BLOCK`` rows) is gathered
+and attended a key block at a time, the softmax carried across the
+blocks, and the loop stops where the furthest lane's last row lies
+(:func:`_attend_view` for the dense block, ``latent_attend_blocks`` for
+the latent one; both carry the one running softmax,
+``models/transformer.attend_key_blocks``).  A view no longer than a key
+block is attended whole, through the dense step's own
+:func:`_attend_cached`.  The engine counts how far each dispatch went
+(``view_rows_reached`` against ``view_rows_configured``).
+
+The layers themselves are written once a block kind (:func:`_dense_layers`,
 :func:`_latent_layers`) and run by every step through
 :func:`_run_layers`.  The entry points:
 
@@ -26,8 +39,9 @@ layers themselves are written once a block kind (:func:`_dense_layers`,
   straight into a slot's blocks (no dense staging cache to copy from);
 - :func:`paged_decode_step`: one token for EVERY active slot at once —
   per-slot positions, scatter-write each slot's K/V into its current
-  block, gather each slot's block list into a [S, h_kv, V, d] view, and
-  attend under per-row causal bands;
+  block, gather each slot's block list into a [S, h_kv, V, d] view (a
+  key block of it at a time when it is long), and attend under per-row
+  causal bands;
 - :func:`paged_decode_span`: the multi-token decode dispatch — a
   ``lax.scan`` of step-identical :func:`paged_decode_step` iterations
   with the engine's token-pick policy between steps (lanes
@@ -65,12 +79,14 @@ ignored host-side.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..models.decoding import (
+    _attend_blocks,
     _attend_cached,
     _check_moe_decodable,
     speculative_acceptance,
@@ -179,7 +195,7 @@ def _v_part(pool_k, pool_v, layer_idx):
 
 
 # A layer's slab larger than this is not windowed out of the pool before
-# the gather (see _layer_views): it cannot be staged in fast memory, and
+# the gather (see _layer_reader): it cannot be staged in fast memory, and
 # the window becomes a copy of the whole slab in HBM.  Measured on a v5e
 # (PERF.md, PR 27): 179 MB of latent rows, a decode span of 4 steps 134.7
 # ms with the window and 117.4 without; 44.7 MB of rotary keys, gathered
@@ -188,11 +204,12 @@ def _v_part(pool_k, pool_v, layer_idx):
 STAGED_SLAB_MAX_BYTES = 40 << 20
 
 
-@jax.named_scope("kv_view")
-def _layer_views(pool_k, pool_v, layer_idx, tables):
-    """Per-lane virtual K/V views of ONE layer of the stacked pool
-    [L, B, h_kv, bs, d], gathered through lane tables [P, T] ->
-    [P, h_kv, T*bs, d]; the head count is the pool's own, so a
+def _layer_reader(pool_k, pool_v, layer_idx, lanes: int, table_width: int):
+    """The read path of ONE layer of the stacked pool [L, B, h_kv, bs, d]
+    for ``lanes`` lanes whose tables are ``table_width`` entries wide:
+    returns ``views(tables [lanes, T]) -> (k, v)``, each lane's virtual
+    view [lanes, h_kv, T*bs, d] gathered through ``T <= table_width`` of
+    its table entries; the head count is the pool's own, so a
     head-sharded pool shard (serving/sharded.py) reads through the same
     function.  The one view construction every paged step attends
     through — a change here is a change to the paged read path, full
@@ -201,23 +218,108 @@ def _layer_views(pool_k, pool_v, layer_idx, tables):
     ``pool[layer_idx]`` is a window at a fixed offset of the donated
     buffer, not a copy in HBM: the TPU compiler stages that layer's
     slab in the chip's fast memory when it fits there and gathers the
-    blocks from the staged copy.  Indexing layer and table in ONE
-    gather (``pool[layer_idx, tables]``) reads the same blocks straight
-    from HBM, 4 KB at a time, and is 1.6 times slower a decode step on a
-    v5e (PERF.md, PR 25) — but a slab over ``STAGED_SLAB_MAX_BYTES``
-    cannot be staged, its window IS a copy in HBM, and there the one
-    gather (of 16 KB blocks) is the faster."""
-    p, t = tables.shape
-
-    def view(pool, layer):
+    blocks from the staged copy.  The window is taken HERE, once a
+    layer, and ``views`` only gathers from it: a caller that asks for
+    the view a key block at a time (:func:`_attend_view`) calls ``views``
+    inside its loop, and a window taken in there is staged again every
+    block.  Indexing layer and table in ONE gather
+    (``pool[layer_idx, tables]``) reads the same blocks straight from
+    HBM, 4 or 8 KB at a time, at a quarter of the bandwidth.  Staging is
+    a pass over the whole slab whatever is read from it, so it is taken
+    only where it can pay: the slab fits (a slab over
+    ``STAGED_SLAB_MAX_BYTES`` cannot be staged, its window IS a copy in
+    HBM) and the lanes' whole views could read as many blocks as the
+    pool has to give — the decode lanes' gathers, not the prefill
+    chunk's one lane, which reads a 256th of a slab a key block.
+    Measured alone on a v5e at the cells' sizes (PERF.md, PR 28; ms, a
+    mixed dispatch of a 256-token chunk and 4 decode steps; 1 B with 2
+    live lanes of 32 reaching 3 key blocks / 32 lanes reaching 6, 3 B
+    with 16 lanes reaching 6 / 1): the whole view at once 39.2 / 39.3,
+    75.8 / 75.8; the window taken inside the loop 51.6 / 77.3, 100.9 /
+    63.5; every gather from HBM 28.9 / 34.3, 71.4 / 52.5; every gather
+    from the slab staged once 33.2 / 34.7, 67.0 / 60.7; this rule 27.3 /
+    27.9, 63.8 / 57.1."""
+    def reader(pool, layer):
         _, blocks, h_kv, bs, d = pool.shape  # K's and V's rows may differ
         slab_bytes = blocks * h_kv * bs * d * pool.dtype.itemsize
-        gathered = (pool[layer][tables] if slab_bytes <= STAGED_SLAB_MAX_BYTES
-                    else pool[layer, tables])
-        return gathered.transpose(0, 2, 1, 3, 4).reshape(p, h_kv, t * bs, d)
+        # block 0 is the scratch block: blocks - 1 can be allocated
+        if (slab_bytes <= STAGED_SLAB_MAX_BYTES
+                and lanes * table_width >= blocks - 1):
+            slab = pool[layer]
 
-    v_layer, lanes = _v_part(pool_k, pool_v, layer_idx)
-    return view(pool_k, layer_idx), view(pool_v, v_layer)[..., lanes]
+            def gather(tables):
+                return slab[tables]
+        else:
+            def gather(tables):
+                return pool[layer, tables]
+
+        @jax.named_scope("kv_view")
+        def view(tables):
+            p, t = tables.shape
+            return gather(tables).transpose(0, 2, 1, 3, 4).reshape(
+                p, h_kv, t * bs, d)
+
+        return view
+
+    v_layer, part = _v_part(pool_k, pool_v, layer_idx)
+    with jax.named_scope("kv_view"):
+        view_k, view_v = reader(pool_k, layer_idx), reader(pool_v, v_layer)
+    return lambda tables: (view_k(tables), view_v(tables)[..., part])
+
+
+def _layer_views(pool_k, pool_v, layer_idx, tables):
+    """Per-lane virtual K/V views of ONE layer through lane tables [P, T]
+    -> [P, h_kv, T*bs, d]: :func:`_layer_reader`, read once."""
+    return _layer_reader(pool_k, pool_v, layer_idx, *tables.shape)(tables)
+
+
+KEY_BLOCK = 512  # view rows a step of the blockwise attention takes
+
+
+def key_block_entries(table_width: int, block_size: int) -> int:
+    """Table entries a key block: the most that divide the table's width
+    and hold no more than ``KEY_BLOCK`` rows."""
+    return max(e for e in range(1, table_width + 1)
+               if table_width % e == 0
+               and e * block_size <= max(KEY_BLOCK, block_size))
+
+
+def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window):
+    """The dense block's attention of ``q`` [B, h, C, d] over each lane's
+    view of pool layer ``layer_idx``, under the per-query causal band.
+
+    A view longer than one key block is attended a key block at a time
+    (``_layer_reader``'s views of that part of the table), as far as the
+    furthest lane reaches: the cells' 4096-row views cost what their
+    lanes hold.  A view no longer than a key block is attended whole — a
+    static shape, not a knob: a one-trip loop buys nothing."""
+    if tables.shape[1] * pool_k.shape[3] <= KEY_BLOCK:
+        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
+        return _attend_cached(q, view_k, view_v, positions, window=window)
+    entries = key_block_entries(tables.shape[1], pool_k.shape[3])
+    return _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables,
+                               positions, entries, window)
+
+
+@functools.partial(jax.jit, static_argnames=("entries", "window"),
+                   inline=True)
+def _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables, positions,
+                        entries, window):
+    """:func:`_attend_view` of a long view, ``entries`` table entries a
+    key block.  Jitted to be traced ONCE for
+    all the layers of a step program (the layer is an argument, and the
+    shapes are the same in every layer) and inlined: the program is what
+    it would be written out a layer, and ``engine.warmup()`` does not
+    trace a key-block loop a layer (30 layers x 20 programs: 16 s of
+    ``setup_s`` at 3 B; PERF.md, PR 28)."""
+    views = _layer_reader(pool_k, pool_v, layer_idx, *tables.shape)
+
+    def view_block(i):
+        return views(jax.lax.dynamic_slice_in_dim(
+            tables, i * entries, entries, axis=1))
+
+    return _attend_blocks(q, view_block, entries * pool_k.shape[3],
+                          pool_k.shape[2], positions, window)
 
 
 @jax.named_scope("mlp")
@@ -249,8 +351,8 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
                   tables, positions, blk, off, x):
     """The dense block's layers over ``x`` [B, C, d]: lane b's C rows sit
     at virtual positions ``positions[b]`` of ``tables[b]`` and are
-    written at ``(blk, off)`` [B, C] first, then attend the lane's whole
-    view under the per-query causal band."""
+    written at ``(blk, off)`` [B, C] first, then attend the lane's view
+    under the per-query causal band (:func:`_attend_view`)."""
     dtype = config.dtype
     use_rope = config.positional == "rope"
     for layer_idx, layer in enumerate(params["layers"]):
@@ -269,19 +371,14 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
         pool_k, pool_v = _write_rows(
             pool_k, pool_v, layer_idx, blk, off,
             k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         with jax.named_scope("attention"):
-            o = _attend_cached(
-                q, view_k, view_v, positions, window=config.attention_window
-            ).astype(dtype)
+            o = _attend_view(q, pool_k, pool_v, layer_idx, tables, positions,
+                             config.attention_window).astype(dtype)
             x = x + jnp.einsum("bhsk,hkd->bsd", o,
                                layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _moe_or_mlp(layer, config, y)
     return x, pool_k, pool_v, None
-
-
-LATENT_KEY_BLOCK = 512  # view rows a step of the latent attention takes
 
 
 def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
@@ -303,11 +400,7 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
     shortcut_experts_apply)."""
     counts = jnp.zeros((4,), jnp.int32)
     block_size = pool_k.shape[3]
-    # table entries a key block: the most that divide the table's width
-    # and hold no more than LATENT_KEY_BLOCK rows
-    entries = max(e for e in range(1, tables.shape[1] + 1)
-                  if tables.shape[1] % e == 0
-                  and e * block_size <= max(LATENT_KEY_BLOCK, block_size))
+    entries = key_block_entries(tables.shape[1], block_size)
     for layer_idx, layer in enumerate(params["layers"]):
 
         def attend(j, attn, y):

@@ -79,9 +79,9 @@ from ..models.decoding import _attend_cached, speculative_acceptance
 from ..models.transformer import TransformerConfig, _rms_norm
 from ..ops.rope import apply_rope
 from ..parallel.mesh import MeshSpec, make_mesh, param_spec_tree, shard_params
-from .paged import (_decode_loop_impl, _layer_views, _moe_or_mlp,
-                    _spec_loop_impl, _write_rows, paged_copy_block,
-                    paged_upload_block)
+from .paged import (_attend_view, _decode_loop_impl, _layer_views,
+                    _moe_or_mlp, _spec_loop_impl, _write_rows,
+                    paged_copy_block, paged_upload_block)
 
 # the paged pool is [n_layers, num_blocks, kv_heads, block_size, head_dim];
 # head-sharding splits axis 2, so every block's rows for a device's KV
@@ -186,11 +186,13 @@ def _chunk_attend(cfg: TransformerConfig, dec: ShardDecision,
     long-context threshold (prefill only), the Ulysses re-shard swaps
     heads for sequence: all_to_all q to [P, H, C/tp, d], gather the KV
     views, attend this device's query rows, and swap back — every step
-    data movement or per-query-row math, so still exact."""
-    view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
+    data movement or per-query-row math, so still exact.  Otherwise
+    the attention is paged's own (``_attend_view``: a long view a key
+    block at a time, as far as the lanes reach)."""
     c = q.shape[2]
     if (dec.attn_sharded and lct is not None and c >= lct
             and c % dec.tp == 0):
+        view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         q_s = lax.all_to_all(q, "tp", split_axis=2, concat_axis=1,
                              tiled=True)
         vk = lax.all_gather(view_k, "tp", axis=1, tiled=True)
@@ -203,9 +205,8 @@ def _chunk_attend(cfg: TransformerConfig, dec: ShardDecision,
         ).astype(cfg.dtype)
         return lax.all_to_all(o_s, "tp", split_axis=1, concat_axis=2,
                               tiled=True)
-    return _attend_cached(
-        q, view_k, view_v, positions, window=cfg.attention_window
-    ).astype(cfg.dtype)
+    return _attend_view(q, pool_k, pool_v, layer_idx, tables, positions,
+                        cfg.attention_window).astype(cfg.dtype)
 
 
 def _ffn(layer, cfg: TransformerConfig, dec: ShardDecision, y):

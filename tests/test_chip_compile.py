@@ -29,7 +29,7 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
 from kubeshare_tpu.serving.paged import (  # noqa: E402
-    paged_decode_loop, paged_decode_span, paged_decode_step,
+    KEY_BLOCK, paged_decode_loop, paged_decode_span, paged_decode_step,
     paged_mixed_step, paged_prefill_step)
 
 V5E_HBM_BYTES = 16 << 30
@@ -197,17 +197,16 @@ def test_serving_program_compiles_and_fits(one_chip, case):
     assert memory.temp_size_in_bytes < pool_half // 2, memory
 
 
-def _latent_case(kind):
-    """The 'latent_shortcut' block's decode and mixed programs at the
-    published widths of ``chipbench/configs/longcat-flash-chat.json`` (one
-    expert-parallel rank: 10.35 GB of bf16 weights, a 1.5 GiB latent
-    pool), as shapes only, compiled as the engine compiles them."""
+def _cell_case(name, kind):
+    """The decode-span or mixed program of ``chipbench/configs/<name>.json``
+    at its published widths, with its pool, as shapes only, compiled as
+    the engine compiles them (a routed block returns its routing counts)."""
     import json
 
     from kubeshare_tpu.serving.kv_blocks import kv_row_layout
 
     with open(os.path.join(REPO, "chipbench", "configs",
-                           "longcat-flash-chat.json")) as f:
+                           name + ".json")) as f:
         config_file = json.load(f)
     tc = dict(config_file["transformer_config"])
     tc["dtype"] = jnp.dtype(tc["dtype"])
@@ -226,15 +225,16 @@ def _latent_case(kind):
         for shape in layout.block_shapes(e["block_size"]))
     s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
     span = 4
+    routing = {"routing": True} if config.latent else {}
     lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
              jax.ShapeDtypeStruct((s,), jnp.float32),
              jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
     if kind == "decode":
         fn = lambda w, pk, pv, *rest: paged_decode_span(
-            w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+            w, config, _greedy_pick, span, None, pk, pv, *rest, **routing)
         return config, fn, (params, pool_k, pool_v, *lanes)
     fn = lambda w, pk, pv, *rest: paged_mixed_step(
-        w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+        w, config, _greedy_pick, span, None, pk, pv, *rest, **routing)
     return config, fn, (
         params, pool_k, pool_v, _i32(1, t), _i32(1),
         _i32(1, e["prefill_chunk"]), _i32(1),
@@ -242,22 +242,17 @@ def _latent_case(kind):
         jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
 
 
-@pytest.mark.parametrize("kind", ["decode", "mixed"])
-def test_latent_block_program_compiles_and_fits(one_chip, kind):
-    """One expert-parallel rank at the published widths fits the chip with
-    its pool; the pool is written in place (no copy of a pool-shaped
-    array); and the expert layer's work follows the routing: nothing in
-    the program has a row of every lane or chunk row for each of the 16
-    held experts (``rows x 16`` expert rows a layer is what a
-    capacity-pinned dispatch would multiply)."""
+def _compiled_in_place(fn, args, sharding, resident_limit):
+    """``fn`` compiled with its pool donated: it fits, the pool is
+    aliased (written in place) and no pool-shaped array is copied.
+    Returns (memory analysis, program text)."""
     import re
 
-    config, fn, args = _latent_case(kind)
-    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2))
+    compiled = _compile(fn, args, sharding, donate_argnums=(1, 2))
     memory = compiled.memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes - memory.alias_size_in_bytes)
-    assert resident < 15 * 10 ** 9, memory
+    assert resident < resident_limit, memory
     pool_bytes = sum(a.size * a.dtype.itemsize for a in args[1:3])
     assert memory.alias_size_in_bytes >= pool_bytes, memory
     text = compiled.as_text()
@@ -265,6 +260,46 @@ def test_latent_block_program_compiles_and_fits(one_chip, kind):
         | {",".join(map(str, a.shape[:2] + a.shape[3:])) for a in args[1:3]}
     for shape in pool_shapes:
         assert not re.search(rf"bf16\[{shape}\][^ ]* copy\(", text), shape
+    return memory, text
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+@pytest.mark.parametrize("name", ["starcoderbase-1b", "starcoder2-3b"])
+def test_dense_cell_program_attends_by_key_block(one_chip, name, kind):
+    """The dense cells' decode-span and mixed programs at their published
+    widths: they fit with the pool written in place, and nothing in them
+    is as long as the view — no scores and no gathered view over
+    ``max_request_len`` (4096) rows, which is what the whole-view
+    attention built for every lane, every layer, whatever the lanes held.
+    The longest thing attended is a key block."""
+    import re
+
+    _, fn, args = _cell_case(name, kind)
+    _, text = _compiled_in_place(fn, args, one_chip, V5E_HBM_BYTES)
+    lanes, table_width = args[-7].shape
+    view_rows = table_width * args[1].shape[3]
+    assert view_rows == 4096
+    assert not re.search(rf"(f32|bf16)\[[0-9,]*\b{view_rows}\b[0-9,]*\]", text)
+    assert re.search(rf"f32\[{lanes},[0-9,]*,{KEY_BLOCK}\]", text)
+
+
+@pytest.mark.parametrize("kind,temporaries", [("decode", 158_880_768),
+                                              ("mixed", 362_003_456)])
+def test_latent_block_program_compiles_and_fits(one_chip, kind,
+                                                temporaries):
+    """One expert-parallel rank at the published widths fits the chip with
+    its pool; the pool is written in place (no copy of a pool-shaped
+    array); and the expert layer's work follows the routing: nothing in
+    the program has a row of every lane or chunk row for each of the 16
+    held experts (``rows x 16`` expert rows a layer is what a
+    capacity-pinned dispatch would multiply).  Its temporaries are to the
+    byte what they were before the dense block shared its running
+    softmax (PR 28)."""
+    import re
+
+    config, fn, args = _cell_case("longcat-flash-chat", kind)
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    assert memory.temp_size_in_bytes == temporaries, memory
     held, d, f = config.held_experts, config.d_model, config.expert_d_ff
     rows = "|".join(str(a.shape[0] * a.shape[-1]) for a in (args[3], args[5])
                     if len(a.shape) == 2) + "|32|512|544"

@@ -128,7 +128,7 @@ class TestAgainstThePlainReference:
         far as the lanes reach (one block at these sizes): in blocks of 8
         rows, five of them by the last row, the logits are the
         reference's still."""
-        monkeypatch.setattr(paged, "LATENT_KEY_BLOCK", 8)
+        monkeypatch.setattr(paged, "KEY_BLOCK", 8)
         params, config = _params(11, jnp.float32), _config("float32")
         served = _served_logits(params, config, tokens, self.PROMPT,
                                 steps=_jitted_steps())
