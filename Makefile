@@ -4,20 +4,7 @@
 #   make native          build tokend/pmgr/client/shim into native/build
 #   make test            run the test suite (CPU mesh)
 #   make chip-smoke      start the whole system once on the TPU (fails without one)
-#   make serve-smoke     continuous-batching serving bench, fast CPU path
-#   make serve-prefix-smoke  prefix-cache on/off serving bench, fast CPU path
-#   make serve-qos-smoke multi-tenant QoS serving bench, fast CPU path
-#   make serve-mixed-smoke  stall-free mixed batching on/off bench, fast CPU path
-#   make serve-tier-smoke   host-RAM KV tier on/off bench, fast CPU path
-#   make serve-spec-smoke   speculative decoding on/off bench, fast CPU path
-#   make serve-disagg-smoke disaggregated prefill/decode bench, fast CPU path
-#   make serve-sharded-smoke tensor-parallel sharded serving bench, fast CPU path
-#   make serve-loop-smoke   device-resident multi-step loop bench, fast CPU path
-#   make serve-loop-v2-smoke  verify-in-loop + admission ring bench, fast CPU path
-#   make serve-fleet-smoke  replica-fleet routing bench, fast CPU path
-#   make serve-autotune-smoke  cost-model autotuner bench, fast CPU path
-#   make serve-chaos-smoke  fault-injection fleet recovery bench, fast CPU path
-#   make serve-fabric-smoke cluster KV fabric cross-process bench, fast CPU path
+#   make rehearse        the benchmark's whole path at a tiny size on the CPU (prints no metric)
 #   make images          build the kubeshare-tpu:latest container image
 #   make image-check     validate everything the Dockerfile needs, sans docker
 #   make e2e-kind        kind-based end-to-end (skips cleanly without kind)
@@ -25,7 +12,7 @@
 IMAGE ?= kubeshare-tpu:latest
 DOCKER ?= $(shell command -v docker || command -v podman)
 
-.PHONY: all native test chip-smoke serve-smoke serve-prefix-smoke serve-qos-smoke serve-mixed-smoke serve-tier-smoke serve-spec-smoke serve-disagg-smoke serve-sharded-smoke serve-loop-smoke serve-loop-v2-smoke serve-fleet-smoke serve-autotune-smoke serve-chaos-smoke serve-fabric-smoke images image-check e2e-kind tsan clean
+.PHONY: all native test chip-smoke rehearse images image-check e2e-kind tsan clean
 
 all: native
 
@@ -43,47 +30,11 @@ test:
 chip-smoke:
 	python3 chip_smoke.py
 
-serve-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --smoke
-
-serve-prefix-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --shared-prefix --smoke
-
-serve-qos-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --multi-tenant --smoke
-
-serve-mixed-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --mixed --smoke
-
-serve-tier-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --tiered --smoke
-
-serve-spec-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --speculative --smoke
-
-serve-disagg-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --disagg --smoke
-
-serve-sharded-smoke:
-	XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --sharded --smoke
-
-serve-loop-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --device-loop --smoke
-
-serve-loop-v2-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --device-loop --speculative --smoke
-
-serve-fleet-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --fleet --smoke
-
-serve-autotune-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --autotune --smoke
-
-serve-chaos-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --chaos --smoke
-
-serve-fabric-smoke:
-	JAX_PLATFORMS=cpu python3 benchmarks/serving_bench.py --fabric --smoke
+# what `python3 -m chipbench.run` does on the chip, end to end, on the CPU:
+# each prints one line with "correct": true and no metric
+rehearse: native
+	JAX_PLATFORMS=cpu python3 -m chipbench.tests.rehearse rate tiny
+	JAX_PLATFORMS=cpu python3 -m chipbench.tests.rehearse backlog tiny_moe
 
 images: image-check
 ifeq ($(strip $(DOCKER)),)

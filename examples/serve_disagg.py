@@ -32,8 +32,8 @@ Run (no TPU needed; the chip is CPU here, the runtime is real):
 
     JAX_PLATFORMS=cpu python -m examples.serve_disagg
 
-`benchmarks/serving_bench.py --disagg` measures disagg-on vs the
-monolithic mixed engine on the long-prefill adversarial mix.
+The split pools have no cell in `BENCHMARK.json` yet: nothing about
+them is measured on the chip (`PERF.md` section 7).
 """
 
 from __future__ import annotations

@@ -28,8 +28,8 @@ Run (no TPU needed; the cluster is in-memory, the engines are real):
 
     JAX_PLATFORMS=cpu python -m examples.serve_fleet
 
-`benchmarks/serving_bench.py --fleet` measures affinity routing vs the
-round-robin control at equal aggregate KV budget.
+Cache-aware routing has no cell in `BENCHMARK.json` yet (`ROADMAP.md`
+2.16): nothing about it is measured on the chip.
 """
 
 from __future__ import annotations

@@ -27,9 +27,9 @@ Run (no TPU needed; the chip is CPU here, the runtime is real):
 
     JAX_PLATFORMS=cpu python -m examples.serve_fractional
 
-`bench.py --suite serve` measures co-tenancy (two decode pods at 0.5
-chip each vs solo); `benchmarks/serving_bench.py` measures continuous
-batching vs the run-to-completion baseline this example used to drive.
+What this path costs on the chip, alone and beside a co-tenant, is
+measured by `python3 -m chipbench.run` (`scb-1b.gen.rate`,
+`scb-1b.gen.shared`; numbers in `PERF.md`); this run prints counts.
 """
 
 from __future__ import annotations

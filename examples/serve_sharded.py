@@ -25,9 +25,9 @@ Run (no TPU needed; a forced 4-device CPU mesh, the runtime is real):
 
     JAX_PLATFORMS=cpu python -m examples.serve_sharded
 
-`benchmarks/serving_bench.py --sharded` measures the sharded engine
-vs single-device at equal per-device KV budget on the same traffic
-shape.
+On four chips the sharded engine runs in `chip_smoke.py --chips 4`; it
+has no cell in `BENCHMARK.json` yet (`ROADMAP.md` 2.1), so nothing about
+it is measured.
 """
 
 from __future__ import annotations

@@ -120,9 +120,9 @@ class TokenClient:
         same synchronous step loop (never from a runtime callback), so
         the connection can safely park server-side and the handoff is
         event-driven — a released token wakes this waiter immediately
-        instead of at a poll tick (the polling alternative measurably
-        costs the co-run bench on a serial-core host; tokend.cc protocol
-        notes).  Falls back to ``REQ`` polling against an older daemon
+        instead of at a poll tick (the polling alternative cost a two-pod
+        co-run on a serial-core host in an earlier round; tokend.cc
+        protocol notes).  Falls back to ``REQ`` polling against an older daemon
         that answers ``ERR`` for REQB."""
         with span("kubeshare.client.acquire", pod=self.pod_name) as asked:
             return self._acquire(est_ms, asked)

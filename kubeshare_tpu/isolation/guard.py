@@ -161,8 +161,8 @@ class ExecutionGuard:
             # the coming burst: running a full step on a sliver of
             # leftover budget overdraws the grant AND skips the broker's
             # re-arbitration — under exclusive co-tenancy that steals a
-            # whole extra turn from a parked peer (measured ~25% of the
-            # co-run bench's aggregate before this check)
+            # whole extra turn from a parked peer (an earlier round's
+            # two-pod co-run lost ~25% of its aggregate before this check)
             if self._held and self._budget_ms >= 0.5 * self._estimate_ms:
                 return self._budget_ms, False
             if self._held:

@@ -1,7 +1,7 @@
 """MNIST CNN — the north-star workload (BASELINE.md: two 0.5-chip MNIST
 pods co-run on one chip).  The reference schedules PyTorch MNIST pods
-(ref test/mnist/mnist1.yaml); this is the TPU-native equivalent the bench
-and e2e tests run under token gating.
+(ref test/mnist/mnist1.yaml); this is the TPU-native equivalent the e2e
+tests run under token gating.
 
 Functional-pytree style: init returns params, apply is pure — jit/pjit
 compose without a framework dependency.

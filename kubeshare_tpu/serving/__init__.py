@@ -79,7 +79,7 @@ The serving subsystem the fractional-chip runtime was built to host:
   plane: per-consumer interval windows over cumulative counters and
   histogram buckets (``increase()``), quantile estimation
   (``histogram_quantile()``), and snapshot flattening — the one
-  implementation the autoscaler, the autotuner, and the benches all
+  implementation the autoscaler, the autotuner and a scraper all
   diff through;
 - :mod:`autotune` — the cost-model-driven online autotuner: a
   per-dispatch-kind cost model fitted from the engine's own interval

@@ -385,8 +385,8 @@ class AutoTuner:
     ``decisions`` counts every outcome by ``(knob, direction)`` with
     direction in {"up", "down", "rejected"} — exported as
     ``kubeshare_serving_tuner_decisions_total``; ``trajectory`` records
-    each applied change as ``(round, knob, old, new)`` for the bench's
-    knob-trajectory log."""
+    each applied change as ``(round, knob, old, new)``, a log of the
+    knobs' trajectory."""
 
     def __init__(self, knobs: Sequence[Knob], policy: TuningPolicy,
                  read_signals: Callable[[], Tuple[Dict[str, float],

@@ -411,7 +411,7 @@ class DisaggRouter:
     """The disaggregated front end: one :class:`PrefillPool`, one
     :class:`DecodePool`, a :class:`KVMigrator` between them, and a
     submit/step/run surface shaped like ``ServingEngine``'s so callers
-    (bench, examples, tests) swap it in directly.
+    (examples, tests) swap it in directly.
 
     ``prefill_config`` / ``decode_config`` size the two pools
     independently (slots, blocks, host budgets); the fields in

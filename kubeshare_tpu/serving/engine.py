@@ -35,7 +35,7 @@ schedules at TOKEN granularity instead:
   existing prefill bucket, preserving the zero-recompile invariant.
   Streams are bit-exact with ``mixed=False`` (the fused program is a
   composition of the unchanged prefill/decode entry points over
-  disjoint writable blocks — test- and bench-hard-asserted);
+  disjoint writable blocks — hard-asserted by the tests);
 - host/device overlap: dispatches synchronize ONLY when charging an
   ExecutionGuard (token accounting needs measured wall time);
   unguarded, the engine pipelines one step ahead — admission and the
@@ -70,7 +70,7 @@ schedules at TOKEN granularity instead:
 Everything device-side is static-shaped — slot count, block tables,
 chunk widths — so after one warmup pass NOTHING recompiles
 (``compile_counts`` exposes the jit cache sizes; the zero-recompile
-property is test- and bench-asserted).
+property is test-asserted; the benchmark counts a window's compiles).
 
 Fractional-chip integration: every device dispatch (prefill chunk with
 its fused first-token pick, decode span) charges through an
@@ -247,10 +247,10 @@ def plan_prefill_chunks(
 @dataclass(frozen=True)
 class EngineConfig:
     """Static serving-pool geometry.  ``num_slots`` bounds in-flight
-    requests; ``num_blocks``/``block_size`` size the KV pool
-    (HBM = num_blocks x bytes_per_block, sizing guidance in
-    docs/perf.md); ``max_request_len`` bounds prompt + generation per
-    request and fixes the block-table width."""
+    requests; ``num_blocks``/``block_size`` size the KV pool (HBM =
+    num_blocks x bytes_per_block; the cells' sizes: PERF.md section 4);
+    ``max_request_len`` bounds prompt + generation per request and fixes
+    the block-table width."""
 
     num_slots: int = 8
     block_size: int = 16
@@ -283,13 +283,13 @@ class EngineConfig:
     # blocks are indexed and shared with later requests (refcounted,
     # copy-on-write on mid-block divergence, LRU-evicted only when a
     # reservation would otherwise fail).  Output is bit-exact either
-    # way; False buys back nothing but is the bench's control arm.
+    # way; False buys back nothing but is the tests' reference arm.
     prefix_cache: bool = True
     # stall-free mixed batching: when prefill and decode work coexist,
     # fuse ONE bounded prefill chunk into the decode dispatch instead
     # of stalling every decode lane behind the prompt (the either/or
     # Orca discipline's tail-latency cost).  Streams are bit-exact
-    # either way; False is the bench's control arm and restores strict
+    # either way; False is the tests' reference arm and restores strict
     # prefill priority.
     mixed: bool = True
     # KV cache tiering (kv_tier.py): a host-RAM byte budget for demoted
@@ -316,8 +316,8 @@ class EngineConfig:
     # Streams are bit-exact either way.
     disk_tier_bytes: Optional[int] = None
     # arena file path for the disk tier (None = an anonymous unlinked
-    # tempfile).  A named path is what the fabric bench exports across
-    # the process boundary.
+    # tempfile).  A named path is what an exported prefix store reads
+    # across a process boundary (fabric.export_prefix_store).
     disk_tier_path: Optional[str] = None
     # per-step cap on the prefill tokens fused into a mixed dispatch —
     # the bound on the extra latency ANY decode lane (a Guarantee
@@ -337,7 +337,7 @@ class EngineConfig:
     # under that emission's PRNG key), so streams are bit-exact with
     # speculation off BY CONSTRUCTION, greedy and sampled alike, and
     # the per-request key schedule is consumed identically.  False is
-    # the bench's control arm.
+    # the tests' reference arm.
     speculative: bool = False
     # max drafted tokens per lane per verify round.  Must be a power of
     # two: the per-lane ADAPTIVE width (driven by a rolling acceptance
@@ -364,7 +364,7 @@ class EngineConfig:
     # dispatch counts (and the zero-recompile warmup contract) are
     # unchanged by the device count.  Streams are BIT-EXACT with the
     # single-device engine (sharded.py's no-partial-sums construction),
-    # greedy and sampled, so None vs a mesh is the bench's control pair.
+    # greedy and sampled, so None vs a mesh is the tests' reference pair.
     mesh_spec: Optional[MeshSpec] = None
     # route prefill chunks at/above this width through the Ulysses
     # sequence-parallel attention re-shard inside the sharded program
@@ -882,7 +882,7 @@ class ServingEngine:
         # with no decode capacity behind it is a stalled stream, not
         # progress).  None = admit whenever a slot and blocks exist.
         self.admission_gate = None
-        # counters (the bench's and the metrics endpoint's raw material):
+        # counters (the metrics endpoint's raw material, collect_metrics):
         # prefill_chunks / decode_steps / verify_steps count WORK UNITS
         # (chunks processed, spans/verify chunks run — standalone or
         # fused); mixed_steps / mixed_verify_steps count fused
@@ -910,9 +910,9 @@ class ServingEngine:
         # one in-loop draft + width-W verify + acceptance round,
         # absorbed into verify_steps the way loop_units absorb into
         # decode_steps); loop exits by reason; and a realized-fusion-
-        # depth summary (units per launch, BOTH loop kinds) so the
-        # bench reads depth straight off the metrics plane instead of
-        # dividing counters
+        # depth summary (units per launch, BOTH loop kinds) so a reader
+        # of the metrics endpoint (collect_metrics) gets depth directly
+        # instead of dividing counters
         self.spec_loop_launches = 0
         self.spec_loop_units = 0
         self.loop_exit_reasons: Dict[str, int] = {
@@ -926,8 +926,8 @@ class ServingEngine:
         self.last_launch_units = 1
         # host-overhead observability (the device loop's proof plane):
         # wall seconds per scheduling phase of step(), and the number
-        # of planner invocations — the numerator and denominator the
-        # --device-loop bench divides by emitted tokens
+        # of planner invocations — the numerator and denominator a
+        # reader of collect_metrics divides by emitted tokens
         self.host_seconds: Dict[str, float] = {
             "admit": 0.0, "plan": 0.0, "dispatch": 0.0, "consume": 0.0,
             "tune": 0.0}
@@ -1014,7 +1014,7 @@ class ServingEngine:
         # chaos seam (serving/chaos.py): a FaultClock the engine
         # CONSULTS — at the top of step() (replica kill) and inside
         # _dispatch (slow/hung dispatch) — never a monkeypatch.  None
-        # outside chaos runs; the fleet/bench installs it.
+        # outside chaos runs; the fleet (or a chaos test) installs it.
         self.fault_clock = None
         self._ttft_counts = [0] * (len(TTFT_BUCKETS) + 1)  # +Inf tail
         self._ttft_sum = 0.0
@@ -1680,7 +1680,7 @@ class ServingEngine:
         adds one VERIFY shape per reachable draft width (and the fused
         mixed-verify cross product).  After this, a workload of any
         shape runs with ZERO recompilation (compile_counts stays fixed
-        — test- and bench-asserted)."""
+        — test-asserted)."""
         ec = self.engine_config
         # the bucket universe is computed once in __init__ (shared with
         # the autotuner's fused-budget envelope): the configured chunk
@@ -1930,7 +1930,7 @@ class ServingEngine:
             "kubeshare_serving_loop_realized_depth",
             "Realized fusion depth per device-loop launch (span-units "
             "actually executed, both loop kinds) — the direct summary "
-            "serving_bench reads instead of dividing counter "
+            "a scraper reads instead of dividing counter "
             "families.", "summary")
         depth_summary.samples.append(Sample(
             "kubeshare_serving_loop_realized_depth_sum", dict(plabel),
@@ -3174,9 +3174,9 @@ class ServingEngine:
         # ONE lane per prefill dispatch: chunks are already MXU-shaped
         # [width, d] work, so batching lanes buys nothing compute-wise —
         # and a static multi-lane shape would bill every dispatch for
-        # its padded lanes (measured ~2x on the serving bench when most
-        # dispatches carry one mid-flight admission).  The first-token
-        # pick rides fused in the same dispatch.
+        # its padded lanes (~2x in an earlier round's CPU timing; not
+        # measured on the chip).  The first-token pick rides fused in the
+        # same dispatch.
         if chunk is None:
             chunk = slot.plan.pop(0)
         final, table, start, segment, last_row, temp, key = \

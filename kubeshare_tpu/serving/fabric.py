@@ -23,11 +23,11 @@ Pieces:
   the receiver drops the frame and the SENDER's redelivery recovers it;
 - the **transport** (:class:`FabricTransport`): a byte channel moving
   opaque frames.  :class:`LoopbackTransport` is the in-process default
-  (tests, bench, single-host fleets) and the chaos seam's attach point
+  (tests, single-host fleets) and the chaos seam's attach point
   (drop / duplicate / reorder / corrupt in transit);
   :class:`SocketTransport` is the real byte-channel implementation over
-  connected sockets (``socketpair`` in tests, TCP in the cross-process
-  bench) — the same frames, the same envelope, an actual kernel
+  connected sockets (``socketpair`` in tests, TCP across processes)
+  — the same frames, the same envelope, an actual kernel
   boundary;
 - the **endpoint** (:class:`FabricEndpoint`): at-least-once delivery
   over any transport — an outbox with TTL (virtual ticks, the disagg
@@ -609,8 +609,8 @@ class FabricEndpoint:
 def fabric_metric_families(
         endpoints: Iterable[FabricEndpoint]) -> List[MetricFamily]:
     """The fabric's three metric families, summed over ``endpoints`` —
-    one implementation shared by every owner (fleet, disagg router,
-    bench) so the satellite counters can't drift apart."""
+    one implementation shared by every owner (fleet, disagg router)
+    so the satellite counters can't drift apart."""
     msgs: Dict[Tuple[str, str], int] = {}
     total_bytes = 0
     redeliveries = 0
@@ -700,7 +700,7 @@ class FabricDirectory:
 
 
 # ---------------------------------------------------------------------------
-# cross-process prefix store (the bench's process boundary)
+# cross-process prefix store (a real process boundary)
 
 _STORE_MAGIC = b"KVPS"
 _STORE_HEADER = struct.Struct("<4sHHI")  # magic, version, reserved, count
@@ -779,7 +779,7 @@ def serve_prefix_store(path: str, port: int = 0,
     stdout (the parent reads it), accepts ONE connection, then answers
     K_FETCH(key) with K_RESP(packed chain | empty) until EOF (or
     ``max_requests``).  Runs on a plain Python + numpy footprint — no
-    jax anywhere on the import path, so the bench's cross-process
+    jax anywhere on the import path, so a cross-process
     server is genuinely another process serving bytes, not a second
     accelerator runtime."""
     store = load_prefix_store(path)

@@ -15,7 +15,7 @@ ideas, composed:
   depth).  Affinity never wins over QoS: a Guarantee request whose
   affinity target would queue it spills to a replica with a free slot,
   and any request spills off a saturated target.  Policies are
-  pluggable (:class:`RoutingPolicy`); the bench's control arm is
+  pluggable (:class:`RoutingPolicy`); the tests' reference arm is
   :class:`RoundRobinPolicy`.
 
 - **Drain-then-retire with cache inheritance.**  :meth:`drain` stops
@@ -50,7 +50,7 @@ composition, not special cases.
 Streams stay BIT-EXACT with one monolithic engine at equal aggregate
 KV budget: a stream is deterministic in (prompt, budget, temperature,
 rng) regardless of which replica runs it or how scheduling interleaves
-— test- and bench-hard-asserted.  Zero recompiles per replica after
+— hard-asserted by the tests.  Zero recompiles per replica after
 warmup, same invariant as everywhere else in the serving stack.
 """
 
@@ -286,7 +286,7 @@ class PrefixAffinityPolicy(RoutingPolicy):
 
 
 class RoundRobinPolicy(RoutingPolicy):
-    """Cache-blind rotation over the active set — the bench's control
+    """Cache-blind rotation over the active set — the tests' reference
     arm: whatever prefix-skip rate this achieves is what replica
     placement gives you for free, and the affinity policy's margin over
     it is the router's whole contribution."""
@@ -490,12 +490,13 @@ class ReplicaFleet:
         self.watchdog_grace = watchdog_grace
         self.replica_failures: Dict[str, int] = {}
         self.salvaged_tokens = 0
-        # denominator for the bench's salvage rate: tokens of every
+        # denominator of a salvage rate (no reader since the CPU bench
+        # went, PR 29; ROADMAP 3.7): tokens of every
         # host-resident node a dead replica HELD (salvageable in
         # principle), whether or not a survivor adopted it
         self.salvage_candidate_tokens = 0
-        # exact recovery latencies (the histogram buckets coarsen;
-        # the chaos bench reports true p50/p95 from these)
+        # exact recovery latencies (the histogram buckets coarsen; no
+        # reader since the CPU bench went, PR 29; ROADMAP 3.7)
         self.recovery_durations: List[float] = []
         self.orphans_readmitted = 0
         self._recovery_counts = [0] * (len(RECOVERY_BUCKETS) + 1)

@@ -2,8 +2,8 @@
 
 Every consumer that reasons about the engine's Prometheus-shaped
 families — the fleet autoscaler diffing TTFT histogram intervals, the
-online autotuner diffing dispatch counters between ticks, the benches
-computing quantiles from a scraped snapshot — needs the same three
+online autotuner diffing dispatch counters between ticks, a scraper
+computing quantiles from a snapshot — needs the same three
 primitives:
 
 - **interval diffing**: counters and histogram bucket counts are
@@ -19,7 +19,7 @@ primitives:
 This module owns those primitives. It deliberately imports nothing from
 :mod:`engine` (or anywhere else in the serving package): bucket bounds
 are always explicit parameters, and the windows operate on plain lists
-and dicts, so the tuner/autoscaler/bench layers can all depend on it
+and dicts, so the tuner, the autoscaler and a scraper can all depend on it
 without import cycles.
 """
 
@@ -141,7 +141,7 @@ def hist_quantile(buckets, q: float):
     """Interpolated quantile from :func:`metric_histogram` buckets.
 
     Linear interpolation inside the bucket the rank falls in (the
-    smoother bench-side convention); an observation in the ``+Inf`` tail
+    smoother convention); an observation in the ``+Inf`` tail
     reports the highest finite bound.  Returns ``None`` on an empty
     histogram.
     """

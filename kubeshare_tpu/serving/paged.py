@@ -472,8 +472,9 @@ def paged_prefill_step(
     Inactive lanes write to the scratch block and compute garbage the
     caller ignores.  NOTE: the engine deliberately dispatches P=1 (one
     lane per chunk) — a static multi-lane shape bills every dispatch
-    for its padded lanes, measured ~2x worse on the serving bench; see
-    engine._run_prefill_chunk before batching lanes here.
+    for its padded lanes (~2x worse in an earlier round's CPU timing, not
+    measured on the chip); see engine._run_prefill_chunk before batching
+    lanes here.
     """
     dtype = config.dtype
     chunk = tokens.shape[1]

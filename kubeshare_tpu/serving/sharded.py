@@ -680,7 +680,7 @@ class ShardedServingContext:
         return total
 
     def describe(self) -> Dict[str, object]:
-        """Human/bench-facing summary (the example script prints it)."""
+        """Human-facing summary (the example script prints it)."""
         dec = self.decision
         return {
             "tp": self.tp,

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (chip_smoke.py, bench.py, the benchmark
-scripts, the serving examples, tests/conftest.py): the cache is placed
+One rule for every entry point (chip_smoke.py, chipbench/run.py, the
+benchmark scripts, the serving examples, tests/conftest.py): the cache is placed
 from OUTSIDE.  When ``JAX_COMPILATION_CACHE_DIR`` is in the environment
 JAX reads it itself and nothing here touches the config; otherwise the
 cache goes to one fixed directory under the checkout.  The path is part

@@ -16,6 +16,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 from kubeshare_tpu.utils.compile_cache import (  # noqa: E402
     configure_compile_cache)
@@ -36,8 +37,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "serving: continuous-batching serving engine suite (tier-1; "
-        "kept fast — heavyweight captures live in benchmarks/"
-        "serving_bench.py)",
+        "kept fast — what a cell costs on the chip is chipbench's to "
+        "measure, PERF.md)",
     )
     config.addinivalue_line(
         "markers",
@@ -47,8 +48,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos: fault-injection suite (serving/chaos.py seams; "
-        "deterministic — virtual clocks, seeded faults; the heavyweight "
-        "chaos capture lives in benchmarks/serving_bench.py --chaos)",
+        "deterministic — virtual clocks, seeded faults; counts reach the "
+        "metrics endpoint, ServingEngine.collect_metrics)",
     )
 
 
@@ -121,3 +122,39 @@ def _build_native() -> None:
 
 
 _build_native()
+
+
+@pytest.fixture
+def chipbench_apart(monkeypatch, tmp_path):
+    """For the collectors of ``chipbench/tests``, whose whole-window cases
+    stand the system up (``chipbench.run.Session``: scheduler, configd,
+    tokend, one pmgr a pod).  A session empties ``run.STATE_DIR`` and its
+    pods listen on the scheduler's first ports, so two sessions on one
+    host (two xdist workers, or ``test_e2e.py`` beside one) would take each
+    other's files and ports: each test gets a state directory of its own
+    and each worker a port range of its own.  What a session sets for the
+    whole process (pod A's HBM share in the environment, every compile to
+    the persistent cache) is put back for the tests that follow."""
+    from chipbench import run
+    from kubeshare_tpu import constants
+
+    worker = int(os.environ.get("PYTEST_XDIST_WORKER", "gw0")[2:])
+    monkeypatch.setattr(run, "STATE_DIR", str(tmp_path / "state"))
+    monkeypatch.setattr(
+        constants, "POD_MANAGER_PORT_START",
+        constants.POD_MANAGER_PORT_START
+        + (1 + worker) * constants.POD_MANAGER_PORT_POOL)
+    environ = {var: os.environ.get(var) for var in (
+        constants.ENV_MEM_FRACTION, "XLA_PYTHON_CLIENT_MEM_FRACTION",
+        "XLA_PYTHON_CLIENT_PREALLOCATE")}
+    config = {name: getattr(jax.config, name) for name in (
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_max_size")}
+    yield
+    for var, value in environ.items():
+        if value is None:
+            os.environ.pop(var, None)
+        else:
+            os.environ[var] = value
+    for name, value in config.items():
+        jax.config.update(name, value)
