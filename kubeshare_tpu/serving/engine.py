@@ -119,8 +119,8 @@ from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
-from .paged import (key_block_entries, paged_copy_block, paged_decode_loop,
-                    paged_decode_span, paged_mixed_step,
+from .paged import (attend_path, key_block_entries, paged_copy_block,
+                    paged_decode_loop, paged_decode_span, paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
                     paged_spec_loop, paged_upload_block,
                     paged_verify_span)
@@ -964,10 +964,12 @@ class ServingEngine:
         self.moe_passes = 0
         # how far the step programs' attention had to go: summed over
         # planned dispatches, the furthest lane's rows rounded up to key
-        # blocks (what the key-block loop runs over) and the view's
-        # whole width (what a lane may hold)
+        # blocks (what the key-block loop runs over), the view's whole
+        # width (what a lane may hold) and the decode lanes' own rows
+        # (what the paged kernel reads)
         self.view_rows_reached = 0
         self.view_rows_configured = 0
+        self.view_rows_held = 0
         self.peak_blocks_in_use = 0
         self.requests_admitted = 0
         self.requests_finished = 0
@@ -2205,11 +2207,14 @@ class ServingEngine:
             "View rows a lane of the step programs' attention, summed "
             "over planned dispatches: reached (the furthest lane's rows "
             "at launch, rounded up to key blocks: what the attention ran "
-            "over) and configured (max_request_len: what it would run "
-            "over whatever the lanes hold).", "counter")
+            "over), configured (max_request_len: what it would run "
+            "over whatever the lanes hold) and held (the decode lanes' "
+            "own rows, all of them: what the paged kernel reads).",
+            "counter")
         view_rows.add({"kind": "reached", **plabel}, self.view_rows_reached)
         view_rows.add({"kind": "configured", **plabel},
                       self.view_rows_configured)
+        view_rows.add({"kind": "held", **plabel}, self.view_rows_held)
         return [req, blocks, tokens, dispatches, loop_units,
                 moe_assign, moe_touched, view_rows,
                 spec_loop_units, exit_reason, depth_summary, host_s,
@@ -3019,7 +3024,8 @@ class ServingEngine:
         upload or a copy-on-write."""
         if plan is None:
             attrs = {"kind": "upload" if fn is self._upload_step else "copy",
-                     "lanes": 0, "rows": 0, "chunk": 0, "reach": 0}
+                     "lanes": 0, "rows": 0, "chunk": 0, "reach": 0,
+                     "attend": ""}
         else:
             # the furthest row any lane holds once the dispatch's first
             # rows are written: a decode lane's length and its new row,
@@ -3029,7 +3035,8 @@ class ServingEngine:
             attrs = {"kind": plan.kind, "lanes": len(plan.decode_slots),
                      "rows": sum(s.length for s in plan.decode_slots),
                      "chunk": plan.chunk[1] if plan.chunk else 0,
-                     "reach": reach}
+                     "reach": reach, "attend": self._attend_of(plan)}
+            self.view_rows_held += attrs["rows"]
             # whole key blocks, which divide the view
             self.view_rows_reached += (
                 -(-reach // self._key_block_rows) * self._key_block_rows)
@@ -3043,6 +3050,20 @@ class ServingEngine:
             if result.first_dispatch_at is None:
                 result.first_dispatch_at = launch.start
         return out, launch
+
+    def _attend_of(self, plan: _StepPlan) -> str:
+        """What ``plan``'s decode lanes' attention runs ("kernel",
+        "blocks" or "whole": ``paged.attend_path``, which the step
+        programs choose by); the chunk's, where no lane decodes."""
+        if plan.kind in ("verify", "mixed_verify"):
+            query_rows = plan.verify_width
+        elif plan.decode_slots:
+            query_rows = 1  # a span is so many one-row steps
+        else:
+            query_rows = plan.chunk[1]
+        config = self.model_config
+        return attend_path(config.block, query_rows, self._table_width,
+                           self.pool.k, self.pool.v, config.head_dim)
 
     def _report_slow_dispatch(self, entered: float, start: float,
                               launch: profiling.span,

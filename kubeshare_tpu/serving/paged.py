@@ -26,10 +26,17 @@ and attended a key block at a time, the softmax carried across the
 blocks, and the loop stops where the furthest lane's last row lies
 (:func:`_attend_view` for the dense block, ``latent_attend_blocks`` for
 the latent one; both carry the one running softmax,
-``models/transformer.attend_key_blocks``).  A view no longer than a key
-block is attended whole, through the dense step's own
-:func:`_attend_cached`.  The engine counts how far each dispatch went
-(``view_rows_reached`` against ``view_rows_configured``).
+``models/transformer.attend_key_blocks``).  Where the dense block's
+queries are one row a lane (the decode step) and the program is built
+for a TPU, a Pallas kernel stands in for that loop
+(``ops/paged_attention``): each lane reads its OWN pages of the pool up
+to its own position, an idle lane none, and no slab is staged
+(:func:`attend_path` makes the choice, from shapes and the backend).  A
+view no longer than a key block is attended whole, through the dense
+step's own :func:`_attend_cached`.  The engine counts how far each
+dispatch went (``view_rows_reached`` against ``view_rows_configured``,
+``view_rows_held`` for the decode lanes' own rows) and names what its
+decode lanes ran on the launch span (``attend``).
 
 The layers themselves are written once a block kind (:func:`_dense_layers`,
 :func:`_latent_layers`) and run by every step through
@@ -94,6 +101,7 @@ from ..models.decoding import (
 from ..models.transformer import (TransformerConfig, _rms_norm,
                                   latent_attend_blocks, latent_layer,
                                   latent_qkv)
+from ..ops.paged_attention import kernel_fits, paged_decode_attention
 from ..ops.rope import apply_rope
 from .drafter import ngram_propose_rows
 
@@ -284,18 +292,56 @@ def key_block_entries(table_width: int, block_size: int) -> int:
                and e * block_size <= max(KEY_BLOCK, block_size))
 
 
+def _kernel_mode():
+    """How the paged kernel (``ops/paged_attention``) can run where this
+    step program is being built: compiled for a TPU, not at all
+    elsewhere.  A test holds the kernel to the loop off the chip by
+    returning "interpret" from here."""
+    return "compiled" if jax.default_backend() == "tpu" else None
+
+
+def attend_path(block: str, query_rows: int, table_width: int, pool_k,
+                pool_v, head_dim: int) -> str:
+    """What a step's queries of ``query_rows`` rows a lane run over views
+    of ``table_width`` table entries, chosen from what the program can
+    see — the view's width, the query's, the pool's shapes, the backend:
+    "whole" (a view no longer than one key block, attended at once),
+    "kernel" (one row a lane of the dense block, where the paged kernel
+    can run: each lane reads its own pages, bounded by its own length) or
+    "blocks" (the key-block loop, as far as the furthest lane reaches).
+    The engine names it on its launch spans (``attend``)."""
+    if block != "dense":
+        return "blocks"  # the latent block's own loop
+    if table_width * pool_k.shape[3] <= KEY_BLOCK:
+        return "whole"
+    if (query_rows == 1 and _kernel_mode()
+            and kernel_fits(pool_k, pool_v, head_dim)):
+        return "kernel"
+    return "blocks"
+
+
 def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window):
     """The dense block's attention of ``q`` [B, h, C, d] over each lane's
     view of pool layer ``layer_idx``, under the per-query causal band.
 
-    A view longer than one key block is attended a key block at a time
-    (``_layer_reader``'s views of that part of the table), as far as the
-    furthest lane reaches: the cells' 4096-row views cost what their
-    lanes hold.  A view no longer than a key block is attended whole — a
-    static shape, not a knob: a one-trip loop buys nothing."""
-    if tables.shape[1] * pool_k.shape[3] <= KEY_BLOCK:
+    A view longer than one key block is attended only as far as its
+    lanes hold rows.  One query row a lane (the decode step) goes through
+    the paged kernel where that can run: each lane reads its OWN pages of
+    the pool, up to its own position, and an idle lane reads none.  Wider
+    queries (the prefill chunk, a verify span), and every step off the
+    TPU, run the key-block loop (``_layer_reader``'s views of that part
+    of the table), as far as the furthest lane reaches.  A view no longer
+    than a key block is attended whole — a static shape, not a knob: a
+    one-trip loop buys nothing."""
+    path = attend_path("dense", q.shape[2], tables.shape[1], pool_k, pool_v,
+                       q.shape[3])
+    if path == "whole":
         view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         return _attend_cached(q, view_k, view_v, positions, window=window)
+    if path == "kernel":
+        return paged_decode_attention(
+            q[:, :, 0], pool_k, pool_v, layer_idx, tables, positions[:, 0],
+            window=window, interpret=_kernel_mode() == "interpret")[:, :, None]
     entries = key_block_entries(tables.shape[1], pool_k.shape[3])
     return _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables,
                                positions, entries, window)

@@ -28,6 +28,7 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
     TransformerConfig, transformer_init)
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
+from kubeshare_tpu.serving import paged  # noqa: E402
 from kubeshare_tpu.serving.paged import (  # noqa: E402
     KEY_BLOCK, paged_decode_loop, paged_decode_span, paged_decode_step,
     paged_mixed_step, paged_prefill_step)
@@ -263,24 +264,45 @@ def _compiled_in_place(fn, args, sharding, resident_limit):
     return memory, text
 
 
+# temporaries of the same programs on the key-block loop over a staged
+# slab (the parent of PR 30, compiled the same way)
+LOOP_TEMPORARIES = {
+    ("starcoderbase-1b", "decode"): 230_034_432,
+    ("starcoderbase-1b", "mixed"): 257_891_840,
+    ("starcoder2-3b", "decode"): 719_114_240,
+    ("starcoder2-3b", "mixed"): 777_756_160,
+}
+
+
 @pytest.mark.parametrize("kind", ["decode", "mixed"])
 @pytest.mark.parametrize("name", ["starcoderbase-1b", "starcoder2-3b"])
-def test_dense_cell_program_attends_by_key_block(one_chip, name, kind):
+def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
+                                                 kind):
     """The dense cells' decode-span and mixed programs at their published
-    widths: they fit with the pool written in place, and nothing in them
-    is as long as the view — no scores and no gathered view over
-    ``max_request_len`` (4096) rows, which is what the whole-view
-    attention built for every lane, every layer, whatever the lanes held.
-    The longest thing attended is a key block."""
+    widths, built as on the chip (the backend is the one thing a
+    described device cannot tell ``_attend_view``): they fit with the
+    pool written in place; the decode lanes attend through the paged
+    kernel, one call a layer, so no layer's slab is staged, no key block
+    is gathered or scored for every lane, and nothing is as long as the
+    view (``max_request_len``, 4096 rows); the chunk's one lane still
+    attends a key block at a time; and the temporaries are no more than
+    the loop's."""
     import re
 
-    _, fn, args = _cell_case(name, kind)
-    _, text = _compiled_in_place(fn, args, one_chip, V5E_HBM_BYTES)
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    config, fn, args = _cell_case(name, kind)
+    memory, text = _compiled_in_place(fn, args, one_chip, V5E_HBM_BYTES)
     lanes, table_width = args[-7].shape
-    view_rows = table_width * args[1].shape[3]
-    assert view_rows == 4096
-    assert not re.search(rf"(f32|bf16)\[[0-9,]*\b{view_rows}\b[0-9,]*\]", text)
-    assert re.search(rf"f32\[{lanes},[0-9,]*,{KEY_BLOCK}\]", text)
+    _, blocks, h_kv, block_size, d = args[1].shape
+    assert table_width * block_size == 4096
+    assert not re.search(r"(f32|bf16)\[[0-9,]*\b4096\b[0-9,]*\]", text)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == config.n_layers
+    assert not re.search(rf"bf16\[{blocks},{h_kv},{block_size},{d}\]", text)
+    assert not re.search(rf"f32\[{lanes},[0-9,]*,{KEY_BLOCK}\]", text)
+    chunk_scores = re.search(rf"f32\[[0-9,]*\b256,{KEY_BLOCK}\]", text)
+    assert bool(chunk_scores) == (kind == "mixed")
+    assert memory.temp_size_in_bytes <= LOOP_TEMPORARIES[name, kind], memory
 
 
 @pytest.mark.parametrize("kind,temporaries", [("decode", 158_880_768),

@@ -200,17 +200,37 @@ def test_block_path_compiles_nothing_after_warmup(key_block):
     assert engine.compile_counts() == baseline
 
 
-@pytest.mark.parametrize("forced", [True, False],
-                         ids=["key_block_8", "whole_view"])
-def test_view_row_counters_read_what_the_lanes_held(monkeypatch, forced):
-    """Every planned dispatch adds the view's width to ``configured`` and
-    its furthest lane's rows, rounded up to key blocks, to ``reached``;
-    the launch span carries the rows themselves.  A view no longer than
-    one key block is attended whole: reached is configured."""
+def _fits_the_kernel():
+    """A model and an engine the paged kernel can read: a head of 128
+    lanes, pages of 8 rows of float32."""
+    config = TransformerConfig(
+        vocab_size=64, d_model=256, n_heads=2, n_kv_heads=1, n_layers=2,
+        d_ff=64, max_seq_len=64, dtype=jnp.float32, attention="reference",
+        positional="rope")
+    params = transformer_init(jax.random.PRNGKey(0), config)
+    return _engine(params, config, block_size=8, num_blocks=25)
+
+
+@pytest.mark.parametrize("attend", ["blocks", "whole", "kernel"],
+                         ids=["key_block_8", "whole_view", "paged_kernel"])
+def test_view_row_counters_read_what_the_lanes_held(monkeypatch, attend):
+    """Every planned dispatch adds the view's width to ``configured``,
+    its furthest lane's rows, rounded up to key blocks, to ``reached``
+    and its decode lanes' own rows to ``held``; the launch span carries
+    the rows themselves and what the decode lanes' attention ran
+    (``attend``).  A view no longer than one key block is attended whole:
+    reached is configured.  Through the paged kernel (interpreted here)
+    the decode lanes read what they hold; a chunk with no lane beside it
+    still runs the key-block loop."""
+    forced = attend != "whole"
     if forced:
         monkeypatch.setattr(paged, "KEY_BLOCK", KEY_BLOCK)
-    params, config = _model("gqa_rope")
-    engine = _engine(params, config)
+    if attend == "kernel":
+        monkeypatch.setattr(paged, "_kernel_mode", lambda: "interpret")
+        engine = _fits_the_kernel()
+    else:
+        params, config = _model("gqa_rope")
+        engine = _engine(params, config)
     since = time.monotonic()
     _streams(engine, _workload(False))
     launches = [r[4] for r in profiling.spans(
@@ -228,8 +248,14 @@ def test_view_row_counters_read_what_the_lanes_held(monkeypatch, forced):
     assert engine.view_rows_reached == sum(
         -(-a["reach"] // block) * block for a in planned)
     assert (engine.view_rows_reached < engine.view_rows_configured) == forced
+    # what the decode lanes ran, and the rows they held
+    assert {a["attend"] for a in planned if a["lanes"]} == {attend}
+    assert {a["attend"] for a in planned if not a["lanes"]} \
+        == {"whole" if attend == "whole" else "blocks"}
+    assert engine.view_rows_held == sum(a["rows"] for a in planned) > 0
     families = {f.name: f for f in engine.collect_metrics()}
     by_kind = {s.labels["kind"]: s.value for s in families[
         "kubeshare_serving_view_rows_total"].samples}
     assert by_kind == {"reached": engine.view_rows_reached,
-                       "configured": engine.view_rows_configured}
+                       "configured": engine.view_rows_configured,
+                       "held": engine.view_rows_held}
