@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..ops.rope import apply_rope
 from .transformer import (TransformerConfig, _rms_norm, attend_key_blocks,
-                          latent_attend, latent_layer, latent_qkv)
+                          latent_attend, latent_layers, latent_qkv)
 
 
 def _check_moe_decodable(config: TransformerConfig) -> None:
@@ -83,9 +83,9 @@ def init_kv_cache(config: TransformerConfig, batch: int) -> Dict:
     """Static [layers x batch x kv_heads x max_seq x head_dim] cache.
 
     Under GQA (``n_kv_heads < n_heads``) the cache — decode's dominant
-    HBM cost — shrinks by the query-group factor.  The
-    'latent_shortcut' block caches one headless row an attention
-    sub-layer instead: ``k`` its latent values, ``v`` its rotary key."""
+    HBM cost — shrinks by the query-group factor.  A latent block
+    caches one headless row an attention sub-layer instead: ``k`` its
+    latent values, ``v`` its rotary key."""
     if config.latent:
         # the head axis stays (1): every cached path reads capacity there
         shape = (config.attn_sublayers, batch, 1, config.max_seq_len)
@@ -175,8 +175,8 @@ def _attend_blocks(q, view_block, block_rows: int, kv_heads: int,
 
 def _latent_chunk_layers(params, config: TransformerConfig, cache: Dict,
                          x, positions):
-    """The 'latent_shortcut' block's layers of a width-C cached step:
-    each sub-layer writes its chunk's latent rows at ``positions`` and
+    """A latent block's layers of a width-C cached step: each
+    sub-layer writes its chunk's latent rows at ``positions`` and
     attends the whole dense cache in the absorbed form — the paged
     steps' math (serving/paged.py) over a lockstep cache."""
     batch, chunk = x.shape[:2]
@@ -184,22 +184,19 @@ def _latent_chunk_layers(params, config: TransformerConfig, cache: Dict,
     positions = jnp.broadcast_to(positions[None, :], (batch, chunk))
     cache_k, cache_v = cache["k"], cache["v"]
 
-    for layer_idx, layer in enumerate(params["layers"]):
+    def attend(sub, attn, y):
+        nonlocal cache_k, cache_v
+        q_nope, q_rope, c_kv, k_rope = latent_qkv(
+            attn, y, positions, config)
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, c_kv[None, :, None], (sub, 0, 0, start, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, k_rope[None, :, None], (sub, 0, 0, start, 0))
+        return latent_attend(attn, q_nope, q_rope, cache_k[sub, :, 0],
+                             cache_v[sub, :, 0], positions, config,
+                             absorbed=True)
 
-        def attend(j, attn, y):
-            nonlocal cache_k, cache_v
-            sub = 2 * layer_idx + j
-            q_nope, q_rope, c_kv, k_rope = latent_qkv(
-                attn, y, positions, config)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, c_kv[None, :, None], (sub, 0, 0, start, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, k_rope[None, :, None], (sub, 0, 0, start, 0))
-            return latent_attend(attn, q_nope, q_rope, cache_k[sub, :, 0],
-                                 cache_v[sub, :, 0], positions, config,
-                                 absorbed=True)
-
-        x, _ = latent_layer(layer, x, config, attend)
+    x, _ = latent_layers(params, x, config, attend)
     return x, {"k": cache_k, "v": cache_v,
                "length": cache["length"] + chunk}
 

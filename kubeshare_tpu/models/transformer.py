@@ -59,46 +59,81 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     # block kind.  "dense": norm -> MHA/MQA/GQA -> norm -> two-matrix
-    # GELU MLP (or a moe_every mixture).  "latent_shortcut": a DOUBLE
-    # layer — two latent (MLA) attentions, two dense gated (SwiGLU)
-    # FFNs of width d_ff, and ONE routed expert layer that reads the
-    # first sub-layer's post-attention norm and is added after the
-    # second sub-layer's FFN (the shortcut).  The fields below describe
-    # it and mean nothing to the dense block.
+    # GELU MLP (or a moe_every mixture).  The two latent kinds share
+    # the latent (MLA) attention, the gated (SwiGLU) FFN and the routed
+    # expert layer, and differ in how a layer is put together.
+    # "latent_shortcut": a DOUBLE layer — two latent attentions, two
+    # dense gated FFNs of width d_ff, and ONE routed expert layer that
+    # reads the first sub-layer's post-attention norm and is added after
+    # the second sub-layer's FFN (the shortcut).  "latent_moe": a SINGLE
+    # layer — one latent attention and one feed-forward, which is a
+    # dense gated FFN of width d_ff in the first first_dense_layers
+    # layers and the routed expert layer beside n_shared_experts
+    # always-on experts in the rest.  The fields below describe them
+    # and mean nothing to the dense block.
     block: str = "dense"
     q_lora_rank: int = 0  # query down-projection rank
     kv_lora_rank: int = 0  # cached latent width
     qk_nope_head_dim: int = 0  # per-head no-position q.k width
     qk_rope_head_dim: int = 0  # rotary q.k width (one key for all heads)
     v_head_dim: int = 0
+    # whether q and the cached latent are multiplied by
+    # sqrt(d_model / their rank) after their projections
+    mla_rank_scaling: bool = True
     # the expert layer: a router over n_routed_experts + n_zero_experts
     # outputs (zero-compute experts return their input), router_top_k
-    # choices a token, softmax scores x routed_scaling_factor, no
-    # capacity, nothing dropped.  This device holds experts_held of the
-    # routed experts from first_expert_held on (None = all) and leaves
-    # out what the others would add (ops/moe.py shortcut_experts_apply)
+    # choices a token, no capacity, nothing dropped.  The router's law
+    # (ops/moe.py router_choices): scores are the softmax over all
+    # outputs or each output's sigmoid; with router_choice_bias a
+    # per-expert bias is added to the scores for the CHOICE only; the
+    # chosen scores are renormalised to sum to one or left as they are,
+    # then multiplied by routed_scaling_factor.  This device holds
+    # experts_held of the routed experts from first_expert_held on
+    # (None = all) and leaves out what the others would add (ops/moe.py
+    # routed_experts_apply)
     n_routed_experts: int = 0
     n_zero_experts: int = 0
     router_top_k: int = 0
     routed_scaling_factor: float = 1.0
+    router_scoring: str = "softmax"  # softmax | sigmoid
+    router_choice_bias: bool = False
+    router_renormalise: bool = False
     expert_d_ff: int = 0
     experts_held: Optional[int] = None
     first_expert_held: int = 0
+    # "latent_moe" only: always-on experts of width expert_d_ff each,
+    # and the leading layers whose feed-forward is dense
+    n_shared_experts: int = 0
+    first_dense_layers: int = 0
 
     def __post_init__(self) -> None:
         _check_block(self)
 
     @property
     def latent(self) -> bool:
-        """The block caches one latent row a sub-layer, not a K and a V
-        a head."""
-        return self.block == "latent_shortcut"
+        """The block caches one latent row an attention sub-layer, not a
+        K and a V a head."""
+        return self.block in _LATENT_SUBLAYERS
 
     @property
     def attn_sublayers(self) -> int:
         """Attention sub-layers, each with a cache row of its own: the
         layers of the KV pool."""
-        return 2 * self.n_layers if self.latent else self.n_layers
+        return _LATENT_SUBLAYERS.get(self.block, 1) * self.n_layers
+
+    @property
+    def expert_layers(self) -> int:
+        """Routed expert layers a forward pass runs (ops/moe.py
+        routed_experts_apply; a ``moe_every`` mixture is not one)."""
+        if not self.latent:
+            return 0
+        return self.n_layers - self.first_dense_layers
+
+    @property
+    def routed(self) -> bool:
+        """The block has a routed expert layer: its step programs return
+        routing counts beside their tokens."""
+        return self.expert_layers > 0
 
     @property
     def held_experts(self) -> int:
@@ -119,33 +154,53 @@ class TransformerConfig:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
 
+# attention sub-layers a layer of each latent kind
+_LATENT_SUBLAYERS = {"latent_shortcut": 2, "latent_moe": 1}
+
+
 def _check_block(config: TransformerConfig) -> None:
-    if config.block not in ("dense", "latent_shortcut"):
+    if config.block != "dense" and not config.latent:
         raise ValueError(
-            f"block must be 'dense' or 'latent_shortcut', got "
-            f"{config.block!r}")
+            f"block must be 'dense', 'latent_shortcut' or 'latent_moe', "
+            f"got {config.block!r}")
     if not config.latent:
         if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
             raise ValueError(
                 "the dense block's rotary base (10000) and norm epsilon "
                 "(1e-6) are fixed; rope_theta and norm_eps are the "
-                "'latent_shortcut' block's")
+                "latent blocks'")
         return
     for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                  "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
                  "router_top_k", "expert_d_ff"):
         if getattr(config, name) < 1:
             raise ValueError(
-                f"block 'latent_shortcut' needs {name} >= 1, got "
+                f"block {config.block!r} needs {name} >= 1, got "
                 f"{getattr(config, name)}")
     if config.qk_rope_head_dim % 2:
         raise ValueError("qk_rope_head_dim must be even")
     if config.moe_every is not None or config.attention_window is not None \
             or config.positional != "rope":
         raise ValueError(
-            "block 'latent_shortcut' takes neither moe_every nor "
-            "attention_window, and positional='rope' (it has no learned "
-            "positions)")
+            f"block {config.block!r} takes neither moe_every nor "
+            f"attention_window, and positional='rope' (it has no learned "
+            f"positions)")
+    if config.router_scoring not in ("softmax", "sigmoid"):
+        raise ValueError(
+            f"router_scoring must be 'softmax' or 'sigmoid', got "
+            f"{config.router_scoring!r}")
+    single = config.block == "latent_moe"
+    if config.n_shared_experts < 0 or not (
+            0 <= config.first_dense_layers <= config.n_layers):
+        raise ValueError(
+            f"n_shared_experts must be >= 0 and first_dense_layers in "
+            f"[0, {config.n_layers}], got {config.n_shared_experts} and "
+            f"{config.first_dense_layers}")
+    if not single and (config.n_shared_experts or config.first_dense_layers):
+        raise ValueError(
+            "n_shared_experts and first_dense_layers are block "
+            "'latent_moe''s: a 'latent_shortcut' double layer has two "
+            "dense FFNs and one expert layer, every layer alike")
     total = config.n_routed_experts + config.n_zero_experts
     if not 1 <= config.router_top_k <= total:
         raise ValueError(
@@ -159,8 +214,10 @@ def _check_block(config: TransformerConfig) -> None:
             f"among the {config.n_routed_experts} routed experts")
 
 
-def _latent_layer_init(keys, config: TransformerConfig, dense) -> Dict:
-    """One double layer of the 'latent_shortcut' block."""
+def _latent_layer_init(keys, config: TransformerConfig, dense,
+                       layer_idx: int) -> Dict:
+    """One layer of a latent block: a 'latent_shortcut' double layer, or
+    a 'latent_moe' single layer (dense or routed, by its place)."""
     d, h, f = config.d_model, config.n_heads, config.d_ff
     qr, kr = config.q_lora_rank, config.kv_lora_rank
     nope, rope, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
@@ -180,21 +237,38 @@ def _latent_layer_init(keys, config: TransformerConfig, dense) -> Dict:
             "wo": dense(next(keys), (h, vd, d), h * vd),
         }
 
-    def ffn():
-        return {"w_gate": dense(next(keys), (d, f), d),
-                "w_up": dense(next(keys), (d, f), d),
-                "w_down": dense(next(keys), (f, d), f)}
+    def ffn(width):
+        return {"w_gate": dense(next(keys), (d, width), d),
+                "w_up": dense(next(keys), (d, width), d),
+                "w_down": dense(next(keys), (width, d), width)}
 
-    return {
-        "attn": [attn(), attn()],
-        "norm_attn": [{"scale": jnp.ones((d,))} for _ in range(2)],
-        "norm_ffn": [{"scale": jnp.ones((d,))} for _ in range(2)],
-        "ffn": [ffn(), ffn()],
-        "moe": {"router": dense(next(keys), (d, outputs), d),
-                "w_gate": dense(next(keys), (e, d, fe), d),
-                "w_up": dense(next(keys), (e, d, fe), d),
-                "w_down": dense(next(keys), (e, fe, d), fe)},
-    }
+    def moe():
+        out = {"router": dense(next(keys), (d, outputs), d),
+               "w_gate": dense(next(keys), (e, d, fe), d),
+               "w_up": dense(next(keys), (e, d, fe), d),
+               "w_down": dense(next(keys), (e, fe, d), fe)}
+        if config.router_choice_bias:
+            # what a load balancer would move; a fresh model's is 0
+            out["bias"] = jnp.zeros((outputs,))
+        return out
+
+    norm = lambda: {"scale": jnp.ones((d,))}
+    if config.block == "latent_shortcut":
+        return {
+            "attn": [attn(), attn()],
+            "norm_attn": [norm(), norm()],
+            "norm_ffn": [norm(), norm()],
+            "ffn": [ffn(f), ffn(f)],
+            "moe": moe(),
+        }
+    layer = {"attn": attn(), "norm_attn": norm(), "norm_ffn": norm()}
+    if layer_idx < config.first_dense_layers:
+        layer["ffn"] = ffn(f)
+        return layer
+    layer["moe"] = moe()
+    if config.n_shared_experts:
+        layer["shared"] = ffn(config.n_shared_experts * fe)
+    return layer
 
 
 def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
@@ -207,8 +281,8 @@ def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
         d = config.d_model
         return {
             "embed": dense(next(keys), (config.vocab_size, d), d),
-            "layers": [_latent_layer_init(keys, config, dense)
-                       for _ in range(config.n_layers)],
+            "layers": [_latent_layer_init(keys, config, dense, i)
+                       for i in range(config.n_layers)],
             "final_norm": {"scale": jnp.ones((d,))},
             "lm_head": dense(next(keys), (d, config.vocab_size), d),
         }
@@ -281,10 +355,12 @@ def _rms_norm(x, scale, eps: float = 1e-6):
 
 
 # ---------------------------------------------------------------------------
-# the 'latent_shortcut' block: latent (MLA) attention, gated FFNs and one
-# shortcut-connected routed expert layer a double layer.  These pieces are
-# shared by the unpaged forward below and the paged step programs
-# (serving/paged.py); only the cache plumbing differs.
+# the latent blocks: latent (MLA) attention, gated FFNs and a routed expert
+# layer, put together as a double layer with a shortcut ('latent_shortcut')
+# or as single layers whose feed-forward is dense or routed by their place
+# ('latent_moe').  These pieces are shared by the unpaged forward below and
+# the paged step programs (serving/paged.py); only the cache plumbing
+# differs.
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("mla")
@@ -292,18 +368,20 @@ def latent_qkv(attn, y, positions, config: TransformerConfig):
     """One sub-layer's projections of ``y`` [B, C, d] at ``positions``
     [B, C]: ``q_nope`` [B, H, C, nope], ``q_rope`` [B, H, C, rope]
     (rotated), and the two parts of the row the sub-layer caches —
-    ``c_kv`` [B, C, kv_lora_rank] (normed and scaled) and ``k_rope``
-    [B, C, rope] (rotated; ONE key for all heads)."""
+    ``c_kv`` [B, C, kv_lora_rank] (normed, and scaled where the model
+    has ``mla_rank_scaling``) and ``k_rope`` [B, C, rope] (rotated; ONE
+    key for all heads)."""
     dtype = config.dtype
     d, eps = config.d_model, config.norm_eps
     kr, nope = config.kv_lora_rank, config.qk_nope_head_dim
     c_q = _rms_norm(y @ attn["wdq"].astype(dtype),
                     attn["q_norm"]["scale"], eps)
     q = jnp.einsum("bcr,rhk->bhck", c_q, attn["wuq"].astype(dtype))
-    q = q * jnp.asarray((d / config.q_lora_rank) ** 0.5, dtype)
     a = y @ attn["wdkv"].astype(dtype)
-    c_kv = _rms_norm(a[..., :kr], attn["kv_norm"]["scale"], eps) \
-        * jnp.asarray((d / kr) ** 0.5, dtype)
+    c_kv = _rms_norm(a[..., :kr], attn["kv_norm"]["scale"], eps)
+    if config.mla_rank_scaling:
+        q = q * jnp.asarray((d / config.q_lora_rank) ** 0.5, dtype)
+        c_kv = c_kv * jnp.asarray((d / kr) ** 0.5, dtype)
     rope = lambda x: apply_rope(x, positions, theta=config.rope_theta,
                                 interleaved=True)
     k_rope = rope(a[:, None, :, kr:])[:, 0]
@@ -466,7 +544,6 @@ def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
     return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
 
 
-@jax.named_scope("ffn")
 def gated_ffn(ffn, y, dtype):
     """SwiGLU: ``(silu(y Wg) * (y Wu)) Wd``."""
     hidden = jax.nn.silu(y @ ffn["w_gate"].astype(dtype)) \
@@ -474,25 +551,27 @@ def gated_ffn(ffn, y, dtype):
     return hidden @ ffn["w_down"].astype(dtype)
 
 
-def shortcut_experts(layer, config: TransformerConfig, y):
-    """The double layer's expert layer over ``y`` [B, C, d] ->
-    (out [B, C, d], routing counts int32[4]: see ops/moe.py)."""
-    from ..ops.moe import shortcut_experts_apply
+def routed_experts(moe, config: TransformerConfig, y, live=None):
+    """A layer's routed experts over ``y`` [B, C, d] -> (out [B, C, d],
+    routing counts int32[6]: see ops/moe.py).  A row that ``live``
+    [B, C] says is dead (an idle lane, a chunk's padding) chooses
+    nothing."""
+    from ..ops.moe import routed_experts_apply
 
     b, c, d = y.shape
-    out, counts = shortcut_experts_apply(
-        layer["moe"], y.reshape(b * c, d),
+    out, counts = routed_experts_apply(
+        moe, y.reshape(b * c, d),
         n_routed=config.n_routed_experts, top_k=config.router_top_k,
         scale=config.routed_scaling_factor,
-        first_held=config.first_expert_held)
+        first_held=config.first_expert_held,
+        scoring=config.router_scoring,
+        renormalise=config.router_renormalise,
+        live=None if live is None else live.reshape(b * c))
     return out.reshape(b, c, d), counts
 
 
-def latent_layer(layer, x, config: TransformerConfig, attend):
-    """One double layer.  ``attend(j, attn_weights, y)`` is sub-layer
-    j's attention of the normed input ``y`` — where the callers differ
-    (the unpaged forward attends its own rows, a paged step writes the
-    cache row and attends the lane's view).  Returns (x, counts)."""
+def _shortcut_layer(layer, x, config: TransformerConfig, attend, live):
+    """One 'latent_shortcut' double layer."""
     dtype, eps = config.dtype, config.norm_eps
     expert_out = counts = None
     for j in range(2):
@@ -502,15 +581,63 @@ def latent_layer(layer, x, config: TransformerConfig, attend):
         if j == 0:
             # the shortcut: routed on the first sub-layer's normed
             # hidden state, added after the second sub-layer's FFN
-            expert_out, counts = shortcut_experts(layer, config, y)
-        x = x + gated_ffn(layer["ffn"][j], y, dtype)
+            expert_out, counts = routed_experts(layer["moe"], config, y,
+                                                live)
+        with jax.named_scope("ffn"):
+            x = x + gated_ffn(layer["ffn"][j], y, dtype)
     return x + expert_out, counts
+
+
+def _single_layer(layer, x, config: TransformerConfig, attend, live):
+    """One 'latent_moe' layer: the attention, then the feed-forward the
+    layer holds — a dense FFN, or the routed experts beside the shared
+    expert, which runs once over every row, outside the routed loop."""
+    dtype, eps = config.dtype, config.norm_eps
+    x = x + attend(0, layer["attn"],
+                   _rms_norm(x, layer["norm_attn"]["scale"], eps))
+    y = _rms_norm(x, layer["norm_ffn"]["scale"], eps)
+    if "moe" not in layer:
+        with jax.named_scope("dense_ffn"):
+            return x + gated_ffn(layer["ffn"], y, dtype), None
+    out, counts = routed_experts(layer["moe"], config, y, live)
+    if "shared" in layer:
+        with jax.named_scope("shared_expert"):
+            out = out + gated_ffn(layer["shared"], y, dtype)
+    return x + out, counts
+
+
+_LATENT_LAYER = {"latent_shortcut": _shortcut_layer,
+                 "latent_moe": _single_layer}
+
+
+def latent_layers(params, x, config: TransformerConfig, attend_row,
+                  live=None):
+    """Every layer of a latent block over ``x`` [B, C, d].
+    ``attend_row(row, attn_weights, y)`` is the attention of the normed
+    input ``y`` by the sub-layer whose cache row is ``row`` (0 ..
+    ``attn_sublayers - 1``) — where the callers differ: the unpaged
+    forward attends its own rows, a cached step writes the row and
+    attends the lane's view.  ``live`` [B, C] says which rows are real
+    (None: all).  Returns (x, routing counts int32[6] summed over the
+    expert layers: ops/moe.py)."""
+    from ..ops.moe import ROUTING_COUNTS
+
+    layer_fn = _LATENT_LAYER[config.block]
+    per_layer = _LATENT_SUBLAYERS[config.block]
+    counts = jnp.zeros((len(ROUTING_COUNTS),), jnp.int32)
+    for i, layer in enumerate(params["layers"]):
+        attend = lambda j, attn, y, first=per_layer * i: attend_row(
+            first + j, attn, y)
+        x, layer_counts = layer_fn(layer, x, config, attend, live)
+        if layer_counts is not None:
+            counts = counts + layer_counts
+    return x, counts
 
 
 def _latent_forward(params, tokens, config: TransformerConfig,
                     apply_head: bool = True):
-    """The unpaged forward of the 'latent_shortcut' block: every
-    sub-layer attends its own rows in the expanded form."""
+    """The unpaged forward of a latent block: every sub-layer attends
+    its own rows in the expanded form."""
     dtype = config.dtype
     b, seq = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
@@ -521,8 +648,7 @@ def _latent_forward(params, tokens, config: TransformerConfig,
         return latent_attend(attn, q_nope, q_rope, c_kv, k_rope, positions,
                              config, absorbed=False)
 
-    for layer in params["layers"]:
-        x, _ = latent_layer(layer, x, config, attend)
+    x, _ = latent_layers(params, x, config, attend)
     x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
     if not apply_head:
         return x, jnp.float32(0.0)
@@ -558,9 +684,9 @@ def _forward(params, tokens, config, attention_fn, pos_offset,
     if config.latent:
         if kv_sink is not None or jnp.ndim(pos_offset) != 0:
             raise ValueError(
-                "block 'latent_shortcut' has no dense-cache or "
-                "sequence-sharded forward: its cache is the paged latent "
-                "pool (serving/paged.py)")
+                f"block {config.block!r} has no dense-cache or "
+                f"sequence-sharded forward: its cache is the paged latent "
+                f"pool (serving/paged.py)")
         return _latent_forward(params, tokens, config, apply_head)
     dtype = config.dtype
     seq = tokens.shape[1]

@@ -233,10 +233,17 @@ def _experts_choose(params, x, tokens, probs, config, capacity):
 
 
 # ---------------------------------------------------------------------------
-# one expert-parallel rank's share of a shortcut-connected expert layer
+# a routed expert layer without capacity: the law of the router, and the
+# share of the layer that THIS device computes
 # ---------------------------------------------------------------------------
 
 EXPERT_TILE = 128  # rows of one grouped-matmul tile, at most
+
+# what routed_experts_apply counts: router choices by where the chosen
+# expert lives (held here, zero-compute, held elsewhere), the held experts
+# that got at least one row, and the tiles its loop ran with their rows
+# (an expert's last tile is padded, so tile_rows >= held)
+ROUTING_COUNTS = ("held", "zero", "absent", "touched", "tiles", "tile_rows")
 
 
 def expert_tile_rows(n: int) -> int:
@@ -248,8 +255,37 @@ def expert_tile_rows(n: int) -> int:
     return min(EXPERT_TILE, max(8, 1 << (max(n, 1) - 1).bit_length()))
 
 
+def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
+                   top_k: int, scale: float, scoring: str = "softmax",
+                   renormalise: bool = False
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The router's law: a row's float32 ``logits`` [n, outputs] ->
+    (``gate`` [n, top_k] float32, ``index`` [n, top_k]).
+
+    A row's scores are the softmax over all its outputs (``scoring``
+    "softmax") or each output's sigmoid ("sigmoid").  It chooses the
+    ``top_k`` largest of ``scores + bias``: the bias (None: none) moves
+    the CHOICE only and never the weight, which is the chosen expert's
+    own score — renormalised over the chosen ones (``renormalise``) or
+    left as it is — times ``scale``."""
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if bias is None:
+        gate, index = jax.lax.top_k(scores, top_k)
+    else:
+        _, index = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(scores, index, axis=-1)
+    if renormalise:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    return gate * scale, index
+
+
 @jax.named_scope("experts")
-def shortcut_experts_apply(
+def routed_experts_apply(
     moe: Dict,
     y: jax.Array,
     *,
@@ -257,19 +293,25 @@ def shortcut_experts_apply(
     top_k: int,
     scale: float,
     first_held: int = 0,
+    scoring: str = "softmax",
+    renormalise: bool = False,
+    live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The part of a routed expert layer that THIS device computes.
 
-    ``y`` [n, d].  The router (``moe["router"]`` [d, n_routed + n_zero])
-    keeps every output: a token's scores are the float32 softmax over all
-    of them, it chooses the ``top_k`` largest, and choice ``e`` weighs
-    ``scale * P_e`` (not renormalised).  Experts ``>= n_routed`` are
-    zero-compute: they return their input, so all of a token's identity
-    choices are ONE weighted add.  Of the routed experts this device
-    holds ``moe["w_gate"].shape[0]`` from ``first_held`` on (SwiGLU,
-    ``w_gate``/``w_up`` [e, d, f], ``w_down`` [e, f, d]); a choice of
-    an expert held elsewhere adds nothing here — its owner adds it, and
-    no code stands in for that exchange.
+    ``y`` [n, d].  The router (``moe["router"]`` [d, n_routed + n_zero],
+    with ``moe["bias"]`` [n_routed + n_zero] where the model has a choice
+    bias) keeps every output, and :func:`router_choices` turns a row's
+    float32 outputs into its ``top_k`` choices and their weights.
+    Experts ``>= n_routed`` are zero-compute: they return their input,
+    so all of a token's identity choices are ONE weighted add.  Of the
+    routed experts this device holds ``moe["w_gate"].shape[0]`` from
+    ``first_held`` on (SwiGLU, ``w_gate``/``w_up`` [e, d, f], ``w_down``
+    [e, f, d]); a choice of an expert held elsewhere adds nothing here —
+    its owner adds it, and no code stands in for that exchange.  A row
+    that ``live`` [n] says is dead (an idle lane, a chunk's padding)
+    chooses nothing: it is in no tile, reads no expert and is in no
+    count, and its output is 0.
 
     Nothing is dropped and nothing is padded to a capacity: the
     assignments to held experts are grouped by expert into tiles of
@@ -280,10 +322,11 @@ def shortcut_experts_apply(
     is not read.  A row's result depends on that row alone: routing is
     per row, and a row's choices are added in expert order.
 
-    Returns (out [n, d] in ``y``'s dtype, counts int32[4]): assignments
-    to held, zero-compute and absent experts (they add up to
-    ``n * top_k``) and the held experts that got at least one row.
-    Forward only (the loop's length is data).
+    Returns (out [n, d] in ``y``'s dtype, counts int32[6] in the order
+    of ``ROUTING_COUNTS``): assignments to held, zero-compute and absent
+    experts (they add up to ``top_k`` times the live rows), the held
+    experts that got at least one row, the tiles the loop ran and their
+    rows, padding included.  Forward only (the loop's length is data).
     """
     n, d = y.shape
     e_held = moe["w_gate"].shape[0]
@@ -292,12 +335,13 @@ def shortcut_experts_apply(
         # float32 product of the values the block holds
         logits = jnp.dot(y, moe["router"].astype(y.dtype),
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, index = jax.lax.top_k(probs, top_k)  # [n, k]
-        gate = gate * scale
+        gate, index = router_choices(
+            logits, moe.get("bias"), top_k=top_k, scale=scale,
+            scoring=scoring, renormalise=renormalise)  # [n, k]
+    chose = jnp.ones((n, 1), bool) if live is None else live[:, None]
     local = index - first_held
-    held = (local >= 0) & (local < e_held) & (index < n_routed)
-    zero = index >= n_routed
+    held = chose & (local >= 0) & (local < e_held) & (index < n_routed)
+    zero = chose & (index >= n_routed)
     y32 = y.astype(jnp.float32)
     out = jnp.sum(jnp.where(zero, gate, 0.0), -1, keepdims=True) * y32
 
@@ -345,11 +389,14 @@ def shortcut_experts_apply(
         return acc.at[token].add(weight[:, None] * result)
 
     acc = jnp.concatenate([out, jnp.zeros((1, d), jnp.float32)])
-    acc = jax.lax.fori_loop(0, tile_end[-1], run_tile, acc)
+    n_tiles = tile_end[-1].astype(jnp.int32)
+    acc = jax.lax.fori_loop(0, n_tiles, run_tile, acc)
     n_held = jnp.sum(held, dtype=jnp.int32)
     n_zero = jnp.sum(zero, dtype=jnp.int32)
-    stats = jnp.stack([n_held, n_zero, a - n_held - n_zero,
-                       jnp.sum(counts > 0, dtype=jnp.int32)])
+    n_chose = jnp.sum(chose, dtype=jnp.int32) * top_k
+    stats = jnp.stack([n_held, n_zero, n_chose - n_held - n_zero,
+                       jnp.sum(counts > 0, dtype=jnp.int32),
+                       n_tiles, n_tiles * tile])
     return acc[:n].astype(dtype), stats
 
 

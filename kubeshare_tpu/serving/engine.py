@@ -953,14 +953,17 @@ class ServingEngine:
         self._spec_accept: Dict[str, list] = {}
         self.tokens_generated = 0
         # a routed block's step programs return their routing counts
-        # (ops/moe.py shortcut_experts_apply), read with the tokens in
+        # (ops/moe.py routed_experts_apply), read with the tokens in
         # _consume_inflight: assignments by where the chosen expert
         # lives, held experts that got a row (summed over passes and
-        # layers), and expert-layer passes (one a chunk, one a decode
-        # step of a span)
+        # layers), the tiles the expert loop ran and their rows
+        # (padding included), and expert-layer passes (one a chunk,
+        # one a decode step of a span)
         self.moe_assignments: Dict[str, int] = {
             "held": 0, "zero": 0, "absent": 0}
         self.moe_experts_touched = 0
+        self.moe_tiles = 0
+        self.moe_tile_rows = 0
         self.moe_passes = 0
         # how far the step programs' attention had to go: summed over
         # planned dispatches, the furthest lane's rows rounded up to key
@@ -1055,7 +1058,7 @@ class ServingEngine:
         # dispatch for its first token.
         sharded = self._sharded
         sharded_prefill = sharded.prefill if sharded is not None else None
-        routed = config.latent
+        routed = config.routed
 
         def prefill(w, pk, pv, tables, starts, active, tokens, last_rows,
                     temps, keys):
@@ -2191,7 +2194,7 @@ class ServingEngine:
             "the chosen expert lives: held (a routed expert this device "
             "holds and computes), zero (a zero-compute identity expert) "
             "or absent (a routed expert another device holds: adds "
-            "nothing here).  Padded and inactive rows choose too.",
+            "nothing here).  A padded or an inactive row chooses none.",
             "counter")
         for kind in sorted(self.moe_assignments):
             moe_assign.add({"kind": kind, **plabel},
@@ -2202,6 +2205,18 @@ class ServingEngine:
             "expert-layer passes and layers: the experts whose weights a "
             "pass had to read.", "counter")
         moe_touched.add(dict(plabel), self.moe_experts_touched)
+        moe_tiles = MetricFamily(
+            "kubeshare_serving_moe_tiles_total",
+            "Tiles the expert loop of a routed block's step programs "
+            "ran: each is one held expert over at most a tile's rows, "
+            "and reads that expert's matrices once.", "counter")
+        moe_tiles.add(dict(plabel), self.moe_tiles)
+        moe_tile_rows = MetricFamily(
+            "kubeshare_serving_moe_tile_rows_total",
+            "Rows of those tiles, an expert's last tile's padding "
+            "included: held assignments over this is how full the tiles "
+            "ran.", "counter")
+        moe_tile_rows.add(dict(plabel), self.moe_tile_rows)
         view_rows = MetricFamily(
             "kubeshare_serving_view_rows_total",
             "View rows a lane of the step programs' attention, summed "
@@ -2216,7 +2231,7 @@ class ServingEngine:
                       self.view_rows_configured)
         view_rows.add({"kind": "held", **plabel}, self.view_rows_held)
         return [req, blocks, tokens, dispatches, loop_units,
-                moe_assign, moe_touched, view_rows,
+                moe_assign, moe_touched, moe_tiles, moe_tile_rows, view_rows,
                 spec_loop_units, exit_reason, depth_summary, host_s,
                 guard_wait, guard_calls, slow, planner, prefix,
                 hit_tokens, evicted, tier_blocks,
@@ -3643,15 +3658,20 @@ class ServingEngine:
         ``kubeshare.engine.routing`` span (its attributes are what a
         trace's reader can reach; its length is this bookkeeping's).
         ``rows`` are the rows the dispatch's passes carried, padded and
-        inactive ones too — each went through the router and chose."""
-        held, zero, absent, touched = (int(c) for c in counts)
+        inactive ones too; ``live`` of them chose (``held + zero +
+        absent`` is ``top_k`` x expert layers x ``live``)."""
+        held, zero, absent, touched, tiles, tile_rows, live = (
+            int(c) for c in counts)
         with profiling.span("kubeshare.engine.routing", rows=rows,
-                            passes=passes, held=held, zero=zero,
-                            absent=absent, touched=touched):
+                            live=live, passes=passes, held=held, zero=zero,
+                            absent=absent, touched=touched, tiles=tiles,
+                            tile_rows=tile_rows):
             self.moe_assignments["held"] += held
             self.moe_assignments["zero"] += zero
             self.moe_assignments["absent"] += absent
             self.moe_experts_touched += touched
+            self.moe_tiles += tiles
+            self.moe_tile_rows += tile_rows
             self.moe_passes += passes
 
     def _finish_prefill(self, slot: _Slot, first: int) -> None:

@@ -64,10 +64,12 @@ class KVRowLayout:
       query heads — ``k`` holds its ``kv_lora_rank`` latent values, ``v``
       its ``qk_rope_head_dim`` rotary-key values, the head axis 1.  The
       V rows of ``v_packed`` consecutive layers lie side by side in one
-      row of the V array (``[layers / v_packed, ..., v_packed x
-      width]``): a row of 64 values is half a TPU vector register, and
-      the compiler keeps an array that narrow blocks-minor — it would
-      transpose the whole array into and out of every program.
+      row of the V array (``[ceil(layers / v_packed), ..., v_packed x
+      width]``; where the count of sub-layers is odd the last row's
+      second half is spare, and counted): a row of 64 values is half a
+      TPU vector register, and the compiler keeps an array that narrow
+      blocks-minor — it would transpose the whole array into and out
+      of every program.
 
     Every step program writes rows through ``paged._write_rows`` and
     reads them through ``paged._layer_views`` whatever the layout; what
@@ -82,20 +84,28 @@ class KVRowLayout:
     v_row: Tuple[int, int]
     v_packed: int = 1
 
+    @property
+    def v_layers(self) -> int:
+        """Layers of the V array: ``v_packed`` pool layers share one."""
+        return -(-self.layers // self.v_packed)
+
     def values_per_row(self) -> int:
-        """Cached values a token, over all pool layers."""
-        return self.layers * (self.k_row[0] * self.k_row[1]
-                              + self.v_row[0] * self.v_row[1])
+        """Values the pool holds a token, over all pool layers (a spare
+        half row of an odd count of packed layers among them)."""
+        return (self.layers * self.k_row[0] * self.k_row[1]
+                + self.v_layers * self.v_packed
+                * self.v_row[0] * self.v_row[1])
 
     def block_shapes(self, block_size: int):
         """One block's K and V slabs, all layers."""
         return ((self.layers, self.k_row[0], block_size, self.k_row[1]),
-                (self.layers // self.v_packed, self.v_row[0], block_size,
+                (self.v_layers, self.v_row[0], block_size,
                  self.v_row[1] * self.v_packed))
 
 
 def kv_row_layout(config: TransformerConfig) -> KVRowLayout:
     if config.latent:
+        # one row an attention sub-layer, as many as the model has
         return KVRowLayout("latent", config.attn_sublayers,
                            (1, config.kv_lora_rank),
                            (1, config.qk_rope_head_dim), v_packed=2)

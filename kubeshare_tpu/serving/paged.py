@@ -87,7 +87,7 @@ ignored host-side.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -99,8 +99,9 @@ from ..models.decoding import (
     speculative_acceptance,
 )
 from ..models.transformer import (TransformerConfig, _rms_norm,
-                                  latent_attend_blocks, latent_layer,
+                                  latent_attend_blocks, latent_layers,
                                   latent_qkv)
+from ..ops.moe import ROUTING_COUNTS
 from ..ops.paged_attention import kernel_fits, paged_decode_attention
 from ..ops.rope import apply_rope
 from .drafter import ngram_propose_rows
@@ -182,22 +183,23 @@ def _write_rows(pool_k, pool_v, layer_idx, blk, off, k, v):
         # a decode step's one row a lane: the scatter it always was
         blk, off, k, v = blk[:, 0], off[:, 0], k[:, 0], v[:, 0]
     blk, off = blk[..., None], off[..., None]
-    v_layer, lanes = _v_part(pool_k, pool_v, layer_idx)
+    v_layer, lanes = _v_part(pool_v, layer_idx, v.shape[-1])
     return (pool_k.at[layer_idx, blk, jnp.arange(pool_k.shape[2]), off,
                       :].set(k),
             pool_v.at[v_layer, blk, jnp.arange(pool_v.shape[2]), off,
                       lanes].set(v))
 
 
-def _v_part(pool_k, pool_v, layer_idx):
-    """Where pool layer ``layer_idx``'s V row lies: the V array's layer
-    and the lanes of its row.  The V rows of ``packed`` consecutive
-    layers share one array row (kv_blocks.KVRowLayout ``v_packed``; 1,
-    the whole row, for a K and a V a head)."""
-    packed = pool_k.shape[0] // pool_v.shape[0]
+def _v_part(pool_v, layer_idx, width: Optional[int] = None):
+    """Where pool layer ``layer_idx``'s V row of ``width`` values (None:
+    the array's whole row) lies: the V array's layer and the lanes of
+    its row.  The V rows of ``packed`` consecutive layers share one
+    array row (kv_blocks.KVRowLayout ``v_packed``; 1, the whole row, for
+    a K and a V a head); an odd count of layers leaves the last row's
+    second half spare."""
+    packed = 1 if width is None else pool_v.shape[-1] // width
     if packed == 1:
         return layer_idx, slice(None)
-    width = pool_v.shape[-1] // packed
     lo = layer_idx % packed * width
     return layer_idx // packed, slice(lo, lo + width)
 
@@ -212,8 +214,11 @@ def _v_part(pool_k, pool_v, layer_idx):
 STAGED_SLAB_MAX_BYTES = 40 << 20
 
 
-def _layer_reader(pool_k, pool_v, layer_idx, lanes: int, table_width: int):
+def _layer_reader(pool_k, pool_v, layer_idx, lanes: int, table_width: int,
+                  v_width: Optional[int] = None):
     """The read path of ONE layer of the stacked pool [L, B, h_kv, bs, d]
+    (``v_width``: the width of the layer's own V row where several
+    layers' share one array row, see :func:`_v_part`)
     for ``lanes`` lanes whose tables are ``table_width`` entries wide:
     returns ``views(tables [lanes, T]) -> (k, v)``, each lane's virtual
     view [lanes, h_kv, T*bs, d] gathered through ``T <= table_width`` of
@@ -269,16 +274,18 @@ def _layer_reader(pool_k, pool_v, layer_idx, lanes: int, table_width: int):
 
         return view
 
-    v_layer, part = _v_part(pool_k, pool_v, layer_idx)
+    v_layer, part = _v_part(pool_v, layer_idx, v_width)
     with jax.named_scope("kv_view"):
         view_k, view_v = reader(pool_k, layer_idx), reader(pool_v, v_layer)
     return lambda tables: (view_k(tables), view_v(tables)[..., part])
 
 
-def _layer_views(pool_k, pool_v, layer_idx, tables):
+def _layer_views(pool_k, pool_v, layer_idx, tables,
+                 v_width: Optional[int] = None):
     """Per-lane virtual K/V views of ONE layer through lane tables [P, T]
     -> [P, h_kv, T*bs, d]: :func:`_layer_reader`, read once."""
-    return _layer_reader(pool_k, pool_v, layer_idx, *tables.shape)(tables)
+    return _layer_reader(pool_k, pool_v, layer_idx, *tables.shape,
+                         v_width)(tables)
 
 
 KEY_BLOCK = 512  # view rows a step of the blockwise attention takes
@@ -394,11 +401,13 @@ def _moe_or_mlp(layer, config: TransformerConfig, y):
 
 
 def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
-                  tables, positions, blk, off, x):
+                  tables, positions, blk, off, x, live):
     """The dense block's layers over ``x`` [B, C, d]: lane b's C rows sit
     at virtual positions ``positions[b]`` of ``tables[b]`` and are
     written at ``(blk, off)`` [B, C] first, then attend the lane's view
-    under the per-query causal band (:func:`_attend_view`)."""
+    under the per-query causal band (:func:`_attend_view`).  ``live``
+    [B, C] marks the rows that are real (not an idle lane's, not a
+    chunk's padding); the dense block computes every row alike."""
     dtype = config.dtype
     use_rope = config.positional == "rope"
     for layer_idx, layer in enumerate(params["layers"]):
@@ -428,52 +437,57 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
 
 
 def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
-                   tables, positions, blk, off, x):
-    """The 'latent_shortcut' block's double layers, same contract as
-    :func:`_dense_layers`.  Pool layer ``2 * l + j`` is sub-layer j of
-    layer l; its row is the latent ``c_kv`` (``pool_k``) and the one
-    rotary key (``pool_v``), written and viewed through the same two
-    functions as a K and a V.  Every step attends in the absorbed form,
-    over the latent rows themselves: a decode step could not expand a
-    view (32 lanes x 8192 rows x 64 heads), and a 512-row prefill chunk
-    against an 8192-row view measured 8.75 ms absorbed, 11.26 ms
-    expanded on a v5e (PERF.md, PR 27) — and a key block of the view at
-    a time, through ``_layer_views`` of that part of the table, only as
-    far as the lanes reach, not the whole ``max_request_len`` view at
-    once: a decode span of 4 steps over 32 lanes of 600-3000 rows 66.3
-    against 125.0 ms, a mixed dispatch 117.2 against 237.9.  Also returns
-    the routing counts int32[4] summed over the layers (ops/moe.py
-    shortcut_experts_apply)."""
-    counts = jnp.zeros((4,), jnp.int32)
+                   tables, positions, blk, off, x, live):
+    """The latent blocks' layers (double layers with a shortcut, or
+    single layers whose feed-forward is dense or routed:
+    ``transformer.latent_layers`` puts either together), same contract
+    as :func:`_dense_layers`.  Every attention sub-layer has a pool
+    layer of its own; its row is the latent ``c_kv`` (``pool_k``) and
+    the one rotary key (``pool_v``), written and viewed through the same
+    two functions as a K and a V.  Every step attends in the absorbed
+    form, over the latent rows themselves: a decode step could not
+    expand a view (32 lanes x 8192 rows x 64 heads), and a 512-row
+    prefill chunk against an 8192-row view measured 8.75 ms absorbed,
+    11.26 ms expanded on a v5e (PERF.md, PR 27) — and a key block of the
+    view at a time, through ``_layer_views`` of that part of the table,
+    only as far as the lanes reach, not the whole ``max_request_len``
+    view at once: a decode span of 4 steps over 32 lanes of 600-3000
+    rows 66.3 against 125.0 ms, a mixed dispatch 117.2 against 237.9.
+    The rows ``live`` [B, C] says are dead choose no expert.  Also
+    returns the step's routing counts int32[7]: the expert layers'
+    (ops/moe.py ROUTING_COUNTS) summed, then the rows that chose."""
     block_size = pool_k.shape[3]
     entries = key_block_entries(tables.shape[1], block_size)
-    for layer_idx, layer in enumerate(params["layers"]):
+    rope = config.qk_rope_head_dim
 
-        def attend(j, attn, y):
-            nonlocal pool_k, pool_v
-            sub = 2 * layer_idx + j
-            q_nope, q_rope, c_kv, k_rope = latent_qkv(
-                attn, y, positions, config)
-            pool_k, pool_v = _write_rows(
-                pool_k, pool_v, sub, blk, off,
-                c_kv[:, :, None, :], k_rope[:, :, None, :])
+    def attend(sub, attn, y):
+        nonlocal pool_k, pool_v
+        q_nope, q_rope, c_kv, k_rope = latent_qkv(
+            attn, y, positions, config)
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, sub, blk, off,
+            c_kv[:, :, None, :], k_rope[:, :, None, :])
 
-            def view_block(i):
-                part = jax.lax.dynamic_slice_in_dim(
-                    tables, i * entries, entries, axis=1)
-                view_c, view_r = _layer_views(pool_k, pool_v, sub, part)
-                return view_c[:, 0], view_r[:, 0]
+        def view_block(i):
+            part = jax.lax.dynamic_slice_in_dim(
+                tables, i * entries, entries, axis=1)
+            view_c, view_r = _layer_views(pool_k, pool_v, sub, part, rope)
+            return view_c[:, 0], view_r[:, 0]
 
-            return latent_attend_blocks(
-                attn, q_nope, q_rope, view_block, entries * block_size,
-                positions, config)
+        return latent_attend_blocks(
+            attn, q_nope, q_rope, view_block, entries * block_size,
+            positions, config)
 
-        x, layer_counts = latent_layer(layer, x, config, attend)
-        counts = counts + layer_counts
+    x, counts = latent_layers(params, x, config, attend, live)
+    counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
     return x, pool_k, pool_v, counts
 
 
-_LAYERS = {"dense": _dense_layers, "latent_shortcut": _latent_layers}
+# the layers' counts, then the rows that chose: what a routed step returns
+N_STEP_COUNTS = len(ROUTING_COUNTS) + 1
+
+_LAYERS = {"dense": _dense_layers, "latent_shortcut": _latent_layers,
+           "latent_moe": _latent_layers}
 
 
 def _run_layers(params, config: TransformerConfig, *args):
@@ -532,8 +546,12 @@ def paged_prefill_step(
     x = params["embed"][tokens].astype(dtype)  # [P, C, d]
     if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
+    # a chunk's rows after its last real one are padding
+    live = active[:, None] & (
+        jnp.arange(chunk)[None, :] <= last_rows[:, None])
     x, pool_k, pool_v, counts = _run_layers(
-        params, config, pool_k, pool_v, tables, positions, blk, off, x)
+        params, config, pool_k, pool_v, tables, positions, blk, off, x,
+        live)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
@@ -578,7 +596,7 @@ def paged_decode_step(
     # every slot a one-row chunk at its own position: the same layer loop
     x, pool_k, pool_v, counts = _run_layers(
         params, config, pool_k, pool_v, block_tables, positions[:, None],
-        blk[:, None], off[:, None], x)
+        blk[:, None], off[:, None], x, active[:, None])
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
@@ -632,7 +650,7 @@ def paged_decode_span(
         return (pk, pv, lens, nxt, cont, *counts), nxt
 
     carry = (pool_k, pool_v, lengths, tokens, active,
-             *([jnp.zeros((4,), jnp.int32)] if routing else []))
+             *([jnp.zeros((N_STEP_COUNTS,), jnp.int32)] if routing else []))
     (pk, pv, _, _, _, *counts), emitted = jax.lax.scan(
         body, carry, jnp.arange(span))
     return (emitted, pk, pv, *counts)
@@ -814,7 +832,8 @@ def paged_verify_span(
     if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
     x, pool_k, pool_v, _ = _run_layers(
-        params, config, pool_k, pool_v, tables, positions, blk, off, x)
+        params, config, pool_k, pool_v, tables, positions, blk, off, x,
+        valid)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)

@@ -226,7 +226,7 @@ def _cell_case(name, kind):
         for shape in layout.block_shapes(e["block_size"]))
     s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
     span = 4
-    routing = {"routing": True} if config.latent else {}
+    routing = {"routing": True} if config.routed else {}
     lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
              jax.ShapeDtypeStruct((s,), jnp.float32),
              jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
@@ -305,8 +305,8 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     assert memory.temp_size_in_bytes <= LOOP_TEMPORARIES[name, kind], memory
 
 
-@pytest.mark.parametrize("kind,temporaries", [("decode", 158_880_768),
-                                              ("mixed", 362_003_456)])
+@pytest.mark.parametrize("kind,temporaries", [("decode", 159_269_376),
+                                              ("mixed", 362_616_320)])
 def test_latent_block_program_compiles_and_fits(one_chip, kind,
                                                 temporaries):
     """One expert-parallel rank at the published widths fits the chip with
@@ -314,15 +314,42 @@ def test_latent_block_program_compiles_and_fits(one_chip, kind,
     array); and the expert layer's work follows the routing: nothing in
     the program has a row of every lane or chunk row for each of the 16
     held experts (``rows x 16`` expert rows a layer is what a
-    capacity-pinned dispatch would multiply).  Its temporaries are to the
-    byte what they were before the dense block shared its running
-    softmax (PR 28)."""
-    import re
-
+    capacity-pinned dispatch would multiply).  Its temporaries were
+    158,880,768 / 362,003,456 B until the expert layer took the rows'
+    liveness: the masks of the dead rows and the three counts more a step
+    (tiles, their rows, the rows that chose) are 388,608 / 612,864 B."""
     config, fn, args = _cell_case("longcat-flash-chat", kind)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes == temporaries, memory
+    _no_row_of_every_expert(config, args, text)
+
+
+def _no_row_of_every_expert(config, args, text):
+    import re
+
     held, d, f = config.held_experts, config.d_model, config.expert_d_ff
     rows = "|".join(str(a.shape[0] * a.shape[-1]) for a in (args[3], args[5])
                     if len(a.shape) == 2) + "|32|512|544"
     assert not re.search(rf"\[{held},({rows}),({d}|{f})\]", text)
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_single_latent_layers_program_compiles_and_fits(one_chip, kind):
+    """The first pipeline stage of ``joyai-llm-flash`` at the published
+    widths — five single latent layers, the last four with all 256 routed
+    experts and the shared one, the whole vocabulary — fits the chip
+    under 15 GB with its pool in place (an odd count of 64-wide rotary
+    rows, packed two to a row); and nothing in the program has ``rows x
+    256`` expert rows a layer: the only arrays with the experts' axis are
+    the experts' own matrices."""
+    import re
+
+    config, fn, args = _cell_case("joyai-llm-flash", kind)
+    assert (config.attn_sublayers, config.expert_layers) == (5, 4)
+    assert args[1].shape[0] == 5 and args[2].shape[0] == 3
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    assert memory.temp_size_in_bytes < 128 << 20, memory
+    _no_row_of_every_expert(config, args, text)
+    with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
+    assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
+        with_experts

@@ -2,7 +2,8 @@
 ``chipbench/tests/test_chipbench.py`` (the generator, the metrics'
 arithmetic, the trace reduction, the roofline counts, `correct` against the
 plain reference and the two controls that have to fail) and
-``test_spans.py`` (the readers of the program's own spans), collected here
+``test_spans.py`` and ``test_tiles.py`` (the readers of the program's own
+spans), collected here
 as ``tests/test_chipbench_contract.py`` collects the contract.  A PR that
 edits ``kubeshare_tpu/serving/`` learns here, not from the driver's
 refusal, what ``chipbench/system.py``, ``trace.py`` or a ``layer_metrics/``
@@ -19,6 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench.tests.test_chipbench import *  # noqa: E402,F401,F403
 from chipbench.tests.test_spans import *  # noqa: E402,F401,F403
+from chipbench.tests.test_tiles import *  # noqa: E402,F401,F403
 
 pytestmark = pytest.mark.usefixtures("chipbench_apart")
 

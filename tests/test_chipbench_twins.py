@@ -1,9 +1,9 @@
 """Tier-1 runs a whole window of each configuration's small twin, as
 ``tests/test_chipbench_suite.py`` runs the harness's own cases:
 ``chipbench/tests/test_second_block.py`` (a configuration of another block
-as new files only) and ``test_longcat_twin.py`` (the latent, routed block's
-twin under the modules its cell names), each served through the normal
-path, judged against its plain reference, and failed by its lower-precision
+as new files only), ``test_longcat_twin.py`` and ``test_joyai_twin.py`` (the
+two latent, routed blocks' twins under the modules their cells name), each
+served through the normal path, judged against its plain reference, and failed by its lower-precision
 control."""
 
 import os
@@ -13,7 +13,17 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from chipbench.tests import test_joyai_twin as _joyai  # noqa: E402
 from chipbench.tests.test_longcat_twin import *  # noqa: E402,F401,F403
 from chipbench.tests.test_second_block import *  # noqa: E402,F401,F403
 
 pytestmark = pytest.mark.usefixtures("chipbench_apart")
+
+# the second twin's cases under names of their own (the two files give
+# theirs the same three)
+test_the_joyai_cell_names_the_same_modules_as_its_twin = \
+    _joyai.test_the_cell_names_the_same_modules_as_its_twin
+test_a_whole_window_of_the_joyai_twin_is_correct = \
+    _joyai.test_a_whole_window_of_the_twin_is_correct
+test_the_joyai_twins_lower_precision_is_not_correct = \
+    _joyai.test_the_twins_lower_precision_is_not_correct

@@ -3,7 +3,8 @@ two gated FFNs and one shortcut-connected expert layer with zero-compute
 experts a double layer, served through a paged latent cache by one
 expert-parallel rank — against the plain reference of the benchmark
 (``chipbench/longcat_flash_reference.py``: float32, the expanded attention,
-every held expert on every row, nothing imported from the program).
+every held expert on every row, nothing imported from the program).  What
+the two latent kinds must do alike is in ``tests/test_latent_moe_block.py``.
 
 Sizes: d 64, 4 heads, ranks 32 / 16, nope 16 / rope 8 / v 16, 2 double
 layers, 16 routed + 8 zero experts, top 4, 4 held by rank 0.
@@ -12,90 +13,35 @@ layers, 16 routed + 8 zero experts, top 4, 4 held by rank 0.
 import dataclasses
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+from latent_kinds import (BLOCK, KINDS, REPO, ROWS, config_of, jitted_steps,
+                          params_of, served_logits)
+from kubeshare_tpu.models.decoding import greedy_decode
+from kubeshare_tpu.models.transformer import (
+    TransformerConfig, latent_attend, latent_qkv, transformer_apply)
+from kubeshare_tpu.ops.moe import routed_experts_apply
+from kubeshare_tpu.serving import EngineConfig, Request, ServingEngine
+from kubeshare_tpu.serving.kv_blocks import init_paged_pool, kv_row_layout
+from kubeshare_tpu.serving import paged
 
-from chipbench import longcat_flash_reference as reference  # noqa: E402
-from chipbench import longcat_flash_weights as weights  # noqa: E402
-from kubeshare_tpu.models.decoding import greedy_decode  # noqa: E402
-from kubeshare_tpu.models.transformer import (  # noqa: E402
-    TransformerConfig, latent_attend, latent_qkv, transformer_apply,
-    transformer_init)
-from kubeshare_tpu.ops.moe import shortcut_experts_apply  # noqa: E402
-from kubeshare_tpu.serving import (EngineConfig, Request,  # noqa: E402
-                                   ServingEngine)
-from kubeshare_tpu.serving.kv_blocks import (  # noqa: E402
-    init_paged_pool, kv_row_layout)
-from kubeshare_tpu.serving import paged  # noqa: E402
-from kubeshare_tpu.utils import profiling  # noqa: E402
-
-with open(os.path.join(REPO, "chipbench", "tests", "configs",
-                       "tiny_longcat.json")) as f:
-    TC = json.load(f)["transformer_config"]
-
-
-def _jitted_steps():
-    return (jax.jit(paged.paged_prefill_step, static_argnums=(1,)),
-            jax.jit(paged.paged_decode_step, static_argnums=(1,)))
-
-
-STEPS = paged_prefill_step, paged_decode_step = _jitted_steps()
-BLOCK = 4  # rows a pool block
-ROWS = 64  # a lane's table covers this many
+KIND = "latent_shortcut"
+TC = KINDS[KIND].tc
+reference, weights = KINDS[KIND].reference, KINDS[KIND].weights
+_served_logits = served_logits
+_jitted_steps = jitted_steps
 
 
 def _config(dtype, **changes):
-    return TransformerConfig(**{**TC, "dtype": jnp.dtype(dtype), **changes})
+    return config_of(KIND, dtype, **changes)
 
 
 def _params(seed, dtype):
-    """The benchmark's seeded weights (bf16 values), in ``dtype``."""
-    return jax.tree.map(lambda a: a.astype(dtype),
-                        weights.make_weights(seed, TC))
-
-
-def _served_logits(params, config, tokens, prompt_len, chunk=8, lanes=3,
-                   lane=1, steps=None):
-    """Logits [len(tokens) - prompt_len + 1, vocab] at the rows from the
-    prompt's last on, as the step programs give them: the prompt prefilled
-    in chunks of ``chunk`` into lane ``lane`` of a paged latent pool, then
-    one decode step a token, the other lanes inactive."""
-    paged_prefill_step, paged_decode_step = steps or STEPS
-    pool = init_paged_pool(config, 1 + lanes * ROWS // BLOCK, BLOCK)
-    pk, pv = pool.k, pool.v
-    per = ROWS // BLOCK
-    tables = jnp.asarray(1 + np.arange(lanes * per).reshape(lanes, per),
-                         jnp.int32)
-    table = tables[lane][None]
-    rows = []
-    for start in range(0, prompt_len, chunk):
-        piece = np.zeros((1, chunk), np.int32)
-        real = tokens[start:min(start + chunk, prompt_len)]
-        piece[0, :len(real)] = real
-        logits, pk, pv = paged_prefill_step(
-            params, config, pk, pv, table, jnp.asarray([start]),
-            jnp.ones((1,), bool), jnp.asarray(piece),
-            jnp.asarray([len(real) - 1]))
-    rows.append(np.asarray(logits[0]))
-    active = np.zeros((lanes,), bool)
-    active[lane] = True
-    for i in range(prompt_len, len(tokens)):
-        lengths = np.zeros((lanes,), np.int32)
-        lengths[lane] = i
-        toks = np.zeros((lanes,), np.int32)
-        toks[lane] = tokens[i]
-        logits, pk, pv = paged_decode_step(
-            params, config, pk, pv, tables, jnp.asarray(lengths),
-            jnp.asarray(active), jnp.asarray(toks))
-        rows.append(np.asarray(logits[lane]))
-    return np.stack(rows)
+    return params_of(KIND, seed, dtype)
 
 
 @pytest.fixture(scope="module")
@@ -182,7 +128,7 @@ def _expert_case(seed=4, n=24):
 
 
 def _apply(moe, y, first_held=0):
-    return shortcut_experts_apply(
+    return routed_experts_apply(
         moe, y, n_routed=TC["n_routed_experts"], top_k=TC["router_top_k"],
         scale=TC["routed_scaling_factor"], first_held=first_held)
 
@@ -200,7 +146,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer():
     whole = reference.expert_layer(y, uncut, _sizes())
     empty = {k: (v if k == "router" else v[:0]) for k, v in uncut.items()}
     identity = reference.expert_layer(y, empty, _sizes())
-    total, counts = identity, np.zeros((4,), np.int64)
+    total, counts = identity, np.zeros((6,), np.int64)
     for rank in range(4):
         share = {k: (v if k == "router" else v[4 * rank:4 * rank + 4])
                  for k, v in uncut.items()}
@@ -228,11 +174,11 @@ def _forced_router(moe, chosen):
 
 
 def test_nothing_is_dropped_at_any_skew():
-    """(d) Every row sent to the same held experts — 24 rows, 4 choices
-    each, all on this rank, three tiles of expert 0 alone at a tile of 8:
-    the result is still the reference's, which runs every expert on
-    every row."""
-    moe, _, y = _expert_case(n=24)
+    """(d) Every row sent to the same held experts — 300 rows, 4 choices
+    each, three tiles of 128 rows an expert, the last one padded: the
+    result is still the reference's, which runs every expert on every
+    row."""
+    moe, _, y = _expert_case(n=300)
     y = jnp.abs(y)  # so that the pinned scores win on every row
     for chosen in ([0, 1, 2, 3], [0, 5, 9, 13]):
         forced = _forced_router(moe, chosen)
@@ -241,7 +187,7 @@ def test_nothing_is_dropped_at_any_skew():
             out, reference.expert_layer(y, forced, _sizes()), atol=2e-5)
         held = sum(1 for e in chosen if e < 4)
         assert list(np.asarray(counts)) == [
-            24 * held, 0, 24 * (4 - held), held]
+            300 * held, 0, 300 * (4 - held), held, 3 * held, 384 * held]
 
 
 def test_identity_experts_alone_weigh_the_input():
@@ -254,34 +200,7 @@ def test_identity_experts_alone_weigh_the_input():
     probs = jax.nn.softmax(y @ forced["router"], -1)
     weight = 6.0 * jax.lax.top_k(probs, 4)[0].sum(-1, keepdims=True)
     np.testing.assert_allclose(out, weight * y, rtol=1e-5, atol=1e-6)
-    assert list(np.asarray(counts)) == [0, 24 * 4, 0, 0]
-
-
-def test_a_lanes_logits_do_not_depend_on_its_co_batched_lanes(tokens):
-    """(d) Routing is a function of the row alone and nothing has a
-    capacity, so a decode lane reads the same logits whatever rides
-    beside it."""
-    params, config = _params(7, jnp.bfloat16), _config("bfloat16")
-    lanes, per = 3, ROWS // BLOCK
-    pool = init_paged_pool(config, 1 + lanes * per, BLOCK)
-    tables = jnp.asarray(1 + np.arange(lanes * per).reshape(lanes, per),
-                         jnp.int32)
-    pk, pv = pool.k, pool.v
-    for lane in range(lanes):  # 9 rows of context a lane
-        _, pk, pv = paged_prefill_step(
-            params, config, pk, pv, tables[lane][None], jnp.asarray([0]),
-            jnp.ones((1,), bool),
-            jnp.asarray(tokens[9 * lane:9 * lane + 9][None]),
-            jnp.asarray([8]))
-    lengths = jnp.full((lanes,), 9, jnp.int32)
-    toks = jnp.asarray(tokens[30:33])
-    alone = paged_decode_step(
-        params, config, pk, pv, tables, lengths,
-        jnp.asarray([False, True, False]), toks)[0]
-    together = paged_decode_step(
-        params, config, pk, pv, tables, lengths,
-        jnp.asarray([True, True, True]), toks)[0]
-    np.testing.assert_array_equal(alone[1], together[1])
+    assert list(np.asarray(counts)) == [0, 24 * 4, 0, 0, 0, 0]
 
 
 @pytest.mark.parametrize("name,pool_shape", [
@@ -309,67 +228,6 @@ def test_the_dense_configurations_are_what_they_were(name, pool_shape):
     k, v = jax.eval_shape(lambda: dataclasses.astuple(init_paged_pool(
         config, num_blocks, e["block_size"]))[:2])
     assert k.shape == v.shape == pool_shape
-
-
-def test_the_latent_pool_holds_one_row_a_sub_layer():
-    config = _config("bfloat16")
-    layout = kv_row_layout(config)
-    assert (layout.kind, layout.layers) == ("latent", 4)
-    pool = init_paged_pool(config, 9, BLOCK)
-    assert pool.k.shape == (4, 9, 1, BLOCK, TC["kv_lora_rank"])
-    # a layer's two rotary keys side by side in one row
-    assert pool.v.shape == (2, 9, 1, BLOCK, 2 * TC["qk_rope_head_dim"])
-    assert pool.bytes_per_block() == 4 * (16 + 8) * 2 * BLOCK
-    assert int(pool.k.nbytes + pool.v.nbytes) == 9 * pool.bytes_per_block()
-
-
-def _engine(**changes):
-    config = _config("bfloat16")
-    params = _params(5, jnp.bfloat16)
-    ec = EngineConfig(**{**dict(num_slots=3, block_size=BLOCK, num_blocks=64,
-                                max_request_len=ROWS, prefill_chunk=8),
-                         **changes})
-    return ServingEngine(params, config, ec), params, config
-
-
-def test_the_engines_counters_and_spans_add_up():
-    """(g) Every routed dispatch leaves one ``kubeshare.engine.routing``
-    span whose counts add up to top_k x layers x the rows of its passes,
-    and the engine's counters are their sums; streams are those of the
-    unpaged forward (greedy, so a near-tie at the top may differ: the
-    float32 engine is held to it instead)."""
-    engine, params, config = _engine()
-    engine.warmup()
-    rng = np.random.default_rng(2)
-    reqs = [(f"r{i}", rng.integers(0, TC["vocab_size"], n), new)
-            for i, (n, new) in enumerate([(5, 6), (13, 4), (21, 9), (3, 3)])]
-    since = profiling.spans()[-1][2] if profiling.spans() else 0.0
-    for rid, prompt, new in reqs:
-        engine.submit(Request(rid, prompt, new))
-    out = engine.run()
-    assert all(len(out[rid].tokens) == new for rid, _, new in reqs)
-    assert engine.compile_counts() == {
-        **engine.compile_counts(), "verify": 0, "loop": 0}
-    routed = [attrs for name, start, _, _, attrs in profiling.spans()
-              if name == "kubeshare.engine.routing" and start >= since]
-    per_row = TC["router_top_k"] * TC["n_layers"]
-    assert routed and all(
-        a["held"] + a["zero"] + a["absent"] == per_row * a["rows"]
-        and 0 <= a["touched"] <= a["passes"] * TC["n_layers"] * 4
-        for a in routed)
-    for kind in ("held", "zero", "absent"):
-        assert engine.moe_assignments[kind] == sum(a[kind] for a in routed)
-    assert engine.moe_experts_touched == sum(a["touched"] for a in routed)
-    assert engine.moe_passes == sum(a["passes"] for a in routed)
-    dispatches = (engine.prefill_chunks + engine.decode_steps
-                  - engine.mixed_steps)
-    assert len(routed) == dispatches
-    families = {f.name: f for f in engine.collect_metrics()}
-    by_kind = {s.labels["kind"]: s.value for s in families[
-        "kubeshare_serving_moe_assignments_total"].samples}
-    assert by_kind == engine.moe_assignments
-    assert families["kubeshare_serving_moe_experts_touched_total"] \
-        .samples[0].value == engine.moe_experts_touched
 
 
 def test_the_float32_engine_serves_the_dense_caches_streams():
@@ -400,15 +258,6 @@ def test_the_float32_engine_serves_the_dense_caches_streams():
         toks.append(int(jnp.argmax(
             forward(jnp.asarray(padded))[0, len(toks) - 1])))
     assert out["r1"].tokens == toks[len(prompt):]
-
-
-def test_transformer_init_makes_the_pytree_the_benchmark_serves():
-    config = _config("float32")
-    made = transformer_init(jax.random.PRNGKey(0), config)
-    served = weights.make_weights(0, TC)
-    assert jax.tree.structure(made) == jax.tree.structure(served)
-    assert jax.tree.map(lambda a: a.shape, made) \
-        == jax.tree.map(lambda a: a.shape, served)
 
 
 def _refusals():
@@ -443,12 +292,14 @@ def _refusals():
     }
 
 
+@pytest.mark.parametrize("kind", sorted(KINDS))
 @pytest.mark.parametrize("what", [
     "speculative", "device_loop", "mesh_spec", "kv_tier", "shared_tier",
     "disagg_pool", "sharded_context", "disagg_router", "fabric_fleet"])
-def test_what_does_not_serve_the_latent_row_says_so(what):
-    """Each refuses at construction, naming the layout."""
-    config = _config("bfloat16")
-    params = _params(5, jnp.bfloat16)
+def test_what_does_not_serve_the_latent_row_says_so(what, kind):
+    """Each refuses at construction, naming the layout, whichever latent
+    kind asks."""
+    config = config_of(kind, "bfloat16")
+    params = params_of(kind, 5, jnp.bfloat16)
     with pytest.raises(ValueError, match=r"'latent'.*row"):
         _refusals()[what](params, config)
