@@ -420,30 +420,25 @@ def latent_attend(attn, q_nope, q_rope, view_c, view_r, positions,
     queries (a prefill chunk).  Both are the same numbers."""
     dtype = config.dtype
     nope = config.qk_nope_head_dim
-    scale = (nope + config.qk_rope_head_dim) ** -0.5
-    wukv = attn["wukv"].astype(dtype)
-    wuk, wuv = wukv[..., :nope], wukv[..., nope:]
-    if absorbed:
-        q_nope = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
-    else:
-        k_nope = jnp.einsum("bvr,rhn->bhvn", view_c, wuk)
-        v = jnp.einsum("bvr,rhm->bhvm", view_c, wuv)
-
+    scale = latent_scale(config)
     f32 = jnp.float32
-    scores = jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
-                        preferred_element_type=f32)
+    rope_scores = jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                             preferred_element_type=f32)
     if absorbed:
-        scores += jnp.einsum("bhcr,bvr->bhcv", q_nope, view_c,
-                             preferred_element_type=f32)
-    else:
-        scores += jnp.einsum("bhcn,bhvn->bhcv", q_nope, k_nope,
-                             preferred_element_type=f32)
+        def attend(q_abs):
+            scores = rope_scores + jnp.einsum(
+                "bhcr,bvr->bhcv", q_abs, view_c, preferred_element_type=f32)
+            probs = _latent_softmax(scores * scale, positions, dtype)
+            return jnp.einsum("bhcv,bvr->bhcr", probs, view_c)
+
+        return latent_absorbed(attn, q_nope, attend, config)
+    wukv = attn["wukv"].astype(dtype)
+    k_nope = jnp.einsum("bvr,rhn->bhvn", view_c, wukv[..., :nope])
+    v = jnp.einsum("bvr,rhm->bhvm", view_c, wukv[..., nope:])
+    scores = rope_scores + jnp.einsum("bhcn,bhvn->bhcv", q_nope, k_nope,
+                                      preferred_element_type=f32)
     probs = _latent_softmax(scores * scale, positions, dtype)
-    if absorbed:
-        ctx = jnp.einsum("bhcv,bvr->bhcr", probs, view_c)
-        o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
-    else:
-        o = jnp.einsum("bhcv,bhvm->bhcm", probs, v)
+    o = jnp.einsum("bhcv,bhvm->bhcm", probs, v)
     return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
 
 
@@ -509,7 +504,31 @@ def attend_key_blocks(view_block, block_rows: int, scores_of, context_of,
     return ctx / total[..., None]
 
 
+def latent_scale(config: TransformerConfig) -> float:
+    """What the latent blocks' scores are multiplied by."""
+    return (config.qk_nope_head_dim + config.qk_rope_head_dim) ** -0.5
+
+
 @jax.named_scope("mla")
+def latent_absorbed(attn, q_nope, attend, config: TransformerConfig):
+    """The absorbed form around the attention itself: the key
+    up-projection folded into the query (``q_abs`` [B, H, C,
+    kv_lora_rank]), ``attend(q_abs)`` the normalised context over the
+    latent rows [B, H, C, kv_lora_rank], the value up-projection and the
+    output projection applied to it -> [B, C, d].  Where the paged steps'
+    two ways through a view meet: the key-block loop
+    (:func:`latent_attend_blocks`) and the paged kernel
+    (``ops/paged_attention.paged_latent_decode_attention``)."""
+    dtype = config.dtype
+    nope = config.qk_nope_head_dim
+    wukv = attn["wukv"].astype(dtype)
+    wuk, wuv = wukv[..., :nope], wukv[..., nope:]
+    q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
+    ctx = attend(q_abs).astype(dtype)
+    o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
+    return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
+
+
 def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
                          positions, config: TransformerConfig):
     """:func:`latent_attend` in the absorbed form over a view that is
@@ -519,29 +538,25 @@ def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
     attended through :func:`attend_key_blocks` as far as the lanes
     reach.  Same numbers as the whole view at once, up to the order of
     the sums."""
-    dtype = config.dtype
-    nope = config.qk_nope_head_dim
-    scale = (nope + config.qk_rope_head_dim) ** -0.5
-    wukv = attn["wukv"].astype(dtype)
-    wuk, wuv = wukv[..., :nope], wukv[..., nope:]
-    q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope, wuk)
+    scale = latent_scale(config)
     f32 = jnp.float32
 
-    def scores_of(view_c, view_r):
-        return (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
-                           preferred_element_type=f32)
-                + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
-                             preferred_element_type=f32)) * scale
+    def attend(q_abs):
+        def scores_of(view_c, view_r):
+            return (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
+                               preferred_element_type=f32)
+                    + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                                 preferred_element_type=f32)) * scale
 
-    def context_of(weights, view_c, _):
-        return jnp.einsum("bhcv,bvr->bhcr", weights.astype(dtype), view_c,
-                          preferred_element_type=f32)
+        def context_of(weights, view_c, _):
+            return jnp.einsum("bhcv,bvr->bhcr", weights.astype(config.dtype),
+                              view_c, preferred_element_type=f32)
 
-    ctx = attend_key_blocks(
-        view_block, block_rows, scores_of, context_of, positions,
-        q_abs.shape[1:2], q_abs.shape[3]).astype(dtype)
-    o = jnp.einsum("bhcr,rhm->bhcm", ctx, wuv)
-    return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
+        return attend_key_blocks(
+            view_block, block_rows, scores_of, context_of, positions,
+            q_abs.shape[1:2], q_abs.shape[3])
+
+    return latent_absorbed(attn, q_nope, attend, config)
 
 
 def gated_ffn(ffn, y, dtype):

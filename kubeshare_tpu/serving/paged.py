@@ -25,18 +25,21 @@ hold: a view longer than one key block (``KEY_BLOCK`` rows) is gathered
 and attended a key block at a time, the softmax carried across the
 blocks, and the loop stops where the furthest lane's last row lies
 (:func:`_attend_view` for the dense block, ``latent_attend_blocks`` for
-the latent one; both carry the one running softmax,
-``models/transformer.attend_key_blocks``).  Where the dense block's
-queries are one row a lane (the decode step) and the program is built
-for a TPU, a Pallas kernel stands in for that loop
-(``ops/paged_attention``): each lane reads its OWN pages of the pool up
-to its own position, an idle lane none, and no slab is staged
-(:func:`attend_path` makes the choice, from shapes and the backend).  A
-view no longer than a key block is attended whole, through the dense
-step's own :func:`_attend_cached`.  The engine counts how far each
-dispatch went (``view_rows_reached`` against ``view_rows_configured``,
-``view_rows_held`` for the decode lanes' own rows) and names what its
-decode lanes ran on the launch span (``attend``).
+the latent ones; both carry the one running softmax,
+``models/transformer.attend_key_blocks``).  Where the queries are one
+row a lane (the decode step) and the program is built for a TPU, a
+Pallas kernel stands in for that loop (``ops/paged_attention``: one for
+the dense block's K and V a head, one for the latent blocks' latent row
+and packed rotary keys, over one shared page walk): each lane reads its
+OWN pages of the pool up to its own position, an idle lane none, and
+nothing is gathered or staged for all the lanes (:func:`attend_path`
+makes the choice, from shapes, the block's row layout and the backend).
+The dense block's view no longer than a key block is attended whole,
+through the dense step's own :func:`_attend_cached`.  The engine counts
+how far each dispatch went (``view_rows_reached`` against
+``view_rows_configured``, ``view_rows_held`` for the decode lanes' own
+rows) and names what its decode lanes ran on the launch span
+(``attend``).
 
 The layers themselves are written once a block kind (:func:`_dense_layers`,
 :func:`_latent_layers`) and run by every step through
@@ -99,10 +102,12 @@ from ..models.decoding import (
     speculative_acceptance,
 )
 from ..models.transformer import (TransformerConfig, _rms_norm,
-                                  latent_attend_blocks, latent_layers,
-                                  latent_qkv)
+                                  latent_absorbed, latent_attend_blocks,
+                                  latent_layers, latent_qkv, latent_scale)
 from ..ops.moe import ROUTING_COUNTS
-from ..ops.paged_attention import kernel_fits, paged_decode_attention
+from ..ops.paged_attention import (kernel_fits, latent_kernel_fits,
+                                   paged_decode_attention,
+                                   paged_latent_decode_attention)
 from ..ops.rope import apply_rope
 from .drafter import ngram_propose_rows
 
@@ -311,18 +316,22 @@ def attend_path(block: str, query_rows: int, table_width: int, pool_k,
                 pool_v, head_dim: int) -> str:
     """What a step's queries of ``query_rows`` rows a lane run over views
     of ``table_width`` table entries, chosen from what the program can
-    see — the view's width, the query's, the pool's shapes, the backend:
-    "whole" (a view no longer than one key block, attended at once),
-    "kernel" (one row a lane of the dense block, where the paged kernel
-    can run: each lane reads its own pages, bounded by its own length) or
-    "blocks" (the key-block loop, as far as the furthest lane reaches).
-    The engine names it on its launch spans (``attend``)."""
-    if block != "dense":
-        return "blocks"  # the latent block's own loop
-    if table_width * pool_k.shape[3] <= KEY_BLOCK:
-        return "whole"
-    if (query_rows == 1 and _kernel_mode()
-            and kernel_fits(pool_k, pool_v, head_dim)):
+    see — the view's width, the query's, the block's row layout (the
+    pool's shapes), the backend: "whole" (the dense block's view no
+    longer than one key block, attended at once), "kernel" (one row a
+    lane over a longer view, where the paged kernel of the block's row
+    layout can run: each lane reads its own pages, bounded by its own
+    length) or "blocks" (the key-block loop, as far as the furthest lane
+    reaches; the latent blocks run it over a short view too).  The
+    engine names it on its launch spans (``attend``)."""
+    short = table_width * pool_k.shape[3] <= KEY_BLOCK
+    if block == "dense":
+        if short:
+            return "whole"
+        fits = kernel_fits(pool_k, pool_v, head_dim)
+    else:
+        fits = not short and latent_kernel_fits(pool_k, pool_v)
+    if query_rows == 1 and fits and _kernel_mode():
         return "kernel"
     return "blocks"
 
@@ -443,22 +452,31 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
     ``transformer.latent_layers`` puts either together), same contract
     as :func:`_dense_layers`.  Every attention sub-layer has a pool
     layer of its own; its row is the latent ``c_kv`` (``pool_k``) and
-    the one rotary key (``pool_v``), written and viewed through the same
-    two functions as a K and a V.  Every step attends in the absorbed
+    the one rotary key (``pool_v``), written through the same function
+    as a K and a V.  Every step attends in the absorbed
     form, over the latent rows themselves: a decode step could not
     expand a view (32 lanes x 8192 rows x 64 heads), and a 512-row
     prefill chunk against an 8192-row view measured 8.75 ms absorbed,
-    11.26 ms expanded on a v5e (PERF.md, PR 27) — and a key block of the
-    view at a time, through ``_layer_views`` of that part of the table,
-    only as far as the lanes reach, not the whole ``max_request_len``
-    view at once: a decode span of 4 steps over 32 lanes of 600-3000
-    rows 66.3 against 125.0 ms, a mixed dispatch 117.2 against 237.9.
+    11.26 ms expanded on a v5e (PERF.md, PR 27).  One query row a lane
+    (the decode step) goes through the paged latent kernel where
+    :func:`attend_path` says it can run: each lane reads its own latent
+    pages and rotary keys up to its own position, an idle lane none
+    (1.28 ms of gathers and scores a sub-layer -> under 0.23 ms alone
+    on a v5e at 22 live lanes of 300-6100 rows: PERF.md, PR 33).  Wider
+    queries (the prefill chunk) and every step off the TPU attend a key
+    block of the view at a time, through ``_layer_views`` of that part
+    of the table, only as far as the lanes reach, not the whole
+    ``max_request_len`` view at once: a decode span of 4 steps over 32
+    lanes of 600-3000 rows 66.3 against 125.0 ms, a mixed dispatch 117.2
+    against 237.9 (PR 27).
     The rows ``live`` [B, C] says are dead choose no expert.  Also
     returns the step's routing counts int32[7]: the expert layers'
     (ops/moe.py ROUTING_COUNTS) summed, then the rows that chose."""
     block_size = pool_k.shape[3]
     entries = key_block_entries(tables.shape[1], block_size)
     rope = config.qk_rope_head_dim
+    kernel = attend_path(config.block, positions.shape[1], tables.shape[1],
+                         pool_k, pool_v, config.head_dim) == "kernel"
 
     def attend(sub, attn, y):
         nonlocal pool_k, pool_v
@@ -467,6 +485,14 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
         pool_k, pool_v = _write_rows(
             pool_k, pool_v, sub, blk, off,
             c_kv[:, :, None, :], k_rope[:, :, None, :])
+        if kernel:
+            return latent_absorbed(
+                attn, q_nope,
+                lambda q_abs: paged_latent_decode_attention(
+                    q_abs[:, :, 0], q_rope[:, :, 0], pool_k, pool_v, sub,
+                    tables, positions[:, 0], scale=latent_scale(config),
+                    interpret=_kernel_mode() == "interpret")[:, :, None],
+                config)
 
         def view_block(i):
             part = jax.lax.dynamic_slice_in_dim(

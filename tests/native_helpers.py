@@ -13,5 +13,19 @@ def free_port():
     return port
 
 
+def free_ports(count):
+    """``count`` free ports, all different: the sockets are held open
+    together, so none is handed out twice (two :func:`free_port` calls in
+    a row can read the same port)."""
+    socks = [socket.socket() for _ in range(count)]
+    try:
+        for s in socks:
+            s.bind(("127.0.0.1", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
+
+
 def wait_listening(port, timeout=10.0):
     _wait_listening(port, deadline_s=timeout)

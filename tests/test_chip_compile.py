@@ -305,23 +305,42 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     assert memory.temp_size_in_bytes <= LOOP_TEMPORARIES[name, kind], memory
 
 
-@pytest.mark.parametrize("kind,temporaries", [("decode", 159_269_376),
-                                              ("mixed", 362_616_320)])
-def test_latent_block_program_compiles_and_fits(one_chip, kind,
+def _attends_through_the_latent_kernel(config, text):
+    """The decode step's attention sub-layers each run the paged kernel
+    (the span is a loop of one step, the chunk attends by key block), and
+    no key block of every lane's table entries is gathered: 32 lanes x 32
+    entries of one 16-row page, latent or rotary."""
+    import re
+
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == config.attn_sublayers
+    assert not re.search(r"bf16\[1024,16,(512|128)\]", text)
+
+
+@pytest.mark.parametrize("kind,temporaries", [("decode", 158_672_384),
+                                              ("mixed", 355_068_416)])
+def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
                                                 temporaries):
-    """One expert-parallel rank at the published widths fits the chip with
-    its pool; the pool is written in place (no copy of a pool-shaped
-    array); and the expert layer's work follows the routing: nothing in
-    the program has a row of every lane or chunk row for each of the 16
-    held experts (``rows x 16`` expert rows a layer is what a
+    """One expert-parallel rank at the published widths, built as on the
+    chip, fits it with its pool; the pool is written in place (no copy of
+    a pool-shaped array); the decode lanes attend through the paged
+    latent kernel, one call a sub-layer, and no key block of all 32 lanes
+    is gathered; and the expert layer's work follows the routing: nothing
+    in the program has a row of every lane or chunk row for each of the
+    16 held experts (``rows x 16`` expert rows a layer is what a
     capacity-pinned dispatch would multiply).  Its temporaries were
     158,880,768 / 362,003,456 B until the expert layer took the rows'
-    liveness: the masks of the dead rows and the three counts more a step
-    (tiles, their rows, the rows that chose) are 388,608 / 612,864 B."""
+    liveness (the masks of the dead rows and the three counts more a
+    step: 388,608 / 612,864 B), then 159,269,376 / 362,616,320 B on the
+    key-block loop; with the kernel (PR 33) the decode lanes' gathered
+    key blocks and their scores go: 159,269,376 -> 158,672,384 (decode
+    span), 362,616,320 -> 355,068,416 (mixed)."""
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("longcat-flash-chat", kind)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes == temporaries, memory
     _no_row_of_every_expert(config, args, text)
+    _attends_through_the_latent_kernel(config, text)
 
 
 def _no_row_of_every_expert(config, args, text):
@@ -334,22 +353,40 @@ def _no_row_of_every_expert(config, args, text):
 
 
 @pytest.mark.parametrize("kind", ["decode", "mixed"])
-def test_single_latent_layers_program_compiles_and_fits(one_chip, kind):
+def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
+                                                        kind):
     """The first pipeline stage of ``joyai-llm-flash`` at the published
     widths — five single latent layers, the last four with all 256 routed
-    experts and the shared one, the whole vocabulary — fits the chip
-    under 15 GB with its pool in place (an odd count of 64-wide rotary
-    rows, packed two to a row); and nothing in the program has ``rows x
-    256`` expert rows a layer: the only arrays with the experts' axis are
-    the experts' own matrices."""
+    experts and the shared one, the whole vocabulary — built as on the
+    chip, fits it under 15 GB with its pool in place (an odd count of
+    64-wide rotary rows, packed two to a row); its decode lanes attend
+    through the paged latent kernel, one call a layer; and nothing in the
+    program has ``rows x 256`` expert rows a layer: the only arrays with
+    the experts' axis are the experts' own matrices."""
     import re
 
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("joyai-llm-flash", kind)
     assert (config.attn_sublayers, config.expert_layers) == (5, 4)
     assert args[1].shape[0] == 5 and args[2].shape[0] == 3
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes < 128 << 20, memory
     _no_row_of_every_expert(config, args, text)
+    _attends_through_the_latent_kernel(config, text)
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
     assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
         with_experts
+
+
+@pytest.mark.parametrize("name", ["longcat-flash-chat", "joyai-llm-flash"])
+def test_latent_program_off_the_chip_gathers_key_blocks(one_chip, name):
+    """What the two assertions above tell apart: the same decode span
+    built for no TPU (``_kernel_mode()`` is None here) runs the key-block
+    loop — no kernel, and the key blocks of all 32 lanes gathered."""
+    import re
+
+    config, fn, args = _cell_case(name, "decode")
+    _, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert re.search(r"bf16\[1024,16,512\]", text)
+    assert re.search(r"bf16\[1024,16,128\]", text)

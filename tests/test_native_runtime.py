@@ -14,7 +14,7 @@ from kubeshare_tpu.isolation.guard import apply_hbm_cap
 from kubeshare_tpu.runtime import ChipSupervisor, find_binary
 from kubeshare_tpu.utils.atomicfile import write_atomic
 
-from native_helpers import free_port, wait_listening
+from native_helpers import free_port, free_ports, wait_listening
 
 TOKEND = find_binary("tpushare-tokend")
 PMGR = find_binary("tpushare-pmgr")
@@ -1102,7 +1102,7 @@ def _start_gang_pair(tmp_path, exclusive=False):
                  "2\ngang/pod-x 1.0 0.4 0\nns/heavy 1.0 0.5 0\n")
     write_atomic(str(config_dir / "chip-1"),
                  "1\ngang/pod-x 1.0 0.4 0\n")
-    ports = [free_port(), free_port()]
+    ports = free_ports(2)
     procs = []
     for i in range(2):
         cmd = [TOKEND, "-p", str(config_dir), "-f", f"chip-{i}",
