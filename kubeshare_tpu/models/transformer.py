@@ -105,6 +105,23 @@ class TransformerConfig:
     # and the leading layers whose feed-forward is dense
     n_shared_experts: int = 0
     first_dense_layers: int = 0
+    # "gqa_moe": the dense block's attention and cache row (a K and a V
+    # a KV head) at an explicit head width (None = d_model // n_heads;
+    # n_heads x head_width need not be d_model), with an RMSNorm over
+    # each head's q and k before the rotation, and the routed expert
+    # layer above as EVERY layer's feed-forward (no dense MLP, no shared
+    # expert).  rope_theta and norm_eps are its own.
+    head_width: Optional[int] = None
+    # the generation law, "gqa_moe" only.  diffusion_block B = 0: one
+    # token after another under the causal mask.  B > 0: generation by
+    # diffusion over aligned blocks of B positions — row i sees row j
+    # iff j // B <= i // B (bidirectional inside a block, causal across
+    # blocks); a block's unknown rows hold mask_token's embedding and
+    # are committed over diffusion_steps denoising passes, the most
+    # confident rows first (serving/paged.py paged_diffusion_pass)
+    diffusion_block: int = 0
+    diffusion_steps: int = 0
+    mask_token: int = 0
 
     def __post_init__(self) -> None:
         _check_block(self)
@@ -125,6 +142,8 @@ class TransformerConfig:
     def expert_layers(self) -> int:
         """Routed expert layers a forward pass runs (ops/moe.py
         routed_experts_apply; a ``moe_every`` mixture is not one)."""
+        if self.block == "gqa_moe":
+            return self.n_layers
         if not self.latent:
             return 0
         return self.n_layers - self.first_dense_layers
@@ -146,7 +165,18 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width is not None:
+            return self.head_width
         return self.d_model // self.n_heads
+
+    def transfer_counts(self) -> Tuple[int, ...]:
+        """Rows a diffusion block commits at each of its denoising
+        passes: ``diffusion_block`` spread over ``diffusion_steps``, the
+        remainder to the first passes (a pass commits fewer where fewer
+        rows are still masked)."""
+        base, extra = divmod(self.diffusion_block, self.diffusion_steps)
+        return tuple(base + (i < extra)
+                     for i in range(self.diffusion_steps))
 
     @property
     def kv_heads(self) -> int:
@@ -158,27 +188,13 @@ class TransformerConfig:
 _LATENT_SUBLAYERS = {"latent_shortcut": 2, "latent_moe": 1}
 
 
-def _check_block(config: TransformerConfig) -> None:
-    if config.block != "dense" and not config.latent:
-        raise ValueError(
-            f"block must be 'dense', 'latent_shortcut' or 'latent_moe', "
-            f"got {config.block!r}")
-    if not config.latent:
-        if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
-            raise ValueError(
-                "the dense block's rotary base (10000) and norm epsilon "
-                "(1e-6) are fixed; rope_theta and norm_eps are the "
-                "latent blocks'")
-        return
-    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                 "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
-                 "router_top_k", "expert_d_ff"):
+def _check_routed(config: TransformerConfig) -> None:
+    """What every block with the routed expert layer needs of it."""
+    for name in ("n_routed_experts", "router_top_k", "expert_d_ff"):
         if getattr(config, name) < 1:
             raise ValueError(
                 f"block {config.block!r} needs {name} >= 1, got "
                 f"{getattr(config, name)}")
-    if config.qk_rope_head_dim % 2:
-        raise ValueError("qk_rope_head_dim must be even")
     if config.moe_every is not None or config.attention_window is not None \
             or config.positional != "rope":
         raise ValueError(
@@ -189,6 +205,79 @@ def _check_block(config: TransformerConfig) -> None:
         raise ValueError(
             f"router_scoring must be 'softmax' or 'sigmoid', got "
             f"{config.router_scoring!r}")
+    total = config.n_routed_experts + config.n_zero_experts
+    if not 1 <= config.router_top_k <= total:
+        raise ValueError(
+            f"router_top_k must be in [1, {total}], got "
+            f"{config.router_top_k}")
+    last = config.first_expert_held + config.held_experts
+    if config.first_expert_held < 0 or config.held_experts < 1 \
+            or last > config.n_routed_experts:
+        raise ValueError(
+            f"experts held [{config.first_expert_held}, {last}) are not "
+            f"among the {config.n_routed_experts} routed experts")
+
+
+def _check_gqa_moe(config: TransformerConfig) -> None:
+    _check_routed(config)
+    if config.head_dim < 2 or config.head_dim % 2:
+        raise ValueError(
+            f"head_width must be even and >= 2, got {config.head_dim}")
+    if config.kv_heads < 1 or config.n_heads % config.kv_heads:
+        raise ValueError(
+            f"n_heads ({config.n_heads}) must be a multiple of n_kv_heads "
+            f"({config.kv_heads})")
+    if config.n_zero_experts or config.n_shared_experts \
+            or config.first_dense_layers or config.router_choice_bias:
+        raise ValueError(
+            "block 'gqa_moe' has the routed experts alone as every "
+            "layer's feed-forward: no zero-compute or shared expert, no "
+            "leading dense layer, no choice bias (the latent blocks')")
+    b, steps = config.diffusion_block, config.diffusion_steps
+    if b < 0 or (b == 0 and (steps or config.mask_token)):
+        raise ValueError(
+            f"diffusion_block must be >= 0, and diffusion_steps and "
+            f"mask_token mean nothing without it; got {b}, {steps}, "
+            f"{config.mask_token}")
+    if b and not 1 <= steps <= b:
+        raise ValueError(
+            f"diffusion_steps must be in [1, diffusion_block = {b}] (a "
+            f"pass commits at least one row), got {steps}")
+    if b and not 0 <= config.mask_token < config.vocab_size:
+        raise ValueError(
+            f"mask_token {config.mask_token} is not among the "
+            f"{config.vocab_size} ids")
+
+
+def _check_block(config: TransformerConfig) -> None:
+    if config.block == "gqa_moe":
+        return _check_gqa_moe(config)
+    if config.block != "dense" and not config.latent:
+        raise ValueError(
+            f"block must be 'dense', 'gqa_moe', 'latent_shortcut' or "
+            f"'latent_moe', got {config.block!r}")
+    if config.head_width is not None or config.diffusion_block \
+            or config.diffusion_steps or config.mask_token:
+        raise ValueError(
+            f"head_width, diffusion_block, diffusion_steps and "
+            f"mask_token are block 'gqa_moe''s; block {config.block!r} "
+            f"takes none of them")
+    if not config.latent:
+        if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
+            raise ValueError(
+                "the dense block's rotary base (10000) and norm epsilon "
+                "(1e-6) are fixed; rope_theta and norm_eps are the "
+                "latent blocks' and 'gqa_moe''s")
+        return
+    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim"):
+        if getattr(config, name) < 1:
+            raise ValueError(
+                f"block {config.block!r} needs {name} >= 1, got "
+                f"{getattr(config, name)}")
+    if config.qk_rope_head_dim % 2:
+        raise ValueError("qk_rope_head_dim must be even")
+    _check_routed(config)
     single = config.block == "latent_moe"
     if config.n_shared_experts < 0 or not (
             0 <= config.first_dense_layers <= config.n_layers):
@@ -201,17 +290,6 @@ def _check_block(config: TransformerConfig) -> None:
             "n_shared_experts and first_dense_layers are block "
             "'latent_moe''s: a 'latent_shortcut' double layer has two "
             "dense FFNs and one expert layer, every layer alike")
-    total = config.n_routed_experts + config.n_zero_experts
-    if not 1 <= config.router_top_k <= total:
-        raise ValueError(
-            f"router_top_k must be in [1, {total}], got "
-            f"{config.router_top_k}")
-    last = config.first_expert_held + config.held_experts
-    if config.first_expert_held < 0 or config.held_experts < 1 \
-            or last > config.n_routed_experts:
-        raise ValueError(
-            f"experts held [{config.first_expert_held}, {last}) are not "
-            f"among the {config.n_routed_experts} routed experts")
 
 
 def _latent_layer_init(keys, config: TransformerConfig, dense,
@@ -271,17 +349,43 @@ def _latent_layer_init(keys, config: TransformerConfig, dense,
     return layer
 
 
+def _gqa_moe_layer_init(keys, config: TransformerConfig, dense,
+                        layer_idx: int) -> Dict:
+    """One 'gqa_moe' layer: the dense block's attention at the explicit
+    head width with its two per-head norms, two norms, and the routed
+    experts, all held."""
+    d, h, h_kv, hd = (config.d_model, config.n_heads, config.kv_heads,
+                      config.head_dim)
+    e, fe = config.held_experts, config.expert_d_ff
+    attn = {"wq": dense(next(keys), (d, h, hd), d),
+            "wk": dense(next(keys), (d, h_kv, hd), d),
+            "wv": dense(next(keys), (d, h_kv, hd), d),
+            "wo": dense(next(keys), (h, hd, d), h * hd),
+            "q_norm": {"scale": jnp.ones((hd,))},
+            "k_norm": {"scale": jnp.ones((hd,))}}
+    return {"attn": attn,
+            "norm1": {"scale": jnp.ones((d,))},
+            "norm2": {"scale": jnp.ones((d,))},
+            "moe": {"router": dense(next(keys),
+                                    (d, config.n_routed_experts), d),
+                    "w_gate": dense(next(keys), (e, d, fe), d),
+                    "w_up": dense(next(keys), (e, d, fe), d),
+                    "w_down": dense(next(keys), (e, fe, d), fe)}}
+
+
 def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
-    if config.latent:
+    if config.latent or config.block == "gqa_moe":
         def dense(key, shape, fan_in):
             return (jax.random.normal(key, shape, jnp.float32)
                     * (1.0 / fan_in) ** 0.5)
 
+        layer_init = (_latent_layer_init if config.latent
+                      else _gqa_moe_layer_init)
         keys = iter(jax.random.split(rng, 2 + 20 * config.n_layers))
         d = config.d_model
         return {
             "embed": dense(next(keys), (config.vocab_size, d), d),
-            "layers": [_latent_layer_init(keys, config, dense, i)
+            "layers": [layer_init(keys, config, dense, i)
                        for i in range(config.n_layers)],
             "final_norm": {"scale": jnp.ones((d,))},
             "lm_head": dense(next(keys), (d, config.vocab_size), d),
@@ -671,6 +775,95 @@ def _latent_forward(params, tokens, config: TransformerConfig,
             jnp.float32(0.0))
 
 
+# ---------------------------------------------------------------------------
+# the 'gqa_moe' block: the dense block's K/V-a-head attention at an explicit
+# head width with per-head q/k norms, the routed experts as every layer's
+# feed-forward, and — where the configuration generates by diffusion over
+# blocks — a block-causal mask.  Shared, like the latent pieces, by the
+# unpaged forward below and the paged step programs (serving/paged.py).
+# ---------------------------------------------------------------------------
+
+def attend_reach(config: TransformerConfig, positions):
+    """The last row the query at each of ``positions`` sees: itself
+    under the causal mask, the last row of its aligned block of
+    ``diffusion_block`` positions under the block-causal one (row i sees
+    row j iff ``j // B <= i // B``).  Every cached attention masks by
+    ``key position <= reach``, so this is all of the mask."""
+    b = config.diffusion_block
+    if not b:
+        return positions
+    return positions // b * b + (b - 1)
+
+
+@jax.named_scope("attention")
+def gqa_qkv(attn, y, positions, config: TransformerConfig):
+    """A 'gqa_moe' layer's projections of ``y`` [B, C, d] at ``positions``
+    [B, C]: ``q`` [B, H, C, hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k
+    normed a head and then rotated (split halves)."""
+    dtype, eps = config.dtype, config.norm_eps
+    q = jnp.einsum("bsd,dhk->bhsk", y, attn["wq"].astype(dtype))
+    k = jnp.einsum("bsd,dhk->bhsk", y, attn["wk"].astype(dtype))
+    v = jnp.einsum("bsd,dhk->bhsk", y, attn["wv"].astype(dtype))
+    with jax.named_scope("qk_norm"):
+        q = _rms_norm(q, attn["q_norm"]["scale"], eps)
+        k = _rms_norm(k, attn["k_norm"]["scale"], eps)
+    return (apply_rope(q, positions, theta=config.rope_theta),
+            apply_rope(k, positions, theta=config.rope_theta), v)
+
+
+def gqa_moe_layers(params, x, config: TransformerConfig, attend_row,
+                   live=None):
+    """Every layer of a 'gqa_moe' block over ``x`` [B, C, d]:
+    ``attend_row(layer, attn_weights, y)`` is the attention's context of
+    the normed input ``y``, [B, H, C, hd] before the output projection —
+    where the callers differ, as in :func:`latent_layers`.  Returns (x,
+    routing counts int32[6] summed over the layers: ops/moe.py)."""
+    from ..ops.moe import ROUTING_COUNTS
+
+    dtype, eps = config.dtype, config.norm_eps
+    counts = jnp.zeros((len(ROUTING_COUNTS),), jnp.int32)
+    for i, layer in enumerate(params["layers"]):
+        y = _rms_norm(x, layer["norm1"]["scale"], eps)
+        o = attend_row(i, layer["attn"], y).astype(dtype)
+        with jax.named_scope("attention"):
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
+        y = _rms_norm(x, layer["norm2"]["scale"], eps)
+        out, layer_counts = routed_experts(layer["moe"], config, y, live)
+        x = x + out
+        counts = counts + layer_counts
+    return x, counts
+
+
+def _gqa_moe_forward(params, tokens, config: TransformerConfig,
+                     apply_head: bool = True):
+    """The unpaged forward of a 'gqa_moe' block: every layer attends its
+    own rows, each as far as :func:`attend_reach` says."""
+    dtype = config.dtype
+    b, seq = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
+    seen = jnp.arange(seq)[None, None, :] \
+        <= attend_reach(config, positions)[:, :, None]  # [B, C, S]
+    group = config.n_heads // config.kv_heads
+
+    def attend(_, attn, y):
+        q, k, v = gqa_qkv(attn, y, positions, config)
+        qg = q.reshape(b, config.kv_heads, group, seq, config.head_dim)
+        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k).astype(
+            jnp.float32) * config.head_dim ** -0.5
+        scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        return jnp.einsum("bhgqk,bhkd->bhgqd", probs, v).reshape(q.shape)
+
+    x = params["embed"][tokens].astype(dtype)
+    x, _ = gqa_moe_layers(params, x, config, attend)
+    x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
+    if not apply_head:
+        return x, jnp.float32(0.0)
+    return ((x @ params["lm_head"].astype(dtype)).astype(jnp.float32),
+            jnp.float32(0.0))
+
+
 def _select_attention(config: TransformerConfig):
     kind = config.attention
     window = config.attention_window
@@ -696,13 +889,14 @@ def _forward(params, tokens, config, attention_fn, pos_offset,
     ``kv_sink`` (a list) collects each layer's (k, v) projections —
     the bulk-prefill path fills the decode cache from them; remat is
     bypassed there (inference has no backward to rematerialize for)."""
-    if config.latent:
+    if config.latent or config.block == "gqa_moe":
         if kv_sink is not None or jnp.ndim(pos_offset) != 0:
             raise ValueError(
                 f"block {config.block!r} has no dense-cache or "
-                f"sequence-sharded forward: its cache is the paged latent "
+                f"sequence-sharded forward: its cache is the paged "
                 f"pool (serving/paged.py)")
-        return _latent_forward(params, tokens, config, apply_head)
+        forward = _latent_forward if config.latent else _gqa_moe_forward
+        return forward(params, tokens, config, apply_head)
     dtype = config.dtype
     seq = tokens.shape[1]
     x = params["embed"][tokens].astype(dtype)

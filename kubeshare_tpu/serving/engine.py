@@ -67,6 +67,17 @@ schedules at TOKEN granularity instead:
   reservation.  Hit-rate, not HBM, sets the cache ceiling; streams
   stay bit-exact with tiering off.
 
+- GENERATION BY DIFFUSION OVER BLOCKS (a model whose
+  ``TransformerConfig.diffusion_block`` is B > 0): a dispatch no longer
+  yields one token a lane.  Prefill covers the prompt's whole blocks
+  and yields no token; a lane holds a block state (which of B positions
+  are committed) and each dispatch is ONE pass over every lane's block
+  — a denoising pass that commits 0..B rows by confidence, or the
+  commit pass over a finished block, after which the lane's cached
+  length advances by B.  The same plan / dispatch / consume machinery
+  carries them (plan kinds "diffusion" and "mixed_diffusion"); a token
+  counts once, when it is served (docs/serving.md).
+
 Everything device-side is static-shaped — slot count, block tables,
 chunk widths — so after one warmup pass NOTHING recompiles
 (``compile_counts`` exposes the jit cache sizes; the zero-recompile
@@ -120,7 +131,9 @@ from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
 from .paged import (attend_path, key_block_entries, paged_copy_block,
-                    paged_decode_loop, paged_decode_span, paged_mixed_step,
+                    paged_decode_loop, paged_decode_span,
+                    paged_diffusion_pass, paged_diffusion_prefill,
+                    paged_mixed_diffusion_step, paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
                     paged_spec_loop, paged_upload_block,
                     paged_verify_span)
@@ -169,7 +182,7 @@ SLOW_DISPATCH_FACTOR = 8.0
 # device arrays at the head of each kind of in-flight record — what
 # _consume_inflight fetches before its bookkeeping
 _INFLIGHT_DEVICE_ARRAYS = {"span": 1, "verify": 2, "loop": 2,
-                           "spec_loop": 5}
+                           "spec_loop": 5, "diffusion": 2}
 
 
 def _pow2_ceil(n: int) -> int:
@@ -398,14 +411,16 @@ class EngineConfig:
     admission_ring: int = 0
 
 
-def _warmed_prefill_widths(ec: EngineConfig) -> set:
+def _warmed_prefill_widths(ec: EngineConfig, diffusion_block: int = 0) -> set:
     """The prefill-chunk bucket universe warmup compiles (and the
     autotuner's fused-budget envelope): the configured chunk plus every
     smaller power of two, capped at the slot row bound so a short pool
     folds over-wide buckets into one max_request_len-wide shape.  Empty
-    on a decode-role pool — no prefill shape ever dispatches there."""
+    on a decode-role pool — no prefill shape ever dispatches there.
+    Under generation by diffusion over blocks a chunk is whole blocks of
+    ``diffusion_block`` rows: no narrower bucket is ever planned."""
     widths = {ec.prefill_chunk}
-    w = 1
+    w = max(1, diffusion_block)
     while w < ec.prefill_chunk:
         widths.add(w)
         w *= 2
@@ -420,7 +435,8 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
     to be a scatter of inline raises — every interacting-knob
     constraint (and its loud message) is visible and extendable in ONE
     place, and a new knob adds a row instead of another branch."""
-    widths = _warmed_prefill_widths(ec)
+    b = config.diffusion_block
+    widths = _warmed_prefill_widths(ec, b)
     min_piece = min(widths) if widths else 1
     wire = (wire_block_bytes(
         ec.block_size, config.n_layers, config.kv_heads,
@@ -442,7 +458,45 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
         (f"pool_role={ec.pool_role!r} (serving/disagg.py migrates K/V "
          f"head slabs)", ec.pool_role != "both"),
     ) if asked]
+    # what still assumes that a dispatch yields one token a lane, causal,
+    # from the last token served
+    no_diffusion = [name for name, asked in (
+        ("speculative=True (a pass commits rows by confidence: there is "
+         "no draft to verify)", ec.speculative),
+        ("steps_per_launch > 1 (the device-resident loops advance one "
+         "token a lane a step)", ec.steps_per_launch > 1),
+        ("mesh_spec (serving/sharded.py has no twin of the diffusion "
+         "pass)", ec.mesh_spec is not None),
+        ("host_tier_bytes (a demoted page may hold rows of an unfinished "
+         "block)", ec.host_tier_bytes is not None),
+        ("a shared host tier (serving/kv_tier.py, serving/fabric.py)",
+         shared_host_tier is not None),
+        (f"pool_role={ec.pool_role!r} (serving/disagg.py hands over a "
+         f"first token, and prefill yields none here)",
+         ec.pool_role != "both"),
+        ("autotune=True (the tuner's knobs are the span's and the "
+         "drafts')", ec.autotune),
+        ("eos_token (a block's rows are served out of order: nothing "
+         "truncates a stream at a token yet)", ec.eos_token is not None),
+    ) if asked]
     return [
+        (bool(b) and bool(no_diffusion),
+         f"diffusion_block {b}: generation by diffusion over blocks (a "
+         f"lane holds a block state and a pass commits 0..{b} tokens) is "
+         f"not served yet by: {'; '.join(no_diffusion)}"),
+        (bool(b) and (bool(b & (b - 1)) or ec.block_size % b != 0
+                      or ec.prefill_chunk % b != 0),
+         f"diffusion_block {b} must be a power of two that divides "
+         f"block_size {ec.block_size} and prefill_chunk "
+         f"{ec.prefill_chunk}: a prefill chunk, a page and a prefix "
+         f"match are whole diffusion blocks"),
+        (bool(b) and ec.mixed_prefill_budget is not None
+         and ec.mixed_prefill_budget < b,
+         f"mixed_prefill_budget {ec.mixed_prefill_budget} is below one "
+         f"diffusion block of {b} rows — a fused chunk is whole blocks"),
+        (config.block == "gqa_moe" and not b and ec.mesh_spec is not None,
+         "block 'gqa_moe' is not served yet by mesh_spec: "
+         "serving/sharded.py shards the dense block's parameters"),
         (layout.kind != "kv_heads" and bool(not_served),
          f"block {config.block!r} caches the {layout.kind!r} row layout "
          f"(one row of {layout.k_row[1]} + {layout.v_row[1]} values a "
@@ -589,6 +643,27 @@ class _Pending:
     # the continuation's first token is a real inter-token stall and
     # must land in the TBT histogram (the metric exists for that tail)
     last_token_at: Optional[float] = None
+    # a RESUMED diffusion lane's unfinished block, as it stood: the
+    # passes over it left nothing in the pool, so the state is all of it
+    block: Optional["_BlockState"] = None
+
+
+@dataclass
+class _BlockState:
+    """The block a diffusion lane is denoising: rows ``slot.length ..
+    slot.length + B - 1``.  ``tokens`` [B] holds what is known (the
+    prompt's tail, the rows committed so far); ``masked`` [B] says which
+    rows are still unknown — position state, never ``token ==
+    mask_token``; ``open`` [B] which of them may be committed (a row
+    past the request's budget stays masked for good); ``step`` counts
+    the denoising passes the block has had, ``served`` the tokens it has
+    served."""
+
+    tokens: np.ndarray
+    masked: np.ndarray
+    open: np.ndarray
+    step: int = 0
+    served: int = 0
 
 
 @dataclass
@@ -652,7 +727,10 @@ class _StepPlan:
     and launches.  ``kind`` selects the dispatch — "prefill" (one
     standalone chunk), "decode" (the plain span), "verify" (the
     speculative draft-verify chunk), "mixed" / "mixed_verify" (the
-    fused prefill + decode-phase programs).  ``drafts`` maps slot index
+    fused prefill + decode-phase programs), and for a configuration that
+    generates by diffusion over blocks "diffusion" / "mixed_diffusion"
+    (one pass over every lane's block, alone or beside a chunk).
+    ``drafts`` maps slot index
     to that lane's proposed tokens; ``verify_width`` is the dispatch
     width W = 1 + the power-of-two-bucketed max draft length (a warmed
     shape by construction)."""
@@ -671,6 +749,7 @@ class _Slot:
         "prompt", "plan", "max_new", "temperature", "first_key",
         "step_keys", "result", "tenant", "emitted_prefix",
         "last_token_at", "drafter", "draft_width", "accept_rate",
+        "block",
     )
 
     def __init__(self, idx: int, table_width: int) -> None:
@@ -708,6 +787,10 @@ class _Slot:
         self.drafter: Optional[NGramDrafter] = None
         self.draft_width = 0
         self.accept_rate = 0.5
+        # a diffusion lane's current block (a resumed one's rides in
+        # from its _Pending until the prefill is done); ``generated``
+        # then holds the finished blocks' tokens only
+        self.block: Optional[_BlockState] = None
 
 
 def _step_program(kind: str, fn, donate_argnums):
@@ -835,7 +918,14 @@ class ServingEngine:
         # exactly this set, and the autotuner's fused-budget envelope
         # is confined to it (a tuned budget can only select among
         # already-compiled shapes)
-        self._warmed_widths = _warmed_prefill_widths(ec)
+        self._warmed_widths = _warmed_prefill_widths(
+            ec, config.diffusion_block)
+        # generation by diffusion over blocks: the block length B (0: one
+        # token after another) and the rows a block commits at each of
+        # its denoising passes
+        self._diffusion = config.diffusion_block
+        self._transfer = (config.transfer_counts() if self._diffusion
+                          else ())
         # autotuner-owned scheduling state: the effective device-loop
         # depth (starts at the configured ceiling; the tuner moves it
         # among warmed loop-K shapes) and the per-lane draft-width cap
@@ -952,6 +1042,14 @@ class ServingEngine:
         self.spec_accepted: Dict[str, int] = {}
         self._spec_accept: Dict[str, list] = {}
         self.tokens_generated = 0
+        # a diffusion configuration's passes: lane-passes by kind (a
+        # denoising pass over a block with masked rows, the commit pass
+        # over a finished one), the query rows they computed, the tokens
+        # they served and the blocks they finished
+        self.diffusion_passes: Dict[str, int] = {"denoise": 0, "commit": 0}
+        self.diffusion_rows = 0
+        self.diffusion_tokens_committed = 0
+        self.diffusion_blocks = 0
         # a routed block's step programs return their routing counts
         # (ops/moe.py routed_experts_apply), read with the tokens in
         # _consume_inflight: assignments by where the chosen expert
@@ -1071,10 +1169,39 @@ class ServingEngine:
                 routing=routed)
             return (pick_rows(logits, temps, keys), pk, pv, *counts)
 
+        if self._diffusion:
+            # whole blocks of the prompt under the block-causal mask;
+            # prefill yields no token, so there is no pick and no head
+            def prefill(w, pk, pv, tables, starts, active, tokens,
+                        last_rows):
+                return paged_diffusion_prefill(
+                    w, cfg, pk, pv, tables, starts, active, tokens,
+                    last_rows, routing=routed)
+
         # the pool buffers are DONATED: each step updates the cache in
         # place device-side instead of materializing a second pool (on a
         # fractional-HBM pod a transient 2x cache would blow the cap)
         self._prefill_step = _step_program("prefill", prefill, (1, 2))
+
+        def diffusion(w, pk, pv, tables, lengths, active, tokens, masked,
+                      open_rows, quota):
+            # one pass over every lane's block: the denoising pass and
+            # the commit pass are this one program (paged.py)
+            return paged_diffusion_pass(
+                w, cfg, pk, pv, tables, lengths, active, tokens, masked,
+                open_rows, quota, routing=routed)
+
+        def mixed_diffusion(w, pk, pv, p_table, p_start, p_tokens,
+                            p_last_row, d_tables, d_lengths, d_active,
+                            d_tokens, d_masked, d_open, d_quota):
+            return paged_mixed_diffusion_step(
+                w, cfg, pk, pv, p_table, p_start, p_tokens, p_last_row,
+                d_tables, d_lengths, d_active, d_tokens, d_masked, d_open,
+                d_quota, routing=routed)
+
+        self._diffusion_step = _step_program("diffusion", diffusion, (1, 2))
+        self._mixed_diffusion_step = _step_program(
+            "mixed_diffusion", mixed_diffusion, (1, 2))
 
         span = ec.decode_span
         eos = ec.eos_token
@@ -1261,7 +1388,27 @@ class ServingEngine:
         front."""
         if self.engine_config.pool_role == "prefill":
             return cover
-        return max(cover, prompt_len + max_new)
+        return max(cover, self._request_rows(prompt_len, max_new))
+
+    def _request_rows(self, prompt_len: int, max_new: int) -> int:
+        """Rows a request's prompt and generation reach: under
+        generation by diffusion over blocks the last block is written
+        whole, so up to the next multiple of the block length."""
+        rows, b = prompt_len + max_new, self._diffusion
+        return -(-rows // b) * b if b else rows
+
+    def _prefill_plan(self, prompt_len: int, start: int = 0):
+        """:func:`plan_prefill_chunks` of a prompt from ``start`` on.  A
+        diffusion configuration prefills the prompt's whole blocks only
+        (its last ``len % B`` tokens sit, already known, in the first
+        generated block) and needs no logits of them: a prompt shorter
+        than a block, or one the prefix cache covers, plans nothing."""
+        ec, b = self.engine_config, self._diffusion
+        rows = prompt_len // b * b if b else prompt_len
+        if b and rows <= start:
+            return [], rows
+        return plan_prefill_chunks(rows, ec.prefill_chunk,
+                                   ec.max_request_len, start)
 
     def submit(self, request: Request) -> RequestResult:
         """Queue a request; validation failures raise HERE (loudly), a
@@ -1278,6 +1425,11 @@ class ServingEngine:
                 f"temperature must be >= 0, got {request.temperature}")
         if request.temperature > 0.0 and request.rng is None:
             raise ValueError("sampled requests (temperature > 0) must carry rng")
+        if request.temperature > 0.0 and self._diffusion:
+            raise ValueError(
+                "a configuration that generates by diffusion over blocks "
+                "serves greedy requests only (temperature 0): its passes "
+                "commit the argmax by its confidence")
         if request.rid in self._results and not self._results[request.rid].done:
             raise ValueError(f"request id {request.rid!r} already in flight")
         try:
@@ -1289,9 +1441,9 @@ class ServingEngine:
             raise RuntimeError(
                 "a decode-role pool admits only through admit_migrated() "
                 "— submit to the DisaggRouter (or the prefill pool)")
-        plan, cover = plan_prefill_chunks(
-            prompt.size, ec.prefill_chunk, ec.max_request_len)
-        total_rows = max(cover, prompt.size + request.max_new_tokens)
+        plan, cover = self._prefill_plan(prompt.size)
+        total_rows = max(cover, self._request_rows(
+            prompt.size, request.max_new_tokens))
         if total_rows > ec.max_request_len:
             raise ValueError(
                 f"request {request.rid!r}: prompt {prompt.size} + "
@@ -1515,8 +1667,9 @@ class ServingEngine:
                 return _StepPlan("prefill", prefill_slot=slot,
                                  chunk=chunk)
             plan = self._plan_decode_phase(decode, fused=True)
-            plan.kind = ("mixed_verify" if plan.kind == "verify"
-                         else "mixed")
+            plan.kind = {"verify": "mixed_verify",
+                         "diffusion": "mixed_diffusion"}.get(plan.kind,
+                                                             "mixed")
             plan.prefill_slot, plan.chunk = slot, chunk
             return plan
         if prefill:
@@ -1553,6 +1706,9 @@ class ServingEngine:
         program and the device decides how many units actually
         execute."""
         ec = self.engine_config
+        if self._diffusion:
+            # one pass over every lane's block, whatever each is at
+            return _StepPlan("diffusion", decode_slots=decode)
         if ec.speculative:
             drafts = self._plan_drafts(decode)
             if drafts:
@@ -1608,6 +1764,8 @@ class ServingEngine:
             self._run_spec_loop_step(plan)
         elif plan.kind == "loop":
             self._run_loop_step(plan.decode_slots)
+        elif plan.kind in ("diffusion", "mixed_diffusion"):
+            self._run_diffusion_step(plan)
         else:
             self._run_decode_step(plan.decode_slots)
 
@@ -1691,6 +1849,8 @@ class ServingEngine:
         # the autotuner's fused-budget envelope): the configured chunk
         # plus smaller powers of two, capped at the slot row bound;
         # empty on a decode-role pool
+        if self._diffusion:
+            return self._warmup_diffusion()
         widths = self._warmed_widths
         s = ec.num_slots
         one = jnp.zeros((1,), jnp.int32)
@@ -1828,6 +1988,40 @@ class ServingEngine:
             self.pool = replace(self.pool, k=pk, v=pv)
         jax.block_until_ready(self.pool.k)
 
+    def _warmup_diffusion(self) -> None:
+        """:meth:`warmup` of a configuration that generates by diffusion
+        over blocks: one prefill chunk and (mixed batching on) one mixed
+        shape per bucketed width of whole blocks, the pass over the
+        lanes' blocks alone, and the copy-on-write's one shape.  Every
+        write lands in the scratch block."""
+        ec = self.engine_config
+        s, b = ec.num_slots, self._diffusion
+        one = jnp.zeros((1,), jnp.int32)
+        table = jnp.zeros((1, self._table_width), jnp.int32)
+        lanes = (jnp.zeros((s, self._table_width), jnp.int32),
+                 jnp.zeros((s,), jnp.int32), jnp.zeros((s,), bool),
+                 jnp.zeros((s, b), jnp.int32), jnp.zeros((s, b), bool),
+                 jnp.zeros((s, b), bool), jnp.zeros((s,), jnp.int32))
+        for width in sorted(self._warmed_widths):
+            tokens = jnp.zeros((1, width), jnp.int32)
+            pk, pv, *_ = self._prefill_step(
+                self.params, self.pool.k, self.pool.v, table, one,
+                jnp.zeros((1,), bool), tokens, one)
+            self.pool = replace(self.pool, k=pk, v=pv)
+            if ec.mixed and width <= self._mixed_budget:
+                _, _, pk, pv, *_ = self._mixed_diffusion_step(
+                    self.params, self.pool.k, self.pool.v, table, one,
+                    tokens, one, *lanes)
+                self.pool = replace(self.pool, k=pk, v=pv)
+        _, _, pk, pv, *_ = self._diffusion_step(
+            self.params, self.pool.k, self.pool.v, *lanes)
+        self.pool = replace(self.pool, k=pk, v=pv)
+        if self.prefix_index is not None:
+            zero = jnp.zeros((), jnp.int32)
+            pk, pv = self._copy_step(self.pool.k, self.pool.v, zero, zero)
+            self.pool = replace(self.pool, k=pk, v=pv)
+        jax.block_until_ready(self.pool.k)
+
     def compile_counts(self) -> Dict[str, int]:
         """Jit cache sizes per step function — the zero-recompile
         assertion's raw data."""
@@ -1837,6 +2031,8 @@ class ServingEngine:
             "mixed": self._mixed_step._cache_size(),
             "verify": self._verify_step._cache_size(),
             "mixed_verify": self._mixed_verify_step._cache_size(),
+            "diffusion": self._diffusion_step._cache_size(),
+            "mixed_diffusion": self._mixed_diffusion_step._cache_size(),
             "copy": self._copy_step._cache_size(),
             "upload": self._upload_step._cache_size(),
             "loop": sum(step._cache_size()
@@ -2217,6 +2413,30 @@ class ServingEngine:
             "included: held assignments over this is how full the tiles "
             "ran.", "counter")
         moe_tile_rows.add(dict(plabel), self.moe_tile_rows)
+        diff_passes = MetricFamily(
+            "kubeshare_serving_diffusion_passes_total",
+            "Lane-passes of a configuration that generates by diffusion "
+            "over blocks, by kind: denoise (a pass over a block with "
+            "masked rows: it may commit some) or commit (the pass over a "
+            "finished block, whose K/V stay in the pool).", "counter")
+        for kind in sorted(self.diffusion_passes):
+            diff_passes.add({"kind": kind, **plabel},
+                            self.diffusion_passes[kind])
+        diff_rows = MetricFamily(
+            "kubeshare_serving_diffusion_rows_total",
+            "Query rows those lane-passes computed (a block's rows each "
+            "pass).", "counter")
+        diff_rows.add(dict(plabel), self.diffusion_rows)
+        diff_tokens = MetricFamily(
+            "kubeshare_serving_diffusion_tokens_committed_total",
+            "Tokens the denoising passes committed: each is served, and "
+            "counted in tokens_generated, once, then.", "counter")
+        diff_tokens.add(dict(plabel), self.diffusion_tokens_committed)
+        diff_blocks = MetricFamily(
+            "kubeshare_serving_diffusion_blocks_total",
+            "Diffusion blocks finished and committed to the pool.",
+            "counter")
+        diff_blocks.add(dict(plabel), self.diffusion_blocks)
         view_rows = MetricFamily(
             "kubeshare_serving_view_rows_total",
             "View rows a lane of the step programs' attention, summed "
@@ -2232,6 +2452,7 @@ class ServingEngine:
         view_rows.add({"kind": "held", **plabel}, self.view_rows_held)
         return [req, blocks, tokens, dispatches, loop_units,
                 moe_assign, moe_touched, moe_tiles, moe_tile_rows, view_rows,
+                diff_passes, diff_rows, diff_tokens, diff_blocks,
                 spec_loop_units, exit_reason, depth_summary, host_s,
                 guard_wait, guard_calls, slow, planner, prefix,
                 hit_tokens, evicted, tier_blocks,
@@ -2451,7 +2672,14 @@ class ServingEngine:
         ec = self.engine_config
         prompt = pending.prompt
         matched, chain = self.prefix_index.match_tiered(prompt)
-        matched = min(matched, prompt.size - 1)
+        if self._diffusion:
+            # under the block-causal mask a row's keys depend on its
+            # whole block: a match is good for the whole diffusion blocks
+            # it covers, of those the prompt prefills
+            b = self._diffusion
+            matched = min(matched, prompt.size) // b * b
+        else:
+            matched = min(matched, prompt.size - 1)
         if limit is not None:
             matched = min(matched, limit)
         if matched <= 0:
@@ -2477,8 +2705,7 @@ class ServingEngine:
                 cow_src = tail.block
             else:
                 host_cow = tail
-        plan, cover = plan_prefill_chunks(
-            prompt.size, ec.prefill_chunk, ec.max_request_len, matched)
+        plan, cover = self._prefill_plan(prompt.size, matched)
         total_rows = self._lifetime_rows(prompt.size, pending.max_new,
                                          cover)
         needed = (self.allocator.blocks_for_tokens(total_rows)
@@ -2837,7 +3064,7 @@ class ServingEngine:
             # the match point (or a tiny prompt replans from 0),
             # re-prefilling cached rows — only rows no plan chunk
             # rewrites were actually skipped
-            skipped = min(hit.start, min(s for s, _, _ in plan))
+            skipped = min([hit.start] + [s for s, _, _ in plan])
             self.prefix_hit_requests += 1
             self.prefix_hit_tokens += skipped
         self.requests_admitted += 1
@@ -2869,6 +3096,12 @@ class ServingEngine:
         slot.result = self._results[pending.rid]
         if slot.result.admitted_at is None:
             slot.result.admitted_at = time.monotonic()
+        if self._diffusion:
+            slot.block = pending.block
+            if not slot.plan:
+                # nothing to prefill (a prompt shorter than a block, or
+                # one the prefix cache covers): straight to its passes
+                self._begin_diffusion(slot)
         ec = self.engine_config
         if ec.speculative:
             # drafting state: the lane's lookup window starts as its
@@ -2934,16 +3167,26 @@ class ServingEngine:
         trie walk restarts prefill right there and the continuation is
         bit-exact (sampled requests carry their remaining key schedule:
         emission k of the original consumes ``step_keys[k-1]``, which
-        becomes the resumed request's ``first_key``)."""
-        done = len(slot.generated)  # >= 1 in decode state
-        if self.prefix_index is not None:
-            cached_seq = np.concatenate(
-                [slot.prompt,
-                 np.asarray(slot.generated[:-1], np.int32)])
+        becomes the resumed request's ``first_key``).
+
+        A DIFFUSION lane resumes from its last committed block by the
+        same arithmetic: the pool holds rows ``0 .. slot.length - 1``
+        (the prompt's whole blocks and the finished generated blocks,
+        each left by the pass over the finished block), ``generated``
+        holds the finished blocks' tokens, and the unfinished block's
+        passes left nothing in the pool — its state (what is committed,
+        what is masked, the pass it is at) rides with the request, so
+        the continuation's passes are the unpreempted run's and a token
+        served before the preemption is not served, or counted, again."""
+        done = len(slot.generated)  # >= 1 in decode state, but for a
+        # diffusion lane still in its first generated block
+        resume_prompt = np.concatenate(
+            [slot.prompt, np.asarray(slot.generated, np.int32)])
+        if self.prefix_index is not None and slot.length:
             n_cached = self.allocator.blocks_for_tokens(slot.length)
             cached_blocks = [int(b) for b in slot.table[:n_cached]]
             newly_cached, displaced = self.prefix_index.insert(
-                cached_seq, cached_blocks)
+                resume_prompt[:slot.length], cached_blocks)
             self.allocator.mark_cached(newly_cached)
             for b in displaced:
                 self.allocator.uncache(b)
@@ -2952,14 +3195,10 @@ class ServingEngine:
         # reservation that needs only a few blocks shaves the cached
         # chain instead of wiping it, so the resume still hits
         self.allocator.reclaim(slot.blocks[::-1])
-        ec = self.engine_config
-        resume_prompt = np.concatenate(
-            [slot.prompt, np.asarray(slot.generated, np.int32)])
         remaining = slot.max_new - done
-        plan, cover = plan_prefill_chunks(
-            resume_prompt.size, ec.prefill_chunk, ec.max_request_len)
+        plan, cover = self._prefill_plan(resume_prompt.size)
         needed = self.allocator.blocks_for_tokens(
-            max(cover, resume_prompt.size + remaining))
+            max(cover, self._request_rows(resume_prompt.size, remaining)))
         if slot.temperature > 0.0:
             first_key = np.asarray(slot.step_keys[done - 1])
             step_keys = np.asarray(slot.step_keys[done:])
@@ -2972,7 +3211,7 @@ class ServingEngine:
             plan=plan, needed=needed, first_key=first_key,
             step_keys=step_keys,
             emitted=slot.emitted_prefix + slot.generated,
-            last_token_at=slot.last_token_at)
+            last_token_at=slot.last_token_at, block=slot.block)
         if self.on_preempt_requeue is not None:
             # disagg: the resume must re-prefill, which happens in the
             # PREFILL pool — the router re-plans the entry with that
@@ -3045,7 +3284,8 @@ class ServingEngine:
             # the furthest row any lane holds once the dispatch's first
             # rows are written: a decode lane's length and its new row,
             # the chunk's end
-            reach = max([s.length + 1 for s in plan.decode_slots]
+            step_rows = self._diffusion or 1  # a lane's new rows
+            reach = max([s.length + step_rows for s in plan.decode_slots]
                         + [sum(plan.chunk[:2]) if plan.chunk else 0])
             attrs = {"kind": plan.kind, "lanes": len(plan.decode_slots),
                      "rows": sum(s.length for s in plan.decode_slots),
@@ -3073,7 +3313,8 @@ class ServingEngine:
         if plan.kind in ("verify", "mixed_verify"):
             query_rows = plan.verify_width
         elif plan.decode_slots:
-            query_rows = 1  # a span is so many one-row steps
+            # a span is so many one-row steps; a diffusion pass a block
+            query_rows = self._diffusion or 1
         else:
             query_rows = plan.chunk[1]
         config = self.model_config
@@ -3217,10 +3458,17 @@ class ServingEngine:
             chunk = slot.plan.pop(0)
         final, table, start, segment, last_row, temp, key = \
             self._prefill_lane(slot, chunk)
-        picked, pk, pv, *counts = self._dispatch(
-            self._prefill_step, self.params, self.pool.k, self.pool.v,
-            table, start, jnp.ones((1,), bool), segment, last_row,
-            temp, key)
+        if self._diffusion:
+            # whole blocks of the prompt: no token comes of them
+            picked = None
+            pk, pv, *counts = self._dispatch(
+                self._prefill_step, self.params, self.pool.k, self.pool.v,
+                table, start, jnp.ones((1,), bool), segment, last_row)
+        else:
+            picked, pk, pv, *counts = self._dispatch(
+                self._prefill_step, self.params, self.pool.k, self.pool.v,
+                table, start, jnp.ones((1,), bool), segment, last_row,
+                temp, key)
         self.pool = replace(self.pool, k=pk, v=pv)
         self._routing_inflight = (counts, segment.shape[1], 1)
         self.prefill_chunks += 1
@@ -3234,8 +3482,8 @@ class ServingEngine:
         # the first token; read when consumed (one step later), with
         # a routed block's counts
         if final or counts:
-            self._inflight = ("span", None,
-                              (slot, picked) if final else None)
+            self._inflight = ("diffusion" if self._diffusion else "span",
+                              None, (slot, picked) if final else None)
 
     def _run_decode_step(self, decode_slots: List[_Slot]) -> None:
         tables, lengths, active, tokens, temps, keys, budgets = \
@@ -3491,6 +3739,65 @@ class ServingEngine:
         self._inflight = ("span", (emitted, list(decode_slots), budgets),
                           (p_slot, picked) if final else None)
 
+    def _diffusion_lanes(self, decode_slots: List[_Slot]):
+        """Device arguments for one pass over the lanes' blocks: each
+        lane's table, its cached length (where its block begins), the
+        block's known tokens, which rows are still masked, which of them
+        may be committed, and how many this pass commits."""
+        s, b = self.engine_config.num_slots, self._diffusion
+        tables = np.zeros((s, self._table_width), np.int32)
+        lengths = np.zeros((s,), np.int32)
+        active = np.zeros((s,), bool)
+        tokens = np.zeros((s, b), np.int32)
+        masked = np.zeros((s, b), bool)
+        open_rows = np.zeros((s, b), bool)
+        quota = np.zeros((s,), np.int32)
+        for slot in decode_slots:
+            i, block = slot.idx, slot.block
+            tables[i] = slot.table
+            lengths[i] = slot.length
+            active[i] = True
+            tokens[i] = block.tokens
+            masked[i] = block.masked
+            open_rows[i] = block.open
+            # a finished block (the commit pass) has had every step
+            if block.step < len(self._transfer):
+                quota[i] = self._transfer[block.step]
+        return tables, lengths, active, tokens, masked, open_rows, quota
+
+    def _run_diffusion_step(self, plan: _StepPlan) -> None:
+        """One pass over every decode lane's block — for each lane the
+        denoising pass its block is at, or the commit pass over a
+        finished one (``paged.paged_diffusion_pass``) — and, in the mixed
+        flavour, one block-causal prefill chunk for the filling slot in
+        the same program.  It counts once in ``decode_steps``, and in
+        ``prefill_chunks`` and ``mixed_steps`` when it carries a chunk."""
+        lanes = [jnp.asarray(a) for a in
+                 self._diffusion_lanes(plan.decode_slots)]
+        p_slot, final, chunk_rows = plan.prefill_slot, False, 0
+        if p_slot is not None:
+            final, table, start, segment, last_row, _, _ = \
+                self._prefill_lane(p_slot, plan.chunk)
+            chunk_rows = segment.shape[1]
+            picked, commit, pk, pv, *counts = self._dispatch(
+                self._mixed_diffusion_step, self.params, self.pool.k,
+                self.pool.v, table, start, segment, last_row, *lanes)
+            self.prefill_chunks += 1
+            self.mixed_steps += 1
+            self._queue.charge(p_slot.tenant, plan.chunk[1])
+        else:
+            picked, commit, pk, pv, *counts = self._dispatch(
+                self._diffusion_step, self.params, self.pool.k,
+                self.pool.v, *lanes)
+        self.pool = replace(self.pool, k=pk, v=pv)
+        self.decode_steps += 1
+        self._routing_inflight = (
+            counts, chunk_rows + lanes[3].size, 1 + (p_slot is not None))
+        self._inflight = ("diffusion",
+                          (picked, commit, list(plan.decode_slots),
+                           chunk_rows),
+                          (p_slot, None) if final else None)
+
     def _verify_lanes(self, decode_slots: List[_Slot],
                       drafts: Dict[int, List[int]], width: int):
         """Device arguments for a verify chunk over the slot pool.
@@ -3602,7 +3909,7 @@ class ServingEngine:
         # place (on an unguarded engine the first read waits for the
         # device; on a guarded one the dispatch already has)
         with profiling.span("kubeshare.engine.fetch"):
-            first = (None if prefill_part is None
+            first = (None if prefill_part is None or prefill_part[1] is None
                      else int(np.asarray(prefill_part[1])[0]))
             fetched = ([] if decode_part is None else
                        [np.asarray(x) for x in
@@ -3612,6 +3919,16 @@ class ServingEngine:
         self._routing_inflight = ([], 0, 0)
         if routing:
             self._observe_routing(routing[0], rows, passes)
+        if kind == "diffusion":
+            if prefill_part is not None:
+                self._begin_diffusion(prefill_part[0])
+            if decode_part is not None:
+                # what the SAME dispatch's routing touched, and the rows
+                # of the chunk it carried beside the lanes' blocks
+                self._accept_diffusion(
+                    decode_part[2], *fetched, chunk=decode_part[3],
+                    touched=int(routing[0][3]) if routing else 0)
+            return True
         if prefill_part is not None:
             self._finish_prefill(prefill_part[0], first)
         if decode_part is not None:
@@ -3712,6 +4029,118 @@ class ServingEngine:
             self._retire_handoff(slot)
             return
         self._maybe_retire(slot, first)
+
+    def _begin_diffusion(self, slot: _Slot) -> None:
+        """The prompt's whole blocks are cached (or there were none): the
+        lane joins the decode pool at its first generated block.  No
+        token comes of the prefill: the first is served by a pass."""
+        slot.length = slot.prompt.size // self._diffusion * self._diffusion
+        slot.generated = []
+        slot.state = "decode"
+        if slot.block is None:  # a resumed lane's rode in with it
+            self._open_block(slot)
+
+    def _open_block(self, slot: _Slot) -> None:
+        """The block at ``slot.length``: the prompt's tail where it
+        reaches in is known, the rest masked; a row past the request's
+        budget stays masked and is never committed (nor served)."""
+        b, base = self._diffusion, slot.length
+        positions = base + np.arange(b)
+        known = positions < slot.prompt.size
+        tokens = np.zeros((b,), np.int32)
+        tokens[known] = slot.prompt[base: base + int(known.sum())]
+        slot.block = _BlockState(
+            tokens=tokens, masked=~known,
+            open=~known & (positions < slot.prompt.size + slot.max_new))
+
+    def _accept_diffusion(self, decode_slots: List[_Slot],
+                          picked: np.ndarray, commit: np.ndarray,
+                          chunk: int = 0, touched: int = 0) -> None:
+        """Host acceptance of one pass over the lanes' blocks.  A lane
+        whose block had open rows ran a denoising pass: the rows the
+        device chose are committed — and served: a token counts once,
+        here — and the request retires with the token that fills its
+        budget.  A lane whose block had none ran the commit pass: the
+        finished block's K/V stand in the pool, its cached length
+        advances by the block and the next block opens.  One
+        ``kubeshare.engine.diffusion`` span a dispatch says what it
+        carried: the lanes, their passes by kind, the query ``rows``
+        they computed and how many of them went in masked, the tokens
+        ``committed`` (served), the blocks done, the cached ``kv_rows``
+        the lanes attended — and, of the same dispatch, the ``chunk``
+        rows it carried beside them and the experts its routing
+        ``touched``, so that a reader need not pair two spans."""
+        b = self._diffusion
+        now = time.monotonic()
+        seen = dict(lanes=len(decode_slots), passes=0, commit_passes=0,
+                    rows=b * len(decode_slots), masked_rows=0, committed=0,
+                    blocks_done=0, kv_rows=0, chunk=chunk, touched=touched)
+        for slot in decode_slots:
+            block = slot.block
+            seen["kv_rows"] += slot.length
+            if not block.open.any():
+                seen["commit_passes"] += 1
+                seen["blocks_done"] += 1
+                slot.generated.extend(self._block_generated(slot))
+                slot.length += b
+                self._open_block(slot)
+                continue
+            seen["passes"] += 1
+            seen["masked_rows"] += int(block.masked.sum())
+            rows = np.flatnonzero(commit[slot.idx])
+            block.tokens[rows] = picked[slot.idx, rows]
+            block.masked[rows] = block.open[rows] = False
+            block.step += 1
+            if rows.size:
+                seen["committed"] += int(rows.size)
+                self._serve_tokens(slot, int(rows.size), now)
+            if len(slot.generated) + block.served >= slot.max_new:
+                self._retire_diffusion(slot)
+        # as the routing span: its attributes are what a trace's reader
+        # can reach, its length is this bookkeeping's
+        with profiling.span("kubeshare.engine.diffusion", **seen):
+            self.diffusion_passes["denoise"] += seen["passes"]
+            self.diffusion_passes["commit"] += seen["commit_passes"]
+            self.diffusion_rows += seen["rows"]
+            self.diffusion_tokens_committed += seen["committed"]
+            self.diffusion_blocks += seen["blocks_done"]
+
+    def _serve_tokens(self, slot: _Slot, count: int, now: float) -> None:
+        """``count`` tokens of a diffusion lane are served: the counters,
+        the tenant's charge, the first-token stamp and the token gaps."""
+        slot.block.served += count
+        self.tokens_generated += count
+        self.tenant_tokens[slot.tenant] = \
+            self.tenant_tokens.get(slot.tenant, 0) + count
+        self._queue.charge(slot.tenant, count)
+        gaps = count
+        if slot.result.first_token_at is None:
+            # a RESUMED slot keeps its original first-token time
+            slot.result.first_token_at = now
+            self._observe_ttft(slot.result.ttft, slot.tenant)
+            gaps -= 1
+        if gaps and slot.last_token_at is not None:
+            self._observe_tbt((now - slot.last_token_at) / gaps, gaps,
+                              slot.tenant)
+        slot.last_token_at = now
+
+    def _retire_diffusion(self, slot: _Slot) -> None:
+        """The lane served the token that fills its budget: the request
+        is done, with exactly ``max_new`` tokens in position order (the
+        finished blocks', then the last block's up to the budget — no
+        commit pass is run for a block nothing will read).  Only the
+        prompt's whole diffusion blocks are indexed: the K/V of its tail
+        were written beside generated rows of the same block."""
+        b = self._diffusion
+        self._retire(slot, slot.generated + self._block_generated(slot),
+                     slot.prompt.size // b * b)
+
+    def _block_generated(self, slot: _Slot) -> List[int]:
+        """The generated tokens of the lane's block, in position order:
+        its rows from the prompt's end to the request's budget."""
+        first = max(slot.prompt.size - slot.length, 0)
+        last = slot.prompt.size + slot.max_new - slot.length
+        return [int(t) for t in slot.block.tokens[first:last]]
 
     def _retire_handoff(self, slot: _Slot) -> None:
         """Free a slot whose request just migrated out: index the
@@ -4008,31 +4437,36 @@ class ServingEngine:
         eos = self.engine_config.eos_token
         if len(slot.generated) >= slot.max_new or (
                 eos is not None and token == eos):
-            result = slot.result
-            # a preempted-and-resumed request's earlier incarnations'
-            # tokens come first — the caller sees ONE contiguous stream
-            result.tokens = slot.emitted_prefix + list(slot.generated)
-            result.finished_at = time.monotonic()
-            if self.prefix_index is not None:
-                # index the prompt's blocks BEFORE dropping our refs:
-                # insertion routes them to the idle-cached pool instead
-                # of the free list (blocks past the prompt — pure decode
-                # rows — free normally).  Blocks the trie already held
-                # under identical tokens are simply not re-referenced;
-                # a displaced block (our longer tail upgrading an
-                # existing partial leaf) is uncached so its last reader
-                # frees it.
-                n_prompt = self.allocator.blocks_for_tokens(
-                    slot.prompt.size)
-                prompt_blocks = [int(b) for b in slot.table[:n_prompt]]
-                newly_cached, displaced = self.prefix_index.insert(
-                    slot.prompt, prompt_blocks)
-                self.allocator.mark_cached(newly_cached)
-                for b in displaced:
-                    self.allocator.uncache(b)
-            # tail-first reclaim: see _preempt — eviction shaves chains
-            # from the deepest block, preserving the shared head
-            self.allocator.reclaim(slot.blocks[::-1])
-            self.requests_finished += 1
-            slot._clear()
-            slot.state = "free"
+            self._retire(slot, list(slot.generated), slot.prompt.size)
+
+    def _retire(self, slot: _Slot, tokens: List[int],
+                indexed_rows: int) -> None:
+        """The request is done: its result, the prompt's first
+        ``indexed_rows`` rows into the prefix index, its blocks back."""
+        result = slot.result
+        # a preempted-and-resumed request's earlier incarnations'
+        # tokens come first — the caller sees ONE contiguous stream
+        result.tokens = slot.emitted_prefix + tokens
+        result.finished_at = time.monotonic()
+        if self.prefix_index is not None and indexed_rows:
+            # index the prompt's blocks BEFORE dropping our refs:
+            # insertion routes them to the idle-cached pool instead
+            # of the free list (blocks past the prompt — pure decode
+            # rows — free normally).  Blocks the trie already held
+            # under identical tokens are simply not re-referenced;
+            # a displaced block (our longer tail upgrading an
+            # existing partial leaf) is uncached so its last reader
+            # frees it.
+            n_prompt = self.allocator.blocks_for_tokens(indexed_rows)
+            prompt_blocks = [int(b) for b in slot.table[:n_prompt]]
+            newly_cached, displaced = self.prefix_index.insert(
+                slot.prompt[:indexed_rows], prompt_blocks)
+            self.allocator.mark_cached(newly_cached)
+            for b in displaced:
+                self.allocator.uncache(b)
+        # tail-first reclaim: see _preempt — eviction shaves chains
+        # from the deepest block, preserving the shared head
+        self.allocator.reclaim(slot.blocks[::-1])
+        self.requests_finished += 1
+        slot._clear()
+        slot.state = "free"

@@ -42,7 +42,7 @@ rows) and names what its decode lanes ran on the launch span
 (``attend``).
 
 The layers themselves are written once a block kind (:func:`_dense_layers`,
-:func:`_latent_layers`) and run by every step through
+:func:`_gqa_moe_layers`, :func:`_latent_layers`) and run by every step through
 :func:`_run_layers`.  The entry points:
 
 - :func:`paged_prefill_step`: a width-C prompt chunk writing its K/V
@@ -76,7 +76,14 @@ The layers themselves are written once a block kind (:func:`_dense_layers`,
   column under its own emission's temperature/PRNG key), and counts
   the accepted prefix with the dense decoder's exact acceptance rule;
 - :func:`paged_mixed_verify_step`: the speculative twin of the mixed
-  dispatch (prefill chunk + verify span, one program).
+  dispatch (prefill chunk + verify span, one program);
+- :func:`paged_diffusion_prefill`, :func:`paged_diffusion_pass`,
+  :func:`paged_mixed_diffusion_step`: a configuration that generates by
+  diffusion over blocks (``TransformerConfig.diffusion_block``) — a
+  block-causal chunk that yields no token, one pass over every lane's
+  block of B rows that see one another (the denoising pass and the
+  commit pass are the one program, with the pick, its confidence and the
+  choice of rows to commit on the device), and the two in one program.
 
 Equivalence with the dense cache is test-locked (tests/test_serving.py):
 greedy and sampled streams from the paged pool match ``init_kv_cache``
@@ -102,6 +109,7 @@ from ..models.decoding import (
     speculative_acceptance,
 )
 from ..models.transformer import (TransformerConfig, _rms_norm,
+                                  attend_reach, gqa_moe_layers, gqa_qkv,
                                   latent_absorbed, latent_attend_blocks,
                                   latent_layers, latent_qkv, latent_scale)
 from ..ops.moe import ROUTING_COUNTS
@@ -304,6 +312,11 @@ def key_block_entries(table_width: int, block_size: int) -> int:
                and e * block_size <= max(KEY_BLOCK, block_size))
 
 
+# the blocks whose cache row is a K and a V a KV head (kv_blocks.KVRowLayout
+# kind "kv_heads"): they attend through :func:`_attend_view`
+KV_HEADS_BLOCKS = ("dense", "gqa_moe")
+
+
 def _kernel_mode():
     """How the paged kernel (``ops/paged_attention``) can run where this
     step program is being built: compiled for a TPU, not at all
@@ -325,7 +338,7 @@ def attend_path(block: str, query_rows: int, table_width: int, pool_k,
     reaches; the latent blocks run it over a short view too).  The
     engine names it on its launch spans (``attend``)."""
     short = table_width * pool_k.shape[3] <= KEY_BLOCK
-    if block == "dense":
+    if block in KV_HEADS_BLOCKS:
         if short:
             return "whole"
         fits = kernel_fits(pool_k, pool_v, head_dim)
@@ -509,11 +522,43 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
     return x, pool_k, pool_v, counts
 
 
+def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
+                    tables, positions, blk, off, x, live):
+    """The 'gqa_moe' block's layers (``transformer.gqa_moe_layers`` puts
+    a layer together), same contract as :func:`_dense_layers` and the
+    same cache plumbing: a layer's K and V rows are written through
+    :func:`_write_rows` first and its queries then attend the lane's
+    view through :func:`_attend_view`.  What a query sees is
+    ``transformer.attend_reach`` of its position: itself and everything
+    before it, or — under generation by diffusion over blocks — its
+    whole aligned block, the rows after it too, which the step has just
+    written (write-then-attend is what makes a block's rows see one
+    another).  Queries of more than one row a lane (a diffusion pass's
+    ``diffusion_block`` rows, the prefill chunk) run the key-block loop,
+    as the verify spans do.  Also returns the step's routing counts
+    int32[7], as :func:`_latent_layers` does."""
+    reach = attend_reach(config, positions)
+
+    def attend(layer_idx, attn, y):
+        nonlocal pool_k, pool_v
+        q, k, v = gqa_qkv(attn, y, positions, config)
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        with jax.named_scope("attention"):
+            return _attend_view(q, pool_k, pool_v, layer_idx, tables, reach,
+                                None)
+
+    x, counts = gqa_moe_layers(params, x, config, attend, live)
+    counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
+    return x, pool_k, pool_v, counts
+
+
 # the layers' counts, then the rows that chose: what a routed step returns
 N_STEP_COUNTS = len(ROUTING_COUNTS) + 1
 
-_LAYERS = {"dense": _dense_layers, "latent_shortcut": _latent_layers,
-           "latent_moe": _latent_layers}
+_LAYERS = {"dense": _dense_layers, "gqa_moe": _gqa_moe_layers,
+           "latent_shortcut": _latent_layers, "latent_moe": _latent_layers}
 
 
 def _run_layers(params, config: TransformerConfig, *args):
@@ -526,6 +571,29 @@ def _with_routing(routing: bool, counts, *outputs):
     """A step's outputs, with a routed block's routing counts last when
     the caller asked for them."""
     return outputs + (counts,) if routing else outputs
+
+
+def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
+                  starts, active, tokens, last_rows):
+    """A prefill chunk's rows through the layers (see
+    :func:`paged_prefill_step`): the final hidden states [P, C, d], the
+    pool and the routing counts, before any head."""
+    dtype = config.dtype
+    chunk = tokens.shape[1]
+    bs = pool_k.shape[3]
+    positions = starts[:, None] + jnp.arange(chunk)[None, :]  # [P, C]
+    blk = jnp.take_along_axis(tables, positions // bs, axis=1)  # [P, C]
+    blk = jnp.where(active[:, None], blk, 0)
+    off = positions % bs
+    x = params["embed"][tokens].astype(dtype)  # [P, C, d]
+    if config.positional != "rope":
+        x = x + params["pos_embed"][positions].astype(dtype)
+    # a chunk's rows after its last real one are padding
+    live = active[:, None] & (
+        jnp.arange(chunk)[None, :] <= last_rows[:, None])
+    return _run_layers(
+        params, config, pool_k, pool_v, tables, positions, blk, off, x,
+        live)
 
 
 def paged_prefill_step(
@@ -563,21 +631,9 @@ def paged_prefill_step(
     lanes here.
     """
     dtype = config.dtype
-    chunk = tokens.shape[1]
-    bs = pool_k.shape[3]
-    positions = starts[:, None] + jnp.arange(chunk)[None, :]  # [P, C]
-    blk = jnp.take_along_axis(tables, positions // bs, axis=1)  # [P, C]
-    blk = jnp.where(active[:, None], blk, 0)
-    off = positions % bs
-    x = params["embed"][tokens].astype(dtype)  # [P, C, d]
-    if config.positional != "rope":
-        x = x + params["pos_embed"][positions].astype(dtype)
-    # a chunk's rows after its last real one are padding
-    live = active[:, None] & (
-        jnp.arange(chunk)[None, :] <= last_rows[:, None])
-    x, pool_k, pool_v, counts = _run_layers(
-        params, config, pool_k, pool_v, tables, positions, blk, off, x,
-        live)
+    x, pool_k, pool_v, counts = _prefill_rows(
+        params, config, pool_k, pool_v, tables, starts, active, tokens,
+        last_rows)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
@@ -1249,4 +1305,148 @@ def paged_mixed_step(
         d_tables, d_lengths, d_active, d_tokens, d_temps, d_keys,
         d_budgets, routing=routing)
     return (p_picked, emitted, pk, pv,
+            *[p + d for p, d in zip(p_counts, d_counts)])
+
+
+# ---------------------------------------------------------------------------
+# generation by diffusion over blocks (TransformerConfig.diffusion_block):
+# a dispatch no longer yields one token a lane
+# ---------------------------------------------------------------------------
+
+def paged_diffusion_prefill(
+    params,
+    config: TransformerConfig,
+    pool_k,
+    pool_v,
+    tables,
+    starts,
+    active,
+    tokens,
+    last_rows,
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """:func:`paged_prefill_step` of a configuration that generates by
+    diffusion over blocks: the chunk is whole blocks of the prompt
+    (``starts`` and the real rows' count are multiples of
+    ``diffusion_block``), written and attended under the block-causal
+    mask, and yields NO token — the prompt's first generated block is
+    denoised by the passes that follow — so no head is applied.
+    Returns (pool_k, pool_v) and, with ``routing``, the counts."""
+    _, pool_k, pool_v, counts = _prefill_rows(
+        params, config, pool_k, pool_v, tables, starts, active, tokens,
+        last_rows)
+    return _with_routing(routing, counts, pool_k, pool_v)
+
+
+def paged_diffusion_pass(
+    params,
+    config: TransformerConfig,
+    pool_k,
+    pool_v,
+    tables,
+    lengths,
+    active,
+    tokens,
+    masked,
+    open_rows,
+    quota,
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """One pass over every active lane's current block of B =
+    ``config.diffusion_block`` rows: a denoising pass where the block
+    still has masked rows, the commit pass where it has none.  The two
+    are one program; what differs is what the engine does with it.
+
+    ``lengths`` [S] is each lane's cached length, a multiple of B: its
+    block sits at positions ``lengths[s] .. lengths[s] + B - 1``.
+    ``tokens`` [S, B] holds what is known of it (the prompt's tail, the
+    rows committed so far); a row that ``masked`` [S, B] says is still
+    unknown takes ``config.mask_token``'s embedding instead, whatever id
+    ``tokens`` holds there — masked-ness is the engine's position state,
+    never ``token == mask_token`` (a prompt may hold that id, and a pick
+    may be it).  The B rows' K/V are written at their positions first,
+    then all B attend the lane's view up to the block's last row: they
+    see one another and everything cached before.  Nothing moves
+    ``lengths``: the next pass over the block overwrites these rows, and
+    only the K/V of the pass over the FINISHED block are left standing,
+    when the engine advances the lane past it.
+
+    Every row's logits are its OWN token's (no shift).  In float32:
+    ``picked`` [S, B] the argmax, its confidence the softmax probability
+    of it, and ``commit`` [S, B] the rows this pass commits — of the
+    rows ``open_rows`` [S, B] says may be committed (masked, and inside
+    the request's budget), the ``quota[s]`` of highest confidence, ties
+    to the lowest index.  The ``[S, B, vocab]`` logits stay on the
+    device.  Returns (picked int32, commit bool, pool_k, pool_v) and,
+    with ``routing``, the counts last.  Inactive lanes write the scratch
+    block and commit nothing."""
+    dtype = config.dtype
+    b = tokens.shape[1]
+    bs = pool_k.shape[3]
+    positions = lengths[:, None] + jnp.arange(b)[None, :]  # [S, B]
+    blk = jnp.take_along_axis(tables, positions // bs, axis=1)
+    blk = jnp.where(active[:, None], blk, 0)
+    off = positions % bs
+    ids = jnp.where(masked, config.mask_token, tokens)
+    x = params["embed"][ids].astype(dtype)  # [S, B, d]
+    live = jnp.broadcast_to(active[:, None], positions.shape)
+    x, pool_k, pool_v, counts = _run_layers(
+        params, config, pool_k, pool_v, tables, positions, blk, off, x,
+        live)
+
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
+        logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    with jax.named_scope("denoise_pick"):
+        picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        top = jnp.max(logits, axis=-1, keepdims=True)
+        confidence = 1.0 / jnp.sum(jnp.exp(logits - top), axis=-1)
+        may = open_rows & active[:, None]
+        confidence = jnp.where(may, confidence, -jnp.inf)
+        mine, other = confidence[:, :, None], confidence[:, None, :]
+        row = jnp.arange(b)
+        ahead = (other > mine) | (
+            (other == mine) & (row[None, None, :] < row[None, :, None]))
+        rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)
+        commit = may & (rank < quota[:, None])
+    return _with_routing(routing, counts, picked, commit, pool_k, pool_v)
+
+
+def paged_mixed_diffusion_step(
+    params,
+    config: TransformerConfig,
+    pool_k,
+    pool_v,
+    p_table,
+    p_start,
+    p_tokens,
+    p_last_row,
+    d_tables,
+    d_lengths,
+    d_active,
+    d_tokens,
+    d_masked,
+    d_open,
+    d_quota,
+    routing: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """The mixed flavour of the diffusion dispatch: one pass over every
+    active lane's block and a block-causal prefill chunk for ONE filling
+    slot — the two entry points above in one program, over disjoint
+    writable blocks, as :func:`paged_mixed_step` composes its two.  The
+    filling slot is no lane of the pass and shares only read-only prefix
+    blocks with any, so neither side reads a row the other writes and
+    their order is free: **the pass runs first**.  Compiled chunk-first
+    the TPU compiler copies the whole pool four times (4.86 GB of
+    temporaries at ``sdar-30b-a3b-chat``'s size, 24 MB this way round:
+    ``tests/test_chip_compile.py``).  Returns (picked, commit, pool_k,
+    pool_v) and, with ``routing``, the pass's and the chunk's counts
+    summed."""
+    picked, commit, pk, pv, *d_counts = paged_diffusion_pass(
+        params, config, pool_k, pool_v, d_tables, d_lengths, d_active,
+        d_tokens, d_masked, d_open, d_quota, routing=routing)
+    pk, pv, *p_counts = paged_diffusion_prefill(
+        params, config, pk, pv, p_table, p_start,
+        jnp.ones_like(p_start, bool), p_tokens, p_last_row, routing=routing)
+    return (picked, commit, pk, pv,
             *[p + d for p, d in zip(p_counts, d_counts)])

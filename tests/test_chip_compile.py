@@ -31,7 +31,8 @@ from kubeshare_tpu.ops.attention import (  # noqa: E402
 from kubeshare_tpu.serving import paged  # noqa: E402
 from kubeshare_tpu.serving.paged import (  # noqa: E402
     KEY_BLOCK, paged_decode_loop, paged_decode_span, paged_decode_step,
-    paged_mixed_step, paged_prefill_step)
+    paged_diffusion_pass, paged_mixed_diffusion_step, paged_mixed_step,
+    paged_prefill_step)
 
 V5E_HBM_BYTES = 16 << 30
 
@@ -227,6 +228,20 @@ def _cell_case(name, kind):
     s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
     span = 4
     routing = {"routing": True} if config.routed else {}
+    if kind in ("diffusion", "mixed_diffusion"):
+        # one pass over every lane's block, alone or beside a chunk
+        rows = jax.ShapeDtypeStruct((s, config.diffusion_block), bool)
+        lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool),
+                 _i32(s, config.diffusion_block), rows, rows, _i32(s))
+        if kind == "diffusion":
+            fn = lambda w, pk, pv, *rest: paged_diffusion_pass(
+                w, config, pk, pv, *rest, **routing)
+            return config, fn, (params, pool_k, pool_v, *lanes)
+        fn = lambda w, pk, pv, *rest: paged_mixed_diffusion_step(
+            w, config, pk, pv, *rest, **routing)
+        return config, fn, (
+            params, pool_k, pool_v, _i32(1, t), _i32(1),
+            _i32(1, e["prefill_chunk"]), _i32(1), *lanes)
     lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
              jax.ShapeDtypeStruct((s,), jnp.float32),
              jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
@@ -376,6 +391,38 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
     assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
         with_experts
+
+
+@pytest.mark.parametrize("kind,temporaries", [
+    ("diffusion", 32 << 20), ("mixed_diffusion", 64 << 20)])
+def test_diffusion_program_compiles_and_fits(one_chip, monkeypatch, kind,
+                                             temporaries):
+    """The first pipeline stage of ``sdar-30b-a3b-chat`` at the published
+    widths — six layers of GQA 32 to 4 at head width 128, all 128 routed
+    experts of each, the whole vocabulary — built as on the chip: one pass
+    over 32 lanes' blocks of 4 rows, alone and beside a 512-token chunk,
+    fits under 15 GB with its 3 GiB pool written in place.  The lanes' 4
+    rows run the key-block loop (no kernel call: the paged kernel takes one
+    query row a lane), nothing in the program has ``rows x 128`` expert
+    rows a layer, and the only array as wide as the vocabulary beside the
+    head's matrices is the pass's own float32 logits (78 MB) and what the
+    pick makes of them.  Temporaries read 12,980,736 B (the pass alone)
+    and 26,047,488 B (mixed) with the pass ahead of the chunk; chunk
+    first, the compiler copied the pool four times (4,862,290,944 B)."""
+    import re
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    config, fn, args = _cell_case("sdar-30b-a3b-chat", kind)
+    assert (config.n_layers, config.expert_layers, config.head_dim) \
+        == (6, 6, 128)
+    assert args[1].shape == args[2].shape == (6, 16385, 4, 16, 128)
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    assert memory.temp_size_in_bytes < temporaries, memory
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    with_experts = set(re.findall(r"(?:bf16|f32)\[128,[0-9,]+\]", text))
+    assert {"bf16[128,2048,768]", "bf16[128,768,2048]"} <= with_experts
+    assert not re.search(r"\[128,(128|512|640),(2048|768)\]", text)
+    assert not re.search(r"f32\[[0-9,]*\b8192\b[0-9,]*\]", text)
 
 
 @pytest.mark.parametrize("name", ["longcat-flash-chat", "joyai-llm-flash"])

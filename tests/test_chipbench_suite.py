@@ -2,8 +2,8 @@
 ``chipbench/tests/test_chipbench.py`` (the generator, the metrics'
 arithmetic, the trace reduction, the roofline counts, `correct` against the
 plain reference and the two controls that have to fail) and
-``test_spans.py`` and ``test_tiles.py`` (the readers of the program's own
-spans), collected here
+``test_spans.py``, ``test_tiles.py`` and ``test_diffusion_readers.py`` (the
+readers of the program's own spans), collected here
 as ``tests/test_chipbench_contract.py`` collects the contract.  A PR that
 edits ``kubeshare_tpu/serving/`` learns here, not from the driver's
 refusal, what ``chipbench/system.py``, ``trace.py`` or a ``layer_metrics/``
@@ -21,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench.tests.test_chipbench import *  # noqa: E402,F401,F403
 from chipbench.tests.test_spans import *  # noqa: E402,F401,F403
 from chipbench.tests.test_tiles import *  # noqa: E402,F401,F403
+from chipbench.tests import test_diffusion_readers as _diffusion  # noqa: E402
 
 pytestmark = pytest.mark.usefixtures("chipbench_apart")
 
@@ -35,3 +36,12 @@ test_every_new_metric_has_its_file_and_its_cells = pytest.mark.xfail(
            "13 span metrics to be the LAST 13 of per_layer, and PR 27 added "
            "four after them; a benchmark PR's to repair")(
     test_every_new_metric_has_its_file_and_its_cells)  # noqa: F405
+
+# the diffusion readers' cases under names of their own (test_tiles.py gives
+# its third the same)
+test_diffusion_readers_over_spans_with_the_attributes = \
+    _diffusion.test_readers_over_spans_with_the_attributes
+test_diffusion_spans_that_lack_what_a_reader_reads_give_nothing = \
+    _diffusion.test_spans_that_lack_what_a_reader_reads_give_nothing
+test_a_program_without_the_diffusion_span_gives_nothing = \
+    _diffusion.test_a_program_without_the_span_gives_nothing

@@ -2,9 +2,11 @@
 ``tests/test_chipbench_suite.py`` runs the harness's own cases:
 ``chipbench/tests/test_second_block.py`` (a configuration of another block
 as new files only), ``test_longcat_twin.py`` and ``test_joyai_twin.py`` (the
-two latent, routed blocks' twins under the modules their cells name), each
+two latent, routed blocks' twins under the modules their cells name) and
+``test_sdar_twin.py`` (the diffusion block's), each
 served through the normal path, judged against its plain reference, and failed by its lower-precision
-control."""
+control (the diffusion block's also by the program that commits in index
+order)."""
 
 import os
 import sys
@@ -14,6 +16,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench.tests import test_joyai_twin as _joyai  # noqa: E402
+from chipbench.tests import test_sdar_twin as _sdar  # noqa: E402
 from chipbench.tests.test_longcat_twin import *  # noqa: E402,F401,F403
 from chipbench.tests.test_second_block import *  # noqa: E402,F401,F403
 
@@ -27,3 +30,13 @@ test_a_whole_window_of_the_joyai_twin_is_correct = \
     _joyai.test_a_whole_window_of_the_twin_is_correct
 test_the_joyai_twins_lower_precision_is_not_correct = \
     _joyai.test_the_twins_lower_precision_is_not_correct
+
+# ... and the third's
+test_the_sdar_cell_names_the_same_modules_as_its_twin = \
+    _sdar.test_the_cell_names_the_same_modules_as_its_twin
+test_a_whole_window_of_the_sdar_twin_is_correct = \
+    _sdar.test_a_whole_window_of_the_twin_is_correct
+test_the_sdar_twins_lower_precision_is_not_correct = \
+    _sdar.test_the_twins_lower_precision_is_not_correct
+test_the_sdar_twin_committing_in_index_order_is_not_correct = \
+    _sdar.test_the_twin_committing_in_index_order_is_not_correct
