@@ -1,7 +1,9 @@
 """Paged decode attention: Pallas TPU kernels over the serving pool itself.
 
-One query row a lane (the decode step) against that lane's OWN rows of
-the stacked pool, read through its block table.  The pool stays in HBM
+The query rows of a lane that share one reach — one row (the decode
+step), or the rows of one aligned diffusion block, which all see the
+block's last row — against that lane's OWN rows of the stacked pool,
+read through its block table.  The pool stays in HBM
 and is never transposed, windowed or copied: a page of it is one
 contiguous piece, and a kernel copies a lane's pages into fast memory
 many a compute block with asynchronous copies (started and waited for
@@ -22,9 +24,11 @@ they copy and in a compute block's mathematics:
   -0.5``, the causal band (and window), one running softmax in float32,
   the probabilities cast to the pool's dtype before they meet V, float32
   context, normalised once.  Query heads are grouped over their KV head
-  without repeating K/V; a group that is no whole tile of sublanes (12
-  of ``starcoder2-3b``'s 24 over 2) is padded with zero rows outside the
-  kernel and cut off after it.
+  without repeating K/V, and a lane's C rows of one reach with them: a
+  query group is ``(h / h_kv) x C`` rows (8 x 4 of a diffusion block at
+  ``sdar-30b-a3b-chat``, whose page is 16 KB); a group that is no whole
+  tile of sublanes (12 of ``starcoder2-3b``'s 24 over 2) is padded with
+  zero rows outside the kernel and cut off after it.
 - :func:`paged_latent_decode_attention`, the latent blocks' pool
   (``kv_blocks.KVRowLayout`` "latent"): ``pool_c.at[sub, page]`` is one
   ``[1, block_size, kv_lora_rank]`` piece (16 KB at the two routed
@@ -308,17 +312,26 @@ def _paged_call(kernel, name: str, scalars, arrays, pools, out_shape,
 def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
                            window: Optional[int] = None,
                            interpret: bool = False):
-    """Attention of one query row a lane, ``q`` [lanes, h, d], over each
-    lane's rows ``0 .. positions[lane]`` of pool layer ``layer_idx``
-    through ``tables`` [lanes, T]; returns the context [lanes, h, d],
-    normalised in float32 and rounded once to ``q``'s dtype.  A lane
-    whose table starts at the scratch block is idle and reads zeros.
+    """Attention of the query rows of a lane that share ONE reach — ``q``
+    [lanes, h, d], one row a lane (the decode step), or [lanes, h, C, d],
+    C rows that see the same keys (a diffusion pass's aligned block) —
+    over each lane's rows ``0 .. positions[lane]`` of pool layer
+    ``layer_idx`` through ``tables`` [lanes, T]; returns the context in
+    ``q``'s shape, normalised in float32 and rounded once to ``q``'s
+    dtype.  A lane's C rows of the ``h / h_kv`` query heads over one KV
+    head are one query group of the kernel, ``[lanes, h_kv, (h / h_kv) x
+    C, d]`` (32 rows a KV head at ``sdar-30b-a3b-chat``: two whole bf16
+    tiles), padded to whole tiles here and cut off after.  A lane whose
+    table starts at the scratch block is idle and reads zeros.
     Jitted to be traced once for all the layers of a step program (the
     layer is an argument) and inlined, as the key-block loop it stands in
     for (``serving/paged._attend_view_blocks``)."""
-    lanes, h, d = q.shape
+    one_row = q.ndim == 3
+    if one_row:
+        q = q[:, :, None]
+    lanes, h, rows, d = q.shape
     h_kv, bs = pool_k.shape[2], pool_k.shape[3]
-    group = h // h_kv
+    group = h // h_kv * rows
     tile = sublanes(q.dtype)
     padded = -(-group // tile) * tile
     q = jnp.pad(q.reshape(lanes, h_kv, group, d),
@@ -335,7 +348,8 @@ def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
         [pltpu.VMEM(buf, pool_k.dtype), pltpu.VMEM(buf, pool_v.dtype),
          pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((lanes,), jnp.int32)],
         interpret)
-    return out[:, :, :group].reshape(lanes, h, d)
+    out = out[:, :, :group].reshape(lanes, h, rows, d)
+    return out[:, :, 0] if one_row else out
 
 
 def _latent_kernel(layers_ref, tables_ref, positions_ref, q_abs_ref,

@@ -3319,7 +3319,8 @@ class ServingEngine:
             query_rows = plan.chunk[1]
         config = self.model_config
         return attend_path(config.block, query_rows, self._table_width,
-                           self.pool.k, self.pool.v, config.head_dim)
+                           self.pool.k, self.pool.v, config.head_dim,
+                           config.diffusion_block)
 
     def _report_slow_dispatch(self, entered: float, start: float,
                               launch: profiling.span,

@@ -26,12 +26,13 @@ and attended a key block at a time, the softmax carried across the
 blocks, and the loop stops where the furthest lane's last row lies
 (:func:`_attend_view` for the dense block, ``latent_attend_blocks`` for
 the latent ones; both carry the one running softmax,
-``models/transformer.attend_key_blocks``).  Where the queries are one
-row a lane (the decode step) and the program is built for a TPU, a
+``models/transformer.attend_key_blocks``).  Where a lane's query rows
+all see the same keys (the decode step's one row, a diffusion pass's one
+aligned block) and the program is built for a TPU, a
 Pallas kernel stands in for that loop (``ops/paged_attention``: one for
 the dense block's K and V a head, one for the latent blocks' latent row
 and packed rotary keys, over one shared page walk): each lane reads its
-OWN pages of the pool up to its own position, an idle lane none, and
+OWN pages of the pool up to its own reach, an idle lane none, and
 nothing is gathered or staged for all the lanes (:func:`attend_path`
 makes the choice, from shapes, the block's row layout and the backend).
 The dense block's view no longer than a key block is attended whole,
@@ -326,17 +327,24 @@ def _kernel_mode():
 
 
 def attend_path(block: str, query_rows: int, table_width: int, pool_k,
-                pool_v, head_dim: int) -> str:
+                pool_v, head_dim: int, diffusion_block: int = 0) -> str:
     """What a step's queries of ``query_rows`` rows a lane run over views
     of ``table_width`` table entries, chosen from what the program can
     see — the view's width, the query's, the block's row layout (the
     pool's shapes), the backend: "whole" (the dense block's view no
-    longer than one key block, attended at once), "kernel" (one row a
-    lane over a longer view, where the paged kernel of the block's row
-    layout can run: each lane reads its own pages, bounded by its own
-    length) or "blocks" (the key-block loop, as far as the furthest lane
-    reaches; the latent blocks run it over a short view too).  The
-    engine names it on its launch spans (``attend``)."""
+    longer than one key block, attended at once), "kernel" (a lane's
+    rows all see the same keys, over a longer view, where the paged
+    kernel of the block's row layout can run: each lane reads its own
+    pages, bounded by its own reach) or "blocks" (the key-block loop, as
+    far as the furthest lane reaches; the latent blocks run it over a
+    short view too).  A lane's rows see the same keys where they are one
+    row, or ``diffusion_block`` rows under generation by diffusion over
+    blocks: every program starts a lane's rows at a multiple of B
+    (:func:`paged_diffusion_pass`'s ``lengths``, the chunk's ``starts``),
+    so B rows are one aligned block and ``attend_reach`` gives them all
+    its last row.  A chunk of many blocks and the verify spans, whose
+    rows' reaches differ, run the loop.  The engine names the path on its
+    launch spans (``attend``)."""
     short = table_width * pool_k.shape[3] <= KEY_BLOCK
     if block in KV_HEADS_BLOCKS:
         if short:
@@ -344,33 +352,38 @@ def attend_path(block: str, query_rows: int, table_width: int, pool_k,
         fits = kernel_fits(pool_k, pool_v, head_dim)
     else:
         fits = not short and latent_kernel_fits(pool_k, pool_v)
-    if query_rows == 1 and fits and _kernel_mode():
+    if query_rows in (1, diffusion_block) and fits and _kernel_mode():
         return "kernel"
     return "blocks"
 
 
-def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window):
+def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window,
+                 diffusion_block: int = 0):
     """The dense block's attention of ``q`` [B, h, C, d] over each lane's
-    view of pool layer ``layer_idx``, under the per-query causal band.
+    view of pool layer ``layer_idx``, under the per-query causal band
+    (``positions`` [B, C]: the last row each query sees).
 
     A view longer than one key block is attended only as far as its
-    lanes hold rows.  One query row a lane (the decode step) goes through
-    the paged kernel where that can run: each lane reads its OWN pages of
-    the pool, up to its own position, and an idle lane reads none.  Wider
-    queries (the prefill chunk, a verify span), and every step off the
-    TPU, run the key-block loop (``_layer_reader``'s views of that part
-    of the table), as far as the furthest lane reaches.  A view no longer
-    than a key block is attended whole — a static shape, not a knob: a
-    one-trip loop buys nothing."""
+    lanes hold rows.  A lane's rows that all see the same keys — one row
+    (the decode step), or the ``diffusion_block`` rows of one aligned
+    block (a diffusion pass), which are one query group of C x h / h_kv
+    rows a KV head — go through the paged kernel where that can run:
+    each lane reads its OWN pages of the pool, up to its own reach, and
+    an idle lane reads none.  Queries whose rows' reaches differ (the
+    prefill chunk, a verify span), and every step off the TPU, run the
+    key-block loop (``_layer_reader``'s views of that part of the
+    table), as far as the furthest lane reaches.  A view no longer than a
+    key block is attended whole — a static shape, not a knob: a one-trip
+    loop buys nothing."""
     path = attend_path("dense", q.shape[2], tables.shape[1], pool_k, pool_v,
-                       q.shape[3])
+                       q.shape[3], diffusion_block)
     if path == "whole":
         view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
         return _attend_cached(q, view_k, view_v, positions, window=window)
     if path == "kernel":
         return paged_decode_attention(
-            q[:, :, 0], pool_k, pool_v, layer_idx, tables, positions[:, 0],
-            window=window, interpret=_kernel_mode() == "interpret")[:, :, None]
+            q, pool_k, pool_v, layer_idx, tables, positions[:, 0],
+            window=window, interpret=_kernel_mode() == "interpret")
     entries = key_block_entries(tables.shape[1], pool_k.shape[3])
     return _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables,
                                positions, entries, window)
@@ -533,10 +546,12 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     before it, or — under generation by diffusion over blocks — its
     whole aligned block, the rows after it too, which the step has just
     written (write-then-attend is what makes a block's rows see one
-    another).  Queries of more than one row a lane (a diffusion pass's
-    ``diffusion_block`` rows, the prefill chunk) run the key-block loop,
-    as the verify spans do.  Also returns the step's routing counts
-    int32[7], as :func:`_latent_layers` does."""
+    another).  A diffusion pass's ``diffusion_block`` rows a lane are one
+    aligned block with one reach and attend as one query group through
+    the paged kernel where :func:`attend_path` says it can run, as one
+    row a lane does under the causal mask; the prefill chunk, rows of
+    many blocks, runs the key-block loop.  Also returns the step's
+    routing counts int32[7], as :func:`_latent_layers` does."""
     reach = attend_reach(config, positions)
 
     def attend(layer_idx, attn, y):
@@ -547,7 +562,7 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
             k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
         with jax.named_scope("attention"):
             return _attend_view(q, pool_k, pool_v, layer_idx, tables, reach,
-                                None)
+                                None, config.diffusion_block)
 
     x, counts = gqa_moe_layers(params, x, config, attend, live)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
@@ -1379,7 +1394,9 @@ def paged_diffusion_pass(
     to the lowest index.  The ``[S, B, vocab]`` logits stay on the
     device.  Returns (picked int32, commit bool, pool_k, pool_v) and,
     with ``routing``, the counts last.  Inactive lanes write the scratch
-    block and commit nothing."""
+    block and commit nothing; their ``tables`` rows are the scratch block
+    too (``engine._diffusion_lanes`` marshals zeros), which is how the
+    paged kernel knows a lane is idle and reads nothing for it."""
     dtype = config.dtype
     b = tokens.shape[1]
     bs = pool_k.shape[3]

@@ -393,36 +393,69 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
         with_experts
 
 
-@pytest.mark.parametrize("kind,temporaries", [
-    ("diffusion", 32 << 20), ("mixed_diffusion", 64 << 20)])
-def test_diffusion_program_compiles_and_fits(one_chip, monkeypatch, kind,
-                                             temporaries):
-    """The first pipeline stage of ``sdar-30b-a3b-chat`` at the published
-    widths — six layers of GQA 32 to 4 at head width 128, all 128 routed
-    experts of each, the whole vocabulary — built as on the chip: one pass
-    over 32 lanes' blocks of 4 rows, alone and beside a 512-token chunk,
-    fits under 15 GB with its 3 GiB pool written in place.  The lanes' 4
-    rows run the key-block loop (no kernel call: the paged kernel takes one
-    query row a lane), nothing in the program has ``rows x 128`` expert
-    rows a layer, and the only array as wide as the vocabulary beside the
-    head's matrices is the pass's own float32 logits (78 MB) and what the
-    pick makes of them.  Temporaries read 12,980,736 B (the pass alone)
-    and 26,047,488 B (mixed) with the pass ahead of the chunk; chunk
-    first, the compiler copied the pool four times (4,862,290,944 B)."""
+DIFFUSION_TEMPORARIES = {"diffusion": 32 << 20, "mixed_diffusion": 64 << 20}
+# a key block of all 32 lanes x 32 table entries, 4 KV heads x 16 rows x 128
+DIFFUSION_KEY_BLOCK = r"bf16\[1024,4,16,128\]"
+
+
+def _diffusion_case(one_chip, kind):
+    """The pass (alone, or beside a 512-token chunk) of
+    ``sdar-30b-a3b-chat``'s first pipeline stage, compiled with its 3 GiB
+    pool in place: (config, program text)."""
     import re
 
-    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("sdar-30b-a3b-chat", kind)
     assert (config.n_layers, config.expert_layers, config.head_dim) \
         == (6, 6, 128)
     assert args[1].shape == args[2].shape == (6, 16385, 4, 16, 128)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
-    assert memory.temp_size_in_bytes < temporaries, memory
-    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert memory.temp_size_in_bytes < DIFFUSION_TEMPORARIES[kind], memory
     with_experts = set(re.findall(r"(?:bf16|f32)\[128,[0-9,]+\]", text))
     assert {"bf16[128,2048,768]", "bf16[128,768,2048]"} <= with_experts
     assert not re.search(r"\[128,(128|512|640),(2048|768)\]", text)
     assert not re.search(r"f32\[[0-9,]*\b8192\b[0-9,]*\]", text)
+    return config, text
+
+
+@pytest.mark.parametrize("kind", list(DIFFUSION_TEMPORARIES))
+def test_diffusion_program_compiles_and_fits(one_chip, monkeypatch, kind):
+    """The first pipeline stage of ``sdar-30b-a3b-chat`` at the published
+    widths — six layers of GQA 32 to 4 at head width 128, all 128 routed
+    experts of each, the whole vocabulary — built as on the chip: one pass
+    over 32 lanes' blocks of 4 rows, alone and beside a 512-token chunk,
+    fits under 15 GB with its 3 GiB pool written in place.  The lanes' 4
+    rows are one aligned block with one reach and attend through the paged
+    kernel, one call a layer (a query group of 4 rows x 8 heads a KV
+    head), so no key block of all 32 lanes is gathered
+    (``bf16[1024,4,16,128]``); the chunk's one lane, rows of 128 blocks,
+    still attends a key block at a time.  Nothing in the program has
+    ``rows x 128`` expert rows a layer, and the only array as wide as the
+    vocabulary beside the head's matrices is the pass's own float32
+    logits (78 MB) and what the pick makes of them.  Temporaries read
+    12,980,736 B (the pass alone) and 26,047,488 B (mixed) on the
+    key-block loop (PR 35) and 11,934,720 B / 25,079,808 B
+    through the kernel (PR 37), with the pass ahead of the chunk; chunk
+    first, the compiler copied the pool four times (4,862,290,944 B)."""
+    import re
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    config, text = _diffusion_case(one_chip, kind)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == config.n_layers
+    assert not re.search(DIFFUSION_KEY_BLOCK, text)
+    chunk_scores = re.search(rf"f32\[[0-9,]*\b512,{KEY_BLOCK}\]", text)
+    assert bool(chunk_scores) == (kind == "mixed_diffusion")
+
+
+def test_diffusion_program_off_the_chip_gathers_key_blocks(one_chip):
+    """What the assertions above tell apart: the same pass built for no
+    TPU (``_kernel_mode()`` is None here) runs the key-block loop — no
+    kernel, and the key blocks of all 32 lanes gathered."""
+    import re
+
+    _, text = _diffusion_case(one_chip, "diffusion")
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert re.search(DIFFUSION_KEY_BLOCK, text)
 
 
 @pytest.mark.parametrize("name", ["longcat-flash-chat", "joyai-llm-flash"])
