@@ -124,7 +124,7 @@ class TokenClient:
         co-run on a serial-core host in an earlier round; tokend.cc
         protocol notes).  Falls back to ``REQ`` polling against an older daemon
         that answers ``ERR`` for REQB."""
-        with span("kubeshare.client.acquire", pod=self.pod_name) as asked:
+        with span("kubeshare.client.acquire") as asked:
             return self._acquire(est_ms, asked)
 
     def _acquire(self, est_ms: float, asked: span) -> float:

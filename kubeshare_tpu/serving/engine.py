@@ -138,6 +138,7 @@ from .paged import (attend_path, key_block_entries, paged_copy_block,
                     paged_spec_loop, paged_upload_block,
                     paged_verify_span)
 from .prefix_index import PrefixIndex
+from . import stages
 from .sharded import ShardedServingContext
 from .qos import (DEFAULT_TENANT, QOS_GUARANTEE, QOS_OPPORTUNISTIC,
                   FairQueue, TenantRegistry, TenantSpec)
@@ -1022,7 +1023,6 @@ class ServingEngine:
             "admit": 0.0, "plan": 0.0, "dispatch": 0.0, "consume": 0.0,
             "tune": 0.0}
         self.host_planner_invocations = 0
-        self.steps = 0  # step() calls: `i` on the kubeshare.engine.step span
         # between _begin_launch and _dispatch: the plan being launched and
         # its open kubeshare.engine.marshal span
         self._launching: Optional[_StepPlan] = None
@@ -1606,33 +1606,38 @@ class ServingEngine:
             # the fleet's recovery walk
             self.fault_clock.on_engine_step(self)
         hs = self.host_seconds
-        with profiling.span("kubeshare.engine.step", i=self.steps):
-            self.steps += 1
-            with profiling.span("kubeshare.engine.admit") as phase:
-                self._admit()
-            hs["admit"] += phase.seconds
-            with profiling.span("kubeshare.engine.consume") as phase:
-                consumed = self._consume_inflight()
-            hs["consume"] += phase.seconds
-            # the tuner ticks BETWEEN consume and plan: it reads the
-            # fully-consumed counters and retunes its knobs before
-            # _plan_step consults them — and its wall time lands in the
-            # "tune" phase, never in "plan" (tuner overhead is
-            # first-class observable, and the planner/host counters
-            # exclude it; no tuner: the phase stays exactly zero)
-            if self._tuner is not None:
-                with profiling.span("kubeshare.engine.tune") as phase:
-                    self._tuner.tick()
-                hs["tune"] += phase.seconds
-            with profiling.span("kubeshare.engine.plan") as phase:
-                plan = self._plan_step()
-            hs["plan"] += phase.seconds
-            if plan is None:
-                return consumed
-            with profiling.span("kubeshare.engine.dispatch") as phase:
-                self._dispatch_plan(plan)
-            hs["dispatch"] += phase.seconds
-            return True
+        with profiling.span("kubeshare.engine.admit",
+                            queued=len(self._queue)) as phase:
+            before = (self.requests_admitted, self.prefix_hit_tokens)
+            self._admit()
+            # what the call did, beside how long it took: a longer
+            # admit with nothing admitted is the queue's walk, one
+            # with matched rows the trie's
+            phase.set(admitted=self.requests_admitted - before[0],
+                      matched_rows=self.prefix_hit_tokens - before[1])
+        hs["admit"] += phase.seconds
+        with profiling.span("kubeshare.engine.consume") as phase:
+            consumed = self._consume_inflight()
+        hs["consume"] += phase.seconds
+        # the tuner ticks BETWEEN consume and plan: it reads the
+        # fully-consumed counters and retunes its knobs before
+        # _plan_step consults them — and its wall time lands in the
+        # "tune" phase, never in "plan" (tuner overhead is
+        # first-class observable, and the planner/host counters
+        # exclude it; no tuner: the phase stays exactly zero)
+        if self._tuner is not None:
+            with profiling.span("kubeshare.engine.tune") as phase:
+                self._tuner.tick()
+            hs["tune"] += phase.seconds
+        with profiling.span("kubeshare.engine.plan") as phase:
+            plan = self._plan_step()
+        hs["plan"] += phase.seconds
+        if plan is None:
+            return consumed
+        with profiling.span("kubeshare.engine.dispatch") as phase:
+            self._dispatch_plan(plan)
+        hs["dispatch"] += phase.seconds
+        return True
 
     def _plan_step(self) -> Optional[_StepPlan]:
         """The scheduling decision, free of dispatch mechanics (the
@@ -1843,7 +1848,10 @@ class ServingEngine:
         adds one VERIFY shape per reachable draft width (and the fused
         mixed-verify cross product).  After this, a workload of any
         shape runs with ZERO recompilation (compile_counts stays fixed
-        — test-asserted)."""
+        — test-asserted).  Every program warmed is registered under its
+        name in ``serving/stages.py`` (:meth:`_warm`), from which a
+        trace's reader builds its table of stages; nothing is lowered
+        for that here."""
         ec = self.engine_config
         # the bucket universe is computed once in __init__ (shared with
         # the autotuner's fused-budget envelope): the configured chunk
@@ -1858,7 +1866,8 @@ class ServingEngine:
         for width in sorted(widths):
             # the pool rides through every warmup call (its buffers are
             # donated); the only writes land in the scratch block
-            _, pk, pv, *_ = self._prefill_step(
+            _, pk, pv, *_ = self._warm(
+                "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((1, self._table_width), jnp.int32),
                 one, jnp.zeros((1,), bool),
@@ -1873,7 +1882,8 @@ class ServingEngine:
             # EVERY width warms — the tuned budget may move up to any
             # warmed bucket, and a budget change must never compile
             if ec.mixed and (ec.autotune or width <= self._mixed_budget):
-                _, _, pk, pv, *_ = self._mixed_step(
+                _, _, pk, pv, *_ = self._warm(
+                    "mixed", (width,), self._mixed_step,
                     self.params, self.pool.k, self.pool.v,
                     jnp.zeros((1, self._table_width), jnp.int32), one,
                     jnp.zeros((1, width), jnp.int32), one,
@@ -1889,7 +1899,9 @@ class ServingEngine:
                     # every (prefill bucket) x (verify width) fused
                     # shape the speculative scheduler can reach
                     for k in self._verify_ks():
-                        _, _, _, pk, pv = self._mixed_verify_step(
+                        _, _, _, pk, pv = self._warm(
+                            "mixed_verify", (width, 1 + k),
+                            self._mixed_verify_step,
                             self.params, self.pool.k, self.pool.v,
                             jnp.zeros((1, self._table_width), jnp.int32),
                             one, jnp.zeros((1, width), jnp.int32), one,
@@ -1903,7 +1915,8 @@ class ServingEngine:
                             jnp.zeros((s, 1 + k, 2), jnp.uint32))
                         self.pool = replace(self.pool, k=pk, v=pv)
         if ec.pool_role != "prefill":
-            _, pk, pv, *_ = self._decode_step(
+            _, pk, pv, *_ = self._warm(
+                "decode", (), self._decode_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((s, self._table_width), jnp.int32),
                 zeros_s, jnp.zeros((s,), bool), zeros_s,
@@ -1916,7 +1929,8 @@ class ServingEngine:
             # dynamic).  The all-inactive warmup call exits at unit 0
             # — the loop cond checks any(alive) precisely so each
             # depth costs one compile and zero scratch-block work.
-            _, _, pk, pv = loop_step(
+            _, _, pk, pv = self._warm(
+                "loop", (k_depth,), loop_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((s, self._table_width), jnp.int32),
                 zeros_s, jnp.zeros((s,), bool), zeros_s,
@@ -1933,7 +1947,8 @@ class ServingEngine:
             # static part of the shape, zero rows when the ring is off.
             w = 1 + ec.draft_len
             r = ec.admission_ring
-            _, _, _, _, _, pk, pv = spec_step(
+            _, _, _, _, _, pk, pv = self._warm(
+                "spec_loop", (k_depth,), spec_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((s, self._table_width), jnp.int32),
                 zeros_s, jnp.zeros((s,), bool), zeros_s,
@@ -1957,7 +1972,8 @@ class ServingEngine:
             # controller confined to power-of-two widths <= draft_len,
             # so this small set is exhaustive
             for k in self._verify_ks():
-                _, _, pk, pv = self._verify_step(
+                _, _, pk, pv = self._warm(
+                    "verify", (1 + k,), self._verify_step,
                     self.params, self.pool.k, self.pool.v,
                     jnp.zeros((s, self._table_width), jnp.int32),
                     zeros_s, jnp.zeros((s,), bool),
@@ -1971,7 +1987,8 @@ class ServingEngine:
             # (a decode-role pool never admits through the prefix
             # matcher, so divergence copies cannot occur there)
             zero = jnp.zeros((), jnp.int32)
-            pk, pv = self._copy_step(self.pool.k, self.pool.v, zero, zero)
+            pk, pv = self._warm("copy", (), self._copy_step,
+                                self.pool.k, self.pool.v, zero, zero)
             self.pool = replace(self.pool, k=pk, v=pv)
         if self.host_tier is not None or ec.pool_role == "decode":
             # the ONE upload shape tier promotions AND migration
@@ -1982,11 +1999,19 @@ class ServingEngine:
             k_slab, v_slab = (
                 jnp.zeros(shape, cfg2.dtype) for shape in
                 kv_row_layout(cfg2).block_shapes(ec.block_size))
-            pk, pv = self._upload_step(
+            pk, pv = self._warm(
+                "upload", (), self._upload_step,
                 self.pool.k, self.pool.v, jnp.zeros((), jnp.int32),
                 k_slab, v_slab)
             self.pool = replace(self.pool, k=pk, v=pv)
         jax.block_until_ready(self.pool.k)
+
+    def _warm(self, kind: str, widths: Tuple[int, ...], fn, *args):
+        """One warm-up call of the step program ``fn``, registered as
+        ``<kind>/<width>`` (the name :meth:`_launch` gives the same
+        program's launches) with its arguments' shapes."""
+        stages.register(stages.program_name(kind, *widths), fn, args)
+        return fn(*args)
 
     def _warmup_diffusion(self) -> None:
         """:meth:`warmup` of a configuration that generates by diffusion
@@ -2004,21 +2029,25 @@ class ServingEngine:
                  jnp.zeros((s, b), bool), jnp.zeros((s,), jnp.int32))
         for width in sorted(self._warmed_widths):
             tokens = jnp.zeros((1, width), jnp.int32)
-            pk, pv, *_ = self._prefill_step(
+            pk, pv, *_ = self._warm(
+                "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v, table, one,
                 jnp.zeros((1,), bool), tokens, one)
             self.pool = replace(self.pool, k=pk, v=pv)
             if ec.mixed and width <= self._mixed_budget:
-                _, _, pk, pv, *_ = self._mixed_diffusion_step(
+                _, _, pk, pv, *_ = self._warm(
+                    "mixed_diffusion", (width,), self._mixed_diffusion_step,
                     self.params, self.pool.k, self.pool.v, table, one,
                     tokens, one, *lanes)
                 self.pool = replace(self.pool, k=pk, v=pv)
-        _, _, pk, pv, *_ = self._diffusion_step(
+        _, _, pk, pv, *_ = self._warm(
+            "diffusion", (), self._diffusion_step,
             self.params, self.pool.k, self.pool.v, *lanes)
         self.pool = replace(self.pool, k=pk, v=pv)
         if self.prefix_index is not None:
             zero = jnp.zeros((), jnp.int32)
-            pk, pv = self._copy_step(self.pool.k, self.pool.v, zero, zero)
+            pk, pv = self._warm("copy", (), self._copy_step,
+                                self.pool.k, self.pool.v, zero, zero)
             self.pool = replace(self.pool, k=pk, v=pv)
         jax.block_until_ready(self.pool.k)
 
@@ -3277,9 +3306,9 @@ class ServingEngine:
         any plan is a single-block pool write: a tier promotion's
         upload or a copy-on-write."""
         if plan is None:
-            attrs = {"kind": "upload" if fn is self._upload_step else "copy",
-                     "lanes": 0, "rows": 0, "chunk": 0, "reach": 0,
-                     "attend": ""}
+            kind = "upload" if fn is self._upload_step else "copy"
+            attrs = {"kind": kind, "lanes": 0, "rows": 0, "chunk": 0,
+                     "attend": "", "program": stages.program_name(kind)}
         else:
             # the furthest row any lane holds once the dispatch's first
             # rows are written: a decode lane's length and its new row,
@@ -3290,7 +3319,8 @@ class ServingEngine:
             attrs = {"kind": plan.kind, "lanes": len(plan.decode_slots),
                      "rows": sum(s.length for s in plan.decode_slots),
                      "chunk": plan.chunk[1] if plan.chunk else 0,
-                     "reach": reach, "attend": self._attend_of(plan)}
+                     "attend": self._attend_of(plan),
+                     "program": self._program_of(plan)}
             self.view_rows_held += attrs["rows"]
             # whole key blocks, which divide the view
             self.view_rows_reached += (
@@ -3305,6 +3335,17 @@ class ServingEngine:
             if result.first_dispatch_at is None:
                 result.first_dispatch_at = launch.start
         return out, launch
+
+    def _program_of(self, plan: _StepPlan) -> str:
+        """The name :meth:`warmup` registered ``plan``'s step program
+        under (``serving/stages.py``): its kind and the widths that pick
+        the compiled shape."""
+        widths = [plan.chunk[1]] if plan.chunk else []
+        if plan.kind in ("verify", "mixed_verify"):
+            widths.append(plan.verify_width)
+        elif plan.kind in ("loop", "spec_loop"):
+            widths.append(self._loop_k)
+        return stages.program_name(plan.kind, *widths)
 
     def _attend_of(self, plan: _StepPlan) -> str:
         """What ``plan``'s decode lanes' attention runs ("kernel",
@@ -3325,24 +3366,33 @@ class ServingEngine:
     def _report_slow_dispatch(self, entered: float, start: float,
                               launch: profiling.span,
                               wait: profiling.span) -> None:
-        """One WARNING for a gated dispatch that lasted seconds: what it
-        carried and where the time went (whether the guard went to the
-        broker is on its own span, in the ring)."""
+        """One WARNING for a gated dispatch that lasted seconds: which
+        program it was, what it carried and where the time went (whether
+        the guard went to the broker, and in how many round trips, is on
+        its own spans, in the ring)."""
         me = threading.current_thread().name
-        acquired = [r for r in profiling.spans(
-            since=entered, name="kubeshare.guard.acquire") if r[3] == me]
+
+        def last_of(name: str) -> Dict:
+            mine = [r for r in profiling.spans(since=entered, name=name)
+                    if r[3] == me]
+            return mine[-1][4] if mine else {}
+
+        acquired = last_of("kubeshare.guard.acquire")
+        trips = last_of("kubeshare.client.acquire").get("round_trips")
         seconds = {"acquire": start - entered, "launch": launch.seconds,
                    "device_wait": wait.seconds}
         self.slow_dispatches[max(seconds, key=seconds.get)] += 1
         self.log.warning(
             "slow dispatch: %.3f s against a running estimate of %.1f ms; "
-            "kind=%s lanes=%d chunk=%d guard.acquire=%.3f s (%s) "
+            "program=%s kind=%s lanes=%d chunk=%d guard.acquire=%.3f s (%s) "
             "launch=%.3f s device_wait=%.3f s",
             wait.end - entered, self._dispatch_estimate_ms,
-            launch.attrs["kind"], launch.attrs["lanes"],
-            launch.attrs["chunk"], seconds["acquire"],
+            launch.attrs["program"], launch.attrs["kind"],
+            launch.attrs["lanes"], launch.attrs["chunk"],
+            seconds["acquire"],
             "unnamed guard" if not acquired else
-            "broker" if acquired[-1][4].get("broker") else "held",
+            "held" if not acquired.get("broker") else
+            "broker" if trips is None else f"broker, {trips} round trips",
             seconds["launch"], seconds["device_wait"])
 
     def _next_prefill_slot(self, prefill: List[_Slot]) -> _Slot:

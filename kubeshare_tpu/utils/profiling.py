@@ -18,18 +18,72 @@ Where a phase has a counter, the span is how the counter is fed::
     with span("kubeshare.engine.admit") as s:
         self._admit()
     self.host_seconds["admit"] += s.seconds
+
+**Every span, its attributes, and who reads each.**  A reader is a per-layer
+metric of the benchmark (``chipbench/layer_metrics``: M), a counter of the
+metrics plane (``collect_metrics``: C) or the slow-dispatch WARNING line
+(``ServingEngine._report_slow_dispatch``: W).  A span or an attribute that
+none of them reads is not kept (PR 38 took out ``kubeshare.engine.step``
+and its ``i``, ``reach`` on the launch, ``pod`` on the client's span).
+
+=============================  ============================================
+``kubeshare.engine.admit``     M ``engine.schedule_ms_per_dispatch.*``; C
+                               ``host_seconds{admit}``.  ``queued`` (the
+                               queue's depth on entry), ``admitted``,
+                               ``matched_rows`` (prefix rows matched by this
+                               call): the ``admit`` key of the benchmark's
+                               ``program_stages`` line, beside that metric
+``kubeshare.engine.consume``   C ``host_seconds{consume}``
+``kubeshare.engine.fetch``     M ``engine.fetch_ms_per_dispatch.*``
+``kubeshare.engine.tune``      M ``engine.schedule_ms_*``; C ``{tune}``
+``kubeshare.engine.plan``      M ``engine.schedule_ms_*``; C ``{plan}``
+``kubeshare.engine.dispatch``  C ``host_seconds{dispatch}``
+``kubeshare.engine.marshal``   M ``engine.marshal_ms_per_dispatch.*``
+``kubeshare.engine.launch``    M: every ``*_per_dispatch`` metric divides by
+                               their count.  ``kind`` (M: a ``copy`` or
+                               ``upload`` is no dispatch; the module a launch
+                               ran; W), ``lanes`` and ``chunk`` (W), ``rows``
+                               and ``attend`` (M
+                               ``step.attend_kernel_hbm_roofline.*``: the
+                               rows the kernels had to read, where the lanes
+                               ran one), ``program`` (M ``step.stage_ms.*``
+                               and the rest of ``_stages.py``: the table its
+                               operations are booked by; W)
+``kubeshare.engine.device_wait``  M ``_stages.py`` (a launch's device time
+                               ends with it); W
+``kubeshare.engine.routing``   M ``moe.*``, ``step.*routed*``,
+                               ``step.experts_hbm_roofline.backlog``: ``rows``
+                               ``live`` ``passes`` ``held`` ``zero``
+                               ``absent`` ``touched`` ``tiles`` ``tile_rows``
+``kubeshare.engine.diffusion`` M ``diffusion.*``, ``step.diffusion_*``:
+                               ``lanes`` ``passes`` ``commit_passes`` ``rows``
+                               ``masked_rows`` ``committed`` ``blocks_done``
+                               ``kv_rows`` ``chunk`` ``touched``
+``kubeshare.guard.acquire``    M ``guard.broker_wait_ms`` (``pod``,
+                               ``broker``); C ``guard_wait_seconds``; W
+                               (``broker``)
+``kubeshare.guard.gated``      M ``dispatch.gated_idle_ms.*``,
+                               ``guard.cotenant_wall_over_device`` (``pod``)
+``kubeshare.client.acquire``   W (``round_trips``: how often tokend said
+                               WAIT before the grant)
+=============================  ============================================
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import glob
+import json
+import os
 import sys
 import threading
 import time
 from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["profile_trace", "span", "spans"]
+__all__ = ["STAGES_FILE", "profile_trace", "span", "spans"]
+
+STAGES_FILE = "kubeshare_stages.json"
 
 # (name, start, end, thread name, attrs): start and end on time.monotonic()
 _Record = Tuple[str, float, float, str, Dict[str, Any]]
@@ -107,14 +161,38 @@ def spans(since: Optional[float] = None,
 @contextlib.contextmanager
 def profile_trace(log_dir: Optional[str]) -> Iterator[None]:
     """Capture a profiler trace into ``log_dir`` (no-op when None): the
-    device's operations and every ``span`` of this process, on one clock."""
+    device's operations and every ``span`` of this process, on one clock.
+    On leaving, ``kubeshare_stages.json`` is written beside the
+    ``.xplane.pb``: for every step program a ``kubeshare.engine.launch`` of
+    the session named (of those still in the ring: its last 400 dispatches
+    or so), the table {instruction name: stage} by which the file's ``XLA
+    Ops`` events are booked to stages (``serving/stages.py``)."""
     if not log_dir:
         yield
         return
     import jax
 
+    started = time.monotonic()
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
+    _write_stage_tables(log_dir, started)
+
+
+def _write_stage_tables(log_dir: str, since: float) -> None:
+    stages = sys.modules.get("kubeshare_tpu.serving.stages")
+    if stages is None:
+        return  # this process warmed no step program
+    launched = {r[4].get("program")
+                for r in spans(since=since, name="kubeshare.engine.launch")}
+    tables = {name: table for name in sorted(launched - {None})
+              if (table := stages.stage_table(name)) is not None}
+    if not tables:
+        return
+    traces = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    where = os.path.dirname(traces[-1]) if traces else log_dir
+    with open(os.path.join(where, STAGES_FILE), "w") as f:
+        json.dump({"programs": tables}, f)

@@ -1,8 +1,9 @@
 """The main path's programs compile for the real chip — without the chip.
 
 The TPU compiler is installed wherever libtpu is, and compiles for a chip
-that is described and not attached (``jax.experimental.topologies``).  Each
-case below compiles one program of ``chip_smoke.py``'s main path at the
+that is described and not attached (``jax.experimental.topologies``).  The
+cells' step programs are also read by the program's own table of stages
+(``serving/stages.py``: ``_stages_hold``).  Each case below compiles one program of ``chip_smoke.py``'s main path at the
 flagship width for one v5e device and checks what only the real compiler
 can say: the Pallas kernel survived lowering (``tpu_custom_call`` — a
 silent demotion to the XLA reference fails here), and the program fits the
@@ -28,7 +29,7 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
     TransformerConfig, transformer_init)
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
-from kubeshare_tpu.serving import paged  # noqa: E402
+from kubeshare_tpu.serving import paged, stages  # noqa: E402
 from kubeshare_tpu.serving.paged import (  # noqa: E402
     KEY_BLOCK, paged_decode_loop, paged_decode_span, paged_decode_step,
     paged_diffusion_pass, paged_mixed_diffusion_step, paged_mixed_step,
@@ -279,6 +280,64 @@ def _compiled_in_place(fn, args, sharding, resident_limit):
     return memory, text
 
 
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+                "u32": 4, "f32": 4}
+# what takes no device time of its own: what holds others, what names data
+_NO_WORK = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+            "while", "conditional", "call"}
+
+
+def _stages_hold(config, args, text):
+    """The program's own table of stages (``serving/stages.py``) over the
+    compiled text: every paged kernel call is the attention's, every
+    instruction that reads an array with the experts' axis is the experts',
+    and of the result bytes of the instructions that run as operations of
+    their own (no fused computation's inside, nothing that only holds or
+    names data, and no view of the donated pool, which a write returns
+    whole) less than a tenth is under no stage."""
+    import math
+    import re
+
+    table = stages.instruction_stages(text)
+    fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", text))
+    blocks = str(args[1].shape[1])  # an axis only the pool's views have
+    stacked = {f"{config.held_experts},{a},{b}"
+               for a, b in ((config.d_model, config.expert_d_ff),
+                            (config.expert_d_ff, config.d_model))} \
+        if config.routed else set()
+    shapes, current, by_stage, kernels = {}, None, {}, 0
+    for line in text.splitlines():
+        line = re.sub(r"/\*.*?\*/", "", line)
+        found = stages._INSTRUCTION.match(line)
+        if found is None:
+            header = stages._COMPUTATION.match(line)
+            current = header.group(1) if header else current
+            continue
+        _, name, shape, opcode = found.groups()
+        shapes[name] = shape
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels += 1
+            assert table[name] == "attention", line[:200]
+        reads = {dims for operand in stages._operands(line, found.end())
+                 for dims in re.findall(r"\[([0-9,]+)\]",
+                                        shapes.get(operand, ""))}
+        if reads & stacked and opcode not in _NO_WORK \
+                and current not in fused:
+            assert table[name] == "experts", line[:200]
+        if current in fused or opcode in _NO_WORK:
+            continue
+        size = sum(
+            _DTYPE_BYTES.get(dtype, 4) * math.prod(map(int, dims.split(",")))
+            for dtype, dims in re.findall(r"\b([a-z]+[0-9]*)\[([0-9,]+)\]",
+                                          shape)
+            if blocks not in dims.split(","))
+        by_stage[table[name]] = by_stage.get(table[name], 0) + size
+    assert kernels > 0 and set(by_stage) <= set(stages.STAGES)
+    assert by_stage.get("unscoped", 0) < 0.1 * sum(by_stage.values()), \
+        by_stage
+    return by_stage
+
+
 # temporaries of the same programs on the key-block loop over a staged
 # slab (the parent of PR 30, compiled the same way)
 LOOP_TEMPORARIES = {
@@ -318,6 +377,8 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     chunk_scores = re.search(rf"f32\[[0-9,]*\b256,{KEY_BLOCK}\]", text)
     assert bool(chunk_scores) == (kind == "mixed")
     assert memory.temp_size_in_bytes <= LOOP_TEMPORARIES[name, kind], memory
+    by_stage = _stages_hold(config, args, text)
+    assert {"attention", "ffn", "kv_write", "head"} <= set(by_stage)
 
 
 def _attends_through_the_latent_kernel(config, text):
@@ -356,6 +417,9 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     assert memory.temp_size_in_bytes == temporaries, memory
     _no_row_of_every_expert(config, args, text)
     _attends_through_the_latent_kernel(config, text)
+    by_stage = _stages_hold(config, args, text)
+    assert {"attention", "ffn", "experts", "kv_write", "head"} \
+        <= set(by_stage)
 
 
 def _no_row_of_every_expert(config, args, text):
@@ -391,6 +455,9 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
     assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
         with_experts
+    by_stage = _stages_hold(config, args, text)
+    assert {"attention", "ffn", "experts", "kv_write", "head"} \
+        <= set(by_stage)
 
 
 DIFFUSION_TEMPORARIES = {"diffusion": 32 << 20, "mixed_diffusion": 64 << 20}
@@ -414,6 +481,9 @@ def _diffusion_case(one_chip, kind):
     assert {"bf16[128,2048,768]", "bf16[128,768,2048]"} <= with_experts
     assert not re.search(r"\[128,(128|512|640),(2048|768)\]", text)
     assert not re.search(r"f32\[[0-9,]*\b8192\b[0-9,]*\]", text)
+    if 'custom_call_target="tpu_custom_call"' in text:  # built as on the chip
+        by_stage = _stages_hold(config, args, text)
+        assert {"attention", "experts", "kv_write", "head"} <= set(by_stage)
     return config, text
 
 

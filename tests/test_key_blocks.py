@@ -217,8 +217,8 @@ def test_view_row_counters_read_what_the_lanes_held(monkeypatch, attend):
     """Every planned dispatch adds the view's width to ``configured``,
     its furthest lane's rows, rounded up to key blocks, to ``reached``
     and its decode lanes' own rows to ``held``; the launch span carries
-    the rows themselves and what the decode lanes' attention ran
-    (``attend``).  A view no longer than one key block is attended whole:
+    the lanes' rows and what their attention ran (``rows``, ``attend``:
+    what the kernels' roofline reads).  A view no longer than one key block is attended whole:
     reached is configured.  Through the paged kernel (interpreted here)
     the decode lanes read what they hold; a chunk with no lane beside it
     still runs the key-block loop."""
@@ -231,22 +231,35 @@ def test_view_row_counters_read_what_the_lanes_held(monkeypatch, attend):
     else:
         params, config = _model("gqa_rope")
         engine = _engine(params, config)
+    # what each planned launch held, stated here apart from the engine: a
+    # decode lane reaches its new row, a prefill chunk its end
+    reaches, launch = [], engine._launch
+
+    def watched(plan, fn, args):
+        if plan is not None:
+            reaches.append(max(
+                [s.length + 1 for s in plan.decode_slots]
+                + [sum(plan.chunk[:2]) if plan.chunk else 0]))
+        return launch(plan, fn, args)
+
+    engine._launch = watched
     since = time.monotonic()
     _streams(engine, _workload(False))
     launches = [r[4] for r in profiling.spans(
         since=since, name="kubeshare.engine.launch")
         if r[3] == threading.current_thread().name]
     planned = [a for a in launches if a["kind"] not in ("copy", "upload")]
-    assert planned and all(1 <= a["reach"] <= 48 for a in planned)
-    # a prefill chunk's reach is its end; a decode lane's its new row
+    assert len(planned) == len(reaches) and all(
+        1 <= reach <= 48 for reach in reaches)
+    # the first dispatch is a prompt's first chunk: it reaches its end
     assert planned[0]["kind"] == "prefill" \
-        and planned[0]["reach"] == planned[0]["chunk"]
-    assert all(a["reach"] >= a["chunk"] for a in planned)
+        and reaches[0] == planned[0]["chunk"]
+    assert all(reach >= a["chunk"] for reach, a in zip(reaches, planned))
     block = KEY_BLOCK if forced else 48
     assert engine._key_block_rows == block
     assert engine.view_rows_configured == 48 * len(planned)
     assert engine.view_rows_reached == sum(
-        -(-a["reach"] // block) * block for a in planned)
+        -(-reach // block) * block for reach in reaches)
     assert (engine.view_rows_reached < engine.view_rows_configured) == forced
     # what the decode lanes ran, and the rows they held
     assert {a["attend"] for a in planned if a["lanes"]} == {attend}

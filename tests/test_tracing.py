@@ -5,11 +5,15 @@ A tiny engine on the CPU under a real ``ExecutionGuard`` over a fake token
 client.  What is locked: the span mechanism itself, that the engine's
 ``host_seconds`` are fed by its phase spans, that the parts of a dispatch fit
 inside it, the request's lifecycle stamps, the guard's counters, the slow-
-dispatch line, and that naming the step programs costs no recompile.
+dispatch line, that naming the step programs costs no recompile, and the
+table of stages: the parser, the registry ``warmup()`` fills, that serving
+lowers nothing for it, and the file ``profile_trace`` leaves.
 """
 
 import glob
+import json
 import logging
+import re
 import subprocess
 import sys
 import threading
@@ -25,11 +29,14 @@ from kubeshare_tpu.models.transformer import TransformerConfig, transformer_init
 from kubeshare_tpu.serving import (EngineConfig, Request, ServingEngine,
                                    plan_prefill_chunks)
 from kubeshare_tpu.serving import engine as engine_module
+from kubeshare_tpu.serving import stages
 from kubeshare_tpu.utils import profiling
 
 pytestmark = pytest.mark.serving
 
 PHASES = ("admit", "consume", "tune", "plan", "dispatch")
+# one a lowering of a jitted function to a module (jax._src.interpreters.mlir)
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 QUOTA_MS = 40.0
 
 
@@ -78,17 +85,44 @@ def served(model):
     that run, and the engine as it was left."""
     guard = _guard()
     engine = _engine(model, guard)
-    engine.warmup()
-    warm = engine.compile_counts()
-    before = dict(engine.host_seconds)
-    since = time.monotonic()
-    long = engine.submit(Request("long", np.arange(1, 20, dtype=np.int32), 8))
-    short = engine.submit(Request("short", np.arange(3, 9, dtype=np.int32), 5))
-    engine.run()
-    mine = [r for r in profiling.spans(since=since)
-            if r[3] == threading.current_thread().name]
-    return {"engine": engine, "guard": guard, "warm": warm, "before": before,
-            "spans": mine, "long": long, "short": short}
+    registry, stages._programs = stages._programs, {}  # this engine's alone
+    lowerings = _Lowerings()
+    try:
+        engine.warmup()
+        assert lowerings.count > 0  # the counter counts: warm-up lowers
+        lowerings.count = 0
+        warm = engine.compile_counts()
+        before = dict(engine.host_seconds)
+        since = time.monotonic()
+        long = engine.submit(
+            Request("long", np.arange(1, 20, dtype=np.int32), 8))
+        short = engine.submit(
+            Request("short", np.arange(3, 9, dtype=np.int32), 5))
+        engine.run()
+        mine = [r for r in profiling.spans(since=since)
+                if r[3] == threading.current_thread().name]
+        return {"engine": engine, "guard": guard, "warm": warm,
+                "before": before, "spans": mine, "long": long,
+                "short": short, "registered": sorted(stages._programs),
+                "tables_built": [n for n, p in stages._programs.items()
+                                 if p.table is not None],
+                "lowerings_in_window": lowerings.count}
+    finally:
+        lowerings.counting = False
+        stages._programs = registry
+
+
+class _Lowerings:
+    """Counts the lowerings of jitted functions while ``counting``, by
+    JAX's own monitoring events (a listener cannot be taken off again)."""
+
+    def __init__(self) -> None:
+        self.count, self.counting = 0, True
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event: str, seconds: float, **_) -> None:
+        if self.counting and event == LOWERING_EVENT:
+            self.count += 1
 
 
 def _named(records, name):
@@ -157,14 +191,18 @@ def test_spans_land_in_a_profiler_trace(model, tmp_path):
                 if e.name.startswith("kubeshare."):
                     events.setdefault(e.name, []).append(dict(e.stats))
     assert set(events) >= {"kubeshare." + n for n in (
-        "engine.step", "engine.admit", "engine.consume", "engine.plan",
+        "engine.admit", "engine.consume", "engine.plan",
         "engine.dispatch", "engine.marshal", "engine.launch",
         "engine.device_wait", "engine.fetch", "guard.acquire",
         "guard.gated")}
-    assert [s["i"] for s in events["kubeshare.engine.step"]] == \
-        sorted(s["i"] for s in events["kubeshare.engine.step"])
     first = events["kubeshare.engine.launch"][0]
     assert first["kind"] == "prefill" and first["chunk"] == 8
+    assert first["program"] == "prefill/8"
+    # what the admit phase saw goes with it into the trace
+    admits = events["kubeshare.engine.admit"]
+    assert admits[0]["queued"] == 1 and admits[0]["admitted"] == 1
+    assert sum(a["admitted"] for a in admits) == 1
+    assert {a["matched_rows"] for a in admits} == {0}
     assert {s["broker"] for s in events["kubeshare.guard.acquire"]} <= {0, 1}
     assert all(s["pod"] == "default/serve-a"
                for s in events["kubeshare.guard.gated"])
@@ -181,13 +219,29 @@ def test_host_seconds_keep_five_keys_fed_by_the_phase_spans(served):
                                       rel=1e-9, abs=1e-12), phase
     assert engine.host_seconds["tune"] == 0.0  # no tuner: no span either
     assert not _named(records, "engine.tune")
-    steps = _named(records, "engine.step")
-    assert [r[4]["i"] for r in steps] == list(range(
-        steps[0][4]["i"], steps[0][4]["i"] + len(steps)))
-    # every phase lies inside a step
-    for phase in PHASES:
-        for r in _named(records, "engine." + phase):
-            assert any(s[1] <= r[1] and r[2] <= s[2] for s in steps), phase
+    # the phases of a step follow one another: admit, consume, plan and,
+    # where there was a plan, dispatch; nothing holds them (no reader read
+    # the step's own span, so it went)
+    assert not _named(records, "engine.step")
+    phases = sorted((r for p in PHASES
+                     for r in _named(records, "engine." + p)),
+                    key=lambda r: r[1])
+    assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+    initials = "".join(r[0].rsplit(".", 1)[1][0] for r in phases)
+    assert re.fullmatch("(acpd?)+", initials), initials
+
+
+def test_the_admit_span_says_what_the_call_saw(served):
+    """``queued`` on entry, ``admitted`` and ``matched_rows`` by the call:
+    what a longer admit phase cannot be explained without."""
+    engine, admits = served["engine"], _named(served["spans"], "engine.admit")
+    assert [a[4]["queued"] for a in admits[:2]] == [2, 0]
+    assert sum(a[4]["admitted"] for a in admits) == 2 \
+        == engine.requests_admitted
+    assert sum(a[4]["matched_rows"] for a in admits) \
+        == engine.prefix_hit_tokens
+    assert all(set(a[4]) == {"queued", "admitted", "matched_rows"}
+               for a in admits)
 
 
 def test_the_parts_of_a_dispatch_fit_inside_it(served):
@@ -318,7 +372,7 @@ def test_token_client_span_counts_its_round_trips():
     thread.join(timeout=10)
     assert not thread.is_alive()
     asked, = profiling.spans(since=since, name="kubeshare.client.acquire")
-    assert asked[4] == {"pod": "ns/pod-x", "round_trips": 2}
+    assert asked[4] == {"round_trips": 2}  # the guard's span has the pod
     outer, = profiling.spans(since=since, name="kubeshare.guard.acquire")
     assert outer[4] == {"pod": "ns/pod-x", "broker": 1}
     assert outer[1] <= asked[1] and asked[2] <= outer[2]
@@ -387,7 +441,9 @@ def test_a_slow_dispatch_is_named_once(model, monkeypatch):
     assert len(warnings) == 1
     text = warnings[0].getMessage()
     assert "slow dispatch" in text and "kind=prefill" in text
-    assert "chunk=8" in text and ("(broker)" in text or "(held)" in text)
+    assert "program=prefill/8" in text and "chunk=8" in text
+    # the fake client makes no client span: a real one adds its round trips
+    assert "(broker)" in text or "(held)" in text
     assert engine.slow_dispatches == {"acquire": 0, "launch": 1,
                                       "device_wait": 0}
 
@@ -405,3 +461,188 @@ def test_zero_recompiles_after_warmup_with_the_named_programs(served):
         engine.pool.k, engine.pool.v, jnp.zeros((), jnp.int32),
         jnp.zeros((), jnp.int32))
     assert "jit_kubeshare_copy_step" in lowered.as_text()[:400]
+
+
+# -- the table of stages ----------------------------------------------------
+
+def test_the_parser_gives_each_instruction_its_innermost_stage():
+    """A small jitted function with nested scopes, a ``fori_loop`` and a
+    fusion: the innermost recognised scope wins, a scope the vocabulary
+    does not know is no stage, and what runs under none is ``unscoped``."""
+    def f(x, w):
+        with jax.named_scope("attention"):
+            y = jnp.tanh(x @ w)  # the dot, and a fusion whose root is tanh
+            with jax.named_scope("kv_write"):
+                y = y.at[0].set(1.0)
+            with jax.named_scope("not_a_stage"):
+                y = y * 2.0
+        with jax.named_scope("experts"):
+            y = jax.lax.fori_loop(0, 3, lambda i, c: jnp.sin(c @ w), y)
+        return jnp.cos(y) + 1.0
+
+    x = jnp.ones((8, 8), jnp.float32)
+    text = jax.jit(f).lower(x, x).compile().as_text()
+    table = stages.instruction_stages(text)
+    lines = {name: line for line in text.splitlines()
+             for found in [stages._INSTRUCTION.match(line)] if found
+             for name in [found.group(2)]}
+    assert set(table) == set(lines)
+    assert set(table.values()) == {"attention", "kv_write", "experts",
+                                   "unscoped"}
+    for name, line in lines.items():
+        op_name = stages._OP_NAME.search(line)
+        if op_name is None or not op_name.group(1).startswith("jit("):
+            continue  # the compiler's own: takes its user's or caller's
+        scopes = [s for s in op_name.group(1).split("/")
+                  if s in stages.STAGE_OF_SCOPE]
+        assert table[name] == (stages.STAGE_OF_SCOPE[scopes[-1]]
+                               if scopes else "unscoped"), line
+    # the loop and what its body holds are the experts'; the write inside
+    # attention is the write's; the unknown scope falls to the one around it
+    loops = [n for n, l in lines.items() if " while(" in l]
+    assert loops and all(table[n] == "experts" for n in loops)
+    in_loop = [n for n, l in lines.items() if "/experts/while/body" in l]
+    assert in_loop and all(table[n] == "experts" for n in in_loop)
+    assert any(table[n] == "kv_write" for n, l in lines.items()
+               if "attention/kv_write" in l)
+    assert all(table[n] == "attention" for n, l in lines.items()
+               if "attention/not_a_stage" in l)
+    assert any(table[n] == "unscoped" and "cos" in l
+               for n, l in lines.items())
+    fusions = [n for n, l in lines.items() if " fusion(" in l]
+    assert fusions and {table[n] for n in fusions} <= set(stages.STAGES)
+    assert stages.stage_of("jit(f)/mla/kv_write/scatter") == "kv_write"
+    assert stages.stage_of("jit(f)/router/top_k") == "experts"
+    assert stages.stage_of("w['layers'][0]['attn']['wq']") == "unscoped"
+
+
+HLO_OF_THE_COMPILER = """
+HloModule jit_f
+
+%body (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]) parameter(0)
+  %carried = f32[4] get-tuple-element(%p), index=1
+  %used = f32[4] fusion(%carried), kind=kLoop, calls=%fused, metadata={op_name="jit(f)/while/body/mlp/mul"}
+  %row = f32[1] fusion(%carried), kind=kLoop, calls=%fused.1
+  %update = f32[4] dynamic-update-slice(%carried, %row)
+  %next = f32[4] slice-start(%update)
+  ROOT %result = (s32[], /*index=1*/f32[4]) tuple(%i, %next)
+}
+
+ENTRY %main (w: f32[4]) -> f32[4] {
+  %w = f32[4] parameter(0), metadata={op_name="w"}
+  %prefetch = f32[4] slice-start(%w)
+  %done = f32[4] slice-done(%prefetch)
+  %copy.1 = f32[4] copy(%w), metadata={op_name="w"}
+  %loose = f32[4] copy(%w)
+  %entered = (s32[], f32[4]) tuple(%zero, %done)
+  %while.1 = (s32[], f32[4]) while(%entered), condition=%cond, body=%body, metadata={op_name="jit(f)/kv_write/scatter"}
+  ROOT %out = f32[4] fusion(%copy.1), kind=kLoop, calls=%fused.2, metadata={op_name="jit(f)/lm_head/dot_general"}
+}
+"""
+
+
+def test_what_the_compiler_made_takes_its_users_or_its_callers_stage():
+    """No ``op_name``, or an argument's name where a scope path would be:
+    a prefetch takes the stage of what reads it — through the tuple that
+    enters a loop, and through a body's result into the next iteration —
+    and the loop of updates a scatter became takes the ``while``'s."""
+    table = stages.instruction_stages(HLO_OF_THE_COMPILER)
+    assert table["prefetch"] == table["done"] == "ffn"  # into the loop
+    assert table["next"] == "ffn"  # made for the next iteration
+    assert table["copy.1"] == "head"  # the head's own copy of a weight
+    assert table["row"] == table["update"] == "ffn"  # first use with one
+    assert table["while.1"] == "kv_write" and table["used"] == "ffn"
+    assert table["loose"] == "unscoped"  # nothing uses it, nothing calls
+    assert table["w"] == "ffn"  # a parameter's first reader
+    only_updates = HLO_OF_THE_COMPILER.replace(
+        ', metadata={op_name="jit(f)/while/body/mlp/mul"}', "")
+    table = stages.instruction_stages(only_updates)
+    assert table["update"] == table["row"] == table["used"] == "kv_write"
+
+
+def test_warmup_registers_every_program_and_every_launch_names_one(served):
+    """``compile_counts()`` lists so many programs a kind; so many names of
+    that kind are in the registry, with shapes and no device array; and
+    every launch of the served window names one of them."""
+    engine = served["engine"]
+    by_kind = {}
+    for name in served["registered"]:
+        kind, _, width = name.partition("/")
+        by_kind.setdefault(kind, []).append(width)
+    assert {k: len(v) for k, v in by_kind.items()} == \
+        {k: n for k, n in served["warm"].items() if n}
+    assert sorted(by_kind["prefill"], key=int) == \
+        [str(w) for w in sorted(engine._warmed_widths)]
+    launches = _named(served["spans"], "engine.launch")
+    assert launches and {r[4]["program"] for r in launches} \
+        <= set(served["registered"])
+    assert {r[4]["program"].split("/")[0] == r[4]["kind"]
+            for r in launches} == {True}
+    assert {r[4]["program"] for r in launches if r[4]["kind"] == "decode"} \
+        == {"decode/0"}
+    assert all(r[4]["program"] == f"{r[4]['kind']}/{r[4]['chunk']}"
+               for r in launches)
+    assert not any("reach" in r[4] for r in launches)
+
+
+def test_serving_a_window_builds_no_table_and_lowers_nothing(served):
+    assert served["tables_built"] == []
+    assert served["lowerings_in_window"] == 0
+
+
+def test_a_table_is_built_on_demand_from_shapes_alone(model):
+    """After the engine is gone: the registry holds the jitted function and
+    ``ShapeDtypeStruct``s, one lowering builds the table, a second call
+    builds nothing, and a name nothing registered has none."""
+    import gc
+
+    engine = _engine(model)
+    engine.warmup()
+    engine = None
+    gc.collect()
+    program = stages._programs["mixed/8"]
+    leaves = jax.tree.leaves(program.avals)
+    assert leaves and all(isinstance(a, jax.ShapeDtypeStruct)
+                          for a in leaves)
+    program.table = None
+    lowerings = _Lowerings()
+    try:
+        table = stages.stage_table("mixed/8")
+        built = lowerings.count
+        assert stages.stage_table("mixed/8") is table
+        assert lowerings.count == built <= 1  # 0: the tracing cache had it
+    finally:
+        lowerings.counting = False
+    assert program.table is table
+    assert {"attention", "kv_write", "ffn", "head"} <= set(table.values())
+    assert stages.stage_table("mixed/7") is None
+
+
+def test_profile_trace_leaves_the_tables_of_the_programs_it_saw(model,
+                                                                tmp_path):
+    engine = _engine(model, _guard())
+    engine.warmup()
+    with profiling.profile_trace(str(tmp_path)):
+        since = time.monotonic()
+        engine.submit(Request("r", np.arange(1, 12, dtype=np.int32), 3))
+        engine.run()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / profiling.STAGES_FILE))
+    assert glob.glob(path.replace(profiling.STAGES_FILE, "*.xplane.pb"))
+    with open(path) as f:
+        tables = json.load(f)["programs"]
+    launched = {r[4]["program"] for r in profiling.spans(
+        since=since, name="kubeshare.engine.launch")}
+    assert set(tables) == launched >= {"prefill/8", "decode/0"}
+    for name, table in tables.items():
+        assert table == stages.stage_table(name)
+        assert set(table.values()) <= set(stages.STAGES)
+    # no session, no file; and a session that launched nothing leaves none
+    with profiling.profile_trace(None):
+        pass
+    empty = tmp_path / "empty"
+    with profiling.profile_trace(str(empty)):
+        pass
+    assert not glob.glob(str(empty / "**" / profiling.STAGES_FILE),
+                         recursive=True)
