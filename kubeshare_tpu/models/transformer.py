@@ -674,8 +674,13 @@ def routed_experts(moe, config: TransformerConfig, y, live=None):
     """A layer's routed experts over ``y`` [B, C, d] -> (out [B, C, d],
     routing counts int32[6]: see ops/moe.py).  A row that ``live``
     [B, C] says is dead (an idle lane, a chunk's padding) chooses
-    nothing."""
+    nothing.  The tiles run as one Pallas kernel where the backend the
+    program is being built for can run one and the experts' shapes fit
+    it (ops/moe.py ``expert_path``; ``serving.paged._kernel_mode`` is
+    the one place that says how a kernel can run, the attention's and
+    this one), else as a loop."""
     from ..ops.moe import routed_experts_apply
+    from ..serving.paged import _kernel_mode
 
     b, c, d = y.shape
     out, counts = routed_experts_apply(
@@ -685,7 +690,8 @@ def routed_experts(moe, config: TransformerConfig, y, live=None):
         first_held=config.first_expert_held,
         scoring=config.router_scoring,
         renormalise=config.router_renormalise,
-        live=None if live is None else live.reshape(b * c))
+        live=None if live is None else live.reshape(b * c),
+        kernel_mode=_kernel_mode())
     return out.reshape(b, c, d), counts
 
 

@@ -21,12 +21,15 @@ buffer exactly like token-dropping MoE implementations.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 
@@ -234,25 +237,250 @@ def _experts_choose(params, x, tokens, probs, config, capacity):
 
 # ---------------------------------------------------------------------------
 # a routed expert layer without capacity: the law of the router, and the
-# share of the layer that THIS device computes
+# share of the layer that THIS device computes.  The assignments to held
+# experts are grouped into tiles — one expert over at most a tile's rows —
+# and the tiles are computed by ONE Pallas kernel on a TPU
+# (grouped_experts: the next tile's expert copied while this one
+# multiplies) or, elsewhere and where the shapes do not fit, by a
+# fori_loop; expert_path chooses, from the backend and the shapes
 # ---------------------------------------------------------------------------
 
 EXPERT_TILE = 128  # rows of one grouped-matmul tile, at most
+MIN_TILE = 16  # and at least: whole sublane tiles of a 2-byte row
+ROW_GROUP = 8  # rows the kernel moves between unrolled steps: a sublane tile
+# bytes the two copies of an expert's blocks (the one multiplied, the next
+# in flight) may take of fast memory: 128 MiB on a v5e, less what the
+# rows, the results and the compiler's temporaries need
+EXPERT_VMEM_BYTES = 40 << 20
+KERNEL_VMEM_BYTES = 112 << 20  # and all the kernel may ask for
 
 # what routed_experts_apply counts: router choices by where the chosen
 # expert lives (held here, zero-compute, held elsewhere), the held experts
-# that got at least one row, and the tiles its loop ran with their rows
+# that got at least one row, and the tiles it computed with their rows
 # (an expert's last tile is padded, so tile_rows >= held)
 ROUTING_COUNTS = ("held", "zero", "absent", "touched", "tiles", "tile_rows")
 
 
-def expert_tile_rows(n: int) -> int:
-    """Rows a tile for an ``n``-row pass: a power of two, 8 to
-    ``EXPERT_TILE``.  A tile is one expert's: its three matrices are read
-    once a tile, so a large tile costs a pass of few rows (a decode
-    step) padding FLOPs, and a small one costs a pass of many rows (a
-    prefill chunk) re-reads of the weights."""
-    return min(EXPERT_TILE, max(8, 1 << (max(n, 1) - 1).bit_length()))
+def expert_tile_rows(n: int, top_k: int, outputs: int) -> int:
+    """Rows a tile, from the call's shapes: ``n`` rows that each choose
+    ``top_k`` of the router's ``outputs`` send an expert ``n * top_k /
+    outputs`` rows if they choose evenly; a tile holds twice that, as a
+    power of two from ``MIN_TILE`` to ``EXPERT_TILE`` (a 128-row pass
+    over 128 experts sends each 8 rows: tiles of 16, not of 128).  A
+    tile is one expert's, and twice the expected rows keeps an expert's
+    rows in ONE tile nearly always.  In the loop a tile's padding is
+    multiplied, gathered and scattered (19.8 us a tile of 16 against
+    30.5 us a tile of 128 for the same 8 rows on a v5e: PERF.md, PR
+    39).  In the kernel a tile's rows cost next to nothing — the matrix
+    unit loads the expert's matrices once a tile whatever the rows, and
+    only live rows are moved: 13.2 us a tile at 16, 32 and 128 rows
+    alike — and a second tile of an expert costs its multiplications
+    again (2.8 us), not a second read; there the tile only keeps the
+    grid, the slots and the padding the counts report near the rows
+    there are."""
+    expected = -(-max(n, 1) * top_k // outputs)
+    return min(EXPERT_TILE,
+               max(MIN_TILE, 1 << (2 * expected - 1).bit_length()))
+
+
+def expert_width_block(moe: Dict) -> int:
+    """Columns of an expert's width (``f`` of ``w_gate`` [e, d, f]) a
+    grid step of the kernel holds: the whole width where two copies of
+    the three matrices fit ``EXPERT_VMEM_BYTES`` (9.44 MB an expert at d
+    2048 x f 768), else the largest block of whole 128-lane registers
+    that divides the width and fits (512 of 2048 at d 6144); 0 where
+    none does or the widths do not tile."""
+    _, d, f = moe["w_gate"].shape
+    if d % 128 or f % 128:
+        return 0
+    size = jnp.dtype(moe["w_gate"].dtype).itemsize
+    fits = [b for b in range(128, f + 1, 128)
+            if f % b == 0 and 2 * 3 * d * b * size <= EXPERT_VMEM_BYTES]
+    return max(fits, default=0)
+
+
+def _kernel_vmem_bytes(moe: Dict, n: int, tile: int) -> int:
+    """Fast memory :func:`grouped_experts` asks for over ``n`` rows."""
+    _, d, _ = moe["w_gate"].shape
+    size = jnp.dtype(moe["w_gate"].dtype).itemsize
+    return (2 * 3 * d * expert_width_block(moe) * size  # the matrices, twice
+            + 4 * n * d * 4  # the rows and the result, two buffers each
+            + 2 * tile * d * 4  # a tile's rows and its result
+            + (16 << 20))  # hidden, products, the compiler's own
+
+
+def expert_kernel_fits(moe: Dict, n: int) -> bool:
+    """Whether :func:`grouped_experts` can run these experts over ``n``
+    rows: SwiGLU matrices of one dtype whose widths are whole 128-lane
+    registers, of which some block fits fast memory twice
+    (:func:`expert_width_block`) beside the ``n`` rows and their result,
+    which stay there for the whole grid (``KERNEL_VMEM_BYTES``: a chunk
+    of 512 rows at d 6144 does, one of 4096 at d 2048 does not).  From
+    shapes alone; a tile has at most ``EXPERT_TILE`` rows."""
+    shapes = {moe[k].shape for k in ("w_gate", "w_up")}
+    e, d, f = moe["w_gate"].shape
+    return (len(shapes) == 1 and moe["w_down"].shape == (e, f, d)
+            and len({moe[k].dtype for k in ("w_gate", "w_up", "w_down")}) == 1
+            and expert_width_block(moe) > 0
+            and _kernel_vmem_bytes(moe, n, EXPERT_TILE) <= KERNEL_VMEM_BYTES)
+
+
+def expert_path(moe: Dict, n: int, kernel_mode: Optional[str]) -> str:
+    """What runs the tiles of an expert layer over ``n`` rows, chosen
+    from what the program can see, as ``serving.paged.attend_path``
+    chooses the attention: "kernel" (:func:`grouped_experts`) where the
+    backend can run one (``kernel_mode``: ``serving.paged._kernel_mode()``
+    where the program is being built) and the shapes fit it, else "loop"
+    (the ``fori_loop`` over tiles).  The engine names it on its launch
+    spans (``experts``)."""
+    return "kernel" if kernel_mode and expert_kernel_fits(moe, n) else "loop"
+
+
+def _experts_kernel(expert_ref, rows_ref, n_tiles_ref, token_ref, x_ref,
+                    gate_ref, wg_ref, wu_ref, wd_ref, o_ref, tile_ref,
+                    result_ref, *, dtype):
+    step, block = pl.program_id(0), pl.program_id(1)
+    tile = tile_ref.shape[0]
+    f32 = jnp.float32
+
+    @pl.when((step == 0) & (block == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def each_row(move):
+        """``move(r, token)`` for the tile's live rows, a group at a
+        time (the surplus of the last group are pad slots: row 0's
+        token, weight 0)."""
+        def group(g, _):
+            for k in range(ROW_GROUP):
+                r = g * ROW_GROUP + k
+                move(r, token_ref[step * tile + r])
+            return 0
+
+        jax.lax.fori_loop(0, (rows_ref[step] + ROW_GROUP - 1) // ROW_GROUP,
+                          group, 0)
+
+    # a grid step past the live tiles computes nothing (and, by the index
+    # maps, copies nothing)
+    @pl.when(step < n_tiles_ref[0])
+    def _():
+        @pl.when(block == 0)
+        def _():
+            def gather(r, token):
+                tile_ref[pl.ds(r, 1), :] = x_ref[pl.ds(token, 1), :]
+
+            each_row(gather)
+
+        # the loop's mathematics, rounded where its program on the chip
+        # rounds: the products accumulated in float32 and rounded to the
+        # rows' dtype (a dot's result), SiLU and the product of the two
+        # in float32 (the vector unit has no narrower arithmetic, and
+        # XLA's fused body keeps float32 there too), the hidden rounded
+        # once; the down product and the weight in float32
+        x = tile_ref[...].astype(dtype)
+
+        def product(w_ref):
+            wide = jnp.dot(x, w_ref[...].astype(dtype),
+                           preferred_element_type=f32)
+            return wide.astype(dtype).astype(f32)
+
+        hidden = (jax.nn.silu(product(wg_ref)) * product(wu_ref)).astype(dtype)
+        part = gate_ref[...] * jnp.dot(hidden, wd_ref[...].astype(dtype),
+                                       preferred_element_type=f32)
+
+        @pl.when(block == 0)
+        def _():
+            result_ref[...] = part
+
+        @pl.when(block > 0)
+        def _():
+            result_ref[...] += part
+
+        @pl.when(block == pl.num_programs(1) - 1)
+        def _():
+            def add(r, token):
+                o_ref[pl.ds(token, 1), :] += result_ref[pl.ds(r, 1), :]
+
+            each_row(add)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "dtype", "interpret"),
+                   inline=True)
+def grouped_experts(x, slot_token, slot_gate, tile_expert, tile_rows, n_tiles,
+                    w_gate, w_up, w_down, *, tile: int, dtype,
+                    interpret: bool = False):
+    """Every live tile's expert over its rows, added up a row, as ONE
+    Pallas TPU kernel.  ``x`` [n, d] float32 (values of ``dtype``, the
+    rows' own: a row of 32-bit words can be picked by its index);
+    ``slot_token`` [slots] the row each slot of a tile holds and
+    ``slot_gate`` [slots] float32 its weight (a pad slot: any row, weight
+    0), a tile's ``tile`` slots side by side; ``tile_expert`` /
+    ``tile_rows`` [slots / tile] the expert and the live rows of each
+    tile, ``n_tiles`` how many tiles are live.  Returns [n, d] float32:
+    row t the sum over its slots s of ``slot_gate[s] * (silu(x_t Wg) *
+    (x_t Wu)) Wd`` under the slot's tile's expert, in tile order.
+
+    A grid over tiles, and over blocks of the expert's width where two
+    copies of the whole expert do not fit fast memory
+    (:func:`expert_width_block`).  The tiles' experts, rows and tokens
+    are prefetched scalars and the matrices' index maps pick the expert
+    from them, so the pipeline copies tile i + 1's expert while tile i
+    multiplies; a second tile of the same expert finds it there (with
+    the width whole), and a grid step past ``n_tiles`` names the last
+    live tile's blocks again: it copies nothing and computes nothing.
+    The rows and the result stay in fast memory for the whole grid: a
+    tile picks its live rows from there and adds its weighted rows
+    there, so nothing but the matrices moves a tile, and no accumulator
+    is carried through HBM.  Jitted to be traced once for all the layers
+    of a step program and inlined, as the paged attention kernels are."""
+    n, d = x.shape
+    slots = slot_token.shape[0]
+    f = w_gate.shape[2]
+    width = expert_width_block({"w_gate": w_gate})
+    blocks = f // width
+
+    def live(i, n_ref):  # an idle step stays on the last live tile
+        return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
+
+    def block_of(i, j, n_ref):  # ... and on its last block
+        return jnp.where(i < n_ref[0], j, blocks - 1)
+
+    def whole(i, j, *_):
+        return 0, 0
+
+    def a_tile(i, j, expert_ref, rows_ref, n_ref, token_ref):
+        return live(i, n_ref), 0
+
+    def columns(i, j, expert_ref, rows_ref, n_ref, token_ref):
+        return expert_ref[live(i, n_ref)], 0, block_of(i, j, n_ref)
+
+    def rows_of_down(i, j, expert_ref, rows_ref, n_ref, token_ref):
+        return expert_ref[live(i, n_ref)], block_of(i, j, n_ref), 0
+
+    ints = lambda a: a.astype(jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_experts_kernel, dtype=dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(slots // tile, blocks),
+            in_specs=[pl.BlockSpec((n, d), whole),
+                      pl.BlockSpec((tile, 1), a_tile),
+                      pl.BlockSpec((None, d, width), columns),
+                      pl.BlockSpec((None, d, width), columns),
+                      pl.BlockSpec((None, width, d), rows_of_down)],
+            out_specs=pl.BlockSpec((n, d), whole),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
+                            pltpu.VMEM((tile, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_kernel_vmem_bytes(
+                {"w_gate": w_gate}, n, tile)),
+        interpret=interpret,
+        name="grouped_experts",
+    )(ints(tile_expert), ints(tile_rows),
+      jnp.reshape(n_tiles, (1,)).astype(jnp.int32), ints(slot_token), x,
+      slot_gate[:, None], w_gate, w_up, w_down)
 
 
 def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
@@ -296,6 +524,7 @@ def routed_experts_apply(
     scoring: str = "softmax",
     renormalise: bool = False,
     live: Optional[jax.Array] = None,
+    kernel_mode: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The part of a routed expert layer that THIS device computes.
 
@@ -315,18 +544,27 @@ def routed_experts_apply(
 
     Nothing is dropped and nothing is padded to a capacity: the
     assignments to held experts are grouped by expert into tiles of
-    ``expert_tile_rows(n)`` rows (an expert's last tile is padded), and
-    a loop runs as many tiles as the routing made, each gathering its
-    rows, running its expert, and adding the weighted result to its
-    rows.  The work follows the routing — an expert that no row chose
-    is not read.  A row's result depends on that row alone: routing is
-    per row, and a row's choices are added in expert order.
+    :func:`expert_tile_rows` rows (an expert's last tile is padded), and
+    as many tiles are computed as the routing made, each running its
+    expert over its rows.  The work follows the routing — an expert that
+    no row chose is not read.  That much is one code for every backend;
+    what computes the tiles is :func:`expert_path`'s choice.  "kernel",
+    where ``kernel_mode`` (how a Pallas kernel can run where the program
+    is being built: "compiled" on a TPU, "interpret" under a test, None
+    elsewhere) and the experts' shapes allow: :func:`grouped_experts`,
+    one kernel over all the tiles with the next expert's copy in flight,
+    the rows and the result resident in fast memory.  "loop", everywhere
+    else: a ``fori_loop`` over the tiles, each gathering its rows,
+    picking its expert and adding its weighted rows into an accumulator
+    the loop carries.  A row's result depends on that row alone on
+    either path: routing is per row, and a row's choices are added in
+    expert order.
 
     Returns (out [n, d] in ``y``'s dtype, counts int32[6] in the order
     of ``ROUTING_COUNTS``): assignments to held, zero-compute and absent
     experts (they add up to ``top_k`` times the live rows), the held
-    experts that got at least one row, the tiles the loop ran and their
-    rows, padding included.  Forward only (the loop's length is data).
+    experts that got at least one row, the tiles computed and their
+    rows, padding included.  Forward only (the tiles' count is data).
     """
     n, d = y.shape
     e_held = moe["w_gate"].shape[0]
@@ -346,7 +584,7 @@ def routed_experts_apply(
     out = jnp.sum(jnp.where(zero, gate, 0.0), -1, keepdims=True) * y32
 
     # group the held assignments by expert, in tiles
-    tile = expert_tile_rows(n)
+    tile = expert_tile_rows(n, top_k, moe["router"].shape[1])
     a = n * top_k
     flat_local = jnp.where(held, local, e_held).reshape(a)
     flat_token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), top_k)
@@ -368,36 +606,46 @@ def routed_experts_apply(
         flat_token, mode="drop")
     slot_gate = jnp.zeros((slots,), jnp.float32).at[dest].set(
         gate.reshape(a), mode="drop")
+    # tile i is the expert's whose tiles end after it: how many end by i
     tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(max_tiles), side="right"),
+        jnp.sum(tile_end[None, :] <= jnp.arange(max_tiles)[:, None], axis=1),
         e_held - 1)
+    n_tiles = tile_end[-1].astype(jnp.int32)
 
     dtype = y.dtype
-    rows_of = jnp.concatenate([y, jnp.zeros((1, d), dtype)])  # row n: pad
+    if expert_path(moe, n, kernel_mode) == "kernel":
+        first_tile = tile_end - tiles  # of each expert
+        at = jnp.arange(max_tiles) - first_tile[tile_expert]
+        tile_rows = jnp.clip(counts[tile_expert] - at * tile, 0, tile)
+        out = out + grouped_experts(
+            y32, jnp.minimum(slot_token, n - 1), slot_gate, tile_expert,
+            tile_rows, n_tiles, moe["w_gate"], moe["w_up"], moe["w_down"],
+            tile=tile, dtype=dtype, interpret=kernel_mode == "interpret")
+    else:
+        rows_of = jnp.concatenate([y, jnp.zeros((1, d), dtype)])  # n: pad
 
-    def run_tile(i, acc):
-        token = jax.lax.dynamic_slice(slot_token, (i * tile,), (tile,))
-        weight = jax.lax.dynamic_slice(slot_gate, (i * tile,), (tile,))
-        expert = tile_expert[i]
-        rows = rows_of[token]  # [tile, d]; pad slots read the zero row
-        pick = lambda w: jax.lax.dynamic_index_in_dim(
-            w, expert, keepdims=False).astype(dtype)
-        hidden = jax.nn.silu(rows @ pick(moe["w_gate"])) \
-            * (rows @ pick(moe["w_up"]))
-        result = jnp.dot(hidden, pick(moe["w_down"]),
-                         preferred_element_type=jnp.float32)
-        return acc.at[token].add(weight[:, None] * result)
+        def run_tile(i, acc):
+            token = jax.lax.dynamic_slice(slot_token, (i * tile,), (tile,))
+            weight = jax.lax.dynamic_slice(slot_gate, (i * tile,), (tile,))
+            expert = tile_expert[i]
+            rows = rows_of[token]  # [tile, d]; pad slots read the zero row
+            pick = lambda w: jax.lax.dynamic_index_in_dim(
+                w, expert, keepdims=False).astype(dtype)
+            hidden = jax.nn.silu(rows @ pick(moe["w_gate"])) \
+                * (rows @ pick(moe["w_up"]))
+            result = jnp.dot(hidden, pick(moe["w_down"]),
+                             preferred_element_type=jnp.float32)
+            return acc.at[token].add(weight[:, None] * result)
 
-    acc = jnp.concatenate([out, jnp.zeros((1, d), jnp.float32)])
-    n_tiles = tile_end[-1].astype(jnp.int32)
-    acc = jax.lax.fori_loop(0, n_tiles, run_tile, acc)
+        acc = jnp.concatenate([out, jnp.zeros((1, d), jnp.float32)])
+        out = jax.lax.fori_loop(0, n_tiles, run_tile, acc)[:n]
     n_held = jnp.sum(held, dtype=jnp.int32)
     n_zero = jnp.sum(zero, dtype=jnp.int32)
     n_chose = jnp.sum(chose, dtype=jnp.int32) * top_k
     stats = jnp.stack([n_held, n_zero, n_chose - n_held - n_zero,
                        jnp.sum(counts > 0, dtype=jnp.int32),
                        n_tiles, n_tiles * tile])
-    return acc[:n].astype(dtype), stats
+    return out.astype(dtype), stats
 
 
 def moe_sharding_rules(ep_axis: str = "dp") -> Dict[str, P]:

@@ -130,8 +130,8 @@ from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
-from .paged import (attend_path, key_block_entries, paged_copy_block,
-                    paged_decode_loop, paged_decode_span,
+from .paged import (attend_path, experts_path, key_block_entries,
+                    paged_copy_block, paged_decode_loop, paged_decode_span,
                     paged_diffusion_pass, paged_diffusion_prefill,
                     paged_mixed_diffusion_step, paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
@@ -3321,6 +3321,13 @@ class ServingEngine:
                      "chunk": plan.chunk[1] if plan.chunk else 0,
                      "attend": self._attend_of(plan),
                      "program": self._program_of(plan)}
+            if self.model_config.routed:
+                # every block kind's last layer is an expert layer; the
+                # widest pass over it: the lanes' rows, or the chunk's
+                attrs["experts"] = experts_path(
+                    self.params["layers"][-1]["moe"],
+                    max(len(self._slots) * step_rows * bool(attrs["lanes"]),
+                        attrs["chunk"]))
             self.view_rows_held += attrs["rows"]
             # whole key blocks, which divide the view
             self.view_rows_reached += (
@@ -3384,11 +3391,12 @@ class ServingEngine:
         self.slow_dispatches[max(seconds, key=seconds.get)] += 1
         self.log.warning(
             "slow dispatch: %.3f s against a running estimate of %.1f ms; "
-            "program=%s kind=%s lanes=%d chunk=%d guard.acquire=%.3f s (%s) "
-            "launch=%.3f s device_wait=%.3f s",
+            "program=%s kind=%s lanes=%d chunk=%d experts=%s "
+            "guard.acquire=%.3f s (%s) launch=%.3f s device_wait=%.3f s",
             wait.end - entered, self._dispatch_estimate_ms,
             launch.attrs["program"], launch.attrs["kind"],
             launch.attrs["lanes"], launch.attrs["chunk"],
+            launch.attrs.get("experts", "none"),
             seconds["acquire"],
             "unnamed guard" if not acquired else
             "held" if not acquired.get("broker") else

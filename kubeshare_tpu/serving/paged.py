@@ -113,7 +113,7 @@ from ..models.transformer import (TransformerConfig, _rms_norm,
                                   attend_reach, gqa_moe_layers, gqa_qkv,
                                   latent_absorbed, latent_attend_blocks,
                                   latent_layers, latent_qkv, latent_scale)
-from ..ops.moe import ROUTING_COUNTS
+from ..ops.moe import ROUTING_COUNTS, expert_path
 from ..ops.paged_attention import (kernel_fits, latent_kernel_fits,
                                    paged_decode_attention,
                                    paged_latent_decode_attention)
@@ -355,6 +355,15 @@ def attend_path(block: str, query_rows: int, table_width: int, pool_k,
     if query_rows in (1, diffusion_block) and fits and _kernel_mode():
         return "kernel"
     return "blocks"
+
+
+def experts_path(moe, rows: int) -> str:
+    """What a routed block's step programs compute the tiles of an expert
+    layer ``moe`` over ``rows`` rows with, where they are being built:
+    "kernel" or "loop" (``ops.moe.expert_path`` under
+    :func:`_kernel_mode`, which ``transformer.routed_experts`` hands the
+    layer).  The engine names it on its launch spans (``experts``)."""
+    return expert_path(moe, rows, _kernel_mode())
 
 
 def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window,

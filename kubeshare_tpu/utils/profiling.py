@@ -48,7 +48,9 @@ and its ``i``, ``reach`` on the launch, ``pod`` on the client's span).
                                rows the kernels had to read, where the lanes
                                ran one), ``program`` (M ``step.stage_ms.*``
                                and the rest of ``_stages.py``: the table its
-                               operations are booked by; W)
+                               operations are booked by; W), ``experts`` (a
+                               routed engine's alone: ``kernel`` / ``loop``,
+                               what computes the expert layers' tiles; W)
 ``kubeshare.engine.device_wait``  M ``_stages.py`` (a launch's device time
                                ends with it); W
 ``kubeshare.engine.routing``   M ``moe.*``, ``step.*routed*``,

@@ -317,7 +317,8 @@ def _stages_hold(config, args, text):
         shapes[name] = shape
         if 'custom_call_target="tpu_custom_call"' in line:
             kernels += 1
-            assert table[name] == "attention", line[:200]
+            assert table[name] == ("experts" if name.startswith(EXPERTS_KERNEL)
+                                   else "attention"), line[:200]
         reads = {dims for operand in stages._operands(line, found.end())
                  for dims in re.findall(r"\[([0-9,]+)\]",
                                         shapes.get(operand, ""))}
@@ -381,6 +382,79 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     assert {"attention", "ffn", "kv_write", "head"} <= set(by_stage)
 
 
+EXPERTS_KERNEL = "grouped_experts"  # ops/moe.py: the experts' tiles
+
+
+def _kernel_calls(text):
+    """(the attention's, the experts') Pallas kernel calls of a compiled
+    program, told apart by the kernels' names."""
+    import re
+
+    names = re.findall(
+        r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)
+    experts = sum(name.startswith(EXPERTS_KERNEL) for name in names)
+    return len(names) - experts, experts
+
+
+def _experts_through_the_grouped_kernel(config, text, calls):
+    """Each expert layer's tiles are ONE kernel call a pass over the
+    layers (a mixed program makes two passes: its lanes', its chunk's),
+    ``calls`` in all, and the tile loop is gone: no float32 accumulator
+    of every row is carried (``f32[rows + 1, d]``), no tile of rows is
+    gathered a trip."""
+    import re
+
+    assert _kernel_calls(text)[1] == calls
+    assert not re.search(rf"f32\[(33|129|513),{config.d_model}\]", text)
+
+
+# the routed cells' expert layers: (rows, d, expert width, experts held,
+# router outputs, top_k) -> (tile, width block)
+EXPERT_LAYERS = {
+    "sdar-pass": ((128, 2048, 768, 128, 128, 8), (16, 768)),
+    "sdar-chunk": ((512, 2048, 768, 128, 128, 8), (64, 768)),
+    "joyai-step": ((32, 2048, 768, 256, 256, 8), (16, 768)),
+    "joyai-chunk": ((512, 2048, 768, 256, 256, 8), (32, 768)),
+    "lcf-step": ((32, 6144, 2048, 16, 768, 12), (16, 512)),
+    "lcf-chunk": ((512, 6144, 2048, 16, 768, 12), (16, 512)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_LAYERS))
+def test_grouped_experts_compile_as_kernel(one_chip, case):
+    """One expert layer of each routed cell at its published widths, over
+    a decode step's (or a pass's) rows and over a chunk's: the tiles are
+    one Pallas kernel the chip's compiler takes — an expert's three
+    matrices whole and twice in fast memory (18.9 MB) or, at
+    ``longcat-flash-chat``'s 75 MB an expert, in 512-column blocks
+    (37.7 MB), beside the rows and the result resident for the whole grid
+    — no tile loop is left, and the temporaries are the rows' and the
+    grouping's alone."""
+    from kubeshare_tpu.ops.moe import (expert_tile_rows, expert_width_block,
+                                       routed_experts_apply)
+
+    (n, d, f, held, outputs, top_k), (tile, width) = EXPERT_LAYERS[case]
+    bf16 = jnp.bfloat16
+    moe = {"router": jnp.zeros((d, outputs), bf16),
+           "w_gate": jax.ShapeDtypeStruct((held, d, f), bf16),
+           "w_up": jax.ShapeDtypeStruct((held, d, f), bf16),
+           "w_down": jax.ShapeDtypeStruct((held, f, d), bf16)}
+    assert expert_tile_rows(n, top_k, outputs) == tile
+    assert expert_width_block(moe) == width
+    moe["router"] = jax.ShapeDtypeStruct((d, outputs), bf16)
+    compiled = _compile(
+        lambda moe, y, live: routed_experts_apply(
+            moe, y, n_routed=outputs - (256 if outputs == 768 else 0),
+            top_k=top_k, scale=1.0, live=live, kernel_mode="compiled"),
+        (moe, jax.ShapeDtypeStruct((n, d), bf16),
+         jax.ShapeDtypeStruct((n,), bool)), one_chip)
+    text = compiled.as_text()
+    assert _kernel_calls(text) == (0, 1)
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 def _attends_through_the_latent_kernel(config, text):
     """The decode step's attention sub-layers each run the paged kernel
     (the span is a loop of one step, the chunk attends by key block), and
@@ -388,13 +462,12 @@ def _attends_through_the_latent_kernel(config, text):
     entries of one 16-row page, latent or rotary."""
     import re
 
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == config.attn_sublayers
+    assert _kernel_calls(text)[0] == config.attn_sublayers
     assert not re.search(r"bf16\[1024,16,(512|128)\]", text)
 
 
-@pytest.mark.parametrize("kind,temporaries", [("decode", 158_672_384),
-                                              ("mixed", 355_068_416)])
+@pytest.mark.parametrize("kind,temporaries", [("decode", 157_574_656),
+                                              ("mixed", 350_972_416)])
 def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
                                                 temporaries):
     """One expert-parallel rank at the published widths, built as on the
@@ -410,13 +483,18 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     step: 388,608 / 612,864 B), then 159,269,376 / 362,616,320 B on the
     key-block loop; with the kernel (PR 33) the decode lanes' gathered
     key blocks and their scores go: 159,269,376 -> 158,672,384 (decode
-    span), 362,616,320 -> 355,068,416 (mixed)."""
+    span), 362,616,320 -> 355,068,416 (mixed); with the experts' tiles
+    in the grouped kernel (PR 39: one call a layer and pass, the matrices
+    in 512-column blocks) the loop's accumulator and gathered tiles go:
+    157,574,656 / 350,972,416."""
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("longcat-flash-chat", kind)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes == temporaries, memory
     _no_row_of_every_expert(config, args, text)
     _attends_through_the_latent_kernel(config, text)
+    _experts_through_the_grouped_kernel(
+        config, text, config.expert_layers * (2 if kind == "mixed" else 1))
     by_stage = _stages_hold(config, args, text)
     assert {"attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
@@ -452,6 +530,8 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     assert memory.temp_size_in_bytes < 128 << 20, memory
     _no_row_of_every_expert(config, args, text)
     _attends_through_the_latent_kernel(config, text)
+    _experts_through_the_grouped_kernel(
+        config, text, config.expert_layers * (2 if kind == "mixed" else 1))
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
     assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
         with_experts
@@ -510,8 +590,11 @@ def test_diffusion_program_compiles_and_fits(one_chip, monkeypatch, kind):
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, text = _diffusion_case(one_chip, kind)
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == config.n_layers
+    assert _kernel_calls(text)[0] == config.n_layers
+    # the chunk beside a pass leaves K/V alone (no head reads it): its
+    # last layer's experts feed nothing and are not in the program
+    _experts_through_the_grouped_kernel(
+        config, text, {"diffusion": 6, "mixed_diffusion": 6 + 5}[kind])
     assert not re.search(DIFFUSION_KEY_BLOCK, text)
     chunk_scores = re.search(rf"f32\[[0-9,]*\b512,{KEY_BLOCK}\]", text)
     assert bool(chunk_scores) == (kind == "mixed_diffusion")

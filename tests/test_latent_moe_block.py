@@ -21,7 +21,8 @@ from kubeshare_tpu.models.transformer import (gated_ffn, transformer_apply,
                                               transformer_init)
 from kubeshare_tpu.ops.moe import (ROUTING_COUNTS, expert_tile_rows,
                                    routed_experts_apply, router_choices)
-from kubeshare_tpu.serving import EngineConfig, Request, ServingEngine
+from kubeshare_tpu.serving import (EngineConfig, Request, ServingEngine,
+                                   paged)
 from kubeshare_tpu.serving.kv_blocks import init_paged_pool, kv_row_layout
 from kubeshare_tpu.utils import profiling
 
@@ -137,8 +138,11 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert counts[ABSENT] == 3 * n_choices
     uncut = np.asarray(_apply(moe, y)[1])
     assert list(uncut[:3]) == [n_choices, 0, 0]
-    assert uncut[TILE_ROWS] == uncut[TILES] * expert_tile_rows(y.shape[0]) \
-        >= uncut[HELD]
+    # 24 rows x 4 choices over 16 outputs: 6 rows an expert, tiles of 16
+    tile = expert_tile_rows(y.shape[0], TC["router_top_k"],
+                            moe["router"].shape[1])
+    assert tile == 16
+    assert uncut[TILE_ROWS] == uncut[TILES] * tile >= uncut[HELD]
 
 
 def test_the_bias_chooses_and_never_weighs():
@@ -185,7 +189,8 @@ def _forced_router(moe, chosen):
 
 def test_nothing_is_dropped_at_any_skew():
     """Every row sent to the same four experts — 300 rows, three tiles of
-    128 rows an expert, the last one padded: the result is still the
+    128 rows an expert (75 rows an expert if they chose evenly: the
+    largest tile), the last one padded: the result is still the
     reference's, which runs every expert on every row."""
     moe, _, y = _expert_case(n=300)
     y = jnp.abs(y)  # so that the pinned scores win on every row
@@ -199,14 +204,15 @@ def test_nothing_is_dropped_at_any_skew():
         _forced_router(moe, [0, 1, 2, 3]), y[:24], n_routed=16, top_k=4,
         scale=2.5, scoring="sigmoid", renormalise=True,
         live=jnp.arange(24) < 9)
-    # 9 live rows of 24: a tile of 32 an expert still, the dead rows 0
-    assert list(np.asarray(counts)) == [9 * 4, 0, 0, 4, 4, 4 * 32]
+    # 9 live rows of 24: the tile is the shapes' (24 rows x 4 choices
+    # over 16 outputs: 16), whatever lives; the dead rows read 0
+    assert list(np.asarray(counts)) == [9 * 4, 0, 0, 4, 4, 4 * 16]
     assert not np.asarray(out[9:]).any()
 
 
-def _prefilled(kind, tokens, lanes=3, rows=9):
-    params, config = params_of(kind, 7, jnp.bfloat16), \
-        config_of(kind, "bfloat16")
+def _prefilled(kind, tokens, lanes=3, rows=9, **changes):
+    params, config = params_of(kind, 7, jnp.bfloat16, **changes), \
+        config_of(kind, "bfloat16", **changes)
     pool = init_paged_pool(config, 1 + lanes * ROWS // BLOCK, BLOCK)
     tables = lane_tables(lanes)
     pk, pv = pool.k, pool.v
@@ -219,11 +225,21 @@ def _prefilled(kind, tokens, lanes=3, rows=9):
     return params, config, pk, pv, tables
 
 
+@pytest.mark.parametrize("path", ["loop", "kernel"])
 @BOTH
-def test_a_lanes_logits_do_not_depend_on_its_co_batched_lanes(kind, tokens):
+def test_a_lanes_logits_do_not_depend_on_its_co_batched_lanes(
+        kind, tokens, path, monkeypatch):
     """Routing is a function of the row alone and nothing has a capacity,
-    so a decode lane reads the same logits whatever rides beside it."""
-    params, config, pk, pv, tables = _prefilled(kind, tokens)
+    so a decode lane reads the same logits whatever rides beside it —
+    with the experts' tiles in the loop, and in the grouped kernel (the
+    twin widened to widths the kernel reads, d 128 and experts of 128:
+    programs of their own, traced under the patched mode alone)."""
+    wide = {}
+    if path == "kernel":
+        monkeypatch.setattr(paged, "_kernel_mode", lambda: "interpret")
+        wide = dict(d_model=128, expert_d_ff=128)
+    params, config, pk, pv, tables = _prefilled(kind, tokens, **wide)
+    assert paged.experts_path(params["layers"][-1]["moe"], 3) == path
     lengths = jnp.full((3,), 9, jnp.int32)
     toks = jnp.asarray(tokens[30:33])
     alone = paged_decode_step(

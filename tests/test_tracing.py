@@ -442,6 +442,7 @@ def test_a_slow_dispatch_is_named_once(model, monkeypatch):
     text = warnings[0].getMessage()
     assert "slow dispatch" in text and "kind=prefill" in text
     assert "program=prefill/8" in text and "chunk=8" in text
+    assert "experts=none" in text  # a dense engine: no expert layer
     # the fake client makes no client span: a real one adds its round trips
     assert "(broker)" in text or "(held)" in text
     assert engine.slow_dispatches == {"acquire": 0, "launch": 1,
