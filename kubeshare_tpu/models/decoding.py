@@ -86,10 +86,10 @@ def init_kv_cache(config: TransformerConfig, batch: int) -> Dict:
     HBM cost — shrinks by the query-group factor.  A latent block
     caches one headless row an attention sub-layer instead: ``k`` its
     latent values, ``v`` its rotary key."""
-    if config.block == "gqa_moe":
+    if config.block in ("gqa_moe", "retention"):
         raise ValueError(
-            "block 'gqa_moe' has no dense-cache decoder: it is served "
-            "from the paged pool (serving/paged.py)")
+            f"block {config.block!r} has no dense-cache decoder: it is "
+            f"served from the paged pool (serving/paged.py)")
     if config.latent:
         # the head axis stays (1): every cached path reads capacity there
         shape = (config.attn_sublayers, batch, 1, config.max_seq_len)

@@ -122,6 +122,14 @@ class TransformerConfig:
     diffusion_block: int = 0
     diffusion_steps: int = 0
     mask_token: int = 0
+    # "retention": power-retention layers (ops/retention.py) — 'gqa_moe''s
+    # projections (explicit head width, per-head q/k norms, rotate-half
+    # rope at its own rope_theta) and a log gate a KV head a row; the
+    # weight of key j for query i is exp(A_i - A_j) (q_i . k_j)^2 / hd
+    # over the sum of the weights, no softmax; the feed-forward is a dense
+    # gated FFN of width d_ff in every layer.  Served from a recurrent
+    # state a lane beside a short paged tail of keys and values
+    # (serving/paged.py _retention_layers); it has no field of its own
 
     def __post_init__(self) -> None:
         _check_block(self)
@@ -249,19 +257,40 @@ def _check_gqa_moe(config: TransformerConfig) -> None:
             f"{config.vocab_size} ids")
 
 
+def _check_retention(config: TransformerConfig) -> None:
+    if config.head_dim < 2 or config.head_dim % 2:
+        raise ValueError(
+            f"head_width must be even and >= 2, got {config.head_dim}")
+    if config.kv_heads < 1 or config.n_heads % config.kv_heads:
+        raise ValueError(
+            f"n_heads ({config.n_heads}) must be a multiple of n_kv_heads "
+            f"({config.kv_heads})")
+    if config.moe_every is not None or config.attention_window is not None \
+            or config.positional != "rope" or config.d_ff < 1:
+        raise ValueError(
+            "block 'retention' takes neither moe_every nor "
+            "attention_window, positional='rope', and a gated FFN of "
+            "width d_ff >= 1 in every layer")
+
+
 def _check_block(config: TransformerConfig) -> None:
     if config.block == "gqa_moe":
         return _check_gqa_moe(config)
-    if config.block != "dense" and not config.latent:
+    if config.block not in ("dense", "retention") and not config.latent:
         raise ValueError(
-            f"block must be 'dense', 'gqa_moe', 'latent_shortcut' or "
-            f"'latent_moe', got {config.block!r}")
-    if config.head_width is not None or config.diffusion_block \
-            or config.diffusion_steps or config.mask_token:
+            f"block must be 'dense', 'gqa_moe', 'retention', "
+            f"'latent_shortcut' or 'latent_moe', got {config.block!r}")
+    if config.diffusion_block or config.diffusion_steps \
+            or config.mask_token:
         raise ValueError(
-            f"head_width, diffusion_block, diffusion_steps and "
-            f"mask_token are block 'gqa_moe''s; block {config.block!r} "
-            f"takes none of them")
+            f"diffusion_block, diffusion_steps and mask_token are block "
+            f"'gqa_moe''s; block {config.block!r} takes none of them")
+    if config.block == "retention":
+        return _check_retention(config)
+    if config.head_width is not None:
+        raise ValueError(
+            f"head_width is block 'gqa_moe''s and 'retention''s; block "
+            f"{config.block!r} does not take it")
     if not config.latent:
         if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
             raise ValueError(
@@ -373,14 +402,50 @@ def _gqa_moe_layer_init(keys, config: TransformerConfig, dense,
                     "w_down": dense(next(keys), (e, fe, d), fe)}}
 
 
+# the log gate a fresh 'retention' model starts from: a row keeps
+# sigmoid(GATE_BIAS + N(0, 1)) of what came before, about e^(-1/400)
+GATE_BIAS = 6.0
+
+
+def _retention_layer_init(keys, config: TransformerConfig, dense,
+                          layer_idx: int) -> Dict:
+    """One 'retention' layer: 'gqa_moe''s attention matrices and per-head
+    norms, the gate's map to one logit a KV head (WITH a bias), two
+    norms and a dense gated FFN.  The three input projections are held
+    as MATRICES ``[d, heads x hd]``: a ``[d, 40, hd]`` array's own layout
+    pads 40 heads to 48, so the TPU compiler re-lays every one of them out
+    on every dispatch (72 MB a layer at the cell's size)."""
+    d, h, h_kv, hd, f = (config.d_model, config.n_heads, config.kv_heads,
+                         config.head_dim, config.d_ff)
+    attn = {"wq": dense(next(keys), (d, h * hd), d),
+            "wk": dense(next(keys), (d, h_kv * hd), d),
+            "wv": dense(next(keys), (d, h_kv * hd), d),
+            "wo": dense(next(keys), (h, hd, d), h * hd),
+            "q_norm": {"scale": jnp.ones((hd,))},
+            "k_norm": {"scale": jnp.ones((hd,))},
+            "gate": {"w": dense(next(keys), (d, h_kv), d),
+                     "b": jnp.full((h_kv,), GATE_BIAS)}}
+    return {"attn": attn,
+            "norm1": {"scale": jnp.ones((d,))},
+            "norm2": {"scale": jnp.ones((d,))},
+            "ffn": {"w_gate": dense(next(keys), (d, f), d),
+                    "w_up": dense(next(keys), (d, f), d),
+                    "w_down": dense(next(keys), (f, d), f)}}
+
+
+_LAYER_INIT = {"latent_shortcut": _latent_layer_init,
+               "latent_moe": _latent_layer_init,
+               "gqa_moe": _gqa_moe_layer_init,
+               "retention": _retention_layer_init}
+
+
 def transformer_init(rng: jax.Array, config: TransformerConfig) -> Dict:
-    if config.latent or config.block == "gqa_moe":
+    if config.block in _LAYER_INIT:
         def dense(key, shape, fan_in):
             return (jax.random.normal(key, shape, jnp.float32)
                     * (1.0 / fan_in) ** 0.5)
 
-        layer_init = (_latent_layer_init if config.latent
-                      else _gqa_moe_layer_init)
+        layer_init = _LAYER_INIT[config.block]
         keys = iter(jax.random.split(rng, 2 + 20 * config.n_layers))
         d = config.d_model
         return {
@@ -870,6 +935,93 @@ def _gqa_moe_forward(params, tokens, config: TransformerConfig,
             jnp.float32(0.0))
 
 
+# ---------------------------------------------------------------------------
+# the 'retention' block: 'gqa_moe''s projections (from weights held as
+# matrices), a log gate a KV head, the
+# power-retention weights of ops/retention.py in place of a softmax, and a
+# dense gated FFN.  Shared by the unpaged forward below (every row against
+# every earlier row) and the paged step programs (a state beside a tail).
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("attention")
+def retention_qkv(attn, y, positions, config: TransformerConfig):
+    """A 'retention' layer's projections of ``y`` [B, C, d] at
+    ``positions`` [B, C], as :func:`gqa_qkv` gives them — ``q`` [B, H, C,
+    hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k normed a head and then
+    rotated (split halves) — from weights held as matrices."""
+    dtype, eps = config.dtype, config.norm_eps
+    b, c, _ = y.shape
+
+    def heads(w):
+        out = y @ w.astype(dtype)
+        return out.reshape(b, c, -1, config.head_dim).transpose(0, 2, 1, 3)
+
+    q, k, v = heads(attn["wq"]), heads(attn["wk"]), heads(attn["wv"])
+    with jax.named_scope("qk_norm"):
+        q = _rms_norm(q, attn["q_norm"]["scale"], eps)
+        k = _rms_norm(k, attn["k_norm"]["scale"], eps)
+    return (apply_rope(q, positions, theta=config.rope_theta),
+            apply_rope(k, positions, theta=config.rope_theta), v)
+
+
+@jax.named_scope("gate")
+def retention_gate(attn, y):
+    """The log gate of ``y`` [B, C, d]: ``logsigmoid(y W_g + b_g)``, float32
+    [B, C, h_kv], never positive — what a row keeps of everything before
+    it, one a KV head (its query heads share it)."""
+    logits = jnp.einsum("bcd,dh->bch", y, attn["gate"]["w"].astype(y.dtype),
+                        preferred_element_type=jnp.float32)
+    return jax.nn.log_sigmoid(logits
+                              + attn["gate"]["b"].astype(jnp.float32))
+
+
+def retention_layers(params, x, config: TransformerConfig, attend_row):
+    """Every layer of a 'retention' block over ``x`` [B, C, d]:
+    ``attend_row(layer, attn_weights, y)`` is the retention's output of the
+    normed input ``y``, [B, H, C, hd] before the output projection — where
+    the callers differ, as in :func:`gqa_moe_layers`."""
+    dtype, eps = config.dtype, config.norm_eps
+    for i, layer in enumerate(params["layers"]):
+        y = _rms_norm(x, layer["norm1"]["scale"], eps)
+        o = attend_row(i, layer["attn"], y).astype(dtype)
+        with jax.named_scope("attention"):
+            x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                               layer["attn"]["wo"].astype(dtype))
+        y = _rms_norm(x, layer["norm2"]["scale"], eps)
+        with jax.named_scope("ffn"):
+            x = x + gated_ffn(layer["ffn"], y, dtype)
+    return x
+
+
+def _retention_forward(params, tokens, config: TransformerConfig,
+                       apply_head: bool = True):
+    """The unpaged forward of a 'retention' block, in the quadratic form:
+    every row against every earlier row, no state."""
+    from ..ops.retention import retention_quadratic
+
+    dtype = config.dtype
+    b, seq = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
+
+    def attend(_, attn, y):
+        q, k, v = retention_qkv(attn, y, positions, config)
+        return retention_quadratic(q, k, v, retention_gate(attn, y), dtype)
+
+    x = params["embed"][tokens].astype(dtype)
+    x = retention_layers(params, x, config, attend)
+    x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
+    if not apply_head:
+        return x, jnp.float32(0.0)
+    return ((x @ params["lm_head"].astype(dtype)).astype(jnp.float32),
+            jnp.float32(0.0))
+
+
+_PAGED_ONLY_FORWARD = {"latent_shortcut": _latent_forward,
+                       "latent_moe": _latent_forward,
+                       "gqa_moe": _gqa_moe_forward,
+                       "retention": _retention_forward}
+
+
 def _select_attention(config: TransformerConfig):
     kind = config.attention
     window = config.attention_window
@@ -895,14 +1047,14 @@ def _forward(params, tokens, config, attention_fn, pos_offset,
     ``kv_sink`` (a list) collects each layer's (k, v) projections —
     the bulk-prefill path fills the decode cache from them; remat is
     bypassed there (inference has no backward to rematerialize for)."""
-    if config.latent or config.block == "gqa_moe":
+    if config.block in _PAGED_ONLY_FORWARD:
         if kv_sink is not None or jnp.ndim(pos_offset) != 0:
             raise ValueError(
                 f"block {config.block!r} has no dense-cache or "
                 f"sequence-sharded forward: its cache is the paged "
                 f"pool (serving/paged.py)")
-        forward = _latent_forward if config.latent else _gqa_moe_forward
-        return forward(params, tokens, config, apply_head)
+        return _PAGED_ONLY_FORWARD[config.block](params, tokens, config,
+                                                 apply_head)
     dtype = config.dtype
     seq = tokens.shape[1]
     x = params["embed"][tokens].astype(dtype)

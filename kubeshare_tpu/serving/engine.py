@@ -126,17 +126,19 @@ from ..utils.promtext import (MetricFamily, MetricServer, Sample,
 from .autotune import AnalyticPolicy, AutoTuner
 from .drafter import NGramDrafter
 from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
-                        init_paged_pool, kv_row_layout)
+                        init_paged_pool, init_retention_states,
+                        kv_row_layout)
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
-from .paged import (attend_path, experts_path, key_block_entries,
+from . import paged
+from .paged import (Recurrent, attend_path, experts_path, key_block_entries,
                     paged_copy_block, paged_decode_loop, paged_decode_span,
                     paged_diffusion_pass, paged_diffusion_prefill,
                     paged_mixed_diffusion_step, paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
                     paged_spec_loop, paged_upload_block,
-                    paged_verify_span)
+                    paged_verify_span, tail_pages)
 from .prefix_index import PrefixIndex
 from . import stages
 from .sharded import ShardedServingContext
@@ -412,21 +414,30 @@ class EngineConfig:
     admission_ring: int = 0
 
 
-def _warmed_prefill_widths(ec: EngineConfig, diffusion_block: int = 0) -> set:
+def _warmed_prefill_widths(ec: EngineConfig, floor: int = 0) -> set:
     """The prefill-chunk bucket universe warmup compiles (and the
     autotuner's fused-budget envelope): the configured chunk plus every
     smaller power of two, capped at the slot row bound so a short pool
     folds over-wide buckets into one max_request_len-wide shape.  Empty
     on a decode-role pool — no prefill shape ever dispatches there.
-    Under generation by diffusion over blocks a chunk is whole blocks of
-    ``diffusion_block`` rows: no narrower bucket is ever planned."""
+    No bucket narrower than ``floor`` is ever planned: under generation by
+    diffusion over blocks a chunk is whole blocks of ``diffusion_block``
+    rows, and a 'retention' block pads a prompt's last chunk forward to
+    whole pages (:func:`_bucket_floor`)."""
     widths = {ec.prefill_chunk}
-    w = max(1, diffusion_block)
+    w = max(1, floor)
     while w < ec.prefill_chunk:
         widths.add(w)
         w *= 2
     widths = {min(w, ec.max_request_len) for w in widths}
     return set() if ec.pool_role == "decode" else widths
+
+
+def _bucket_floor(ec: EngineConfig, config: TransformerConfig) -> int:
+    """The narrowest prefill bucket a configuration plans."""
+    if config.block == "retention":
+        return min(ec.block_size, ec.prefill_chunk)
+    return config.diffusion_block
 
 
 def _config_rows(ec: EngineConfig, config: TransformerConfig,
@@ -437,7 +448,7 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
     constraint (and its loud message) is visible and extendable in ONE
     place, and a new knob adds a row instead of another branch."""
     b = config.diffusion_block
-    widths = _warmed_prefill_widths(ec, b)
+    widths = _warmed_prefill_widths(ec, _bucket_floor(ec, config))
     min_piece = min(widths) if widths else 1
     wire = (wire_block_bytes(
         ec.block_size, config.n_layers, config.kv_heads,
@@ -480,7 +491,39 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
         ("eos_token (a block's rows are served out of order: nothing "
          "truncates a stream at a token yet)", ec.eos_token is not None),
     ) if asked]
+    # what cannot roll a recurrent state back, ship it or shard it
+    retention = config.block == "retention"
+    no_state = [name for name, asked in (
+        ("speculative=True (the draft-verify programs would have to roll "
+         "a folded state back)", ec.speculative),
+        ("steps_per_launch > 1 (the device-resident loops carry no "
+         "state and plan no fold)", ec.steps_per_launch > 1),
+        ("mesh_spec (serving/sharded.py has no twin of the state's "
+         "programs)", ec.mesh_spec is not None),
+        ("host_tier_bytes (serving/kv_tier.py packs K/V pages; a folded "
+         "lane's pages are gone and no tier holds a state)",
+         ec.host_tier_bytes is not None),
+        ("a shared host tier (serving/kv_tier.py, serving/fabric.py)",
+         shared_host_tier is not None),
+        (f"pool_role={ec.pool_role!r} (serving/disagg.py migrates K/V "
+         f"pages, not a state)", ec.pool_role != "both"),
+        ("autotune=True (the tuner re-slices chunks and arms the loops)",
+         ec.autotune),
+    ) if asked]
+    key_block = paged.KEY_BLOCK
     return [
+        (retention and bool(no_state),
+         f"block 'retention' serves a recurrent state a lane beside a "
+         f"paged tail of unfolded rows, which is not served yet by: "
+         f"{'; '.join(no_state)}"),
+        (retention and (key_block % ec.block_size != 0
+                        or ec.prefill_chunk > key_block
+                        or ec.decode_span > key_block),
+         f"block 'retention' folds a key block of {key_block} rows at a "
+         f"time: block_size {ec.block_size} must divide it (a fold frees "
+         f"whole pages), and prefill_chunk {ec.prefill_chunk} and "
+         f"decode_span {ec.decode_span} may not exceed it (a dispatch "
+         f"completes at most one key block a lane)"),
         (bool(b) and bool(no_diffusion),
          f"diffusion_block {b}: generation by diffusion over blocks (a "
          f"lane holds a block state and a pass commits 0..{b} tokens) is "
@@ -750,7 +793,7 @@ class _Slot:
         "prompt", "plan", "max_new", "temperature", "first_key",
         "step_keys", "result", "tenant", "emitted_prefix",
         "last_token_at", "drafter", "draft_width", "accept_rate",
-        "block",
+        "block", "folded", "paged_to",
     )
 
     def __init__(self, idx: int, table_width: int) -> None:
@@ -792,6 +835,11 @@ class _Slot:
         # from its _Pending until the prefill is done); ``generated``
         # then holds the finished blocks' tokens only
         self.block: Optional[_BlockState] = None
+        # a 'retention' lane: the rows it has folded into its slot's
+        # state (a multiple of the key block; its pages begin there) and
+        # the table entries it has been given pages for so far
+        self.folded = 0
+        self.paged_to = 0
 
 
 def _step_program(kind: str, fn, donate_argnums):
@@ -860,8 +908,17 @@ class ServingEngine:
             config, ec.num_blocks, ec.block_size,
             kv_sharding=(self._sharded.kv_sharding
                          if self._sharded is not None else None))
+        # a 'retention' block: the lanes' recurrent states, BY SLOT and
+        # beside the pool (kv_blocks.init_retention_states); the pool
+        # holds a lane's unfolded rows only.  No snapshot of a state
+        # exists at a page boundary, so a prefix match could give nothing:
+        # such an engine keeps no prefix index
+        self._retention = config.block == "retention"
+        self.states = (init_retention_states(config, ec.num_slots)
+                       if self._retention else None)
         self.prefix_index = (PrefixIndex(ec.block_size)
-                             if ec.prefix_cache else None)
+                             if ec.prefix_cache and not self._retention
+                             else None)
         # the tenant registry must exist before the tier policy (the
         # QoS-aware policy reads class membership from it)
         self.tenants = tenants or TenantRegistry.default()
@@ -920,7 +977,16 @@ class ServingEngine:
         # is confined to it (a tuned budget can only select among
         # already-compiled shapes)
         self._warmed_widths = _warmed_prefill_widths(
-            ec, config.diffusion_block)
+            ec, _bucket_floor(ec, config))
+        # the rows a 'retention' lane is funded for: its unfolded tail
+        # through the widest step, whatever the request's length
+        self._tail_rows = (
+            ec.block_size * tail_pages(
+                max(self._warmed_widths | {ec.decode_span}), ec.block_size)
+            if self._retention else 0)
+        # beside the in-flight dispatch, what a 'retention' dispatch
+        # carried (:meth:`_observe_retention` reads it in .consume)
+        self._retention_inflight: Optional[Dict] = None
         # generation by diffusion over blocks: the block length B (0: one
         # token after another) and the rows a block commits at each of
         # its denoising passes
@@ -1063,6 +1129,13 @@ class ServingEngine:
         self.moe_tiles = 0
         self.moe_tile_rows = 0
         self.moe_passes = 0
+        # a 'retention' block's dispatches: lane-passes that read a
+        # state, unfolded rows read (over lanes and passes), key blocks
+        # folded and the pages handed back behind them
+        self.retention_state_reads = 0
+        self.retention_tail_rows = 0
+        self.retention_folds = 0
+        self.retention_pages_freed = 0
         # how far the step programs' attention had to go: summed over
         # planned dispatches, the furthest lane's rows rounded up to key
         # blocks (what the key-block loop runs over), the view's whole
@@ -1178,10 +1251,23 @@ class ServingEngine:
                     w, cfg, pk, pv, tables, starts, active, tokens,
                     last_rows, routing=routed)
 
+        if self._retention:
+            # the same programs with the gate array and the states as one
+            # more donated argument, the lanes' fold points and the
+            # chunk's slot after it; the Recurrent comes back last
+            def prefill(w, pk, pv, tables, starts, active, tokens,
+                        last_rows, temps, keys, recurrent, folded, slots):
+                logits, pk, pv, recurrent = paged_prefill_step(
+                    w, cfg, pk, pv, tables, starts, active, tokens,
+                    last_rows, recurrent=recurrent, folded=folded,
+                    slots=slots)
+                return pick_rows(logits, temps, keys), pk, pv, recurrent
+
         # the pool buffers are DONATED: each step updates the cache in
         # place device-side instead of materializing a second pool (on a
         # fractional-HBM pod a transient 2x cache would blow the cap)
-        self._prefill_step = _step_program("prefill", prefill, (1, 2))
+        donated = (1, 2, 10) if self._retention else (1, 2)
+        self._prefill_step = _step_program("prefill", prefill, donated)
 
         def diffusion(w, pk, pv, tables, lengths, active, tokens, masked,
                       open_rows, quota):
@@ -1220,7 +1306,15 @@ class ServingEngine:
 
         if sharded is not None:
             decode = sharded.decode_span(pick_rows, span, eos)
-        self._decode_step = _step_program("decode", decode, (1, 2))
+        if self._retention:
+            def decode(w, pk, pv, tables, lengths, active, tokens, temps,
+                       keys, budgets, recurrent, folded):
+                return paged_decode_span(
+                    w, cfg, pick_rows, span, eos, pk, pv, tables, lengths,
+                    active, tokens, temps, keys, budgets,
+                    recurrent=recurrent, folded=folded)
+
+        self._decode_step = _step_program("decode", decode, donated)
 
         def make_loop(k_units):
             # the device-resident multi-step loop: up to K span-units
@@ -1304,7 +1398,20 @@ class ServingEngine:
 
         if sharded is not None:
             mixed = sharded.mixed_step(pick_rows, span, eos)
-        self._mixed_step = _step_program("mixed", mixed, (1, 2))
+        if self._retention:
+            def mixed(w, pk, pv, p_table, p_start, p_tokens, p_last_row,
+                      p_temp, p_key, d_tables, d_lengths, d_active,
+                      d_tokens, d_temps, d_keys, d_budgets, recurrent,
+                      p_folded, p_slot, d_folded):
+                return paged_mixed_step(
+                    w, cfg, pick_rows, span, eos, pk, pv, p_table, p_start,
+                    p_tokens, p_last_row, p_temp, p_key, d_tables,
+                    d_lengths, d_active, d_tokens, d_temps, d_keys,
+                    d_budgets, recurrent=recurrent, p_folded=p_folded,
+                    p_slot=p_slot, d_folded=d_folded)
+
+        self._mixed_step = _step_program(
+            "mixed", mixed, (1, 2, 16) if self._retention else (1, 2))
 
         def verify(w, pk, pv, tables, lengths, active, tokens, widths,
                    temps, keys):
@@ -1388,7 +1495,10 @@ class ServingEngine:
         front."""
         if self.engine_config.pool_role == "prefill":
             return cover
-        return max(cover, self._request_rows(prompt_len, max_new))
+        rows = max(cover, self._request_rows(prompt_len, max_new))
+        # a 'retention' lane's pages do not grow with its length: a fold
+        # frees the pages behind it, so a request is funded by its tail
+        return min(rows, self._tail_rows) if self._retention else rows
 
     def _request_rows(self, prompt_len: int, max_new: int) -> int:
         """Rows a request's prompt and generation reach: under
@@ -1404,6 +1514,19 @@ class ServingEngine:
         generated block) and needs no logits of them: a prompt shorter
         than a block, or one the prefix cache covers, plans nothing."""
         ec, b = self.engine_config, self._diffusion
+        if self._retention:
+            # chunks from row 0 on, none sliding back over rows a fold may
+            # have taken: the last is padded FORWARD to its bucket, and
+            # the padding's rows are written nowhere (paged._prefill_rows)
+            chunk, floor = ec.prefill_chunk, min(self._warmed_widths)
+            plan = [(s, chunk, chunk - 1)
+                    for s in range(0, prompt_len - chunk + 1, chunk)]
+            rest = prompt_len % chunk
+            if rest:
+                plan.append((prompt_len - rest,
+                             max(bucket_width(rest, chunk), floor),
+                             rest - 1))
+            return plan, prompt_len
         rows = prompt_len // b * b if b else prompt_len
         if b and rows <= start:
             return [], rows
@@ -1863,18 +1986,26 @@ class ServingEngine:
         s = ec.num_slots
         one = jnp.zeros((1,), jnp.int32)
         zeros_s = jnp.zeros((s,), jnp.int32)
+        # a 'retention' engine's last arguments (:meth:`_recurrent_args`):
+        # an all-inactive call folds nothing and leaves every state alone
+        def recurrent():
+            return ((Recurrent(self.pool.gate, self.states),)
+                    if self._retention else ())
+
+        p_fold = (one, one) if self._retention else ()
+        d_fold = (zeros_s,) if self._retention else ()
         for width in sorted(widths):
             # the pool rides through every warmup call (its buffers are
             # donated); the only writes land in the scratch block
-            _, pk, pv, *_ = self._warm(
+            _, pk, pv, *rest = self._warm(
                 "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((1, self._table_width), jnp.int32),
                 one, jnp.zeros((1,), bool),
                 jnp.zeros((1, width), jnp.int32), one,
                 jnp.zeros((1,), jnp.float32),
-                jnp.zeros((1, 2), jnp.uint32))
-            self.pool = replace(self.pool, k=pk, v=pv)
+                jnp.zeros((1, 2), jnp.uint32), *recurrent(), *p_fold)
+            self._keep_cache(pk, pv, rest)
             # mixed shapes only for widths that can actually ride
             # fused: step() routes any chunk wider than the budget to
             # the standalone path, so warming those would burn the most
@@ -1882,7 +2013,7 @@ class ServingEngine:
             # EVERY width warms — the tuned budget may move up to any
             # warmed bucket, and a budget change must never compile
             if ec.mixed and (ec.autotune or width <= self._mixed_budget):
-                _, _, pk, pv, *_ = self._warm(
+                _, _, pk, pv, *rest = self._warm(
                     "mixed", (width,), self._mixed_step,
                     self.params, self.pool.k, self.pool.v,
                     jnp.zeros((1, self._table_width), jnp.int32), one,
@@ -1893,8 +2024,8 @@ class ServingEngine:
                     zeros_s, jnp.zeros((s,), bool), zeros_s,
                     jnp.zeros((s,), jnp.float32),
                     jnp.zeros((s, ec.decode_span, 2), jnp.uint32),
-                    zeros_s)
-                self.pool = replace(self.pool, k=pk, v=pv)
+                    zeros_s, *recurrent(), *p_fold, *d_fold)
+                self._keep_cache(pk, pv, rest)
                 if ec.speculative:
                     # every (prefill bucket) x (verify width) fused
                     # shape the speculative scheduler can reach
@@ -1915,14 +2046,15 @@ class ServingEngine:
                             jnp.zeros((s, 1 + k, 2), jnp.uint32))
                         self.pool = replace(self.pool, k=pk, v=pv)
         if ec.pool_role != "prefill":
-            _, pk, pv, *_ = self._warm(
+            _, pk, pv, *rest = self._warm(
                 "decode", (), self._decode_step,
                 self.params, self.pool.k, self.pool.v,
                 jnp.zeros((s, self._table_width), jnp.int32),
                 zeros_s, jnp.zeros((s,), bool), zeros_s,
                 jnp.zeros((s,), jnp.float32),
-                jnp.zeros((s, ec.decode_span, 2), jnp.uint32), zeros_s)
-            self.pool = replace(self.pool, k=pk, v=pv)
+                jnp.zeros((s, ec.decode_span, 2), jnp.uint32), zeros_s,
+                *recurrent(), *d_fold)
+            self._keep_cache(pk, pv, rest)
         for k_depth, loop_step in sorted(self._loop_steps.items()):
             # one shape per warmed loop depth (K is baked in; lane
             # masks, budgets, and the units-ran count are all
@@ -2442,6 +2574,29 @@ class ServingEngine:
             "included: held assignments over this is how full the tiles "
             "ran.", "counter")
         moe_tile_rows.add(dict(plabel), self.moe_tile_rows)
+        retention = []
+        for name, value, said in (
+                ("state_reads", self.retention_state_reads,
+                 "Lane-passes of a 'retention' block's step programs that "
+                 "read a lane's recurrent state (a decode lane that has "
+                 "folded a key block reads it once a step of a span, a "
+                 "prefill chunk's lane once)."),
+                ("tail_rows", self.retention_tail_rows,
+                 "Unfolded rows those programs read as keys and values, "
+                 "summed over lanes and passes: the paged tails beside "
+                 "the states."),
+                ("folds", self.retention_folds,
+                 "Key blocks folded into a lane's state by a step "
+                 "program's last phase."),
+                ("pages_freed", self.retention_pages_freed,
+                 "Pages handed back to the allocator behind those folds, "
+                 "less the pages the same lanes drew for the rows "
+                 "ahead.")):
+            family = MetricFamily(
+                f"kubeshare_serving_retention_{name}_total", said,
+                "counter")
+            family.add(dict(plabel), value)
+            retention.append(family)
         diff_passes = MetricFamily(
             "kubeshare_serving_diffusion_passes_total",
             "Lane-passes of a configuration that generates by diffusion "
@@ -2482,7 +2637,8 @@ class ServingEngine:
         return [req, blocks, tokens, dispatches, loop_units,
                 moe_assign, moe_touched, moe_tiles, moe_tile_rows, view_rows,
                 diff_passes, diff_rows, diff_tokens, diff_blocks,
-                spec_loop_units, exit_reason, depth_summary, host_s,
+                *retention, spec_loop_units, exit_reason, depth_summary,
+                host_s,
                 guard_wait, guard_calls, slow, planner, prefix,
                 hit_tokens, evicted, tier_blocks,
                 tier_req, tier_tokens, tier_bytes, tier_stall,
@@ -3023,6 +3179,7 @@ class ServingEngine:
         slot.table[:] = 0
         slot.table[: len(slot.blocks)] = slot.blocks
         slot.length = 0
+        slot.folded, slot.paged_to = 0, len(slot.blocks)
         if n_promote or (hit is not None and hit.host_cow is not None):
             # PROMOTION: host payloads back into fresh device blocks.
             # Each upload is one warmed compiled shape dispatched
@@ -3226,8 +3383,13 @@ class ServingEngine:
         self.allocator.reclaim(slot.blocks[::-1])
         remaining = slot.max_new - done
         plan, cover = self._prefill_plan(resume_prompt.size)
-        needed = self.allocator.blocks_for_tokens(
-            max(cover, self._request_rows(resume_prompt.size, remaining)))
+        rows = max(cover, self._request_rows(resume_prompt.size, remaining))
+        if self._retention:
+            # the state is DROPPED with the pages (no tier holds one, and
+            # the index none of its rows): the resumed request prefills
+            # prompt + generated from row 0 and folds its way back
+            rows = min(rows, self._tail_rows)
+        needed = self.allocator.blocks_for_tokens(rows)
         if slot.temperature > 0.0:
             first_key = np.asarray(slot.step_keys[done - 1])
             step_keys = np.asarray(slot.step_keys[done:])
@@ -3448,8 +3610,14 @@ class ServingEngine:
         segment = slot.prompt[start: start + width]
         if segment.size < width:  # short-prompt pad tail (dead rows)
             segment = np.pad(segment, (0, width - segment.size))
+        # a 'retention' lane's table changes while the request lives (a
+        # fold zeroes the entries behind it in .consume, which does not
+        # wait for a chunk that yields no token): the dispatch gets a copy
+        # of it, not a view the backend may still be reading
+        table = slot.table[None].copy() if self._retention \
+            else slot.table[None]
         return (final,
-                jnp.asarray(slot.table[None]),
+                jnp.asarray(table),
                 jnp.asarray([start], np.int32),
                 jnp.asarray(segment[None]),
                 jnp.asarray([last_row], np.int32),
@@ -3458,6 +3626,63 @@ class ServingEngine:
                             np.float32),
                 jnp.asarray((slot.first_key if final else
                              np.zeros(2, np.uint32))[None]))
+
+    def _recurrent_args(self, p_slot: Optional[_Slot],
+                        decode_slots: Optional[List[_Slot]]) -> tuple:
+        """A 'retention' engine's last arguments of a dispatch: the gate
+        array and the states (donated), then the chunk's lane's fold
+        point and slot, then the decode lanes' fold points by slot.  An
+        engine of another block passes nothing more."""
+        if not self._retention:
+            return ()
+        args = [Recurrent(self.pool.gate, self.states)]
+        if p_slot is not None:
+            args += [jnp.asarray([p_slot.folded], np.int32),
+                     jnp.asarray([p_slot.idx], np.int32)]
+        if decode_slots is not None:
+            folded = np.zeros((self.engine_config.num_slots,), np.int32)
+            for slot in decode_slots:
+                folded[slot.idx] = slot.folded
+            args.append(jnp.asarray(folded))
+        return tuple(args)
+
+    def _keep_cache(self, pk, pv, rest: list) -> list:
+        """The pool a dispatch returned (and a 'retention' engine's gate
+        array and states, which come last) back onto the engine; returns
+        what else the dispatch returned (a routed block's counts)."""
+        if self._retention:
+            gate, self.states = rest.pop()  # (gate, a state a layer)
+            self.pool = replace(self.pool, k=pk, v=pv, gate=gate)
+        else:
+            self.pool = replace(self.pool, k=pk, v=pv)
+        return rest
+
+    def _note_retention(self, p_slot: Optional[_Slot],
+                        chunk: Optional[Tuple[int, int, int]],
+                        decode_slots: List[_Slot]) -> None:
+        """What a 'retention' dispatch carries, for :meth:`_observe_retention`:
+        the lanes, those that read a state, and the unfolded rows read
+        (a decode lane's tail once a step, one row longer each)."""
+        if not self._retention:
+            return
+        span = self.engine_config.decode_span
+        tails = [s.length - s.folded for s in decode_slots]
+        note = {
+            "lanes": len(decode_slots) + (p_slot is not None),
+            "state_lanes": sum(1 for s in decode_slots if s.folded),
+            "passes": span if decode_slots else 0,
+            "tail_rows": sum(span * t + span * (span + 1) // 2
+                             for t in tails),
+            "chunk": chunk[1] if chunk else 0, "chunk_state": 0,
+            "decode_slots": [(s, s.rid) for s in decode_slots],
+            "prefill": None,
+        }
+        if p_slot is not None:
+            start, _, last_row = chunk
+            note["chunk_state"] = int(p_slot.folded > 0)
+            note["tail_rows"] += start + last_row + 1 - p_slot.folded
+            note["prefill"] = (p_slot, p_slot.rid, start + last_row + 1)
+        self._retention_inflight = note
 
     def _decode_lanes(self, decode_slots: List[_Slot],
                       n_steps: Optional[int] = None):
@@ -3524,11 +3749,12 @@ class ServingEngine:
                 self._prefill_step, self.params, self.pool.k, self.pool.v,
                 table, start, jnp.ones((1,), bool), segment, last_row)
         else:
+            self._note_retention(slot, chunk, [])
             picked, pk, pv, *counts = self._dispatch(
                 self._prefill_step, self.params, self.pool.k, self.pool.v,
                 table, start, jnp.ones((1,), bool), segment, last_row,
-                temp, key)
-        self.pool = replace(self.pool, k=pk, v=pv)
+                temp, key, *self._recurrent_args(slot, None))
+        counts = self._keep_cache(pk, pv, counts)
         self._routing_inflight = (counts, segment.shape[1], 1)
         self.prefill_chunks += 1
         self._charge_collectives("prefill_chunk", "prefill", lanes=1,
@@ -3540,19 +3766,20 @@ class ServingEngine:
         # the fused pick at the final chunk's last-real-row logits IS
         # the first token; read when consumed (one step later), with
         # a routed block's counts
-        if final or counts:
+        if final or counts or self._retention:
             self._inflight = ("diffusion" if self._diffusion else "span",
                               None, (slot, picked) if final else None)
 
     def _run_decode_step(self, decode_slots: List[_Slot]) -> None:
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
+        self._note_retention(None, None, decode_slots)
         emitted, pk, pv, *counts = self._dispatch(
             self._decode_step, self.params, self.pool.k, self.pool.v,
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
             jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets))
-        self.pool = replace(self.pool, k=pk, v=pv)
+            jnp.asarray(budgets), *self._recurrent_args(None, decode_slots))
+        counts = self._keep_cache(pk, pv, counts)
         span = self.engine_config.decode_span
         self._routing_inflight = (counts, len(tokens) * span, span)
         self.decode_steps += 1
@@ -3776,13 +4003,15 @@ class ServingEngine:
             self._prefill_lane(p_slot, chunk)
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
+        self._note_retention(p_slot, chunk, decode_slots)
         picked, emitted, pk, pv, *counts = self._dispatch(
             self._mixed_step, self.params, self.pool.k, self.pool.v,
             table, start, segment, last_row, temp, key,
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
             jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets))
-        self.pool = replace(self.pool, k=pk, v=pv)
+            jnp.asarray(budgets),
+            *self._recurrent_args(p_slot, decode_slots))
+        counts = self._keep_cache(pk, pv, counts)
         span = self.engine_config.decode_span
         self._routing_inflight = (
             counts, segment.shape[1] + len(tokens) * span, 1 + span)
@@ -4027,7 +4256,69 @@ class ServingEngine:
             else:
                 _, slots, budgets = decode_part
                 self._accept_decode(slots, fetched[0], budgets)
+        if self._retention_inflight is not None:
+            self._observe_retention()
         return True
+
+    def _observe_retention(self) -> None:
+        """One 'retention' dispatch's bookkeeping, after its tokens are
+        accepted: every lane whose tail now holds a key block has folded
+        it in the step program's last phase (``paged.fold_lanes``: the
+        same arithmetic on the same lengths), so the pages behind the
+        fold go back to the allocator here and the lane draws the pages
+        its next rows need — never more than its tail's funding, fewer
+        as the request nears its end.  Then the counters and the
+        ``kubeshare.engine.retention`` span (its attributes are what a
+        trace's reader can reach)."""
+        note, self._retention_inflight = self._retention_inflight, None
+        key_block = paged.KEY_BLOCK
+        bs = self.engine_config.block_size
+        lanes = [(slot, slot.length) for slot, rid in note["decode_slots"]
+                 if slot.rid == rid and slot.state == "decode"]
+        if note["prefill"] is not None:
+            slot, rid, rows = note["prefill"]
+            if slot.rid == rid and slot.state != "free":
+                lanes.append((slot, rows))
+        folds = freed = 0
+        for slot, rows in lanes:
+            if rows - slot.folded < key_block:
+                continue
+            first = slot.folded // bs
+            behind = [int(b) for b in slot.table[first:first
+                                                 + key_block // bs]]
+            slot.table[first:first + key_block // bs] = 0
+            for b in behind:
+                slot.blocks.remove(b)
+            self.allocator.reclaim(behind)
+            slot.folded += key_block
+            folds += 1
+            # the rows this lane may still write: to its request's end,
+            # or as far as a tail is funded past the new fold point
+            total = self._request_rows(slot.prompt.size, slot.max_new)
+            want = self.allocator.blocks_for_tokens(
+                min(total, slot.folded + self._tail_rows))
+            ahead = want - slot.paged_to
+            if ahead > 0:
+                spec = self.tenants.get(slot.tenant)
+                drawn = self.allocator.reserve(
+                    ahead, slot.rid, tenant=spec.name,
+                    quota=spec.kv_block_quota)
+                slot.table[slot.paged_to:want] = drawn
+                slot.blocks += drawn
+                slot.paged_to = want
+            freed += len(behind) - max(ahead, 0)
+        state_reads = (note["state_lanes"] * note["passes"]
+                       + note["chunk_state"])
+        with profiling.span(
+                "kubeshare.engine.retention", lanes=note["lanes"],
+                state_lanes=note["state_lanes"], passes=note["passes"],
+                state_reads=state_reads, tail_rows=note["tail_rows"],
+                folds=folds, folded_rows=folds * key_block,
+                pages_freed=freed, chunk=note["chunk"]):
+            self.retention_state_reads += state_reads
+            self.retention_tail_rows += note["tail_rows"]
+            self.retention_folds += folds
+            self.retention_pages_freed += freed
 
     def _observe_routing(self, counts, rows: int, passes: int) -> None:
         """One routed dispatch's counts into the counters and a

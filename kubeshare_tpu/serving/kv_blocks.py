@@ -83,6 +83,9 @@ class KVRowLayout:
     k_row: Tuple[int, int]  # (heads, width)
     v_row: Tuple[int, int]
     v_packed: int = 1
+    # a 'retention' block's rows also hold the log gate, one float32 a KV
+    # head a layer, in an array of its own (PagedKVPool.gate)
+    gate_heads: int = 0
 
     @property
     def v_layers(self) -> int:
@@ -91,7 +94,8 @@ class KVRowLayout:
 
     def values_per_row(self) -> int:
         """Values the pool holds a token, over all pool layers (a spare
-        half row of an odd count of packed layers among them)."""
+        half row of an odd count of packed layers among them, the log
+        gates not: they are float32 whatever the rows are)."""
         return (self.layers * self.k_row[0] * self.k_row[1]
                 + self.v_layers * self.v_packed
                 * self.v_row[0] * self.v_row[1])
@@ -110,7 +114,9 @@ def kv_row_layout(config: TransformerConfig) -> KVRowLayout:
                            (1, config.kv_lora_rank),
                            (1, config.qk_rope_head_dim), v_packed=2)
     row = (config.kv_heads, config.head_dim)
-    return KVRowLayout("kv_heads", config.n_layers, row, row)
+    return KVRowLayout(
+        "kv_heads", config.n_layers, row, row,
+        gate_heads=config.kv_heads if config.block == "retention" else 0)
 
 
 def require_kv_heads(config: TransformerConfig, who: str) -> None:
@@ -140,16 +146,28 @@ class PagedKVPool:
     k: jnp.ndarray
     v: jnp.ndarray
     block_size: int
+    # a 'retention' block's log gate a row: float32 [layers, kv_heads,
+    # num_blocks x block_size], paged with K and V (a row's column is
+    # page x block_size + offset; the long axis last, so that the array's
+    # own layout has no padding); None elsewhere
+    gate: Optional[jnp.ndarray] = None
 
     @property
     def num_blocks(self) -> int:
         return self.k.shape[1]
 
+    def arrays(self) -> Tuple[jnp.ndarray, ...]:
+        """The pool's device arrays, in the order every step program
+        takes and returns them."""
+        return tuple(x for x in (self.k, self.v, self.gate)
+                     if x is not None)
+
     def bytes_per_block(self) -> int:
-        """HBM cost of one block (K and V, all layers) — the allocation
-        granularity the serving docs size against."""
-        return sum(x.size // x.shape[1] * x.dtype.itemsize
-                   for x in (self.k, self.v))
+        """HBM cost of one block (K and V and, where the block has one,
+        the log gate; all layers) — the allocation granularity the
+        serving docs size against."""
+        return sum(x.size * x.dtype.itemsize
+                   for x in self.arrays()) // self.num_blocks
 
     def read_block(self, block: int) -> Tuple[np.ndarray, np.ndarray]:
         """Host snapshot of one block's K and V slabs, each
@@ -216,12 +234,37 @@ def init_paged_pool(
             f"num_blocks must be >= 2 (block 0 is reserved scratch), "
             f"got {num_blocks}"
         )
+    layout = kv_row_layout(config)
     k, v = (jnp.zeros(shape[:1] + (num_blocks,) + shape[1:], config.dtype)
-            for shape in kv_row_layout(config).block_shapes(block_size))
+            for shape in layout.block_shapes(block_size))
     if kv_sharding is not None:
         k = jax.device_put(k, kv_sharding)
         v = jax.device_put(v, kv_sharding)
-    return PagedKVPool(k=k, v=v, block_size=block_size)
+    gate = None
+    if layout.gate_heads:
+        gate = jnp.zeros((layout.layers, layout.gate_heads,
+                          num_blocks * block_size), jnp.float32)
+    return PagedKVPool(k=k, v=v, block_size=block_size, gate=gate)
+
+
+def init_retention_states(config: TransformerConfig,
+                          num_slots: int) -> Tuple[jnp.ndarray, ...]:
+    """The recurrent states of a 'retention' block, BY SLOT and beside
+    the pool, not in it: one float32 array a layer, ``[num_slots,
+    kv_heads, state_rows, phi_width]`` — a lane's ``[S | z]`` a KV head,
+    transposed, its head_dim + 1 rows rounded up to whole tiles
+    (``ops/retention.py``), as of the lane's fold point.  An array a layer,
+    not one array of all: a layer's window of a stacked array is a copy
+    (1.2 GB a layer a step at the cell's size), a whole array is not.  The
+    paged pool holds a lane's UNFOLDED rows only.  A slot's state means
+    nothing until its lane has folded a key block (the first fold writes
+    it whole), so nothing is zeroed between requests."""
+    from ..ops.retention import phi_width, state_rows
+
+    return tuple(
+        jnp.zeros((num_slots, config.kv_heads, state_rows(config.head_dim),
+                   phi_width(config.head_dim)), jnp.float32)
+        for _ in range(config.n_layers))
 
 
 class BlockAllocator:

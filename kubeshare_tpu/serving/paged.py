@@ -98,7 +98,7 @@ ignored host-side.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -112,8 +112,12 @@ from ..models.decoding import (
 from ..models.transformer import (TransformerConfig, _rms_norm,
                                   attend_reach, gqa_moe_layers, gqa_qkv,
                                   latent_absorbed, latent_attend_blocks,
-                                  latent_layers, latent_qkv, latent_scale)
+                                  latent_layers, latent_qkv, latent_scale,
+                                  retention_gate, retention_layers,
+                                  retention_qkv)
 from ..ops.moe import ROUTING_COUNTS, expert_path
+from ..ops.retention import (fold_update, retention_output, state_sums,
+                             tail_sums)
 from ..ops.paged_attention import (kernel_fits, latent_kernel_fits,
                                    paged_decode_attention,
                                    paged_latent_decode_attention)
@@ -337,14 +341,17 @@ def attend_path(block: str, query_rows: int, table_width: int, pool_k,
     kernel of the block's row layout can run: each lane reads its own
     pages, bounded by its own reach) or "blocks" (the key-block loop, as
     far as the furthest lane reaches; the latent blocks run it over a
-    short view too).  A lane's rows see the same keys where they are one
-    row, or ``diffusion_block`` rows under generation by diffusion over
-    blocks: every program starts a lane's rows at a multiple of B
+    short view too); a 'retention' block has no view: "tail"
+    (:func:`_retention_layers`).  A lane's rows see the same keys where
+    they are one row, or ``diffusion_block`` rows under generation by
+    diffusion over blocks: every program starts a lane's rows at a multiple of B
     (:func:`paged_diffusion_pass`'s ``lengths``, the chunk's ``starts``),
     so B rows are one aligned block and ``attend_reach`` gives them all
     its last row.  A chunk of many blocks and the verify spans, whose
     rows' reaches differ, run the loop.  The engine names the path on its
     launch spans (``attend``)."""
+    if block == "retention":
+        return "tail"  # a window of unfolded rows beside the lane's state
     short = table_width * pool_k.shape[3] <= KEY_BLOCK
     if block in KV_HEADS_BLOCKS:
         if short:
@@ -578,6 +585,133 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     return x, pool_k, pool_v, counts
 
 
+class Recurrent(NamedTuple):
+    """What a 'retention' block's step programs carry beside K and V, both
+    donated: ``gate`` the pool's third array (float32 ``[layers, kv_heads,
+    num_blocks x block_size]``, each unfolded row's log gate in column
+    ``page x block_size + offset``) and ``states``, an array a layer
+    ``[slots, kv_heads, state_rows, phi_width]``: the lanes' recurrent
+    states BY SLOT (``kv_blocks.init_retention_states``)."""
+
+    gate: jax.Array
+    states: Tuple[jax.Array, ...]
+
+
+def tail_pages(rows: int, block_size: int) -> int:
+    """Pages that cover a lane's unfolded rows through a step that adds
+    ``rows``: a tail is under one key block going in (a lane folds as soon
+    as its tail holds one) and a fold starts on a page's first row."""
+    if KEY_BLOCK % block_size:
+        raise ValueError(
+            f"a fold covers whole pages: block_size {block_size} must "
+            f"divide KEY_BLOCK {KEY_BLOCK}")
+    return -(-(KEY_BLOCK + rows) // block_size)
+
+
+def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
+                      tables, positions, blk, off, x, live, carried):
+    """The 'retention' block's layers (``transformer.retention_layers`` puts
+    a layer together), same contract as :func:`_dense_layers` plus
+    ``carried`` = (:class:`Recurrent`, ``folded`` [B], ``slots`` [B] or
+    None, ``grow``): lane b has folded its rows before ``folded[b]`` (a
+    multiple of ``KEY_BLOCK``) into the state of slot ``slots[b]`` (None:
+    lane b IS slot b, the decode lanes) and holds the rows from there on in
+    its pages; ``grow`` bounds the rows a tail gains before its next fold
+    (the chunk's width, the span's steps).
+
+    A layer writes its rows' K, V (:func:`_write_rows`) and log gate, then
+    reads ONE window of pages a lane, from the fold point on — at most a
+    key block and ``grow`` rows, whatever the request's length: the gate's
+    running log is the window's running sum, the unfolded rows weigh in by
+    their squared scores (``ops.retention.tail_sums``, a running sum, no
+    softmax), everything before them by ``phi(q)`` against the state
+    (``state_sums``), which a dispatch whose lanes have folded nothing
+    never reads.  Nothing here writes a state: a fold is the step
+    program's last phase (:func:`fold_lanes`).  Returns the updated gate
+    array where the other blocks return their routing counts."""
+    recurrent, folded, slots, grow = carried
+    gate, states = recurrent
+    bs = pool_k.shape[3]
+    dtype = config.dtype
+    pages = tail_pages(grow, bs)
+    first = (folded // bs)[:, None] + jnp.arange(pages)[None, :]
+    window = jnp.take_along_axis(
+        tables, jnp.minimum(first, tables.shape[1] - 1), axis=1)  # [B, Wp]
+    # the window's rows as columns of the gate array, [B, W]
+    columns = (window[:, :, None] * bs
+               + jnp.arange(bs)[None, None, :]).reshape(window.shape[0], -1)
+    q_row = jnp.where(live, positions - folded[:, None], -1)  # [B, C]
+    has_state = (folded > 0) & jnp.any(live, axis=1)
+
+    def attend(layer_idx, attn, y):
+        nonlocal pool_k, pool_v, gate
+        q, k, v = retention_qkv(attn, y, positions, config)
+        a = retention_gate(attn, y)  # [B, C, h_kv]
+        pool_k, pool_v = _write_rows(
+            pool_k, pool_v, layer_idx, blk, off,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        with jax.named_scope("kv_write"):
+            gate = gate.at[layer_idx, :, blk * bs + off].set(a)
+        with jax.named_scope("retention_tail"):
+            k_win, v_win = _layer_views(pool_k, pool_v, layer_idx, window)
+            cum_win = jnp.cumsum(gate[layer_idx, :, columns], axis=1)
+            cum_q = jnp.take_along_axis(
+                cum_win, jnp.maximum(q_row, 0)[:, :, None], axis=1)
+        tail = tail_sums(q, k_win, v_win, cum_q, cum_win, q_row, dtype)
+
+        def from_state():
+            with jax.named_scope("retention_state"):
+                lane_states = (states[layer_idx] if slots is None
+                               else states[layer_idx][slots])
+            return state_sums(q, lane_states, cum_q, has_state)
+
+        state = jax.lax.cond(
+            jnp.any(has_state), from_state,
+            lambda: (jnp.zeros_like(tail[0]), jnp.zeros_like(tail[1])))
+        return retention_output(tail, state, dtype)
+
+    x = retention_layers(params, x, config, attend)
+    return x, pool_k, pool_v, gate
+
+
+@jax.named_scope("retention_fold")
+def fold_lanes(pool_k, pool_v, recurrent: Recurrent, tables, folded,
+               lengths, live, slots=None) -> Recurrent:
+    """A step program's last phase: every lane whose tail now holds a key
+    block — ``lengths[b] - folded[b] >= KEY_BLOCK``, ``lengths`` the rows
+    it holds once the step is done — folds rows ``folded[b] ..
+    folded[b] + KEY_BLOCK - 1`` into its slot's state, every layer
+    (``ops.retention.fold_update``), from the pages the rows lie in.  A
+    loop over the lanes that are due, one at a time (none, most
+    dispatches); the engine's ``.consume`` does the same arithmetic and
+    hands the pages behind the fold back.  The states change nowhere
+    else."""
+    gate, states = recurrent
+    _, _, h_kv, bs, hd = pool_k.shape
+    entries = KEY_BLOCK // bs
+    due = live & (lengths - folded >= KEY_BLOCK)
+    order = jnp.argsort(~due)  # the due lanes first (a stable sort)
+
+    def fold_lane(i, states):
+        lane = order[i]
+        slot = lane if slots is None else slots[lane]
+        start = folded[lane]
+        pages = jax.lax.dynamic_slice_in_dim(
+            tables[lane], start // bs, entries)
+        columns = (pages[:, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)
+        rows = lambda pool, layer: pool[layer, pages].transpose(
+            1, 0, 2, 3).reshape(h_kv, KEY_BLOCK, hd)
+        return tuple(
+            state.at[slot].set(fold_update(
+                state[slot], rows(pool_k, layer), rows(pool_v, layer),
+                gate[layer, :, columns], start > 0))
+            for layer, state in enumerate(states))
+
+    states = jax.lax.fori_loop(0, jnp.sum(due, dtype=jnp.int32), fold_lane,
+                               states)
+    return Recurrent(gate, states)
+
+
 # the layers' counts, then the rows that chose: what a routed step returns
 N_STEP_COUNTS = len(ROUTING_COUNTS) + 1
 
@@ -585,9 +719,14 @@ _LAYERS = {"dense": _dense_layers, "gqa_moe": _gqa_moe_layers,
            "latent_shortcut": _latent_layers, "latent_moe": _latent_layers}
 
 
-def _run_layers(params, config: TransformerConfig, *args):
+def _run_layers(params, config: TransformerConfig, *args, carried=None):
     """The block's layer loop: the one place a step program's layers
-    run, whatever the step (prefill chunk, decode step, verify chunk)."""
+    run, whatever the step (prefill chunk, decode step, verify chunk).
+    Returns (x, pool_k, pool_v, and what the block's steps hand on beside
+    the pool: a routed block's routing counts, a 'retention' block's gate
+    array, else None); ``carried`` is the 'retention' block's alone."""
+    if config.block == "retention":
+        return _retention_layers(params, config, *args, carried)
     return _LAYERS[config.block](params, config, *args)
 
 
@@ -598,7 +737,7 @@ def _with_routing(routing: bool, counts, *outputs):
 
 
 def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
-                  starts, active, tokens, last_rows):
+                  starts, active, tokens, last_rows, carried=None):
     """A prefill chunk's rows through the layers (see
     :func:`paged_prefill_step`): the final hidden states [P, C, d], the
     pool and the routing counts, before any head."""
@@ -615,9 +754,13 @@ def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
     # a chunk's rows after its last real one are padding
     live = active[:, None] & (
         jnp.arange(chunk)[None, :] <= last_rows[:, None])
+    if carried is not None:
+        # a 'retention' chunk pads FORWARD, past the prompt's last row:
+        # the padding's rows land in the scratch block, not in a page
+        blk = jnp.where(live, blk, 0)
     return _run_layers(
         params, config, pool_k, pool_v, tables, positions, blk, off, x,
-        live)
+        live, carried=carried)
 
 
 def paged_prefill_step(
@@ -631,6 +774,9 @@ def paged_prefill_step(
     tokens,
     last_rows,
     routing: bool = False,
+    recurrent: Optional[Recurrent] = None,
+    folded=None,
+    slots=None,
 ) -> Tuple[jax.Array, ...]:
     """One width-C prefill chunk for P slot lanes at once.
 
@@ -647,6 +793,12 @@ def paged_prefill_step(
     is computed (a full [P, C, vocab] f32 buffer would dominate the
     step at real vocab sizes).
 
+    A 'retention' block also takes ``recurrent`` (:class:`Recurrent`),
+    each lane's fold point ``folded`` [P] and slot ``slots`` [P], and
+    returns the :class:`Recurrent` last: the chunk reads the lane's state
+    and its unfolded rows, and where it completes a key block, the
+    program's last phase folds it (:func:`fold_lanes`).
+
     Inactive lanes write to the scratch block and compute garbage the
     caller ignores.  NOTE: the engine deliberately dispatches P=1 (one
     lane per chunk) — a static multi-lane shape bills every dispatch
@@ -655,9 +807,11 @@ def paged_prefill_step(
     lanes here.
     """
     dtype = config.dtype
+    carried = (None if recurrent is None
+               else (recurrent, folded, slots, tokens.shape[1]))
     x, pool_k, pool_v, counts = _prefill_rows(
         params, config, pool_k, pool_v, tables, starts, active, tokens,
-        last_rows)
+        last_rows, carried)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
@@ -665,6 +819,10 @@ def paged_prefill_step(
             x, last_rows[:, None, None], axis=1)  # [P,1,d]
         logits = (head_in
                   @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    if recurrent is not None:
+        return (logits[:, 0], pool_k, pool_v, fold_lanes(
+            pool_k, pool_v, Recurrent(counts, recurrent.states), tables,
+            folded, starts + last_rows + 1, active, slots))
     return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
 
 
@@ -678,6 +836,9 @@ def paged_decode_step(
     active,
     tokens,
     routing: bool = False,
+    recurrent: Optional[Recurrent] = None,
+    folded=None,
+    grow: int = 1,
 ) -> Tuple[jax.Array, ...]:
     """One decode token for every slot in the pool at once.
 
@@ -687,6 +848,12 @@ def paged_decode_step(
     (logits [S, vocab], pool_k, pool_v); inactive rows compute garbage
     the caller ignores — their K/V writes are routed to the scratch
     block so the pool's live data is never touched.
+
+    A 'retention' block also takes ``recurrent`` and the lanes' fold
+    points ``folded`` [S] (lane s is slot s), and returns the
+    :class:`Recurrent` last with the step's log gates written; it folds
+    nothing — that is the span's last phase, and ``grow`` says how many
+    steps a tail may gain before it.
     """
     dtype = config.dtype
     bs = pool_k.shape[3]
@@ -700,13 +867,18 @@ def paged_decode_step(
     if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)[:, None, :]
     # every slot a one-row chunk at its own position: the same layer loop
+    carried = (None if recurrent is None
+               else (recurrent, folded, None, grow))
     x, pool_k, pool_v, counts = _run_layers(
         params, config, pool_k, pool_v, block_tables, positions[:, None],
-        blk[:, None], off[:, None], x, active[:, None])
+        blk[:, None], off[:, None], x, active[:, None], carried=carried)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
         logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    if recurrent is not None:
+        return (logits[:, 0], pool_k, pool_v,
+                Recurrent(counts, recurrent.states))
     return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
 
 
@@ -726,6 +898,8 @@ def paged_decode_span(
     keys,
     budgets,
     routing: bool = False,
+    recurrent: Optional[Recurrent] = None,
+    folded=None,
 ) -> Tuple[jax.Array, ...]:
     """Advance every active lane up to ``span`` tokens in ONE dispatch.
 
@@ -739,7 +913,17 @@ def paged_decode_span(
     routing counts summed over the span's steps last.
     ``pick_fn``/``span``/``eos`` are trace-time constants (the engine
     closes over them under jit).
+
+    A 'retention' block (``recurrent``, ``folded`` [S]) carries its gate
+    array through the steps, which only READ the states; a lane whose tail
+    holds a key block once the span is done folds it in the program's last
+    phase (:func:`fold_lanes`), and the :class:`Recurrent` comes last.
     """
+    if recurrent is not None:
+        return _retention_span(
+            params, config, pick_fn, span, eos, pool_k, pool_v, tables,
+            lengths, active, tokens, temps, keys, budgets, recurrent,
+            folded)
 
     def body(carry, i):
         pk, pv, lens, toks, alive, *counts = carry
@@ -760,6 +944,35 @@ def paged_decode_span(
     (pk, pv, _, _, _, *counts), emitted = jax.lax.scan(
         body, carry, jnp.arange(span))
     return (emitted, pk, pv, *counts)
+
+
+def _retention_span(params, config, pick_fn, span, eos, pool_k, pool_v,
+                    tables, lengths, active, tokens, temps, keys, budgets,
+                    recurrent: Recurrent, folded):
+    """:func:`paged_decode_span` of a 'retention' block: the same scan with
+    the gate array and the states in its carry (no step writes a state:
+    it rides through, so that the fold after the scan updates the one
+    buffer and the compiler keeps no second copy of 5 GB), then the fold
+    of every lane that is due."""
+
+    def body(carry, i):
+        pk, pv, gate, states, lens, toks, alive = carry
+        logits, pk, pv, (gate, states) = paged_decode_step(
+            params, config, pk, pv, tables, lens, alive, toks,
+            recurrent=Recurrent(gate, states), folded=folded, grow=span)
+        with jax.named_scope("sample"):
+            nxt = pick_fn(logits, temps, keys[:, i])
+        lens = lens + alive.astype(jnp.int32)
+        cont = alive & (i + 1 < budgets)
+        if eos is not None:
+            cont = cont & (nxt != eos)
+        return (pk, pv, gate, states, lens, nxt, cont), nxt
+
+    (pk, pv, gate, states, lens, _, _), emitted = jax.lax.scan(
+        body, (pool_k, pool_v, *recurrent, lengths, tokens, active),
+        jnp.arange(span))
+    return emitted, pk, pv, fold_lanes(
+        pk, pv, Recurrent(gate, states), tables, folded, lens, active)
 
 
 def _decode_loop_impl(
@@ -1292,6 +1505,10 @@ def paged_mixed_step(
     d_keys,
     d_budgets,
     routing: bool = False,
+    recurrent: Optional[Recurrent] = None,
+    p_folded=None,
+    p_slot=None,
+    d_folded=None,
 ) -> Tuple[jax.Array, ...]:
     """One fused mixed dispatch: a bounded prefill chunk for ONE
     filling slot + a full decode span for every active decode lane.
@@ -1316,8 +1533,21 @@ def paged_mixed_step(
     meaningful only when the chunk is the prompt's final one (the
     fused first-token pick, same as the standalone prefill step); with
     ``routing`` the routing counts of the chunk and the span, summed,
-    come last.
+    come last; a 'retention' block's :class:`Recurrent` goes through the
+    chunk (its fold with it) and then the span (and its lanes' folds).
     """
+    if recurrent is not None:
+        p_logits, pk, pv, recurrent = paged_prefill_step(
+            params, config, pool_k, pool_v, p_table, p_start,
+            jnp.ones_like(p_start, bool), p_tokens, p_last_row,
+            recurrent=recurrent, folded=p_folded, slots=p_slot)
+        with jax.named_scope("sample"):
+            p_picked = pick_fn(p_logits, p_temp, p_key)
+        emitted, pk, pv, recurrent = paged_decode_span(
+            params, config, pick_fn, span, eos, pk, pv, d_tables,
+            d_lengths, d_active, d_tokens, d_temps, d_keys, d_budgets,
+            recurrent=recurrent, folded=d_folded)
+        return p_picked, emitted, pk, pv, recurrent
     p_logits, pk, pv, *p_counts = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row,

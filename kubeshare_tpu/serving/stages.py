@@ -53,6 +53,11 @@ STAGE_OF_SCOPE = {
     "mlp": "ffn", "ffn": "ffn", "dense_ffn": "ffn", "shared_expert": "ffn",
     "experts": "experts", "router": "experts",
     "lm_head": "head", "sample": "head", "denoise_pick": "head",
+    # a 'retention' block's own: the state's query, the unfolded rows'
+    # weights, a fold, the log gate, the feature map (its q/k norms stay
+    # `attention`'s, its tail's row writes `kv_write`'s, its SwiGLU `ffn`'s)
+    "retention_state": "retention", "retention_tail": "retention",
+    "retention_fold": "retention", "gate": "retention", "phi": "retention",
 }
 STAGES = tuple(dict.fromkeys(STAGE_OF_SCOPE.values())) + (UNSCOPED,)
 

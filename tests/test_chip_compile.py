@@ -623,3 +623,107 @@ def test_latent_program_off_the_chip_gathers_key_blocks(one_chip, name):
     assert 'custom_call_target="tpu_custom_call"' not in text
     assert re.search(r"bf16\[1024,16,512\]", text)
     assert re.search(r"bf16\[1024,16,128\]", text)
+
+
+# ---------------------------------------------------------------------------
+# the 'retention' block: a recurrent state a lane beside the paged tail
+# ---------------------------------------------------------------------------
+
+# temporaries of `brumby-14b-base`'s two programs as first accepted (PR 41):
+# 552,131,072 B the span alone, 918,589,952 B beside a 512-row chunk
+RETENTION_TEMPORARIES = {"decode": 700 << 20, "mixed": 1100 << 20}
+
+
+def _retention_case(kind):
+    """The decode span or the mixed program of ``brumby-14b-base``'s first
+    pipeline stage at its published widths, as shapes only, compiled as the
+    engine compiles them: the pool's three arrays and the states (an array a
+    layer) donated, the lanes' fold points and the chunk's slot after them."""
+    import json
+
+    from kubeshare_tpu.serving.kv_blocks import (init_paged_pool,
+                                                 init_retention_states)
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "brumby-14b-base.json")) as f:
+        config_file = json.load(f)
+    tc = dict(config_file["transformer_config"])
+    tc["dtype"] = jnp.dtype(tc["dtype"])
+    config = TransformerConfig(**tc)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, config.dtype),
+        jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), config)))
+    e = config_file["engine"]
+    s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
+    num_blocks = e["pool_bytes"] // (20640 * e["block_size"]) + 1
+    pool_k, pool_v, gate = jax.eval_shape(
+        lambda: init_paged_pool(config, num_blocks,
+                                e["block_size"]).arrays())
+    recurrent = paged.Recurrent(gate, jax.eval_shape(
+        lambda: init_retention_states(config, s)))
+    span = 4
+    lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
+             jax.ShapeDtypeStruct((s,), jnp.float32),
+             jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
+    if kind == "decode":
+        fn = lambda w, pk, pv, rec, folded, *rest: paged_decode_span(
+            w, config, _greedy_pick, span, None, pk, pv, *rest,
+            recurrent=rec, folded=folded)
+        return config, fn, (params, pool_k, pool_v, recurrent, _i32(s),
+                            *lanes)
+    fn = lambda w, pk, pv, rec, p_folded, p_slot, d_folded, *rest: \
+        paged_mixed_step(w, config, _greedy_pick, span, None, pk, pv, *rest,
+                         recurrent=rec, p_folded=p_folded, p_slot=p_slot,
+                         d_folded=d_folded)
+    return config, fn, (
+        params, pool_k, pool_v, recurrent, _i32(1), _i32(1), _i32(s),
+        _i32(1, t), _i32(1), _i32(1, e["prefill_chunk"]), _i32(1),
+        jax.ShapeDtypeStruct((1,), jnp.float32),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
+
+
+@pytest.mark.parametrize("kind", list(RETENTION_TEMPORARIES))
+def test_retention_program_compiles_and_fits(one_chip, kind):
+    """The first pipeline stage of ``brumby-14b-base`` at the published
+    widths — five power-retention layers of GQA 40 to 8 at head width 128,
+    a SwiGLU of 17408, the whole vocabulary — with 32 lanes' states (5.79 GB,
+    an array a layer) beside a 1 GiB pool of unfolded rows: the decode span
+    alone and beside a 512-row chunk fit under 14.4 GB (``gpu_mem`` 0.9 of
+    the chip), everything donated is written in place, and NO state is
+    copied.  What the first forms of these programs did, each found here
+    before the chip saw it (PR 41): a state closed over by the scan and
+    folded after it was copied whole (5.4 GB: over the chip); a state array
+    of 129 rows came back in another layout than it went in (a copy each
+    way, each dispatch); one stacked array of all layers was windowed a
+    layer a step (1.16 GB a layer a step); the folds of five layers held
+    five float32 ``phi`` of a key block at once (680 MB); ``[d, 40, 128]``
+    projections were laid out anew every dispatch (72 MB a layer)."""
+    import re
+
+    config, fn, args = _retention_case(kind)
+    assert args[1].shape == args[2].shape == (5, 3252, 8, 16, 128)
+    gate, states = args[3]
+    assert gate.shape == (5, 8, 3252 * 16) and gate.dtype == jnp.float32
+    assert len(states) == 5 and states[0].shape == (32, 8, 136, 8320)
+    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2, 3))
+    memory = compiled.memory_analysis()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert resident < 14.4e9, memory
+    assert memory.temp_size_in_bytes < RETENTION_TEMPORARIES[kind], memory
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(args[1:4]))
+    assert donated > 6.8e9 and memory.alias_size_in_bytes >= donated, memory
+    text = compiled.as_text()
+    for shape in ("32,8,136,8320", "5,3252,8,16,128"):
+        assert not re.search(rf"\[{shape}\][^ ]* copy\(", text), shape
+    # the states come back as they went in: row-major, whole tiles
+    results = text.split("entry_computation_layout=", 1)[1].split(
+        ")->(")[1].split("\n")[0]
+    assert results.count("f32[32,8,136,8320]{3,2,1,0:T(8,128)}") == 5
+    table = stages.instruction_stages(text)
+    assert "retention" in set(table.values())
+    # a decode step's state query reads each state where it lies: one
+    # multiply-and-sum over phi's columns a layer a step, no staged slice
+    assert not re.search(r"f32\[32,8,5,136,8320\][^ ]* fusion\(", text)
