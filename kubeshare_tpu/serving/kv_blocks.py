@@ -69,7 +69,12 @@ class KVRowLayout:
       second half is spare, and counted): a row of 64 values is half a
       TPU vector register, and the compiler keeps an array that narrow
       blocks-minor — it would transpose the whole array into and out
-      of every program.
+      of every program.  A packed row is WRITTEN whole as well
+      (``paged._write_rows`` reads the rows, lays the sub-layer's key
+      into its lanes and scatters all ``v_packed x width`` values back):
+      a scatter whose window is narrower than the array's row becomes a
+      loop of one update a row, 4.5-5.0 us each on a v5e against 0.09 us
+      a whole row (PERF.md, PR 42).
 
     Every step program writes rows through ``paged._write_rows`` and
     reads them through ``paged._layer_views`` whatever the layout; what

@@ -196,12 +196,27 @@ def _write_rows(pool_k, pool_v, layer_idx, blk, off, k, v):
     that ``[layer_idx, blk, :, off, :]`` gives, the TPU compiler re-tiles
     the whole pool so that heads sit next to rows whenever ``h_kv > 1``
     — two pool-sized copies in and two out, every program (PERF.md,
-    PR 25)."""
+    PR 25).
+
+    Nor is the window ever narrower than the array's row.  Where ``v``
+    is a part of a packed V row (:func:`_v_part`: the array's row is
+    wider than ``v``), the rows are read, ``v`` is laid into its lanes
+    and the WHOLE rows are scattered back, so the other lanes keep what
+    they held (the pair's other key, or the page's old contents).
+    Scattered through the part's own 64-wide window the TPU compiler
+    expands the scatter into a ``while`` of one ``dynamic-update-slice``
+    of the pool an update: 4.5-5.0 us a row on a v5e against 0.09 us
+    through the whole row, a quarter of a latent cell's dispatch
+    (PERF.md, PR 42)."""
     if blk.ndim == 2 and blk.shape[1] == 1:
         # a decode step's one row a lane: the scatter it always was
         blk, off, k, v = blk[:, 0], off[:, 0], k[:, 0], v[:, 0]
     blk, off = blk[..., None], off[..., None]
     v_layer, lanes = _v_part(pool_v, layer_idx, v.shape[-1])
+    if pool_v.shape[-1] != v.shape[-1]:
+        # dead rows that share one (blk, off) all read the same other half
+        held = pool_v[v_layer, blk, jnp.arange(pool_v.shape[2]), off]
+        v, lanes = held.at[..., lanes].set(v), slice(None)
     return (pool_k.at[layer_idx, blk, jnp.arange(pool_k.shape[2]), off,
                       :].set(k),
             pool_v.at[v_layer, blk, jnp.arange(pool_v.shape[2]), off,
@@ -214,7 +229,10 @@ def _v_part(pool_v, layer_idx, width: Optional[int] = None):
     its row.  The V rows of ``packed`` consecutive layers share one
     array row (kv_blocks.KVRowLayout ``v_packed``; 1, the whole row, for
     a K and a V a head); an odd count of layers leaves the last row's
-    second half spare."""
+    second half spare.  The lanes are for READING a part out of rows
+    already gathered (:func:`_layer_reader`); a write goes through the
+    whole row all the same (:func:`_write_rows`: a scatter whose window
+    is the part becomes a loop of one update a row on the TPU)."""
     packed = 1 if width is None else pool_v.shape[-1] // width
     if packed == 1:
         return layer_idx, slice(None)

@@ -466,8 +466,33 @@ def _attends_through_the_latent_kernel(config, text):
     assert not re.search(r"bf16\[1024,16,(512|128)\]", text)
 
 
-@pytest.mark.parametrize("kind,temporaries", [("decode", 157_574_656),
-                                              ("mixed", 350_972_416)])
+def _rows_written_whole(args, text):
+    """No row-at-a-time loop is left in ``kv_write`` (PR 42).  A scatter
+    whose window is narrower than the array's row — the 64 values of one
+    rotary key in the 128-wide packed row — is expanded into a ``while``
+    of one ``dynamic-update-slice`` of the V array an update (4.5-5.0 us a
+    row on a v5e; a whole row 0.09).  The program's table of stages gives
+    such a ``while`` the stage of the write it stands for, as it would a
+    loop written under the scope; the decode span's scan, whose body holds
+    every stage of a step, and the chunk's key-block loops have another.
+    So: no ``while`` is ``kv_write``'s, and no ``dynamic-update-slice``
+    makes an array of the V array's shape."""
+    import re
+
+    table = stages.instruction_stages(text)
+    whiles = [found.group(2) for found in map(
+        stages._INSTRUCTION.match,
+        stages._COMMENT.sub("", text).splitlines())
+        if found and found.group(4) == "while"]
+    assert whiles  # the span's scan at least: the text was read
+    assert not [name for name in whiles if table[name] == "kv_write"]
+    v_shape = ",".join(map(str, args[2].shape))
+    assert not re.search(rf"bf16\[{v_shape}\][^ ]* dynamic-update-slice\(",
+                         text)
+
+
+@pytest.mark.parametrize("kind,temporaries", [("decode", 157_404_160),
+                                              ("mixed", 348_711_936)])
 def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
                                                 temporaries):
     """One expert-parallel rank at the published widths, built as on the
@@ -477,7 +502,9 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     is gathered; and the expert layer's work follows the routing: nothing
     in the program has a row of every lane or chunk row for each of the
     16 held experts (``rows x 16`` expert rows a layer is what a
-    capacity-pinned dispatch would multiply).  Its temporaries were
+    capacity-pinned dispatch would multiply); and the packed rotary rows
+    are written whole, no loop of row updates left in ``kv_write``
+    (``_rows_written_whole``).  Its temporaries were
     158,880,768 / 362,003,456 B until the expert layer took the rows'
     liveness (the masks of the dead rows and the three counts more a
     step: 388,608 / 612,864 B), then 159,269,376 / 362,616,320 B on the
@@ -486,7 +513,9 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     span), 362,616,320 -> 355,068,416 (mixed); with the experts' tiles
     in the grouped kernel (PR 39: one call a layer and pass, the matrices
     in 512-column blocks) the loop's accumulator and gathered tiles go:
-    157,574,656 / 350,972,416."""
+    157,574,656 / 350,972,416; with the packed rotary rows written whole
+    (PR 42) the 16 row-at-a-time loops of the write and what they carried
+    go: 157,404,160 / 348,711,936."""
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("longcat-flash-chat", kind)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
@@ -495,6 +524,7 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     _attends_through_the_latent_kernel(config, text)
     _experts_through_the_grouped_kernel(
         config, text, config.expert_layers * (2 if kind == "mixed" else 1))
+    _rows_written_whole(args, text)
     by_stage = _stages_hold(config, args, text)
     assert {"attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
@@ -519,7 +549,9 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     64-wide rotary rows, packed two to a row); its decode lanes attend
     through the paged latent kernel, one call a layer; and nothing in the
     program has ``rows x 256`` expert rows a layer: the only arrays with
-    the experts' axis are the experts' own matrices."""
+    the experts' axis are the experts' own matrices.  The packed rotary
+    rows are written whole (PR 42): no loop of row updates is left in
+    ``kv_write`` (``_rows_written_whole``)."""
     import re
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
@@ -535,6 +567,7 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
     assert with_experts <= {"bf16[256,2048,768]", "bf16[256,768,2048]"}, \
         with_experts
+    _rows_written_whole(args, text)
     by_stage = _stages_hold(config, args, text)
     assert {"attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
