@@ -106,8 +106,10 @@ def init_kv_cache(config: TransformerConfig, batch: int) -> Dict:
     }
 
 
-def _attend_cached(q, cache_k, cache_v, q_positions, window=None):
-    """q: [b,h,Cq,d] against cache [b,h_kv,S,d]; per-query causal band.
+def _attend_cached(q, cache_k, cache_v, q_positions, window=None,
+                   scale=None):
+    """q: [b,h,Cq,d] against cache [b,h_kv,S,d]; per-query causal band
+    (``scale``: what the scores are multiplied by, None ``d ** -0.5``).
 
     ``q_positions`` are the queries' global positions: query i sees
     cache slots ``k_pos <= q_positions[i]`` (and, with a window, within
@@ -125,7 +127,7 @@ def _attend_cached(q, cache_k, cache_v, q_positions, window=None):
     b, h, cq, d = q.shape
     h_kv = cache_k.shape[1]
     group = h // h_kv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     qg = q.reshape(b, h_kv, group, cq, d)
     scores = jnp.einsum(
         "bhgqd,bhkd->bhgqk", qg, cache_k).astype(jnp.float32) * scale
@@ -148,7 +150,7 @@ def _attend_cached(q, cache_k, cache_v, q_positions, window=None):
 
 
 def _attend_blocks(q, view_block, block_rows: int, kv_heads: int,
-                   q_positions, window=None):
+                   q_positions, window=None, scale=None):
     """:func:`_attend_cached` over a view that is handed over a block of
     K = ``block_rows`` rows at a time: ``view_block(i)`` gives rows
     ``[i * K, (i + 1) * K)`` of every lane's view as (k, v), each
@@ -161,7 +163,7 @@ def _attend_blocks(q, view_block, block_rows: int, kv_heads: int,
     softmax's sums."""
     b, h, cq, d = q.shape
     group = h // kv_heads
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     qg = q.reshape(b, kv_heads, group, cq, d)
 
     def scores_of(k, _):

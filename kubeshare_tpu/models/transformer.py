@@ -130,8 +130,25 @@ class TransformerConfig:
     # gated FFN of width d_ff in every layer.  Served from a recurrent
     # state a lane beside a short paged tail of keys and values
     # (serving/paged.py _retention_layers); it has no field of its own
+    # layers that name their OPERATOR ("gqa_moe" under the causal mask
+    # only): None, every layer's operator is the block's attention; else
+    # one name a layer, "attention" (the block's, with a cache row in the
+    # paged pool) or "conv" (the gated short convolution of
+    # ops/short_conv.py over conv_taps rows: no cache row, a state of
+    # conv_taps - 1 rows of d_model values a lane, BY SLOT beside the
+    # pool).  The feed-forward behind either is the block's: a dense gated
+    # FFN of width d_ff in the first first_dense_layers layers, the routed
+    # experts in the rest
+    layer_operators: Optional[Tuple[str, ...]] = None
+    conv_taps: int = 0
+    # what the renormalised weights' sum is kept off zero by
+    router_renormalise_eps: float = 1e-20
 
     def __post_init__(self) -> None:
+        if self.layer_operators is not None:
+            # a JSON file gives a list; the config is hashed (jit)
+            object.__setattr__(self, "layer_operators",
+                               tuple(self.layer_operators))
         _check_block(self)
 
     @property
@@ -144,15 +161,31 @@ class TransformerConfig:
     def attn_sublayers(self) -> int:
         """Attention sub-layers, each with a cache row of its own: the
         layers of the KV pool."""
+        if self.layer_operators is not None:
+            return self.layer_operators.count("attention")
         return _LATENT_SUBLAYERS.get(self.block, 1) * self.n_layers
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose operator is the short convolution: the by-slot
+        states a step program carries."""
+        return (0 if self.layer_operators is None
+                else self.layer_operators.count("conv"))
+
+    def operator_index(self, layer_idx: int) -> Tuple[str, int]:
+        """Layer ``layer_idx``'s operator and its place among the layers
+        of that operator: an attention layer's pool layer, a convolution's
+        state."""
+        if self.layer_operators is None:
+            return "attention", layer_idx
+        name = self.layer_operators[layer_idx]
+        return name, self.layer_operators[:layer_idx].count(name)
 
     @property
     def expert_layers(self) -> int:
         """Routed expert layers a forward pass runs (ops/moe.py
         routed_experts_apply; a ``moe_every`` mixture is not one)."""
-        if self.block == "gqa_moe":
-            return self.n_layers
-        if not self.latent:
+        if self.block != "gqa_moe" and not self.latent:
             return 0
         return self.n_layers - self.first_dense_layers
 
@@ -235,12 +268,18 @@ def _check_gqa_moe(config: TransformerConfig) -> None:
         raise ValueError(
             f"n_heads ({config.n_heads}) must be a multiple of n_kv_heads "
             f"({config.kv_heads})")
-    if config.n_zero_experts or config.n_shared_experts \
-            or config.first_dense_layers or config.router_choice_bias:
+    if config.n_zero_experts or config.n_shared_experts:
         raise ValueError(
-            "block 'gqa_moe' has the routed experts alone as every "
-            "layer's feed-forward: no zero-compute or shared expert, no "
-            "leading dense layer, no choice bias (the latent blocks')")
+            "block 'gqa_moe' has the routed experts alone as a layer's "
+            "feed-forward: no zero-compute or shared expert (the latent "
+            "blocks')")
+    if not 0 <= config.first_dense_layers < config.n_layers \
+            or (config.first_dense_layers and config.d_ff < 1):
+        raise ValueError(
+            f"first_dense_layers must be in [0, {config.n_layers}) (the "
+            f"last layer is an expert layer) with d_ff >= 1 the dense "
+            f"width, got {config.first_dense_layers} and {config.d_ff}")
+    _check_operators(config)
     b, steps = config.diffusion_block, config.diffusion_steps
     if b < 0 or (b == 0 and (steps or config.mask_token)):
         raise ValueError(
@@ -255,6 +294,35 @@ def _check_gqa_moe(config: TransformerConfig) -> None:
         raise ValueError(
             f"mask_token {config.mask_token} is not among the "
             f"{config.vocab_size} ids")
+
+
+OPERATORS = ("attention", "conv")
+
+
+def _check_operators(config: TransformerConfig) -> None:
+    """``layer_operators``, where a model's layers name their operator."""
+    ops, taps = config.layer_operators, config.conv_taps
+    if ops is None:
+        if taps:
+            raise ValueError(
+                f"conv_taps {taps} means nothing without layer_operators")
+        return
+    if len(ops) != config.n_layers or set(ops) - set(OPERATORS):
+        raise ValueError(
+            f"layer_operators names one of {OPERATORS} a layer, "
+            f"{config.n_layers} in all, got {ops!r}")
+    if "attention" not in ops:
+        raise ValueError(
+            "layer_operators names no 'attention' layer: the paged pool "
+            "would hold nothing")
+    if ("conv" in ops) != (taps >= 2):
+        raise ValueError(
+            f"conv_taps must be >= 2 where a layer's operator is 'conv' "
+            f"and 0 where none is, got {taps}")
+    if config.diffusion_block:
+        raise ValueError(
+            "layer_operators is served under the causal mask only: a "
+            "convolution's state has no block to see both ways")
 
 
 def _check_retention(config: TransformerConfig) -> None:
@@ -286,11 +354,19 @@ def _check_block(config: TransformerConfig) -> None:
             f"diffusion_block, diffusion_steps and mask_token are block "
             f"'gqa_moe''s; block {config.block!r} takes none of them")
     if config.block == "retention":
+        if config.layer_operators is not None or config.conv_taps:
+            raise ValueError(
+                "layer_operators and conv_taps are block 'gqa_moe''s; "
+                "block 'retention' takes neither")
         return _check_retention(config)
     if config.head_width is not None:
         raise ValueError(
             f"head_width is block 'gqa_moe''s and 'retention''s; block "
             f"{config.block!r} does not take it")
+    if config.layer_operators is not None or config.conv_taps:
+        raise ValueError(
+            f"layer_operators and conv_taps are block 'gqa_moe''s; block "
+            f"{config.block!r} takes neither")
     if not config.latent:
         if config.rope_theta != 10000.0 or config.norm_eps != 1e-6:
             raise ValueError(
@@ -380,26 +456,49 @@ def _latent_layer_init(keys, config: TransformerConfig, dense,
 
 def _gqa_moe_layer_init(keys, config: TransformerConfig, dense,
                         layer_idx: int) -> Dict:
-    """One 'gqa_moe' layer: the dense block's attention at the explicit
-    head width with its two per-head norms, two norms, and the routed
-    experts, all held."""
+    """One 'gqa_moe' layer: its operator — the dense block's attention
+    at the explicit head width with its two per-head norms or, where the
+    layer names it, the short convolution (``w_in`` [d, 3 d] = [B | C |
+    u], ``filter`` [taps, d], ``w_out`` [d, d]: ops/short_conv.py) — two
+    norms, and its feed-forward: the routed experts, all held, or a dense
+    gated FFN in the first ``first_dense_layers`` layers."""
     d, h, h_kv, hd = (config.d_model, config.n_heads, config.kv_heads,
                       config.head_dim)
     e, fe = config.held_experts, config.expert_d_ff
-    attn = {"wq": dense(next(keys), (d, h, hd), d),
-            "wk": dense(next(keys), (d, h_kv, hd), d),
-            "wv": dense(next(keys), (d, h_kv, hd), d),
-            "wo": dense(next(keys), (h, hd, d), h * hd),
-            "q_norm": {"scale": jnp.ones((hd,))},
-            "k_norm": {"scale": jnp.ones((hd,))}}
-    return {"attn": attn,
-            "norm1": {"scale": jnp.ones((d,))},
-            "norm2": {"scale": jnp.ones((d,))},
-            "moe": {"router": dense(next(keys),
+    layer = {"norm1": {"scale": jnp.ones((d,))},
+             "norm2": {"scale": jnp.ones((d,))}}
+    if config.operator_index(layer_idx)[0] == "conv":
+        taps = config.conv_taps
+        layer["conv"] = {"w_in": dense(next(keys), (d, 3 * d), d),
+                         "filter": dense(next(keys), (taps, d), taps),
+                         "w_out": dense(next(keys), (d, d), d)}
+    else:
+        # where the layers name their operator the three input projections
+        # are held as MATRICES [d, heads x hd], as the 'retention' block's:
+        # a [d, 32, 64] array's own layout pads each head's 64 values to a
+        # whole 128-lane register
+        heads = ((lambda n: (d, n * hd)) if config.layer_operators
+                 else (lambda n: (d, n, hd)))
+        layer["attn"] = {"wq": dense(next(keys), heads(h), d),
+                         "wk": dense(next(keys), heads(h_kv), d),
+                         "wv": dense(next(keys), heads(h_kv), d),
+                         "wo": dense(next(keys), (h, hd, d), h * hd),
+                         "q_norm": {"scale": jnp.ones((hd,))},
+                         "k_norm": {"scale": jnp.ones((hd,))}}
+    if layer_idx < config.first_dense_layers:
+        f = config.d_ff
+        layer["ffn"] = {"w_gate": dense(next(keys), (d, f), d),
+                        "w_up": dense(next(keys), (d, f), d),
+                        "w_down": dense(next(keys), (f, d), f)}
+        return layer
+    layer["moe"] = {"router": dense(next(keys),
                                     (d, config.n_routed_experts), d),
                     "w_gate": dense(next(keys), (e, d, fe), d),
                     "w_up": dense(next(keys), (e, d, fe), d),
-                    "w_down": dense(next(keys), (e, fe, d), fe)}}
+                    "w_down": dense(next(keys), (e, fe, d), fe)}
+    if config.router_choice_bias:
+        layer["moe"]["bias"] = jnp.zeros((config.n_routed_experts,))
+    return layer
 
 
 # the log gate a fresh 'retention' model starts from: a row keeps
@@ -755,6 +854,7 @@ def routed_experts(moe, config: TransformerConfig, y, live=None):
         first_held=config.first_expert_held,
         scoring=config.router_scoring,
         renormalise=config.router_renormalise,
+        renormalise_eps=config.router_renormalise_eps,
         live=None if live is None else live.reshape(b * c),
         kernel_mode=_kernel_mode())
     return out.reshape(b, c, d), counts
@@ -870,7 +970,11 @@ def attend_reach(config: TransformerConfig, positions):
 def gqa_qkv(attn, y, positions, config: TransformerConfig):
     """A 'gqa_moe' layer's projections of ``y`` [B, C, d] at ``positions``
     [B, C]: ``q`` [B, H, C, hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k
-    normed a head and then rotated (split halves)."""
+    normed a head and then rotated (split halves).  Weights held as
+    matrices ``[d, heads x hd]`` (a model whose layers name their operator)
+    go through :func:`retention_qkv`, the same numbers."""
+    if attn["wq"].ndim == 2:
+        return retention_qkv(attn, y, positions, config)
     dtype, eps = config.dtype, config.norm_eps
     q = jnp.einsum("bsd,dhk->bhsk", y, attn["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bhsk", y, attn["wk"].astype(dtype))
@@ -883,33 +987,50 @@ def gqa_qkv(attn, y, positions, config: TransformerConfig):
 
 
 def gqa_moe_layers(params, x, config: TransformerConfig, attend_row,
-                   live=None):
-    """Every layer of a 'gqa_moe' block over ``x`` [B, C, d]:
-    ``attend_row(layer, attn_weights, y)`` is the attention's context of
-    the normed input ``y``, [B, H, C, hd] before the output projection —
-    where the callers differ, as in :func:`latent_layers`.  Returns (x,
-    routing counts int32[6] summed over the layers: ops/moe.py)."""
+                   live=None, conv_row=None):
+    """Every layer of a 'gqa_moe' block over ``x`` [B, C, d], each by the
+    operator it names.  ``attend_row(row, attn_weights, y)`` is the
+    attention's context of the normed input ``y``, [B, H, C, hd] before the
+    output projection, by the layer whose cache row is pool layer ``row``;
+    ``conv_row(state, conv_weights, y)`` the short convolution's output
+    [B, C, d] by the layer whose state is the ``state``-th — where the
+    callers differ, as in :func:`latent_layers`: the unpaged forward attends
+    its own rows and convolves from zeros, a cached step writes the row and
+    attends the lane's view, reads the lane's state and leaves the new one.
+    Returns (x, routing counts int32[6] summed over the expert layers:
+    ops/moe.py)."""
     from ..ops.moe import ROUTING_COUNTS
 
     dtype, eps = config.dtype, config.norm_eps
     counts = jnp.zeros((len(ROUTING_COUNTS),), jnp.int32)
     for i, layer in enumerate(params["layers"]):
+        operator, index = config.operator_index(i)
         y = _rms_norm(x, layer["norm1"]["scale"], eps)
-        o = attend_row(i, layer["attn"], y).astype(dtype)
-        with jax.named_scope("attention"):
-            x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                               layer["attn"]["wo"].astype(dtype))
+        if operator == "conv":
+            x = x + conv_row(index, layer["conv"], y)
+        else:
+            o = attend_row(index, layer["attn"], y).astype(dtype)
+            with jax.named_scope("attention"):
+                x = x + jnp.einsum("bhsk,hkd->bsd", o,
+                                   layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"], eps)
-        out, layer_counts = routed_experts(layer["moe"], config, y, live)
-        x = x + out
-        counts = counts + layer_counts
+        if "moe" in layer:
+            out, layer_counts = routed_experts(layer["moe"], config, y, live)
+            x = x + out
+            counts = counts + layer_counts
+        else:
+            with jax.named_scope("dense_ffn"):
+                x = x + gated_ffn(layer["ffn"], y, dtype)
     return x, counts
 
 
 def _gqa_moe_forward(params, tokens, config: TransformerConfig,
                      apply_head: bool = True):
     """The unpaged forward of a 'gqa_moe' block: every layer attends its
-    own rows, each as far as :func:`attend_reach` says."""
+    own rows, each as far as :func:`attend_reach` says (a layer that names
+    the short convolution convolves from zeros)."""
+    from ..ops.short_conv import short_conv
+
     dtype = config.dtype
     b, seq = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
@@ -926,8 +1047,12 @@ def _gqa_moe_forward(params, tokens, config: TransformerConfig,
         probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
         return jnp.einsum("bhgqk,bhkd->bhgqd", probs, v).reshape(q.shape)
 
+    def conv(_, weights, y):
+        zeros = jnp.zeros((b, config.conv_taps - 1, config.d_model), dtype)
+        return short_conv(weights, y, zeros, dtype)[0]
+
     x = params["embed"][tokens].astype(dtype)
-    x, _ = gqa_moe_layers(params, x, config, attend)
+    x, _ = gqa_moe_layers(params, x, config, attend, conv_row=conv)
     x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
     if not apply_head:
         return x, jnp.float32(0.0)

@@ -485,7 +485,8 @@ def grouped_experts(x, slot_token, slot_gate, tile_expert, tile_rows, n_tiles,
 
 def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
                    top_k: int, scale: float, scoring: str = "softmax",
-                   renormalise: bool = False
+                   renormalise: bool = False,
+                   renormalise_eps: float = 1e-20
                    ) -> Tuple[jax.Array, jax.Array]:
     """The router's law: a row's float32 ``logits`` [n, outputs] ->
     (``gate`` [n, top_k] float32, ``index`` [n, top_k]).
@@ -494,8 +495,9 @@ def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
     "softmax") or each output's sigmoid ("sigmoid").  It chooses the
     ``top_k`` largest of ``scores + bias``: the bias (None: none) moves
     the CHOICE only and never the weight, which is the chosen expert's
-    own score — renormalised over the chosen ones (``renormalise``) or
-    left as it is — times ``scale``."""
+    own score — renormalised over the chosen ones (``renormalise``: each
+    over their sum + ``renormalise_eps``) or left as it is — times
+    ``scale``."""
     if scoring == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
@@ -508,7 +510,8 @@ def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
         _, index = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         gate = jnp.take_along_axis(scores, index, axis=-1)
     if renormalise:
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
+                       + renormalise_eps)
     return gate * scale, index
 
 
@@ -523,6 +526,7 @@ def routed_experts_apply(
     first_held: int = 0,
     scoring: str = "softmax",
     renormalise: bool = False,
+    renormalise_eps: float = 1e-20,
     live: Optional[jax.Array] = None,
     kernel_mode: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -575,7 +579,8 @@ def routed_experts_apply(
                          preferred_element_type=jnp.float32)
         gate, index = router_choices(
             logits, moe.get("bias"), top_k=top_k, scale=scale,
-            scoring=scoring, renormalise=renormalise)  # [n, k]
+            scoring=scoring, renormalise=renormalise,
+            renormalise_eps=renormalise_eps)  # [n, k]
     chose = jnp.ones((n, 1), bool) if live is None else live[:, None]
     local = index - first_held
     held = chose & (local >= 0) & (local < e_held) & (index < n_routed)

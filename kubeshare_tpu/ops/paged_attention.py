@@ -236,11 +236,12 @@ def _walk_pages(tables_ref, positions_ref, next_ref, sems, copies, *, lanes,
 
 
 def _kernel(layer_ref, tables_ref, positions_ref, q_ref, k_hbm, v_hbm,
-            o_ref, k_buf, v_buf, sems, next_ref, *, window, pages):
+            o_ref, k_buf, v_buf, sems, next_ref, *, window, pages,
+            scale=None):
     lanes, h_kv, group, d = q_ref.shape
     rows = pages * k_buf.shape[3]
     layer = layer_ref[0]
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     f32 = jnp.float32
 
     # an idle lane's output is read
@@ -307,11 +308,13 @@ def _paged_call(kernel, name: str, scalars, arrays, pools, out_shape,
     )(*scalars, *arrays, *pools)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"),
+@functools.partial(jax.jit,
+                   static_argnames=("window", "interpret", "scale"),
                    inline=True)
 def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
                            window: Optional[int] = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           scale: Optional[float] = None):
     """Attention of the query rows of a lane that share ONE reach — ``q``
     [lanes, h, d], one row a lane (the decode step), or [lanes, h, C, d],
     C rows that see the same keys (a diffusion pass's aligned block) —
@@ -322,7 +325,10 @@ def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
     head are one query group of the kernel, ``[lanes, h_kv, (h / h_kv) x
     C, d]`` (32 rows a KV head at ``sdar-30b-a3b-chat``: two whole bf16
     tiles), padded to whole tiles here and cut off after.  A lane whose
-    table starts at the scratch block is idle and reads zeros.
+    table starts at the scratch block is idle and reads zeros.  ``scale``
+    is what the scores are multiplied by (None: ``d ** -0.5``; heads
+    narrower than the pool's row, laid into a part of a row of zeros, say
+    their own: ``kv_blocks.KVRowLayout`` ``heads_paired``).
     Jitted to be traced once for all the layers of a step program (the
     layer is an argument) and inlined, as the key-block loop it stands in
     for (``serving/paged._attend_view_blocks``)."""
@@ -339,7 +345,7 @@ def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
     pages = _pages_a_block(bs, tables.shape[1])
     buf = (2, pages, h_kv, bs, d)
     out = _paged_call(
-        functools.partial(_kernel, window=window, pages=pages),
+        functools.partial(_kernel, window=window, pages=pages, scale=scale),
         "paged_decode_attention",
         (jnp.reshape(layer_idx, (1,)).astype(jnp.int32),
          tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32)),

@@ -126,7 +126,8 @@ from ..utils.promtext import (MetricFamily, MetricServer, Sample,
 from .autotune import AnalyticPolicy, AutoTuner
 from .drafter import NGramDrafter
 from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
-                        init_paged_pool, init_retention_states,
+                        init_conv_states, init_paged_pool,
+                        init_retention_states,
                         kv_row_layout)
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
@@ -435,9 +436,16 @@ def _warmed_prefill_widths(ec: EngineConfig, floor: int = 0) -> set:
 
 def _bucket_floor(ec: EngineConfig, config: TransformerConfig) -> int:
     """The narrowest prefill bucket a configuration plans."""
-    if config.block == "retention":
+    if _carries_state(config):
         return min(ec.block_size, ec.prefill_chunk)
     return config.diffusion_block
+
+
+def _carries_state(config: TransformerConfig) -> bool:
+    """The model's lanes hold a state BY SLOT beside the paged pool, which
+    every step program carries (``paged.Recurrent``): a 'retention' block's
+    recurrent states, the short convolutions' windows."""
+    return config.block == "retention" or config.conv_layers > 0
 
 
 def _config_rows(ec: EngineConfig, config: TransformerConfig,
@@ -491,17 +499,20 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
         ("eos_token (a block's rows are served out of order: nothing "
          "truncates a stream at a token yet)", ec.eos_token is not None),
     ) if asked]
-    # what cannot roll a recurrent state back, ship it or shard it
+    # what cannot roll a state by slot back, ship it or shard it: the one
+    # list of a 'retention' block's recurrent states and of the short
+    # convolutions' windows
     retention = config.block == "retention"
+    stateful = _carries_state(config)
     no_state = [name for name, asked in (
         ("speculative=True (the draft-verify programs would have to roll "
-         "a folded state back)", ec.speculative),
+         "a state back past the rejected rows)", ec.speculative),
         ("steps_per_launch > 1 (the device-resident loops carry no "
          "state and plan no fold)", ec.steps_per_launch > 1),
         ("mesh_spec (serving/sharded.py has no twin of the state's "
          "programs)", ec.mesh_spec is not None),
-        ("host_tier_bytes (serving/kv_tier.py packs K/V pages; a folded "
-         "lane's pages are gone and no tier holds a state)",
+        ("host_tier_bytes (serving/kv_tier.py packs K/V pages; no tier "
+         "holds a state, and a folded lane's pages are gone)",
          ec.host_tier_bytes is not None),
         ("a shared host tier (serving/kv_tier.py, serving/fabric.py)",
          shared_host_tier is not None),
@@ -512,10 +523,12 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
     ) if asked]
     key_block = paged.KEY_BLOCK
     return [
-        (retention and bool(no_state),
-         f"block 'retention' serves a recurrent state a lane beside a "
-         f"paged tail of unfolded rows, which is not served yet by: "
-         f"{'; '.join(no_state)}"),
+        (stateful and bool(no_state),
+         f"block {config.block!r} serves a state a lane BY SLOT beside its "
+         f"paged rows ("
+         + ("a recurrent state beside a tail of unfolded rows"
+            if retention else "the short convolutions' windows")
+         + f"), which is not served yet by: {'; '.join(no_state)}"),
         (retention and (key_block % ec.block_size != 0
                         or ec.prefill_chunk > key_block
                         or ec.decode_span > key_block),
@@ -910,14 +923,21 @@ class ServingEngine:
                          if self._sharded is not None else None))
         # a 'retention' block: the lanes' recurrent states, BY SLOT and
         # beside the pool (kv_blocks.init_retention_states); the pool
-        # holds a lane's unfolded rows only.  No snapshot of a state
-        # exists at a page boundary, so a prefix match could give nothing:
-        # such an engine keeps no prefix index
+        # holds a lane's unfolded rows only.  A model whose layers name
+        # the short convolution: its windows, by slot likewise
+        # (kv_blocks.init_conv_states), beside the attention layers'
+        # rows.  No snapshot of a state exists at a page boundary, so a
+        # prefix match could give nothing (it would need the state AT the
+        # matched row): such an engine keeps no prefix index
         self._retention = config.block == "retention"
+        self._conv = config.conv_layers > 0
+        self._stateful = _carries_state(config)
         self.states = (init_retention_states(config, ec.num_slots)
-                       if self._retention else None)
+                       if self._retention else
+                       init_conv_states(config, ec.num_slots)
+                       if self._conv else None)
         self.prefix_index = (PrefixIndex(ec.block_size)
-                             if ec.prefix_cache and not self._retention
+                             if ec.prefix_cache and not self._stateful
                              else None)
         # the tenant registry must exist before the tier policy (the
         # QoS-aware policy reads class membership from it)
@@ -985,8 +1005,11 @@ class ServingEngine:
                 max(self._warmed_widths | {ec.decode_span}), ec.block_size)
             if self._retention else 0)
         # beside the in-flight dispatch, what a 'retention' dispatch
-        # carried (:meth:`_observe_retention` reads it in .consume)
+        # carried (:meth:`_observe_retention` reads it in .consume), and
+        # what one of a model with short convolutions did
+        # (:meth:`_observe_conv`)
         self._retention_inflight: Optional[Dict] = None
+        self._conv_inflight: Optional[Dict] = None
         # generation by diffusion over blocks: the block length B (0: one
         # token after another) and the rows a block commits at each of
         # its denoising passes
@@ -1136,6 +1159,11 @@ class ServingEngine:
         self.retention_tail_rows = 0
         self.retention_folds = 0
         self.retention_pages_freed = 0
+        # the short convolutions' dispatches: lane-passes x convolution
+        # layers that read a slot's state, and the chunks that began at
+        # row 0 (they read zeros, whatever the slot held)
+        self.conv_state_reads = 0
+        self.conv_state_resets = 0
         # how far the step programs' attention had to go: summed over
         # planned dispatches, the furthest lane's rows rounded up to key
         # blocks (what the key-block loop runs over), the view's whole
@@ -1251,22 +1279,24 @@ class ServingEngine:
                     w, cfg, pk, pv, tables, starts, active, tokens,
                     last_rows, routing=routed)
 
-        if self._retention:
-            # the same programs with the gate array and the states as one
-            # more donated argument, the lanes' fold points and the
-            # chunk's slot after it; the Recurrent comes back last
+        if self._stateful:
+            # the same programs with the states by slot (and a
+            # 'retention' block's gate array) as one more donated
+            # argument, the lanes' fold points and the chunk's slot after
+            # it; the Recurrent comes back last, after a routed block's
+            # counts
             def prefill(w, pk, pv, tables, starts, active, tokens,
                         last_rows, temps, keys, recurrent, folded, slots):
-                logits, pk, pv, recurrent = paged_prefill_step(
+                logits, pk, pv, *rest = paged_prefill_step(
                     w, cfg, pk, pv, tables, starts, active, tokens,
-                    last_rows, recurrent=recurrent, folded=folded,
-                    slots=slots)
-                return pick_rows(logits, temps, keys), pk, pv, recurrent
+                    last_rows, routing=routed, recurrent=recurrent,
+                    folded=folded, slots=slots)
+                return (pick_rows(logits, temps, keys), pk, pv, *rest)
 
         # the pool buffers are DONATED: each step updates the cache in
         # place device-side instead of materializing a second pool (on a
         # fractional-HBM pod a transient 2x cache would blow the cap)
-        donated = (1, 2, 10) if self._retention else (1, 2)
+        donated = (1, 2, 10) if self._stateful else (1, 2)
         self._prefill_step = _step_program("prefill", prefill, donated)
 
         def diffusion(w, pk, pv, tables, lengths, active, tokens, masked,
@@ -1306,12 +1336,12 @@ class ServingEngine:
 
         if sharded is not None:
             decode = sharded.decode_span(pick_rows, span, eos)
-        if self._retention:
+        if self._stateful:
             def decode(w, pk, pv, tables, lengths, active, tokens, temps,
                        keys, budgets, recurrent, folded):
                 return paged_decode_span(
                     w, cfg, pick_rows, span, eos, pk, pv, tables, lengths,
-                    active, tokens, temps, keys, budgets,
+                    active, tokens, temps, keys, budgets, routing=routed,
                     recurrent=recurrent, folded=folded)
 
         self._decode_step = _step_program("decode", decode, donated)
@@ -1398,7 +1428,7 @@ class ServingEngine:
 
         if sharded is not None:
             mixed = sharded.mixed_step(pick_rows, span, eos)
-        if self._retention:
+        if self._stateful:
             def mixed(w, pk, pv, p_table, p_start, p_tokens, p_last_row,
                       p_temp, p_key, d_tables, d_lengths, d_active,
                       d_tokens, d_temps, d_keys, d_budgets, recurrent,
@@ -1407,11 +1437,11 @@ class ServingEngine:
                     w, cfg, pick_rows, span, eos, pk, pv, p_table, p_start,
                     p_tokens, p_last_row, p_temp, p_key, d_tables,
                     d_lengths, d_active, d_tokens, d_temps, d_keys,
-                    d_budgets, recurrent=recurrent, p_folded=p_folded,
-                    p_slot=p_slot, d_folded=d_folded)
+                    d_budgets, routing=routed, recurrent=recurrent,
+                    p_folded=p_folded, p_slot=p_slot, d_folded=d_folded)
 
         self._mixed_step = _step_program(
-            "mixed", mixed, (1, 2, 16) if self._retention else (1, 2))
+            "mixed", mixed, (1, 2, 16) if self._stateful else (1, 2))
 
         def verify(w, pk, pv, tables, lengths, active, tokens, widths,
                    temps, keys):
@@ -1514,10 +1544,11 @@ class ServingEngine:
         generated block) and needs no logits of them: a prompt shorter
         than a block, or one the prefix cache covers, plans nothing."""
         ec, b = self.engine_config, self._diffusion
-        if self._retention:
+        if self._stateful:
             # chunks from row 0 on, none sliding back over rows a fold may
-            # have taken: the last is padded FORWARD to its bucket, and
-            # the padding's rows are written nowhere (paged._prefill_rows)
+            # have taken (or a convolution's state has moved past): the
+            # last is padded FORWARD to its bucket, and the padding's rows
+            # are written nowhere (paged._prefill_rows)
             chunk, floor = ec.prefill_chunk, min(self._warmed_widths)
             plan = [(s, chunk, chunk - 1)
                     for s in range(0, prompt_len - chunk + 1, chunk)]
@@ -1984,27 +2015,27 @@ class ServingEngine:
             return self._warmup_diffusion()
         widths = self._warmed_widths
         s = ec.num_slots
-        one = jnp.zeros((1,), jnp.int32)
-        zeros_s = jnp.zeros((s,), jnp.int32)
+        one = np.zeros((1,), np.int32)
+        zeros_s = np.zeros((s,), np.int32)
         # a 'retention' engine's last arguments (:meth:`_recurrent_args`):
         # an all-inactive call folds nothing and leaves every state alone
         def recurrent():
             return ((Recurrent(self.pool.gate, self.states),)
-                    if self._retention else ())
+                    if self._stateful else ())
 
-        p_fold = (one, one) if self._retention else ()
-        d_fold = (zeros_s,) if self._retention else ()
+        p_fold = (one, one) if self._stateful else ()
+        d_fold = (zeros_s,) if self._stateful else ()
         for width in sorted(widths):
             # the pool rides through every warmup call (its buffers are
             # donated); the only writes land in the scratch block
             _, pk, pv, *rest = self._warm(
                 "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v,
-                jnp.zeros((1, self._table_width), jnp.int32),
-                one, jnp.zeros((1,), bool),
-                jnp.zeros((1, width), jnp.int32), one,
-                jnp.zeros((1,), jnp.float32),
-                jnp.zeros((1, 2), jnp.uint32), *recurrent(), *p_fold)
+                np.zeros((1, self._table_width), np.int32),
+                one, np.zeros((1,), bool),
+                np.zeros((1, width), np.int32), one,
+                np.zeros((1,), np.float32),
+                np.zeros((1, 2), np.uint32), *recurrent(), *p_fold)
             self._keep_cache(pk, pv, rest)
             # mixed shapes only for widths that can actually ride
             # fused: step() routes any chunk wider than the budget to
@@ -2016,14 +2047,14 @@ class ServingEngine:
                 _, _, pk, pv, *rest = self._warm(
                     "mixed", (width,), self._mixed_step,
                     self.params, self.pool.k, self.pool.v,
-                    jnp.zeros((1, self._table_width), jnp.int32), one,
-                    jnp.zeros((1, width), jnp.int32), one,
-                    jnp.zeros((1,), jnp.float32),
-                    jnp.zeros((1, 2), jnp.uint32),
-                    jnp.zeros((s, self._table_width), jnp.int32),
-                    zeros_s, jnp.zeros((s,), bool), zeros_s,
-                    jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s, ec.decode_span, 2), jnp.uint32),
+                    np.zeros((1, self._table_width), np.int32), one,
+                    np.zeros((1, width), np.int32), one,
+                    np.zeros((1,), np.float32),
+                    np.zeros((1, 2), np.uint32),
+                    np.zeros((s, self._table_width), np.int32),
+                    zeros_s, np.zeros((s,), bool), zeros_s,
+                    np.zeros((s,), np.float32),
+                    np.zeros((s, ec.decode_span, 2), np.uint32),
                     zeros_s, *recurrent(), *p_fold, *d_fold)
                 self._keep_cache(pk, pv, rest)
                 if ec.speculative:
@@ -2034,25 +2065,25 @@ class ServingEngine:
                             "mixed_verify", (width, 1 + k),
                             self._mixed_verify_step,
                             self.params, self.pool.k, self.pool.v,
-                            jnp.zeros((1, self._table_width), jnp.int32),
-                            one, jnp.zeros((1, width), jnp.int32), one,
-                            jnp.zeros((1,), jnp.float32),
-                            jnp.zeros((1, 2), jnp.uint32),
-                            jnp.zeros((s, self._table_width), jnp.int32),
-                            zeros_s, jnp.zeros((s,), bool),
-                            jnp.full((s, 1 + k), -1, jnp.int32),
-                            jnp.ones((s,), jnp.int32),
-                            jnp.zeros((s,), jnp.float32),
-                            jnp.zeros((s, 1 + k, 2), jnp.uint32))
+                            np.zeros((1, self._table_width), np.int32),
+                            one, np.zeros((1, width), np.int32), one,
+                            np.zeros((1,), np.float32),
+                            np.zeros((1, 2), np.uint32),
+                            np.zeros((s, self._table_width), np.int32),
+                            zeros_s, np.zeros((s,), bool),
+                            np.full((s, 1 + k), -1, np.int32),
+                            np.ones((s,), np.int32),
+                            np.zeros((s,), np.float32),
+                            np.zeros((s, 1 + k, 2), np.uint32))
                         self.pool = replace(self.pool, k=pk, v=pv)
         if ec.pool_role != "prefill":
             _, pk, pv, *rest = self._warm(
                 "decode", (), self._decode_step,
                 self.params, self.pool.k, self.pool.v,
-                jnp.zeros((s, self._table_width), jnp.int32),
-                zeros_s, jnp.zeros((s,), bool), zeros_s,
-                jnp.zeros((s,), jnp.float32),
-                jnp.zeros((s, ec.decode_span, 2), jnp.uint32), zeros_s,
+                np.zeros((s, self._table_width), np.int32),
+                zeros_s, np.zeros((s,), bool), zeros_s,
+                np.zeros((s,), np.float32),
+                np.zeros((s, ec.decode_span, 2), np.uint32), zeros_s,
                 *recurrent(), *d_fold)
             self._keep_cache(pk, pv, rest)
         for k_depth, loop_step in sorted(self._loop_steps.items()):
@@ -2064,11 +2095,10 @@ class ServingEngine:
             _, _, pk, pv = self._warm(
                 "loop", (k_depth,), loop_step,
                 self.params, self.pool.k, self.pool.v,
-                jnp.zeros((s, self._table_width), jnp.int32),
-                zeros_s, jnp.zeros((s,), bool), zeros_s,
-                jnp.zeros((s,), jnp.float32),
-                jnp.zeros((s, k_depth * ec.decode_span, 2),
-                          jnp.uint32),
+                np.zeros((s, self._table_width), np.int32),
+                zeros_s, np.zeros((s,), bool), zeros_s,
+                np.zeros((s,), np.float32),
+                np.zeros((s, k_depth * ec.decode_span, 2), np.uint32),
                 zeros_s)
             self.pool = replace(self.pool, k=pk, v=pv)
         for k_depth, spec_step in sorted(self._spec_loops.items()):
@@ -2082,22 +2112,22 @@ class ServingEngine:
             _, _, _, _, _, pk, pv = self._warm(
                 "spec_loop", (k_depth,), spec_step,
                 self.params, self.pool.k, self.pool.v,
-                jnp.zeros((s, self._table_width), jnp.int32),
-                zeros_s, jnp.zeros((s,), bool), zeros_s,
-                jnp.zeros((s,), jnp.float32),
-                jnp.zeros((s, k_depth * w, 2), jnp.uint32),
-                zeros_s, jnp.zeros((s, SPEC_LOOP_HIST), jnp.int32),
+                np.zeros((s, self._table_width), np.int32),
+                zeros_s, np.zeros((s,), bool), zeros_s,
+                np.zeros((s,), np.float32),
+                np.zeros((s, k_depth * w, 2), np.uint32),
+                zeros_s, np.zeros((s, SPEC_LOOP_HIST), np.int32),
                 zeros_s, zeros_s,
-                jnp.zeros((r, self._table_width), jnp.int32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.zeros((r,), jnp.float32),
-                jnp.zeros((r, k_depth * w, 2), jnp.uint32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.zeros((r, SPEC_LOOP_HIST), jnp.int32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.zeros((), jnp.int32))
+                np.zeros((r, self._table_width), np.int32),
+                np.zeros((r,), np.int32),
+                np.zeros((r,), np.int32),
+                np.zeros((r,), np.float32),
+                np.zeros((r, k_depth * w, 2), np.uint32),
+                np.zeros((r,), np.int32),
+                np.zeros((r, SPEC_LOOP_HIST), np.int32),
+                np.zeros((r,), np.int32),
+                np.zeros((r,), np.int32),
+                np.zeros((), np.int32))
             self.pool = replace(self.pool, k=pk, v=pv)
         if ec.speculative and ec.pool_role != "prefill":
             # verify widths are 1 + pow2(max draft) with the adaptive
@@ -2107,12 +2137,12 @@ class ServingEngine:
                 _, _, pk, pv = self._warm(
                     "verify", (1 + k,), self._verify_step,
                     self.params, self.pool.k, self.pool.v,
-                    jnp.zeros((s, self._table_width), jnp.int32),
-                    zeros_s, jnp.zeros((s,), bool),
-                    jnp.full((s, 1 + k), -1, jnp.int32),
-                    jnp.ones((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s, 1 + k, 2), jnp.uint32))
+                    np.zeros((s, self._table_width), np.int32),
+                    zeros_s, np.zeros((s,), bool),
+                    np.full((s, 1 + k), -1, np.int32),
+                    np.ones((s,), np.int32),
+                    np.zeros((s,), np.float32),
+                    np.zeros((s, 1 + k, 2), np.uint32))
                 self.pool = replace(self.pool, k=pk, v=pv)
         if self.prefix_index is not None and ec.pool_role != "decode":
             # the CoW copy's one shape; scratch -> scratch is a no-op
@@ -2153,18 +2183,18 @@ class ServingEngine:
         write lands in the scratch block."""
         ec = self.engine_config
         s, b = ec.num_slots, self._diffusion
-        one = jnp.zeros((1,), jnp.int32)
-        table = jnp.zeros((1, self._table_width), jnp.int32)
-        lanes = (jnp.zeros((s, self._table_width), jnp.int32),
-                 jnp.zeros((s,), jnp.int32), jnp.zeros((s,), bool),
-                 jnp.zeros((s, b), jnp.int32), jnp.zeros((s, b), bool),
-                 jnp.zeros((s, b), bool), jnp.zeros((s,), jnp.int32))
+        one = np.zeros((1,), np.int32)
+        table = np.zeros((1, self._table_width), np.int32)
+        lanes = (np.zeros((s, self._table_width), np.int32),
+                 np.zeros((s,), np.int32), np.zeros((s,), bool),
+                 np.zeros((s, b), np.int32), np.zeros((s, b), bool),
+                 np.zeros((s, b), bool), np.zeros((s,), np.int32))
         for width in sorted(self._warmed_widths):
-            tokens = jnp.zeros((1, width), jnp.int32)
+            tokens = np.zeros((1, width), np.int32)
             pk, pv, *_ = self._warm(
                 "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v, table, one,
-                jnp.zeros((1,), bool), tokens, one)
+                np.zeros((1,), bool), tokens, one)
             self.pool = replace(self.pool, k=pk, v=pv)
             if ec.mixed and width <= self._mixed_budget:
                 _, _, pk, pv, *_ = self._warm(
@@ -2595,6 +2625,20 @@ class ServingEngine:
             family = MetricFamily(
                 f"kubeshare_serving_retention_{name}_total", said,
                 "counter")
+            family.add(dict(plabel), value)
+            retention.append(family)
+        for name, value, said in (
+                ("state_reads", self.conv_state_reads,
+                 "Lane-passes x convolution layers of a model with short "
+                 "convolutions that read a slot's state (a decode lane "
+                 "once a step of a span, a prefill chunk's lane once; a "
+                 "chunk that begins at row 0 reads zeros instead)."),
+                ("state_resets", self.conv_state_resets,
+                 "Prefill chunks that began at row 0: their lanes' "
+                 "states start from zeros whatever the slot held (a new "
+                 "request in a reused slot, a preempted one resumed).")):
+            family = MetricFamily(
+                f"kubeshare_serving_conv_{name}_total", said, "counter")
             family.add(dict(plabel), value)
             retention.append(family)
         diff_passes = MetricFamily(
@@ -3528,8 +3572,10 @@ class ServingEngine:
         else:
             query_rows = plan.chunk[1]
         config = self.model_config
+        # the width of a head AS THE POOL HOLDS IT: narrower heads lie
+        # paired in a row (kv_blocks.KVRowLayout heads_paired)
         return attend_path(config.block, query_rows, self._table_width,
-                           self.pool.k, self.pool.v, config.head_dim,
+                           self.pool.k, self.pool.v, self.pool.k.shape[-1],
                            config.diffusion_block)
 
     def _report_slow_dispatch(self, entered: float, start: float,
@@ -3616,41 +3662,42 @@ class ServingEngine:
         # of it, not a view the backend may still be reading
         table = slot.table[None].copy() if self._retention \
             else slot.table[None]
-        return (final,
-                jnp.asarray(table),
-                jnp.asarray([start], np.int32),
-                jnp.asarray(segment[None]),
-                jnp.asarray([last_row], np.int32),
+        return (final, table,
+                np.asarray([start], np.int32),
+                np.asarray(segment[None], np.int32),
+                np.asarray([last_row], np.int32),
                 # the pick is consumed only on the prompt's final chunk
-                jnp.asarray([slot.temperature if final else 0.0],
-                            np.float32),
-                jnp.asarray((slot.first_key if final else
-                             np.zeros(2, np.uint32))[None]))
+                np.asarray([slot.temperature if final else 0.0],
+                           np.float32),
+                np.asarray(slot.first_key if final else
+                           np.zeros(2, np.uint32))[None])
 
     def _recurrent_args(self, p_slot: Optional[_Slot],
                         decode_slots: Optional[List[_Slot]]) -> tuple:
-        """A 'retention' engine's last arguments of a dispatch: the gate
-        array and the states (donated), then the chunk's lane's fold
-        point and slot, then the decode lanes' fold points by slot.  An
-        engine of another block passes nothing more."""
-        if not self._retention:
+        """The last arguments of a dispatch of an engine whose lanes hold a
+        state by slot: the states (and a 'retention' block's gate array),
+        donated, then the chunk's lane's fold point and slot, then the
+        decode lanes' fold points by slot (zeros where nothing folds: the
+        short convolutions).  Another engine passes nothing more."""
+        if not self._stateful:
             return ()
         args = [Recurrent(self.pool.gate, self.states)]
         if p_slot is not None:
-            args += [jnp.asarray([p_slot.folded], np.int32),
-                     jnp.asarray([p_slot.idx], np.int32)]
+            args += [np.asarray([p_slot.folded], np.int32),
+                     np.asarray([p_slot.idx], np.int32)]
         if decode_slots is not None:
             folded = np.zeros((self.engine_config.num_slots,), np.int32)
             for slot in decode_slots:
                 folded[slot.idx] = slot.folded
-            args.append(jnp.asarray(folded))
+            args.append(folded)
         return tuple(args)
 
     def _keep_cache(self, pk, pv, rest: list) -> list:
-        """The pool a dispatch returned (and a 'retention' engine's gate
-        array and states, which come last) back onto the engine; returns
-        what else the dispatch returned (a routed block's counts)."""
-        if self._retention:
+        """The pool a dispatch returned (and the states by slot with a
+        'retention' engine's gate array, which come last) back onto the
+        engine; returns what else the dispatch returned (a routed block's
+        counts)."""
+        if self._stateful:
             gate, self.states = rest.pop()  # (gate, a state a layer)
             self.pool = replace(self.pool, k=pk, v=pv, gate=gate)
         else:
@@ -3684,13 +3731,46 @@ class ServingEngine:
             note["prefill"] = (p_slot, p_slot.rid, start + last_row + 1)
         self._retention_inflight = note
 
+    def _note_conv(self, p_slot: Optional[_Slot],
+                   chunk: Optional[Tuple[int, int, int]],
+                   decode_slots: List[_Slot]) -> None:
+        """What a dispatch of a model with short convolutions carries, for
+        :meth:`_observe_conv`: every live lane reads its slot's state once
+        a pass and convolution layer — but a chunk that begins at row 0,
+        which reads zeros (a reset)."""
+        if not self._conv:
+            return
+        span = self.engine_config.decode_span
+        reset = int(chunk is not None and chunk[0] == 0)
+        passes = span if decode_slots else 0
+        self._conv_inflight = {
+            "lanes": len(decode_slots) + (p_slot is not None),
+            "passes": passes, "resets": reset,
+            "chunk": chunk[1] if chunk else 0,
+            "state_reads": self.model_config.conv_layers * (
+                len(decode_slots) * passes
+                + int(p_slot is not None) - reset)}
+
+    def _observe_conv(self) -> None:
+        """One such dispatch into the counters and a
+        ``kubeshare.engine.conv`` span (its attributes are what a trace's
+        reader can reach)."""
+        note, self._conv_inflight = self._conv_inflight, None
+        with profiling.span("kubeshare.engine.conv", **note):
+            self.conv_state_reads += note["state_reads"]
+            self.conv_state_resets += note["resets"]
+
     def _decode_lanes(self, decode_slots: List[_Slot],
                       n_steps: Optional[int] = None):
         """Device arguments for a decode span over the slot pool —
         shared by the standalone, the mixed, and (with ``n_steps`` =
         K*span) the device-loop dispatch.  The key window is sliced
         flat: a K-unit loop consumes exactly the keys K back-to-back
-        span dispatches would, at the same emission indices."""
+        span dispatches would, at the same emission indices.  They are
+        numpy arrays and stay so: the step program's call carries them
+        to the device (as :meth:`warmup`'s does, so a program has one
+        signature); a ``jnp.asarray`` each was most of the host's time
+        a dispatch."""
         ec = self.engine_config
         s = ec.num_slots
         steps = ec.decode_span if n_steps is None else n_steps
@@ -3747,12 +3827,13 @@ class ServingEngine:
             picked = None
             pk, pv, *counts = self._dispatch(
                 self._prefill_step, self.params, self.pool.k, self.pool.v,
-                table, start, jnp.ones((1,), bool), segment, last_row)
+                table, start, np.ones((1,), bool), segment, last_row)
         else:
             self._note_retention(slot, chunk, [])
+            self._note_conv(slot, chunk, [])
             picked, pk, pv, *counts = self._dispatch(
                 self._prefill_step, self.params, self.pool.k, self.pool.v,
-                table, start, jnp.ones((1,), bool), segment, last_row,
+                table, start, np.ones((1,), bool), segment, last_row,
                 temp, key, *self._recurrent_args(slot, None))
         counts = self._keep_cache(pk, pv, counts)
         self._routing_inflight = (counts, segment.shape[1], 1)
@@ -3766,7 +3847,7 @@ class ServingEngine:
         # the fused pick at the final chunk's last-real-row logits IS
         # the first token; read when consumed (one step later), with
         # a routed block's counts
-        if final or counts or self._retention:
+        if final or counts or self._stateful:
             self._inflight = ("diffusion" if self._diffusion else "span",
                               None, (slot, picked) if final else None)
 
@@ -3774,11 +3855,11 @@ class ServingEngine:
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
         self._note_retention(None, None, decode_slots)
+        self._note_conv(None, None, decode_slots)
         emitted, pk, pv, *counts = self._dispatch(
             self._decode_step, self.params, self.pool.k, self.pool.v,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets), *self._recurrent_args(None, decode_slots))
+            tables, lengths, active, tokens, temps, keys, budgets,
+            *self._recurrent_args(None, decode_slots))
         counts = self._keep_cache(pk, pv, counts)
         span = self.engine_config.decode_span
         self._routing_inflight = (counts, len(tokens) * span, span)
@@ -3808,9 +3889,7 @@ class ServingEngine:
         ring, units, pk, pv = self._dispatch(
             self._loop_steps[k_depth], self.params, self.pool.k,
             self.pool.v,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets))
+            tables, lengths, active, tokens, temps, keys, budgets)
         self.pool = replace(self.pool, k=pk, v=pv)
         self.loop_launches += 1
         self._inflight = ("loop", (ring, units, list(decode_slots),
@@ -3939,7 +4018,7 @@ class ServingEngine:
                     self._prefill_lane(staged, chunk)
                 picked, pk, pv = self._dispatch(
                     self._prefill_step, self.params, self.pool.k,
-                    self.pool.v, table, start, jnp.ones((1,), bool),
+                    self.pool.v, table, start, np.ones((1,), bool),
                     segment, last_row, temp, key)
                 self.pool = replace(self.pool, k=pk, v=pv)
                 self.prefill_chunks += 1
@@ -3977,17 +4056,10 @@ class ServingEngine:
         out_p, out_a, out_d, units, head, pk, pv = self._dispatch(
             self._spec_loops[k_depth], self.params, self.pool.k,
             self.pool.v,
-            jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(active), jnp.asarray(tokens),
-            jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets), jnp.asarray(hist),
-            jnp.asarray(hist_len), jnp.asarray(dcaps),
-            jnp.asarray(r_tables), jnp.asarray(r_lengths),
-            jnp.asarray(r_tokens), jnp.asarray(r_temps),
-            jnp.asarray(r_keys), jnp.asarray(r_budgets),
-            jnp.asarray(r_hist), jnp.asarray(r_hist_len),
-            jnp.asarray(r_caps),
-            jnp.asarray(len(staged), jnp.int32))
+            tables, lengths, active, tokens, temps, keys, budgets, hist,
+            hist_len, dcaps, r_tables, r_lengths, r_tokens, r_temps,
+            r_keys, r_budgets, r_hist, r_hist_len, r_caps,
+            np.asarray(len(staged), np.int32))
         self.pool = replace(self.pool, k=pk, v=pv)
         self.spec_loop_launches += 1
         self._inflight = ("spec_loop",
@@ -4004,12 +4076,11 @@ class ServingEngine:
         tables, lengths, active, tokens, temps, keys, budgets = \
             self._decode_lanes(decode_slots)
         self._note_retention(p_slot, chunk, decode_slots)
+        self._note_conv(p_slot, chunk, decode_slots)
         picked, emitted, pk, pv, *counts = self._dispatch(
             self._mixed_step, self.params, self.pool.k, self.pool.v,
             table, start, segment, last_row, temp, key,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            jnp.asarray(budgets),
+            tables, lengths, active, tokens, temps, keys, budgets,
             *self._recurrent_args(p_slot, decode_slots))
         counts = self._keep_cache(pk, pv, counts)
         span = self.engine_config.decode_span
@@ -4060,8 +4131,7 @@ class ServingEngine:
         flavour, one block-causal prefill chunk for the filling slot in
         the same program.  It counts once in ``decode_steps``, and in
         ``prefill_chunks`` and ``mixed_steps`` when it carries a chunk."""
-        lanes = [jnp.asarray(a) for a in
-                 self._diffusion_lanes(plan.decode_slots)]
+        lanes = self._diffusion_lanes(plan.decode_slots)
         p_slot, final, chunk_rows = plan.prefill_slot, False, 0
         if p_slot is not None:
             final, table, start, segment, last_row, _, _ = \
@@ -4138,9 +4208,7 @@ class ServingEngine:
             plan.decode_slots, plan.drafts, plan.verify_width)
         picked, accepts, pk, pv = self._dispatch(
             self._verify_step, self.params, self.pool.k, self.pool.v,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(tokens), jnp.asarray(widths), jnp.asarray(temps),
-            jnp.asarray(keys))
+            tables, lengths, active, tokens, widths, temps, keys)
         self.pool = replace(self.pool, k=pk, v=pv)
         self.verify_steps += 1
         self._charge_collectives(
@@ -4164,9 +4232,7 @@ class ServingEngine:
         picked_p, picked, accepts, pk, pv = self._dispatch(
             self._mixed_verify_step, self.params, self.pool.k,
             self.pool.v, table, start, segment, last_row, temp, key,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(tokens), jnp.asarray(widths), jnp.asarray(temps),
-            jnp.asarray(keys))
+            tables, lengths, active, tokens, widths, temps, keys)
         self.pool = replace(self.pool, k=pk, v=pv)
         self.prefill_chunks += 1
         self.verify_steps += 1
@@ -4197,13 +4263,15 @@ class ServingEngine:
         # place (on an unguarded engine the first read waits for the
         # device; on a guarded one the dispatch already has)
         with profiling.span("kubeshare.engine.fetch"):
-            first = (None if prefill_part is None or prefill_part[1] is None
-                     else int(np.asarray(prefill_part[1])[0]))
-            fetched = ([] if decode_part is None else
-                       [np.asarray(x) for x in
-                        decode_part[:_INFLIGHT_DEVICE_ARRAYS[kind]]])
             routing, rows, passes = self._routing_inflight
-            routing = [np.asarray(x) for x in routing]
+            # one call: the copies to the host start together
+            first, fetched, routing = jax.device_get((
+                None if prefill_part is None else prefill_part[1],
+                [] if decode_part is None else
+                list(decode_part[:_INFLIGHT_DEVICE_ARRAYS[kind]]),
+                list(routing)))
+            if first is not None:
+                first = int(first[0])
         self._routing_inflight = ([], 0, 0)
         if routing:
             self._observe_routing(routing[0], rows, passes)
@@ -4258,6 +4326,8 @@ class ServingEngine:
                 self._accept_decode(slots, fetched[0], budgets)
         if self._retention_inflight is not None:
             self._observe_retention()
+        if self._conv_inflight is not None:
+            self._observe_conv()
         return True
 
     def _observe_retention(self) -> None:

@@ -59,7 +59,28 @@ class KVRowLayout:
     *k_row[1:]]`` and the same with ``v_row``.
 
     - ``"kv_heads"``: a K and a V ``[kv_heads, head_dim]`` pair a model
-      layer (MHA / MQA / GQA).
+      layer (MHA / MQA / GQA) — of the layers that HAVE a cache row: where
+      a model's layers name their operator
+      (``TransformerConfig.layer_operators``) the pool holds the attention
+      layers' rows only, pool layer ``operator_index(layer)[1]``, and a
+      convolution's state lives by slot beside it
+      (:func:`init_conv_states`).  Heads narrower than a 128-lane vector
+      register lie ``heads_paired`` side by side in one array row
+      (``[kv_heads / paired, paired x head_dim]``: two 64-wide heads a
+      row), K heads beside K heads and V beside V, so the pool looks to
+      every step program like one of ``kv_heads / paired`` heads of 128
+      values — written whole a row by ``paged._write_rows``, read by
+      ``paged._layer_views`` and by the paged decode kernel as any
+      128-wide head is.  A query head is laid into its KV head's part of
+      a row of zeros (the products with the other head's values are
+      exact zeros) and its context is that part of the result
+      (``paged._paired_queries`` / ``_paired_context``), the trick the
+      latent kernel plays with its rotary query.  The other choice, a
+      head's K beside its V, would need one array where every program
+      takes two, and a second softmax path; a pool of 64-wide rows is
+      none: the compiler keeps so narrow an array blocks-minor and
+      re-lays it in and out of every program, and row writes through a
+      64-wide window are a loop of one update a row (see below).
     - ``"latent"``: one latent row an attention SUB-layer, shared by all
       query heads — ``k`` holds its ``kv_lora_rank`` latent values, ``v``
       its ``qk_rope_head_dim`` rotary-key values, the head axis 1.  The
@@ -91,6 +112,8 @@ class KVRowLayout:
     # a 'retention' block's rows also hold the log gate, one float32 a KV
     # head a layer, in an array of its own (PagedKVPool.gate)
     gate_heads: int = 0
+    # KV heads that share one array row ("kv_heads" only; 1: a row a head)
+    heads_paired: int = 1
 
     @property
     def v_layers(self) -> int:
@@ -118,10 +141,27 @@ def kv_row_layout(config: TransformerConfig) -> KVRowLayout:
         return KVRowLayout("latent", config.attn_sublayers,
                            (1, config.kv_lora_rank),
                            (1, config.qk_rope_head_dim), v_packed=2)
-    row = (config.kv_heads, config.head_dim)
+    paired = heads_paired(config)
+    row = (config.kv_heads // paired, config.head_dim * paired)
     return KVRowLayout(
-        "kv_heads", config.n_layers, row, row,
-        gate_heads=config.kv_heads if config.block == "retention" else 0)
+        "kv_heads", config.attn_sublayers, row, row,
+        gate_heads=config.kv_heads if config.block == "retention" else 0,
+        heads_paired=paired)
+
+
+LANES = 128  # values a TPU vector register holds across
+
+
+def heads_paired(config: TransformerConfig) -> int:
+    """KV heads an array row of the pool holds side by side: as many as
+    fill a 128-lane register where a model whose layers name their
+    operator has narrower heads (two at head width 64) and its KV heads
+    group so, else 1.  The other blocks' step programs take a row a head."""
+    hd = config.head_dim
+    if config.layer_operators is None or hd >= LANES or LANES % hd \
+            or config.kv_heads % (LANES // hd):
+        return 1
+    return LANES // hd
 
 
 def require_kv_heads(config: TransformerConfig, who: str) -> None:
@@ -270,6 +310,22 @@ def init_retention_states(config: TransformerConfig,
         jnp.zeros((num_slots, config.kv_heads, state_rows(config.head_dim),
                    phi_width(config.head_dim)), jnp.float32)
         for _ in range(config.n_layers))
+
+
+def init_conv_states(config: TransformerConfig,
+                     num_slots: int) -> Tuple[jnp.ndarray, ...]:
+    """The short convolutions' states, BY SLOT and beside the pool, not in
+    it: one array a convolution layer, ``[num_slots, conv_taps - 1,
+    d_model]`` in the served dtype — the last ``conv_taps - 1`` rows of
+    ``B * u`` the slot's lane has seen (``ops/short_conv.py``), 48 KB a
+    lane over six layers at d 2048.  An array a layer, as the retention
+    block's: a step program replaces each whole.  A chunk that starts at
+    row 0 reads zeros whatever the slot holds, so nothing is zeroed
+    between requests."""
+    return tuple(
+        jnp.zeros((num_slots, config.conv_taps - 1, config.d_model),
+                  config.dtype)
+        for _ in range(config.conv_layers))
 
 
 class BlockAllocator:

@@ -43,8 +43,14 @@ rows) and names what its decode lanes ran on the launch span
 (``attend``).
 
 The layers themselves are written once a block kind (:func:`_dense_layers`,
-:func:`_gqa_moe_layers`, :func:`_latent_layers`) and run by every step through
-:func:`_run_layers`.  The entry points:
+:func:`_gqa_moe_layers`, :func:`_latent_layers`, :func:`_retention_layers`)
+and run by every step through :func:`_run_layers`.  A model whose lanes hold
+a state BY SLOT beside the pool — a 'retention' block's recurrent states, the
+short convolutions' windows of a model whose layers name their operator —
+carries it through every step program in a :class:`Recurrent`, donated like
+the pool, and every step returns its outputs, then a routed block's routing
+counts, then the :class:`Recurrent` (:func:`_step_outputs`).  The entry
+points:
 
 - :func:`paged_prefill_step`: a width-C prompt chunk writing its K/V
   straight into a slot's blocks (no dense staging cache to copy from);
@@ -122,6 +128,7 @@ from ..ops.paged_attention import (kernel_fits, latent_kernel_fits,
                                    paged_decode_attention,
                                    paged_latent_decode_attention)
 from ..ops.rope import apply_rope
+from ..ops.short_conv import short_conv, state_after
 from .drafter import ngram_propose_rows
 
 
@@ -392,7 +399,7 @@ def experts_path(moe, rows: int) -> str:
 
 
 def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window,
-                 diffusion_block: int = 0):
+                 diffusion_block: int = 0, scale: Optional[float] = None):
     """The dense block's attention of ``q`` [B, h, C, d] over each lane's
     view of pool layer ``layer_idx``, under the per-query causal band
     (``positions`` [B, C]: the last row each query sees).
@@ -408,25 +415,28 @@ def _attend_view(q, pool_k, pool_v, layer_idx, tables, positions, window,
     key-block loop (``_layer_reader``'s views of that part of the
     table), as far as the furthest lane reaches.  A view no longer than a
     key block is attended whole — a static shape, not a knob: a one-trip
-    loop buys nothing."""
+    loop buys nothing.  ``scale`` is what the scores are multiplied by
+    (None: ``q.shape[3] ** -0.5``), the same on every path."""
     path = attend_path("dense", q.shape[2], tables.shape[1], pool_k, pool_v,
                        q.shape[3], diffusion_block)
     if path == "whole":
         view_k, view_v = _layer_views(pool_k, pool_v, layer_idx, tables)
-        return _attend_cached(q, view_k, view_v, positions, window=window)
+        return _attend_cached(q, view_k, view_v, positions, window=window,
+                              scale=scale)
     if path == "kernel":
         return paged_decode_attention(
             q, pool_k, pool_v, layer_idx, tables, positions[:, 0],
-            window=window, interpret=_kernel_mode() == "interpret")
+            window=window, interpret=_kernel_mode() == "interpret",
+            scale=scale)
     entries = key_block_entries(tables.shape[1], pool_k.shape[3])
     return _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables,
-                               positions, entries, window)
+                               positions, entries, window, scale)
 
 
-@functools.partial(jax.jit, static_argnames=("entries", "window"),
+@functools.partial(jax.jit, static_argnames=("entries", "window", "scale"),
                    inline=True)
 def _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables, positions,
-                        entries, window):
+                        entries, window, scale=None):
     """:func:`_attend_view` of a long view, ``entries`` table entries a
     key block.  Jitted to be traced ONCE for
     all the layers of a step program (the layer is an argument, and the
@@ -441,7 +451,7 @@ def _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables, positions,
             tables, i * entries, entries, axis=1))
 
     return _attend_blocks(q, view_block, entries * pool_k.shape[3],
-                          pool_k.shape[2], positions, window)
+                          pool_k.shape[2], positions, window, scale)
 
 
 @jax.named_scope("mlp")
@@ -502,7 +512,7 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
                                layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
         x = x + _moe_or_mlp(layer, config, y)
-    return x, pool_k, pool_v, None
+    return x, pool_k, pool_v, None, None
 
 
 def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
@@ -566,11 +576,36 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
 
     x, counts = latent_layers(params, x, config, attend, live)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
-    return x, pool_k, pool_v, counts
+    return x, pool_k, pool_v, counts, None
+
+
+def _paired_queries(q, paired: int, kv_heads: int):
+    """``q`` [B, H, C, hd] laid out for a pool whose rows hold ``paired`` KV
+    heads side by side (``kv_blocks.KVRowLayout.heads_paired``): each query
+    head in its KV head's part of a row of ``paired x hd`` zeros, [B, H, C,
+    paired x hd].  KV head j lies in part ``j % paired`` of array row ``j //
+    paired`` and serves ``H / kv_heads`` consecutive query heads; their
+    products with the row's other heads' values are exact zeros, so the
+    scores are the head's own, and the query heads of one array row are one
+    query group of ``paired x H / kv_heads`` heads."""
+    b, h, c, hd = q.shape
+    q = q.reshape(b, kv_heads // paired, paired, h // kv_heads, c, 1, hd)
+    mine = jnp.eye(paired, dtype=bool).reshape(1, 1, paired, 1, 1, paired, 1)
+    return jnp.where(mine, q, 0).reshape(b, h, c, paired * hd)
+
+
+def _paired_context(o, paired: int, kv_heads: int):
+    """A context [B, H, C, paired x hd] over paired value rows -> each
+    query head's own part of it, [B, H, C, hd]."""
+    b, h, c, wide = o.shape
+    o = o.reshape(b, kv_heads // paired, paired, h // kv_heads, c, paired,
+                  wide // paired)
+    return jnp.stack([o[:, :, j, :, :, j] for j in range(paired)],
+                     axis=2).reshape(b, h, c, wide // paired)
 
 
 def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
-                    tables, positions, blk, off, x, live):
+                    tables, positions, blk, off, x, live, carried=None):
     """The 'gqa_moe' block's layers (``transformer.gqa_moe_layers`` puts
     a layer together), same contract as :func:`_dense_layers` and the
     same cache plumbing: a layer's K and V rows are written through
@@ -585,33 +620,80 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     the paged kernel where :func:`attend_path` says it can run, as one
     row a lane does under the causal mask; the prefill chunk, rows of
     many blocks, runs the key-block loop.  Also returns the step's
-    routing counts int32[7], as :func:`_latent_layers` does."""
-    reach = attend_reach(config, positions)
+    routing counts int32[7], as :func:`_latent_layers` does.
 
-    def attend(layer_idx, attn, y):
+    Where the model's layers name their operator
+    (``TransformerConfig.layer_operators``) the pool holds the attention
+    layers' rows alone, two 64-wide KV heads side by side in a row
+    (``kv_blocks.KVRowLayout`` ``heads_paired``: the queries go in laid
+    into their head's part of a row of zeros and the context comes back
+    out of it, so every path above sees heads of 128), and ``carried`` =
+    (:class:`Recurrent`, _, ``slots`` [B] or None, _) holds the short
+    convolutions' states, an array a convolution layer BY SLOT (None:
+    lane b IS slot b, the decode lanes).  A convolution reads its lane's
+    state — zeros where the lane's first row is row 0, whatever the slot
+    holds — and leaves ``B * u`` of the lane's last ``conv_taps - 1`` LIVE
+    rows (``ops/short_conv.py``); a lane with no live row (an idle lane, a
+    slot between two chunks of its prompt) keeps what it held.  The new
+    states come back as the fifth result."""
+    reach = attend_reach(config, positions)
+    dtype = config.dtype
+    h_kv = config.kv_heads
+    paired = h_kv // pool_k.shape[2]
+    scale = config.head_dim ** -0.5
+
+    def attend(row, attn, y):
         nonlocal pool_k, pool_v
         q, k, v = gqa_qkv(attn, y, positions, config)
-        pool_k, pool_v = _write_rows(
-            pool_k, pool_v, layer_idx, blk, off,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        if paired > 1:
+            with jax.named_scope("attention"):
+                q = _paired_queries(q, paired, h_kv)
+                k = k.reshape(*k.shape[:2], *pool_k.shape[2::2])
+                v = v.reshape(*v.shape[:2], *pool_v.shape[2::2])
+        pool_k, pool_v = _write_rows(pool_k, pool_v, row, blk, off, k, v)
         with jax.named_scope("attention"):
-            return _attend_view(q, pool_k, pool_v, layer_idx, tables, reach,
-                                None, config.diffusion_block)
+            o = _attend_view(q, pool_k, pool_v, row, tables, reach, None,
+                             config.diffusion_block, scale)
+            return o if paired == 1 else _paired_context(o, paired, h_kv)
 
-    x, counts = gqa_moe_layers(params, x, config, attend, live)
+    # no convolution, nothing carried: `conv` is never called
+    recurrent, _, slots, _ = carried or (Recurrent(None, ()), None, None,
+                                         None)
+    states = list(recurrent.states)
+    rows_done = jnp.sum(live, axis=1, dtype=jnp.int32)  # live rows lead
+    fresh = live[:, 0] & (positions[:, 0] == 0)
+
+    def conv(idx, weights, y):
+        with jax.named_scope("conv_state"):
+            state = states[idx] if slots is None else states[idx][slots]
+            state = jnp.where(fresh[:, None, None], 0, state)
+        out, window = short_conv(weights, y, state, dtype)
+        new = state_after(window, rows_done, config.conv_taps)
+        with jax.named_scope("conv_state"):
+            states[idx] = (new if slots is None
+                           else states[idx].at[slots].set(new))
+        return out
+
+    x, counts = gqa_moe_layers(params, x, config, attend, live, conv)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
-    return x, pool_k, pool_v, counts
+    return (x, pool_k, pool_v, counts,
+            Recurrent(None, tuple(states)) if states else None)
 
 
 class Recurrent(NamedTuple):
-    """What a 'retention' block's step programs carry beside K and V, both
-    donated: ``gate`` the pool's third array (float32 ``[layers, kv_heads,
-    num_blocks x block_size]``, each unfolded row's log gate in column
-    ``page x block_size + offset``) and ``states``, an array a layer
+    """What the step programs of a model with a state BY SLOT carry beside
+    K and V, all donated, through every program and back to the engine.  A
+    'retention' block: ``gate`` the pool's third array (float32 ``[layers,
+    kv_heads, num_blocks x block_size]``, each unfolded row's log gate in
+    column ``page x block_size + offset``) and ``states``, an array a layer
     ``[slots, kv_heads, state_rows, phi_width]``: the lanes' recurrent
-    states BY SLOT (``kv_blocks.init_retention_states``)."""
+    states (``kv_blocks.init_retention_states``).  A model whose layers
+    name the short convolution: ``gate`` None and ``states`` an array a
+    convolution layer ``[slots, conv_taps - 1, d_model]``
+    (``kv_blocks.init_conv_states``)."""
 
-    gate: jax.Array
+    gate: Optional[jax.Array]
     states: Tuple[jax.Array, ...]
 
 
@@ -645,8 +727,8 @@ def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
     softmax), everything before them by ``phi(q)`` against the state
     (``state_sums``), which a dispatch whose lanes have folded nothing
     never reads.  Nothing here writes a state: a fold is the step
-    program's last phase (:func:`fold_lanes`).  Returns the updated gate
-    array where the other blocks return their routing counts."""
+    program's last phase (:func:`fold_lanes`).  Returns no routing counts
+    and, fifth, the :class:`Recurrent` with the updated gate array."""
     recurrent, folded, slots, grow = carried
     gate, states = recurrent
     bs = pool_k.shape[3]
@@ -689,7 +771,7 @@ def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
         return retention_output(tail, state, dtype)
 
     x = retention_layers(params, x, config, attend)
-    return x, pool_k, pool_v, gate
+    return x, pool_k, pool_v, None, Recurrent(gate, states)
 
 
 @jax.named_scope("retention_fold")
@@ -734,24 +816,40 @@ def fold_lanes(pool_k, pool_v, recurrent: Recurrent, tables, folded,
 N_STEP_COUNTS = len(ROUTING_COUNTS) + 1
 
 _LAYERS = {"dense": _dense_layers, "gqa_moe": _gqa_moe_layers,
-           "latent_shortcut": _latent_layers, "latent_moe": _latent_layers}
+           "latent_shortcut": _latent_layers, "latent_moe": _latent_layers,
+           "retention": _retention_layers}
 
 
 def _run_layers(params, config: TransformerConfig, *args, carried=None):
     """The block's layer loop: the one place a step program's layers
     run, whatever the step (prefill chunk, decode step, verify chunk).
-    Returns (x, pool_k, pool_v, and what the block's steps hand on beside
-    the pool: a routed block's routing counts, a 'retention' block's gate
-    array, else None); ``carried`` is the 'retention' block's alone."""
+    Returns (x, pool_k, pool_v, a routed block's routing counts or None,
+    and the :class:`Recurrent` a model with a state by slot hands on —
+    a 'retention' block's gate array and states, the short convolutions'
+    new states — or None).  ``carried`` = (:class:`Recurrent`, ``folded``,
+    ``slots``, ``grow``) is such a model's alone."""
+    if carried is None:
+        return _LAYERS[config.block](params, config, *args)
+    return _LAYERS[config.block](params, config, *args, carried)
+
+
+def _step_outputs(routing: bool, counts, recurrent, *outputs):
+    """A step's outputs: then a routed block's routing counts when the
+    caller asked for them, then — last — the :class:`Recurrent` of a
+    model that carries one."""
+    if routing:
+        outputs += (counts,)
+    return outputs if recurrent is None else outputs + (recurrent,)
+
+
+def _settled(config: TransformerConfig, pool_k, pool_v, recurrent, *fold):
+    """The :class:`Recurrent` once a step program's rows are in: a
+    'retention' block folds the lanes that are due (:func:`fold_lanes`),
+    its last phase; the short convolutions' states are already what the
+    rows left."""
     if config.block == "retention":
-        return _retention_layers(params, config, *args, carried)
-    return _LAYERS[config.block](params, config, *args)
-
-
-def _with_routing(routing: bool, counts, *outputs):
-    """A step's outputs, with a routed block's routing counts last when
-    the caller asked for them."""
-    return outputs + (counts,) if routing else outputs
+        return fold_lanes(pool_k, pool_v, recurrent, *fold)
+    return recurrent
 
 
 def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
@@ -773,8 +871,9 @@ def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
     live = active[:, None] & (
         jnp.arange(chunk)[None, :] <= last_rows[:, None])
     if carried is not None:
-        # a 'retention' chunk pads FORWARD, past the prompt's last row:
-        # the padding's rows land in the scratch block, not in a page
+        # a chunk of a model with a state pads FORWARD, past the prompt's
+        # last row: the padding's rows land in the scratch block, not in
+        # a page
         blk = jnp.where(live, blk, 0)
     return _run_layers(
         params, config, pool_k, pool_v, tables, positions, blk, off, x,
@@ -811,11 +910,15 @@ def paged_prefill_step(
     is computed (a full [P, C, vocab] f32 buffer would dominate the
     step at real vocab sizes).
 
-    A 'retention' block also takes ``recurrent`` (:class:`Recurrent`),
-    each lane's fold point ``folded`` [P] and slot ``slots`` [P], and
-    returns the :class:`Recurrent` last: the chunk reads the lane's state
-    and its unfolded rows, and where it completes a key block, the
-    program's last phase folds it (:func:`fold_lanes`).
+    A model with a state by slot also takes ``recurrent``
+    (:class:`Recurrent`), each lane's slot ``slots`` [P] and (a 'retention'
+    block's) fold point ``folded`` [P], and returns the :class:`Recurrent`
+    last, after a routed block's counts: a 'retention' chunk reads the
+    lane's state and its unfolded rows, and where it completes a key
+    block, the program's last phase folds it (:func:`fold_lanes`); a
+    convolution reads the two rows its slot holds (zeros at row 0) and
+    leaves those of the chunk's last live rows.  Such a chunk pads
+    FORWARD, past the prompt's last row.
 
     Inactive lanes write to the scratch block and compute garbage the
     caller ignores.  NOTE: the engine deliberately dispatches P=1 (one
@@ -827,7 +930,7 @@ def paged_prefill_step(
     dtype = config.dtype
     carried = (None if recurrent is None
                else (recurrent, folded, slots, tokens.shape[1]))
-    x, pool_k, pool_v, counts = _prefill_rows(
+    x, pool_k, pool_v, counts, recurrent = _prefill_rows(
         params, config, pool_k, pool_v, tables, starts, active, tokens,
         last_rows, carried)
 
@@ -838,10 +941,10 @@ def paged_prefill_step(
         logits = (head_in
                   @ params["lm_head"].astype(dtype)).astype(jnp.float32)
     if recurrent is not None:
-        return (logits[:, 0], pool_k, pool_v, fold_lanes(
-            pool_k, pool_v, Recurrent(counts, recurrent.states), tables,
-            folded, starts + last_rows + 1, active, slots))
-    return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
+        recurrent = _settled(config, pool_k, pool_v, recurrent, tables,
+                             folded, starts + last_rows + 1, active, slots)
+    return _step_outputs(routing, counts, recurrent, logits[:, 0], pool_k,
+                         pool_v)
 
 
 def paged_decode_step(
@@ -867,11 +970,12 @@ def paged_decode_step(
     the caller ignores — their K/V writes are routed to the scratch
     block so the pool's live data is never touched.
 
-    A 'retention' block also takes ``recurrent`` and the lanes' fold
-    points ``folded`` [S] (lane s is slot s), and returns the
-    :class:`Recurrent` last with the step's log gates written; it folds
-    nothing — that is the span's last phase, and ``grow`` says how many
-    steps a tail may gain before it.
+    A model with a state by slot also takes ``recurrent`` (lane s is
+    slot s) and returns the :class:`Recurrent` last: a 'retention' block's
+    (with the lanes' fold points ``folded`` [S]) with the step's log gates
+    written — it folds nothing: that is the span's last phase, and ``grow``
+    says how many steps a tail may gain before it; the short convolutions'
+    states shifted by the step's row, an idle lane's as they were.
     """
     dtype = config.dtype
     bs = pool_k.shape[3]
@@ -887,17 +991,15 @@ def paged_decode_step(
     # every slot a one-row chunk at its own position: the same layer loop
     carried = (None if recurrent is None
                else (recurrent, folded, None, grow))
-    x, pool_k, pool_v, counts = _run_layers(
+    x, pool_k, pool_v, counts, recurrent = _run_layers(
         params, config, pool_k, pool_v, block_tables, positions[:, None],
         blk[:, None], off[:, None], x, active[:, None], carried=carried)
 
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"]["scale"], config.norm_eps)
         logits = (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    if recurrent is not None:
-        return (logits[:, 0], pool_k, pool_v,
-                Recurrent(counts, recurrent.states))
-    return _with_routing(routing, counts, logits[:, 0], pool_k, pool_v)
+    return _step_outputs(routing, counts, recurrent, logits[:, 0], pool_k,
+                         pool_v)
 
 
 def paged_decode_span(
@@ -932,65 +1034,40 @@ def paged_decode_span(
     ``pick_fn``/``span``/``eos`` are trace-time constants (the engine
     closes over them under jit).
 
-    A 'retention' block (``recurrent``, ``folded`` [S]) carries its gate
-    array through the steps, which only READ the states; a lane whose tail
-    holds a key block once the span is done folds it in the program's last
-    phase (:func:`fold_lanes`), and the :class:`Recurrent` comes last.
+    A model with a state by slot carries its :class:`Recurrent` through
+    the scan with the pool (one buffer each, updated in place: the compiler
+    keeps no second copy of a 'retention' block's 5 GB of states) and
+    returns it last.  A 'retention' block's steps (``folded`` [S]) write
+    their log gates and only READ the states; a lane whose tail holds a key
+    block once the span is done folds it in the program's last phase
+    (:func:`fold_lanes`).  The short convolutions' states shift a row a
+    step.
     """
-    if recurrent is not None:
-        return _retention_span(
-            params, config, pick_fn, span, eos, pool_k, pool_v, tables,
-            lengths, active, tokens, temps, keys, budgets, recurrent,
-            folded)
-
     def body(carry, i):
-        pk, pv, lens, toks, alive, *counts = carry
-        logits, pk, pv, *step_counts = paged_decode_step(
+        pk, pv, rec, lens, toks, alive, *counts = carry
+        logits, pk, pv, *rest = paged_decode_step(
             params, config, pk, pv, tables, lens, alive, toks,
-            routing=routing)
+            routing=routing, recurrent=rec, folded=folded, grow=span)
+        if rec is not None:
+            rec = rest.pop()
         with jax.named_scope("sample"):
             nxt = pick_fn(logits, temps, keys[:, i])
         lens = lens + alive.astype(jnp.int32)
         cont = alive & (i + 1 < budgets)
         if eos is not None:
             cont = cont & (nxt != eos)
-        counts = [c + s for c, s in zip(counts, step_counts)]
-        return (pk, pv, lens, nxt, cont, *counts), nxt
+        counts = [c + s for c, s in zip(counts, rest)]
+        return (pk, pv, rec, lens, nxt, cont, *counts), nxt
 
-    carry = (pool_k, pool_v, lengths, tokens, active,
+    carry = (pool_k, pool_v, recurrent, lengths, tokens, active,
              *([jnp.zeros((N_STEP_COUNTS,), jnp.int32)] if routing else []))
-    (pk, pv, _, _, _, *counts), emitted = jax.lax.scan(
+    (pk, pv, recurrent, lens, _, _, *counts), emitted = jax.lax.scan(
         body, carry, jnp.arange(span))
-    return (emitted, pk, pv, *counts)
-
-
-def _retention_span(params, config, pick_fn, span, eos, pool_k, pool_v,
-                    tables, lengths, active, tokens, temps, keys, budgets,
-                    recurrent: Recurrent, folded):
-    """:func:`paged_decode_span` of a 'retention' block: the same scan with
-    the gate array and the states in its carry (no step writes a state:
-    it rides through, so that the fold after the scan updates the one
-    buffer and the compiler keeps no second copy of 5 GB), then the fold
-    of every lane that is due."""
-
-    def body(carry, i):
-        pk, pv, gate, states, lens, toks, alive = carry
-        logits, pk, pv, (gate, states) = paged_decode_step(
-            params, config, pk, pv, tables, lens, alive, toks,
-            recurrent=Recurrent(gate, states), folded=folded, grow=span)
-        with jax.named_scope("sample"):
-            nxt = pick_fn(logits, temps, keys[:, i])
-        lens = lens + alive.astype(jnp.int32)
-        cont = alive & (i + 1 < budgets)
-        if eos is not None:
-            cont = cont & (nxt != eos)
-        return (pk, pv, gate, states, lens, nxt, cont), nxt
-
-    (pk, pv, gate, states, lens, _, _), emitted = jax.lax.scan(
-        body, (pool_k, pool_v, *recurrent, lengths, tokens, active),
-        jnp.arange(span))
-    return emitted, pk, pv, fold_lanes(
-        pk, pv, Recurrent(gate, states), tables, folded, lens, active)
+    if recurrent is not None:
+        recurrent = _settled(config, pk, pv, recurrent, tables, folded,
+                             lens, active)
+    return _step_outputs(routing, counts[0] if counts else None, recurrent,
+                         emitted, pk, pv)
 
 
 def _decode_loop_impl(
@@ -1168,7 +1245,7 @@ def paged_verify_span(
     x = params["embed"][jnp.maximum(tokens, 0)].astype(dtype)  # [S, W, d]
     if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
-    x, pool_k, pool_v, _ = _run_layers(
+    x, pool_k, pool_v, _, _ = _run_layers(
         params, config, pool_k, pool_v, tables, positions, blk, off, x,
         valid)
 
@@ -1551,33 +1628,28 @@ def paged_mixed_step(
     meaningful only when the chunk is the prompt's final one (the
     fused first-token pick, same as the standalone prefill step); with
     ``routing`` the routing counts of the chunk and the span, summed,
-    come last; a 'retention' block's :class:`Recurrent` goes through the
-    chunk (its fold with it) and then the span (and its lanes' folds).
+    come last but for the :class:`Recurrent` of a model with a state by
+    slot, which goes through the chunk (a 'retention' block's fold with it)
+    and then the span (and its lanes' folds): the chunk's slot is no lane
+    of the span, so the span leaves what the chunk wrote there alone.
     """
-    if recurrent is not None:
-        p_logits, pk, pv, recurrent = paged_prefill_step(
-            params, config, pool_k, pool_v, p_table, p_start,
-            jnp.ones_like(p_start, bool), p_tokens, p_last_row,
-            recurrent=recurrent, folded=p_folded, slots=p_slot)
-        with jax.named_scope("sample"):
-            p_picked = pick_fn(p_logits, p_temp, p_key)
-        emitted, pk, pv, recurrent = paged_decode_span(
-            params, config, pick_fn, span, eos, pk, pv, d_tables,
-            d_lengths, d_active, d_tokens, d_temps, d_keys, d_budgets,
-            recurrent=recurrent, folded=d_folded)
-        return p_picked, emitted, pk, pv, recurrent
     p_logits, pk, pv, *p_counts = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row,
-        routing=routing)
+        routing=routing, recurrent=recurrent, folded=p_folded, slots=p_slot)
+    if recurrent is not None:
+        recurrent = p_counts.pop()
     with jax.named_scope("sample"):
         p_picked = pick_fn(p_logits, p_temp, p_key)
     emitted, pk, pv, *d_counts = paged_decode_span(
         params, config, pick_fn, span, eos, pk, pv,
         d_tables, d_lengths, d_active, d_tokens, d_temps, d_keys,
-        d_budgets, routing=routing)
-    return (p_picked, emitted, pk, pv,
-            *[p + d for p, d in zip(p_counts, d_counts)])
+        d_budgets, routing=routing, recurrent=recurrent, folded=d_folded)
+    if recurrent is not None:
+        recurrent = d_counts.pop()
+    counts = [p + d for p, d in zip(p_counts, d_counts)]
+    return _step_outputs(routing, counts[0] if counts else None, recurrent,
+                         p_picked, emitted, pk, pv)
 
 
 # ---------------------------------------------------------------------------
@@ -1604,10 +1676,10 @@ def paged_diffusion_prefill(
     mask, and yields NO token — the prompt's first generated block is
     denoised by the passes that follow — so no head is applied.
     Returns (pool_k, pool_v) and, with ``routing``, the counts."""
-    _, pool_k, pool_v, counts = _prefill_rows(
+    _, pool_k, pool_v, counts, _ = _prefill_rows(
         params, config, pool_k, pool_v, tables, starts, active, tokens,
         last_rows)
-    return _with_routing(routing, counts, pool_k, pool_v)
+    return _step_outputs(routing, counts, None, pool_k, pool_v)
 
 
 def paged_diffusion_pass(
@@ -1664,7 +1736,7 @@ def paged_diffusion_pass(
     ids = jnp.where(masked, config.mask_token, tokens)
     x = params["embed"][ids].astype(dtype)  # [S, B, d]
     live = jnp.broadcast_to(active[:, None], positions.shape)
-    x, pool_k, pool_v, counts = _run_layers(
+    x, pool_k, pool_v, counts, _ = _run_layers(
         params, config, pool_k, pool_v, tables, positions, blk, off, x,
         live)
 
@@ -1683,7 +1755,8 @@ def paged_diffusion_pass(
             (other == mine) & (row[None, None, :] < row[None, :, None]))
         rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)
         commit = may & (rank < quota[:, None])
-    return _with_routing(routing, counts, picked, commit, pool_k, pool_v)
+    return _step_outputs(routing, counts, None, picked, commit, pool_k,
+                         pool_v)
 
 
 def paged_mixed_diffusion_step(

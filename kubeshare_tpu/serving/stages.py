@@ -58,6 +58,12 @@ STAGE_OF_SCOPE = {
     # `attention`'s, its tail's row writes `kv_write`'s, its SwiGLU `ffn`'s)
     "retention_state": "retention", "retention_tail": "retention",
     "retention_fold": "retention", "gate": "retention", "phi": "retention",
+    # the gated short convolution of a model whose layers name it
+    # (ops/short_conv.py): its projections, gates and filter, and the
+    # state's read, shift and write (its attention layers keep
+    # `attention` / `qk_norm` / `kv_write`, its feed-forwards `dense_ffn` /
+    # `router` / `experts`)
+    "short_conv": "conv", "conv_state": "conv",
 }
 STAGES = tuple(dict.fromkeys(STAGE_OF_SCOPE.values())) + (UNSCOPED,)
 

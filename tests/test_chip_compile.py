@@ -418,6 +418,9 @@ EXPERT_LAYERS = {
     "joyai-chunk": ((512, 2048, 768, 256, 256, 8), (32, 768)),
     "lcf-step": ((32, 6144, 2048, 16, 768, 12), (16, 512)),
     "lcf-chunk": ((512, 6144, 2048, 16, 768, 12), (16, 512)),
+    # a third expert shape: 18.9 MB an expert, whole and twice in 37.7 MB
+    "lfm2-step": ((32, 2048, 1536, 64, 64, 4), (16, 1536)),
+    "lfm2-chunk": ((512, 2048, 1536, 64, 64, 4), (64, 1536)),
 }
 
 
@@ -760,3 +763,115 @@ def test_retention_program_compiles_and_fits(one_chip, kind):
     # a decode step's state query reads each state where it lies: one
     # multiply-and-sum over phi's columns a layer a step, no staged slice
     assert not re.search(r"f32\[32,8,5,136,8320\][^ ]* fusion\(", text)
+
+
+# ---------------------------------------------------------------------------
+# layers that name their operator: short convolutions' states by slot beside
+# a pool of the attention layers' rows, two 64-wide heads a row
+# ---------------------------------------------------------------------------
+
+# temporaries of `lfm2-24b-a2b`'s two programs as first compiled (PR 43):
+# 31,684,608 B the span alone, 73,308,160 B beside a 512-row chunk
+CONV_TEMPORARIES = {"decode": 48 << 20, "mixed": 112 << 20}
+
+
+def _conv_case(kind):
+    """The decode span or the mixed program of ``lfm2-24b-a2b``'s first
+    pipeline stage at its published widths, as shapes only, compiled as the
+    engine compiles them: the pool's two arrays and the convolutions' states
+    (an array a convolution layer) donated, the chunk's slot after them."""
+    import json
+
+    from kubeshare_tpu.serving.kv_blocks import (init_conv_states,
+                                                 init_paged_pool)
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        config_file = json.load(f)
+    tc = dict(config_file["transformer_config"])
+    tc["dtype"] = jnp.dtype(tc["dtype"])
+    config = TransformerConfig(**tc)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, config.dtype),
+        jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), config)))
+    e = config_file["engine"]
+    s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
+    num_blocks = e["pool_bytes"] // (4096 * e["block_size"]) + 1
+    pool_k, pool_v = jax.eval_shape(
+        lambda: init_paged_pool(config, num_blocks,
+                                e["block_size"]).arrays())
+    recurrent = paged.Recurrent(None, jax.eval_shape(
+        lambda: init_conv_states(config, s)))
+    span = 4
+    lanes = (_i32(s, t), _i32(s), jax.ShapeDtypeStruct((s,), bool), _i32(s),
+             jax.ShapeDtypeStruct((s,), jnp.float32),
+             jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
+    if kind == "decode":
+        fn = lambda w, pk, pv, rec, folded, *rest: paged_decode_span(
+            w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True,
+            recurrent=rec, folded=folded)
+        return config, fn, (params, pool_k, pool_v, recurrent, _i32(s),
+                            *lanes)
+    fn = lambda w, pk, pv, rec, p_folded, p_slot, d_folded, *rest: \
+        paged_mixed_step(w, config, _greedy_pick, span, None, pk, pv, *rest,
+                         routing=True, recurrent=rec, p_folded=p_folded,
+                         p_slot=p_slot, d_folded=d_folded)
+    return config, fn, (
+        params, pool_k, pool_v, recurrent, _i32(1), _i32(1), _i32(s),
+        _i32(1, t), _i32(1), _i32(1, e["prefill_chunk"]), _i32(1),
+        jax.ShapeDtypeStruct((1,), jnp.float32),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
+
+
+@pytest.mark.parametrize("kind", list(CONV_TEMPORARIES))
+def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
+    """The first pipeline stage of ``lfm2-24b-a2b`` at the published widths
+    — six gated short convolutions and two GQA layers of 32 to 8 at head
+    width 64, two dense SwiGLUs of 11776 and six layers of 64 experts, the
+    whole vocabulary — built as on the chip with 32 lanes' convolution
+    states (an array a layer, 256 KB each) beside a 1 GiB pool of the TWO
+    attention layers' rows: 9.4 GB resident (8.32 GB of weights, the pool)
+    and 32 / 73 MB of temporaries.  The pool's minor dimension is 128 (two
+    64-wide heads side by side), everything donated is written in place
+    and NO program copies or re-lays the pool; the decode lanes' one query
+    row attends through the paged kernel (one call an attention layer: the
+    query laid into its head's half of a row of zeros), the experts' tiles
+    through the grouped kernel (one call an expert layer a pass); the rows
+    are written whole (no loop of row updates in ``kv_write``); and the
+    program's own table of stages has ``conv`` beside the others."""
+    import re
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    config, fn, args = _conv_case(kind)
+    assert (config.conv_layers, config.attn_sublayers,
+            config.expert_layers) == (6, 2, 6)
+    assert args[1].shape == args[2].shape == (2, 16385, 4, 16, 128)
+    gate, states = args[3]
+    assert gate is None and len(states) == 6
+    assert all(s.shape == (32, 2, 2048) and s.dtype == jnp.bfloat16
+               for s in states)
+    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2, 3))
+    memory = compiled.memory_analysis()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 9.3e9 < resident < 9.6e9, memory
+    assert memory.temp_size_in_bytes < CONV_TEMPORARIES[kind], memory
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(args[1:4]))
+    assert donated == 2 * 16385 * 16 * 2048 + 6 * 32 * 2 * 2048 * 2
+    assert memory.alias_size_in_bytes >= donated, memory
+    text = compiled.as_text()
+    for shape in ("2,16385,4,16,128", "16385,4,16,128", "2,16385,16,128"):
+        assert not re.search(rf"bf16\[{shape}\][^ ]* copy\(", text), shape
+    passes = 2 if kind == "mixed" else 1
+    assert _kernel_calls(text) == (config.attn_sublayers,
+                                   config.expert_layers * passes)
+    # no key block of every lane's table entries is gathered for the lanes
+    assert not re.search(r"bf16\[1024,4,16,128\]", text)
+    _rows_written_whole(args, text)
+    with_experts = set(re.findall(r"(?:bf16|f32)\[64,[0-9,]+\]", text))
+    assert {"bf16[64,2048,1536]", "bf16[64,1536,2048]"} <= with_experts
+    by_stage = _stages_hold(config, args, text)
+    assert {"conv", "attention", "ffn", "experts", "kv_write", "head"} \
+        <= set(by_stage)

@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench.tests.test_chipbench import *  # noqa: E402,F401,F403
 from chipbench.tests.test_spans import *  # noqa: E402,F401,F403
 from chipbench.tests.test_tiles import *  # noqa: E402,F401,F403
+from chipbench.tests import test_conv_readers as _conv  # noqa: E402
 from chipbench.tests import test_diffusion_readers as _diffusion  # noqa: E402
 from chipbench.tests import test_retention_readers as _retention  # noqa: E402
 from chipbench.tests import test_stages as _stages  # noqa: E402
@@ -31,11 +32,13 @@ pytestmark = pytest.mark.usefixtures("chipbench_apart")
 # ``test_stages.py`` takes every metric named ``step.stage_*`` for one of PR
 # 38's 14 (``NAMES``) and counts them; PR 41 appended a fifteenth,
 # ``step.stage_ms.retention.backlog``, whose stage no table of those cases
-# has.  Its cases run here over the 14 they were written for; the listing
-# below counts all 15.
+# has, and PR 43 a sixteenth, ``step.stage_ms.conv.backlog``.  Its cases run
+# here over the 14 they were written for; the listing below counts all 16.
 RETENTION_STAGE = "step.stage_ms.retention.backlog"
+CONV_STAGE = "step.stage_ms.conv.backlog"
 ALL_STAGE_NAMES = list(_stages.NAMES)
-_stages.NAMES = [n for n in ALL_STAGE_NAMES if n != RETENTION_STAGE]
+_stages.NAMES = [n for n in ALL_STAGE_NAMES
+                 if n not in (RETENTION_STAGE, CONV_STAGE)]
 
 # 99.9 s of this file's 185 s in one process (PR 29), and it tests
 # `chipbench/tools/sweep.py`, which no cell runs
@@ -66,6 +69,14 @@ test_retention_spans_that_lack_what_a_reader_reads_give_nothing = \
 test_a_program_without_the_retention_span_gives_nothing = \
     _retention.test_a_program_without_the_span_gives_nothing
 
+# ... and the conv readers' (the same three names once more)
+test_conv_readers_over_spans_with_the_attributes = \
+    _conv.test_readers_over_spans_with_the_attributes
+test_conv_spans_that_lack_what_a_reader_reads_give_nothing = \
+    _conv.test_spans_that_lack_what_a_reader_reads_give_nothing
+test_a_program_without_the_conv_span_gives_nothing = \
+    _conv.test_a_program_without_the_span_gives_nothing
+
 # the readers of device time by stage, under names of their own too
 test_stages_a_while_keeps_what_its_body_does_not_cover_and_names_collide = \
     _stages.test_a_while_keeps_what_its_body_does_not_cover_and_names_collide
@@ -86,12 +97,13 @@ def test_every_stage_metric_has_its_file_and_its_cells():
     ``per_layer`` over exactly the cells of that day; a configuration of
     another block has since appended its cell to the stages it has and four
     metrics after them (PR 41: a `benchmark` PR's to repair there, PERF.md
-    section 7, item 11).  The same statements, over the cells of today: PR
+    section 7, item 11), and another its cell and two more (PR 43, with a
+    second dense backlog cell).  The same statements, over the cells of today: PR
     38's 14 in their order before anything later, a file each that says
     what its entry says, and each over the cells whose programs have the
     stage."""
     names = _stages.NAMES
-    assert len(names) == 14 and len(ALL_STAGE_NAMES) == 15
+    assert len(names) == 14 and len(ALL_STAGE_NAMES) == 16
     per_layer = {m["name"]: m for m in _stages.BENCH["per_layer"]}
     e2e = {m["name"]: m for m in _stages.BENCH["end_to_end"]}
     listed = [m["name"] for m in _stages.BENCH["per_layer"]]
@@ -100,9 +112,12 @@ def test_every_stage_metric_has_its_file_and_its_cells():
     assert listed[first + 14:] == [
         RETENTION_STAGE, "step.retention_hbm_roofline.backlog",
         "retention.state_bytes_share.backlog",
-        "retention.tail_rows_per_lane.backlog"]  # appended since
+        "retention.tail_rows_per_lane.backlog",  # appended since (PR 41)
+        CONV_STAGE, "step.conv_roofline.backlog"]  # ... and since (PR 43)
+    conv = {"lfm2-pp5.gen.topics"}  # routed too, behind convolutions
     routed = {"lcf-ep32.gen.topics", "joyai-pp8.gen.topics",
-              "sdar-pp8.gen.topics"}
+              "sdar-pp8.gen.topics"} | conv
+    dense = {"sc2-3b.gen.backlog", "scb-1b.gen.backlog"}
     retention = {"brumby-pp8.gen.topics"}  # dense FFN, no kernel, no expert
     for name in ALL_STAGE_NAMES:
         metric, module = per_layer[name], _stages._reader(name)
@@ -116,12 +131,14 @@ def test_every_stage_metric_has_its_file_and_its_cells():
             assert cells == {"scb-1b.gen.rate"}
         elif ".retention." in name:
             assert cells == retention
+        elif ".conv." in name:
+            assert cells == conv
         elif "experts" in name:
             assert cells == routed
         elif "attend_kernel" in name:
-            assert cells == routed | {"sc2-3b.gen.backlog"}
+            assert cells == routed | dense
         elif ".ffn." in name:  # `sdar`'s every feed-forward is the experts'
-            assert cells == routed - {"sdar-pp8.gen.topics"} \
-                | {"sc2-3b.gen.backlog"} | retention
+            assert cells == routed - {"sdar-pp8.gen.topics"} | dense \
+                | retention
         else:
-            assert cells == routed | {"sc2-3b.gen.backlog"} | retention
+            assert cells == routed | dense | retention

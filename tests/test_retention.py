@@ -429,7 +429,7 @@ def test_the_mechanisms_scopes_are_one_stage(model):
             if stage == "retention"} == {
         "retention_state", "retention_tail", "retention_fold", "gate",
         "phi"}
-    assert stages.STAGES[-2:] == ("retention", "unscoped")
+    assert stages.STAGES[-3:] == ("retention", "conv", "unscoped")
     assert stages.stage_of("jit(f)/attention/qk_norm/mul") == "attention"
     _, config, params = model
     engine = _engine(config, params)
