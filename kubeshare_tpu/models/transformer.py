@@ -797,34 +797,44 @@ def latent_absorbed(attn, q_nope, attend, config: TransformerConfig):
     return jnp.einsum("bhcm,hmd->bcd", o, attn["wo"].astype(dtype))
 
 
-def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
-                         positions, config: TransformerConfig):
-    """:func:`latent_attend` in the absorbed form over a view that is
-    handed over a block of K = ``block_rows`` rows at a time:
+def latent_context_blocks(q_abs, q_rope, view_block, block_rows: int,
+                          positions, config: TransformerConfig):
+    """The normalised context [B, H, C, kv_lora_rank] (float32) of absorbed
+    queries ``q_abs`` [B, H, C, kv_lora_rank] / ``q_rope`` over a view that
+    is handed over a block of K = ``block_rows`` rows at a time:
     ``view_block(i)`` gives rows ``[i * K, (i + 1) * K)`` of every lane's
     view as (``view_c`` [B, K, kv_lora_rank], ``view_r`` [B, K, rope]),
     attended through :func:`attend_key_blocks` as far as the lanes
-    reach.  Same numbers as the whole view at once, up to the order of
-    the sums."""
+    reach: what :func:`latent_absorbed` takes as its ``attend``."""
     scale = latent_scale(config)
     f32 = jnp.float32
 
-    def attend(q_abs):
-        def scores_of(view_c, view_r):
-            return (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
-                               preferred_element_type=f32)
-                    + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
-                                 preferred_element_type=f32)) * scale
+    def scores_of(view_c, view_r):
+        return (jnp.einsum("bhcr,bvr->bhcv", q_abs, view_c,
+                           preferred_element_type=f32)
+                + jnp.einsum("bhce,bve->bhcv", q_rope, view_r,
+                             preferred_element_type=f32)) * scale
 
-        def context_of(weights, view_c, _):
-            return jnp.einsum("bhcv,bvr->bhcr", weights.astype(config.dtype),
-                              view_c, preferred_element_type=f32)
+    def context_of(weights, view_c, _):
+        return jnp.einsum("bhcv,bvr->bhcr", weights.astype(config.dtype),
+                          view_c, preferred_element_type=f32)
 
-        return attend_key_blocks(
-            view_block, block_rows, scores_of, context_of, positions,
-            q_abs.shape[1:2], q_abs.shape[3])
+    return attend_key_blocks(
+        view_block, block_rows, scores_of, context_of, positions,
+        q_abs.shape[1:2], q_abs.shape[3])
 
-    return latent_absorbed(attn, q_nope, attend, config)
+
+def latent_attend_blocks(attn, q_nope, q_rope, view_block, block_rows: int,
+                         positions, config: TransformerConfig):
+    """:func:`latent_attend` in the absorbed form over a view that is
+    handed over a key block at a time (:func:`latent_context_blocks`).
+    Same numbers as the whole view at once, up to the order of the
+    sums."""
+    return latent_absorbed(
+        attn, q_nope,
+        lambda q_abs: latent_context_blocks(
+            q_abs, q_rope, view_block, block_rows, positions, config),
+        config)
 
 
 def gated_ffn(ffn, y, dtype):

@@ -33,9 +33,13 @@ schedules at TOKEN granularity instead:
   Guarantee tenant's included) pays per admission ride-along is
   bounded by the budget — and warmup covers one mixed shape per
   existing prefill bucket, preserving the zero-recompile invariant.
-  Streams are bit-exact with ``mixed=False`` (the fused program is a
-  composition of the unchanged prefill/decode entry points over
-  disjoint writable blocks — hard-asserted by the tests);
+  The chunk rides the span's first pass over the weights (its rows and
+  the lanes' first rows through one layer loop: ``decode_span`` passes a
+  dispatch, ``weight_passes`` on the launch span; a model with a state
+  by slot keeps the chunk a pass of its own).  Streams are token for
+  token those of ``mixed=False`` (the two sides write disjoint blocks,
+  and a row's math is the split entry points' — hard-asserted by the
+  tests);
 - host/device overlap: dispatches synchronize ONLY when charging an
   ExecutionGuard (token accounting needs measured wall time);
   unguarded, the engine pipelines one step ahead — admission and the
@@ -134,9 +138,10 @@ from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       wire_block_bytes)
 from . import paged
 from .paged import (Recurrent, attend_path, experts_path, key_block_entries,
-                    paged_copy_block, paged_decode_loop, paged_decode_span,
-                    paged_diffusion_pass, paged_diffusion_prefill,
-                    paged_mixed_diffusion_step, paged_mixed_step,
+                    mixed_weight_passes, paged_copy_block, paged_decode_loop,
+                    paged_decode_span, paged_diffusion_pass,
+                    paged_diffusion_prefill, paged_mixed_diffusion_step,
+                    paged_mixed_step,
                     paged_mixed_verify_step, paged_prefill_step,
                     paged_spec_loop, paged_upload_block,
                     paged_verify_span, tail_pages)
@@ -932,6 +937,12 @@ class ServingEngine:
         self._retention = config.block == "retention"
         self._conv = config.conv_layers > 0
         self._stateful = _carries_state(config)
+        # a mixed dispatch's chunk rides the span's first pass over the
+        # layer stack unless a state by slot (or the sharded context's own
+        # composition) keeps it a pass of its own
+        self._mixed_fused = not self._stateful and self._sharded is None
+        self._mixed_passes = mixed_weight_passes(ec.decode_span,
+                                                 not self._mixed_fused)
         self.states = (init_retention_states(config, ec.num_slots)
                        if self._retention else
                        init_conv_states(config, ec.num_slots)
@@ -1076,6 +1087,10 @@ class ServingEngine:
         self.mixed_steps = 0
         self.verify_steps = 0
         self.mixed_verify_steps = 0
+        # passes over the layer stack the dispatched programs made, by
+        # plan kind (:meth:`_weight_passes`; a loop's when it is consumed,
+        # with its units)
+        self.weight_passes: Dict[str, int] = {}
         # device-resident loop counters: launches (fused dispatches)
         # and the span-units those launches actually ran.  Each unit is
         # one decode_span's worth of work and is absorbed into
@@ -1416,9 +1431,9 @@ class ServingEngine:
                   p_temp, p_key, d_tables, d_lengths, d_active,
                   d_tokens, d_temps, d_keys, d_budgets):
             # the stall-free fused dispatch: one bounded prefill chunk
-            # + the full decode span, ONE program — composed from the
-            # exact prefill/decode entry points above, so both sides'
-            # math (and therefore the emitted streams) are unchanged.
+            # + the full decode span, ONE program, the chunk riding the
+            # span's first pass over the weights; a row's math (and
+            # therefore the emitted streams) is the entry points' above.
             # Compiles one shape per prefill bucket width (warmed).
             return paged_mixed_step(
                 w, cfg, pick_rows, span, eos, pk, pv, p_table, p_start,
@@ -2293,6 +2308,16 @@ class ServingEngine:
         dispatches.add({"kind": "spec_loop", **plabel},
                        self.spec_loop_launches)
         dispatches.add({"kind": "cow_copy", **plabel}, self.cow_copies)
+        weight_passes = MetricFamily(
+            "kubeshare_serving_weight_passes_total",
+            "Passes over the layer stack the dispatched step programs "
+            "made, by plan kind: decode_span a decode dispatch, 1 a "
+            "prefill chunk, decode_span a mixed dispatch whose chunk "
+            "rides the span's first pass and decode_span + 1 one that "
+            "runs the chunk and the span back to back (a model with a "
+            "state by slot).", "counter")
+        for kind, passes in sorted(self.weight_passes.items()):
+            weight_passes.add({"kind": kind, **plabel}, passes)
         loop_units = MetricFamily(
             "kubeshare_serving_loop_units_total",
             "Decode span-units executed inside device-resident loop "
@@ -2678,7 +2703,7 @@ class ServingEngine:
         view_rows.add({"kind": "configured", **plabel},
                       self.view_rows_configured)
         view_rows.add({"kind": "held", **plabel}, self.view_rows_held)
-        return [req, blocks, tokens, dispatches, loop_units,
+        return [req, blocks, tokens, dispatches, weight_passes, loop_units,
                 moe_assign, moe_touched, moe_tiles, moe_tile_rows, view_rows,
                 diff_passes, diff_rows, diff_tokens, diff_blocks,
                 *retention, spec_loop_units, exit_reason, depth_summary,
@@ -3527,13 +3552,21 @@ class ServingEngine:
                      "chunk": plan.chunk[1] if plan.chunk else 0,
                      "attend": self._attend_of(plan),
                      "program": self._program_of(plan)}
+            passes = self._weight_passes(plan)
+            if passes is not None:
+                attrs["weight_passes"] = passes
+                self._count_weight_passes(plan.kind, passes)
             if self.model_config.routed:
                 # every block kind's last layer is an expert layer; the
-                # widest pass over it: the lanes' rows, or the chunk's
+                # widest pass over it: the lanes' rows, or the chunk's, or
+                # both side by side in a fused mixed dispatch's first step
+                lane_rows = (len(self._slots) * step_rows
+                             * bool(attrs["lanes"]))
                 attrs["experts"] = experts_path(
                     self.params["layers"][-1]["moe"],
-                    max(len(self._slots) * step_rows * bool(attrs["lanes"]),
-                        attrs["chunk"]))
+                    lane_rows + attrs["chunk"]
+                    if plan.kind == "mixed" and self._mixed_fused
+                    else max(lane_rows, attrs["chunk"]))
             self.view_rows_held += attrs["rows"]
             # whole key blocks, which divide the view
             self.view_rows_reached += (
@@ -3559,6 +3592,25 @@ class ServingEngine:
         elif plan.kind in ("loop", "spec_loop"):
             widths.append(self._loop_k)
         return stages.program_name(plan.kind, *widths)
+
+    def _weight_passes(self, plan: _StepPlan) -> Optional[int]:
+        """The passes over the layer stack ``plan``'s program makes: a
+        chunk's (prefill, verify, a diffusion pass) 1, a span's
+        ``decode_span``, and a mixed dispatch's the span's alone where the
+        chunk rides its first (``paged.mixed_weight_passes``), else the
+        two parts' sum.  None for a device-resident loop, whose units are
+        data: counted when it is consumed."""
+        span = self.engine_config.decode_span
+        if plan.kind in ("loop", "spec_loop"):
+            return None
+        if plan.kind == "mixed":
+            return self._mixed_passes
+        if plan.kind.startswith("mixed"):
+            return 2
+        return span if plan.kind == "decode" else 1
+
+    def _count_weight_passes(self, kind: str, passes: int) -> None:
+        self.weight_passes[kind] = self.weight_passes.get(kind, 0) + passes
 
     def _attend_of(self, plan: _StepPlan) -> str:
         """What ``plan``'s decode lanes' attention runs ("kernel",
@@ -4085,7 +4137,8 @@ class ServingEngine:
         counts = self._keep_cache(pk, pv, counts)
         span = self.engine_config.decode_span
         self._routing_inflight = (
-            counts, segment.shape[1] + len(tokens) * span, 1 + span)
+            counts, segment.shape[1] + len(tokens) * span,
+            self._mixed_passes)
         self.prefill_chunks += 1
         self.decode_steps += 1
         self.mixed_steps += 1
@@ -4304,6 +4357,7 @@ class ServingEngine:
                 span = self.engine_config.decode_span
                 self.decode_steps += units
                 self.loop_units += units
+                self._count_weight_passes("loop", units * span)
                 self._charge_collectives(
                     "decode_span", "decode",
                     lanes=self.engine_config.num_slots,
@@ -4742,6 +4796,7 @@ class ServingEngine:
         w = 1 + ec.draft_len
         self.verify_steps += units
         self.spec_loop_units += units
+        self._count_weight_passes("spec_loop", units)
         for _ in range(units):
             self._charge_collectives(
                 "verify_span", "verify", lanes=ec.num_slots, width=w)

@@ -70,13 +70,19 @@ points:
   (the host's cue that the lane set changed and scheduling must run);
 - :func:`paged_mixed_step`: the stall-free mixed dispatch — ONE program
   that consumes one bounded prefill chunk for one filling slot AND runs
-  a full decode span for every active lane.  It is a pure composition
-  of the two entry points above (prefill first, then the span), so the
-  per-lane math is op-for-op the split dispatches' math: the prefill
-  lane's blocks are disjoint from every decode lane's writable blocks
-  (shared prefix blocks are read-only to both — divergence is
-  copied-on-write before any append), so fusing the phases cannot
-  change either side's values, only the number of device round-trips;
+  a full decode span for every active lane.  The chunk rides the span's
+  FIRST pass over the weights: its rows and the lanes' first rows go
+  through one layer loop side by side (:func:`_mixed_first_step`), the
+  attention alone a group at a time (:func:`_attend_rows`), and the scan
+  over the span's other steps follows — ``span`` passes over the weights a
+  dispatch, not ``span + 1``.  The prefill lane's blocks are disjoint from
+  every decode lane's writable blocks (shared prefix blocks are read-only
+  to both — divergence is copied-on-write before any append), so both
+  groups' rows are written before either attends and neither reads a row
+  the other writes.  A model with a state by slot still runs the two
+  entry points above back to back (:func:`paged_mixed_back_to_back`:
+  prefill first, then the span), op for op the split dispatches' math —
+  the composition the fused step is held to, token for token;
 - :func:`paged_verify_span`: the speculative draft-verify dispatch —
   one width-W chunk scores every lane's self-drafted tokens at once,
   picks what sequential decoding would emit at each position (each
@@ -117,7 +123,7 @@ from ..models.decoding import (
 )
 from ..models.transformer import (TransformerConfig, _rms_norm,
                                   attend_reach, gqa_moe_layers, gqa_qkv,
-                                  latent_absorbed, latent_attend_blocks,
+                                  latent_absorbed, latent_context_blocks,
                                   latent_layers, latent_qkv, latent_scale,
                                   retention_gate, retention_layers,
                                   retention_qkv)
@@ -454,6 +460,47 @@ def _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables, positions,
                           pool_k.shape[2], positions, window, scale)
 
 
+class RowGroup(NamedTuple):
+    """One group of a fused step's rows (:func:`_mixed_first_step`): ``B``
+    lanes of ``C`` rows each, lane-major along the step's one row axis,
+    which attend ``tables`` [B, T] at ``positions`` [B, C]."""
+
+    tables: jax.Array
+    positions: jax.Array
+
+
+def _attend_rows(tables, positions, attend, *queries):
+    """A layer's attention, which is ``attend(tables, positions, *queries)
+    -> context`` over lanes that each hold their own rows: ``tables``
+    [B, T], ``positions`` [B, C], ``queries`` and the context [B, H, C, .].
+
+    A fused step hands the layers a tuple of :class:`RowGroup` as their
+    ``tables``: its rows are ONE lane [1, R] of all the groups' rows side by
+    side, so everything that is a row's own (the norms, the projections,
+    rope by the row's position, the row's write, the feed-forward) runs
+    once over all of them, and only here do the groups part: each group's
+    rows are cut out of ``queries`` [1, H, R, .], laid out as its lanes
+    hold them and attended over its own tables by whatever
+    :func:`attend_path` chooses for its width, and the contexts go back
+    side by side."""
+    if not isinstance(tables, tuple):
+        return attend(tables, positions, *queries)
+    out, lo = [], 0
+    for group in tables:
+        b, c = group.positions.shape
+
+        def lanes_of(q, lo=lo):  # [1, H, R, w] -> [B, H, C, w]
+            h, w = q.shape[1], q.shape[3]
+            return q[0, :, lo:lo + b * c].reshape(h, b, c, w).transpose(
+                1, 0, 2, 3)
+
+        o = attend(group.tables, group.positions, *map(lanes_of, queries))
+        out.append(o.transpose(1, 0, 2, 3).reshape(
+            1, o.shape[1], b * c, o.shape[3]))
+        lo += b * c
+    return jnp.concatenate(out, axis=2)
+
+
 @jax.named_scope("mlp")
 def _moe_or_mlp(layer, config: TransformerConfig, y):
     """The post-attention feed-forward shared by both paged steps —
@@ -486,9 +533,15 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
     written at ``(blk, off)`` [B, C] first, then attend the lane's view
     under the per-query causal band (:func:`_attend_view`).  ``live``
     [B, C] marks the rows that are real (not an idle lane's, not a
-    chunk's padding); the dense block computes every row alike."""
+    chunk's padding); the dense block computes every row alike.  A fused
+    step's ``tables`` are its row groups (:func:`_attend_rows`)."""
     dtype = config.dtype
     use_rope = config.positional == "rope"
+
+    def attend(tables, positions, q):  # of the layer the loop is at
+        return _attend_view(q, pool_k, pool_v, layer_idx, tables, positions,
+                            config.attention_window).astype(dtype)
+
     for layer_idx, layer in enumerate(params["layers"]):
         y = _rms_norm(x, layer["norm1"]["scale"])
         with jax.named_scope("attention"):
@@ -506,8 +559,7 @@ def _dense_layers(params, config: TransformerConfig, pool_k, pool_v,
             pool_k, pool_v, layer_idx, blk, off,
             k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
         with jax.named_scope("attention"):
-            o = _attend_view(q, pool_k, pool_v, layer_idx, tables, positions,
-                             config.attention_window).astype(dtype)
+            o = _attend_rows(tables, positions, attend, q)
             x = x + jnp.einsum("bhsk,hkd->bsd", o,
                                layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"])
@@ -539,14 +591,12 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
     ``max_request_len`` view at once: a decode span of 4 steps over 32
     lanes of 600-3000 rows 66.3 against 125.0 ms, a mixed dispatch 117.2
     against 237.9 (PR 27).
+    A fused step's ``tables`` are its row groups (:func:`_attend_rows`).
     The rows ``live`` [B, C] says are dead choose no expert.  Also
     returns the step's routing counts int32[7]: the expert layers'
     (ops/moe.py ROUTING_COUNTS) summed, then the rows that chose."""
     block_size = pool_k.shape[3]
-    entries = key_block_entries(tables.shape[1], block_size)
     rope = config.qk_rope_head_dim
-    kernel = attend_path(config.block, positions.shape[1], tables.shape[1],
-                         pool_k, pool_v, config.head_dim) == "kernel"
 
     def attend(sub, attn, y):
         nonlocal pool_k, pool_v
@@ -555,24 +605,32 @@ def _latent_layers(params, config: TransformerConfig, pool_k, pool_v,
         pool_k, pool_v = _write_rows(
             pool_k, pool_v, sub, blk, off,
             c_kv[:, :, None, :], k_rope[:, :, None, :])
-        if kernel:
-            return latent_absorbed(
-                attn, q_nope,
-                lambda q_abs: paged_latent_decode_attention(
+
+        def context(tables, positions, q_abs, q_rope):
+            if attend_path(config.block, positions.shape[1], tables.shape[1],
+                           pool_k, pool_v, config.head_dim) == "kernel":
+                return paged_latent_decode_attention(
                     q_abs[:, :, 0], q_rope[:, :, 0], pool_k, pool_v, sub,
                     tables, positions[:, 0], scale=latent_scale(config),
-                    interpret=_kernel_mode() == "interpret")[:, :, None],
-                config)
+                    interpret=_kernel_mode() == "interpret")[:, :, None]
+            entries = key_block_entries(tables.shape[1], block_size)
 
-        def view_block(i):
-            part = jax.lax.dynamic_slice_in_dim(
-                tables, i * entries, entries, axis=1)
-            view_c, view_r = _layer_views(pool_k, pool_v, sub, part, rope)
-            return view_c[:, 0], view_r[:, 0]
+            def view_block(i):
+                part = jax.lax.dynamic_slice_in_dim(
+                    tables, i * entries, entries, axis=1)
+                view_c, view_r = _layer_views(pool_k, pool_v, sub, part,
+                                              rope)
+                return view_c[:, 0], view_r[:, 0]
 
-        return latent_attend_blocks(
-            attn, q_nope, q_rope, view_block, entries * block_size,
-            positions, config)
+            return latent_context_blocks(
+                q_abs, q_rope, view_block, entries * block_size, positions,
+                config).astype(config.dtype)
+
+        return latent_absorbed(
+            attn, q_nope,
+            lambda q_abs: _attend_rows(tables, positions, context, q_abs,
+                                       q_rope),
+            config)
 
     x, counts = latent_layers(params, x, config, attend, live)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
@@ -620,7 +678,8 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     the paged kernel where :func:`attend_path` says it can run, as one
     row a lane does under the causal mask; the prefill chunk, rows of
     many blocks, runs the key-block loop.  Also returns the step's
-    routing counts int32[7], as :func:`_latent_layers` does.
+    routing counts int32[7], as :func:`_latent_layers` does.  A fused
+    step's ``tables`` are its row groups (:func:`_attend_rows`).
 
     Where the model's layers name their operator
     (``TransformerConfig.layer_operators``) the pool holds the attention
@@ -636,6 +695,8 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     rows (``ops/short_conv.py``); a lane with no live row (an idle lane, a
     slot between two chunks of its prompt) keeps what it held.  The new
     states come back as the fifth result."""
+    # a fused step's groups bring their own positions, which ARE their
+    # reach: generation by diffusion has a mixed entry point of its own
     reach = attend_reach(config, positions)
     dtype = config.dtype
     h_kv = config.kv_heads
@@ -652,9 +713,13 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
                 k = k.reshape(*k.shape[:2], *pool_k.shape[2::2])
                 v = v.reshape(*v.shape[:2], *pool_v.shape[2::2])
         pool_k, pool_v = _write_rows(pool_k, pool_v, row, blk, off, k, v)
+
+        def view(tables, reach, q):
+            return _attend_view(q, pool_k, pool_v, row, tables, reach, None,
+                                config.diffusion_block, scale)
+
         with jax.named_scope("attention"):
-            o = _attend_view(q, pool_k, pool_v, row, tables, reach, None,
-                             config.diffusion_block, scale)
+            o = _attend_rows(tables, reach, view, q)
             return o if paired == 1 else _paired_context(o, paired, h_kv)
 
     # no convolution, nothing carried: `conv` is never called
@@ -1578,6 +1643,70 @@ def paged_mixed_verify_step(
     return p_picked, picked, accepts, pk, pv
 
 
+def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
+                      p_table, p_start, p_tokens, p_last_row, d_tables,
+                      d_lengths, d_active, d_tokens):
+    """A mixed dispatch's fused first step: the chunk's ``W`` rows and every
+    decode lane's row of the span's step 0, ``W + S`` rows through ONE
+    layer loop — one pass over the weights where the chunk and the step
+    made two.  The rows are two :class:`RowGroup` s of one lane
+    (:func:`_attend_rows`): the embedding, the norms, the projections, rope
+    by each row's own position, :func:`_write_rows`, the output projection
+    and the feed-forward (a routed block's router and experts: ONE grouping
+    over all the live rows, so an expert both groups touch is read once)
+    run once over all of them, and only the attention runs a group at a
+    time — the chunk's queries over ``p_table`` on the key-block loop, the
+    lanes' one row each over ``d_tables`` through the paged kernel where it
+    can run.  Both groups' K/V rows are written before either attends: the
+    filling slot is no decode lane, the groups write disjoint blocks and
+    share read-only prefix blocks only, so neither reads a row the other
+    writes.  Returns (logits [S + P, vocab] float32 — the lanes' rows, then
+    each chunk's ``p_last_row`` — pool_k, pool_v, the routing counts or
+    None): the head runs over those rows alone."""
+    dtype = config.dtype
+    bs = pool_k.shape[3]
+    lanes, width = p_tokens.shape
+    p_positions = p_start[:, None] + jnp.arange(width)[None, :]  # [P, W]
+    groups = (RowGroup(p_table, p_positions),
+              RowGroup(d_tables, d_lengths[:, None]))
+
+    def side_by_side(chunk, steps):  # [P, W], [S] -> [1, P * W + S]
+        return jnp.concatenate([chunk.reshape(-1), steps])[None, :]
+
+    positions = side_by_side(p_positions, d_lengths)
+    d_blk = jnp.take_along_axis(
+        d_tables, (d_lengths // bs)[:, None], axis=1)[:, 0]
+    # an idle lane's row lands in the scratch block 0
+    blk = side_by_side(
+        jnp.take_along_axis(p_table, p_positions // bs, axis=1),
+        jnp.where(d_active, d_blk, 0))
+    x = params["embed"][side_by_side(p_tokens, d_tokens)].astype(dtype)
+    if config.positional != "rope":
+        x = x + params["pos_embed"][positions].astype(dtype)
+    # a chunk's rows after its last real one are padding
+    live = side_by_side(
+        jnp.arange(width)[None, :] <= p_last_row[:, None], d_active)
+    x, pool_k, pool_v, counts, _ = _run_layers(
+        params, config, pool_k, pool_v, groups, positions, blk,
+        positions % bs, x, live)
+
+    with jax.named_scope("lm_head"):
+        last = jnp.arange(lanes) * width + p_last_row
+        head_in = jnp.concatenate([x[0, lanes * width:], x[0, last]])
+        head_in = _rms_norm(head_in, params["final_norm"]["scale"],
+                            config.norm_eps)
+        logits = (head_in
+                  @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    return logits, pool_k, pool_v, counts
+
+
+def mixed_weight_passes(span: int, back_to_back: bool) -> int:
+    """The passes over the layer stack a mixed dispatch makes: the span's
+    steps, and one more where the chunk has a pass of its own
+    (``back_to_back``: a model with a state by slot)."""
+    return span + 1 if back_to_back else span
+
+
 def paged_mixed_step(
     params,
     config: TransformerConfig,
@@ -1615,24 +1744,100 @@ def paged_mixed_step(
     into one program keeps every decode lane advancing while the
     prompt fills, and pays ONE dispatch where the split path pays two.
 
-    The composition is deliberately nothing but the two existing entry
-    points run back to back — :func:`paged_prefill_step` on the
-    prefill lane, then :func:`paged_decode_span` over the decode lanes
-    — so the per-row-position attention math is reused unchanged and
-    the emitted streams are bit-exact with the split dispatches:
-    the prefill lane writes only its own (fresh or CoW-private)
-    blocks, every decode lane writes only its own current block, and
-    the prefill-then-decode order inside the program matches the split
-    scheduler's dispatch order.  Returns
+    The chunk rides the span's FIRST pass over the weights
+    (:func:`_mixed_first_step`: the chunk's rows and the lanes' first
+    rows through one layer loop, the attention alone a group at a time),
+    and the scan over the span's remaining ``span - 1`` steps follows,
+    unchanged: ``span`` passes over the weights a dispatch, where the two
+    entry points back to back make ``span + 1``
+    (:func:`mixed_weight_passes`).  The fused step IS step 0 of the span:
+    the lanes' pick under ``d_keys[:, 0]``, their lengths, budgets and EOS
+    move as :func:`paged_decode_span`'s body moves them, and the chunk's
+    first-token pick comes from the same head pass.  A row's values are
+    what the split programs give up to the rounding of a sum over a batch
+    of another height; the row positions, the masks, the keys consumed and
+    what is written where are the same.  A model with a state by slot
+    (``recurrent``) keeps the back-to-back composition
+    (:func:`paged_mixed_back_to_back`): a chunk's state crosses its rows in
+    order while a lane's is by slot.  Returns
     (p_picked [1], emitted [span, S], pool_k, pool_v); ``p_picked`` is
     meaningful only when the chunk is the prompt's final one (the
     fused first-token pick, same as the standalone prefill step); with
-    ``routing`` the routing counts of the chunk and the span, summed,
-    come last but for the :class:`Recurrent` of a model with a state by
-    slot, which goes through the chunk (a 'retention' block's fold with it)
-    and then the span (and its lanes' folds): the chunk's slot is no lane
-    of the span, so the span leaves what the chunk wrote there alone.
+    ``routing`` the dispatch's routing counts come last.
     """
+    if recurrent is not None:
+        return paged_mixed_back_to_back(
+            params, config, pick_fn, span, eos, pool_k, pool_v, p_table,
+            p_start, p_tokens, p_last_row, p_temp, p_key, d_tables,
+            d_lengths, d_active, d_tokens, d_temps, d_keys, d_budgets,
+            routing=routing, recurrent=recurrent, p_folded=p_folded,
+            p_slot=p_slot, d_folded=d_folded)
+    logits, pk, pv, counts = _mixed_first_step(
+        params, config, pool_k, pool_v, p_table, p_start, p_tokens,
+        p_last_row, d_tables, d_lengths, d_active, d_tokens)
+    s = d_tokens.shape[0]
+    with jax.named_scope("sample"):
+        p_picked = pick_fn(logits[s:], p_temp, p_key)
+        first = pick_fn(logits[:s], d_temps, d_keys[:, 0])
+    emitted = first[None]
+    if span > 1:
+        # step 0's end, as paged_decode_span's body leaves it; the scan's
+        # step j is the span's step j + 1: its budgets are one emission
+        # short
+        cont = d_active & (1 < d_budgets)
+        if eos is not None:
+            cont = cont & (first != eos)
+        rest, pk, pv, *more = paged_decode_span(
+            params, config, pick_fn, span - 1, eos, pk, pv, d_tables,
+            d_lengths + d_active.astype(jnp.int32), cont, first, d_temps,
+            d_keys[:, 1:], d_budgets - 1, routing=routing)
+        emitted = jnp.concatenate([emitted, rest])
+        if routing:
+            counts = counts + more[0]
+    return _step_outputs(routing, counts, None, p_picked, emitted, pk, pv)
+
+
+def paged_mixed_back_to_back(
+    params,
+    config: TransformerConfig,
+    pick_fn,
+    span: int,
+    eos,
+    pool_k,
+    pool_v,
+    p_table,
+    p_start,
+    p_tokens,
+    p_last_row,
+    p_temp,
+    p_key,
+    d_tables,
+    d_lengths,
+    d_active,
+    d_tokens,
+    d_temps,
+    d_keys,
+    d_budgets,
+    routing: bool = False,
+    recurrent: Optional[Recurrent] = None,
+    p_folded=None,
+    p_slot=None,
+    d_folded=None,
+) -> Tuple[jax.Array, ...]:
+    """:func:`paged_mixed_step` as nothing but the two entry points run
+    back to back — :func:`paged_prefill_step` on the prefill lane, then
+    :func:`paged_decode_span` over the decode lanes, ``span + 1`` passes
+    over the weights — so the per-row-position math is the split
+    dispatches' op for op: the prefill lane writes only its own (fresh or
+    CoW-private) blocks, every decode lane writes only its own current
+    block, and the prefill-then-decode order inside the program matches
+    the split scheduler's dispatch order.  What a model with a state by
+    slot runs: its :class:`Recurrent` goes through the chunk (a
+    'retention' block's fold with it) and then the span (and its lanes'
+    folds) — the chunk's slot is no lane of the span, so the span leaves
+    what the chunk wrote there alone — and comes back last, after the
+    chunk's and the span's routing counts, summed.  And what the fused
+    step is held to, token for token (``tests/test_mixed_fused.py``)."""
     p_logits, pk, pv, *p_counts = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row,

@@ -53,6 +53,20 @@ def pytest_configure(config):
     )
 
 
+@pytest.fixture(scope="module", autouse=True)
+def executables_let_go():
+    """Every file's executables are let go with the file.  A tier-1 worker
+    runs half a dozen files in one process and each loaded executable keeps
+    memory maps of its own (`tests/test_chaos.py` alone leaves 19,000, the
+    kernel allows a process 65,530): whichever file came late in a worker
+    that had the large ones crashed where XLA loads or stores an executable
+    (`tests/test_chaos.py`'s fleet warm-up, on this tree and on its parent
+    alike, PR 44).  The persistent cache keeps what a later file compiles
+    again cheap."""
+    yield
+    jax.clear_caches()
+
+
 def _build_native() -> None:
     """Build the native runtime, interposer fixtures, and TSAN binaries so a
     fresh checkout runs the full isolation suite instead of silently

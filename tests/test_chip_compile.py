@@ -63,12 +63,15 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, args, sharding, donate_argnums=()):
+def _lower(fn, args, sharding, donate_argnums=()):
     shaped = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         args)
-    return jax.jit(fn, donate_argnums=donate_argnums).lower(
-        *shaped).compile()
+    return jax.jit(fn, donate_argnums=donate_argnums).lower(*shaped)
+
+
+def _compile(fn, args, sharding, donate_argnums=()):
+    return _lower(fn, args, sharding, donate_argnums).compile()
 
 
 def _flash_args(b, h, h_kv, s, d):
@@ -340,13 +343,30 @@ def _stages_hold(config, args, text):
 
 
 # temporaries of the same programs on the key-block loop over a staged
-# slab (the parent of PR 30, compiled the same way)
+# slab (the parent of PR 30, compiled the same way).  The mixed programs'
+# are those of the fused first step (PR 44): 297,772,032 and 854,657,024 B
+# as first compiled, against 243,377,664 and 757,508,608 B back to back.
+# The span's scan has the compiler lay every layer's `wq` and `wo` out anew
+# before it (8.4 MB each at 1 B: 201 MB), and those copies, made after the
+# chunk's pass when the chunk had one of its own, now stand through the
+# first step, which reads them too; the rows' own arrays are 272 or 288
+# rows where they were 256.  No pool-shaped array is copied.
 LOOP_TEMPORARIES = {
     ("starcoderbase-1b", "decode"): 230_034_432,
-    ("starcoderbase-1b", "mixed"): 257_891_840,
+    ("starcoderbase-1b", "mixed"): 300 << 20,
     ("starcoder2-3b", "decode"): 719_114_240,
-    ("starcoder2-3b", "mixed"): 777_756_160,
+    ("starcoder2-3b", "mixed"): 860 << 20,
 }
+
+
+def _kernel_calls_in_entry(text):
+    """(in the entry computation, in every other) Pallas kernel calls of a
+    compiled program: a mixed program's fused first step is written out in
+    the entry computation, its span's remaining steps are a loop's body."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    call = 'custom_call_target="tpu_custom_call"'
+    return entry.count(call), text.count(call) - entry.count(call)
 
 
 @pytest.mark.parametrize("kind", ["decode", "mixed"])
@@ -361,7 +381,11 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     is gathered or scored for every lane, and nothing is as long as the
     view (``max_request_len``, 4096 rows); the chunk's one lane still
     attends a key block at a time; and the temporaries are no more than
-    the loop's."""
+    the loop's.  The mixed program's first step carries the chunk's rows
+    and the lanes' first rows through one layer loop (PR 44): its lanes
+    attend through the kernel there too, one call a layer beside the
+    chunk's key-block loop, and the span's other steps are the scan's one
+    call a layer."""
     import re
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
@@ -371,8 +395,8 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
     _, blocks, h_kv, block_size, d = args[1].shape
     assert table_width * block_size == 4096
     assert not re.search(r"(f32|bf16)\[[0-9,]*\b4096\b[0-9,]*\]", text)
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == config.n_layers
+    assert _kernel_calls_in_entry(text) == (
+        config.n_layers * (kind == "mixed"), config.n_layers)
     assert not re.search(rf"bf16\[{blocks},{h_kv},{block_size},{d}\]", text)
     assert not re.search(rf"f32\[{lanes},[0-9,]*,{KEY_BLOCK}\]", text)
     chunk_scores = re.search(rf"f32\[[0-9,]*\b256,{KEY_BLOCK}\]", text)
@@ -399,14 +423,18 @@ def _kernel_calls(text):
 
 def _experts_through_the_grouped_kernel(config, text, calls):
     """Each expert layer's tiles are ONE kernel call a pass over the
-    layers (a mixed program makes two passes: its lanes', its chunk's),
+    layers (a mixed program's text has two: its first step's, over the
+    chunk's rows and the lanes' together, and the scan body's),
     ``calls`` in all, and the tile loop is gone: no float32 accumulator
-    of every row is carried (``f32[rows + 1, d]``), no tile of rows is
-    gathered a trip."""
+    of every row is carried (``f32[rows + 1, d]`` under the experts' scope:
+    a fused first step's head reads 32 lanes' rows and the chunk's one),
+    no tile of rows is gathered a trip."""
     import re
 
     assert _kernel_calls(text)[1] == calls
-    assert not re.search(rf"f32\[(33|129|513),{config.d_model}\]", text)
+    carried = re.compile(rf"f32\[(33|129|513|545),{config.d_model}\]")
+    assert not [line[:160] for line in text.splitlines()
+                if carried.search(line) and "/experts/" in line]
 
 
 # the routed cells' expert layers: (rows, d, expert width, experts held,
@@ -458,14 +486,26 @@ def test_grouped_experts_compile_as_kernel(one_chip, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
-def _attends_through_the_latent_kernel(config, text):
+def _attends_through_the_latent_kernel(config, text, kind):
     """The decode step's attention sub-layers each run the paged kernel
     (the span is a loop of one step, the chunk attends by key block), and
     no key block of every lane's table entries is gathered: 32 lanes x 32
-    entries of one 16-row page, latent or rotary."""
+    entries of one 16-row page, latent or rotary.  A mixed program's fused
+    first step (PR 44), written out before the loop, runs the kernel for
+    its lanes' rows too, a call a sub-layer beside the chunk's key-block
+    loop; the kernels left are the experts'."""
     import re
 
-    assert _kernel_calls(text)[0] == config.attn_sublayers
+    first, rest = _kernel_calls_in_entry(text)
+    experts = _kernel_calls(text)[1]
+    assert first + rest - experts == config.attn_sublayers * (
+        2 if kind == "mixed" else 1)
+    assert first == (config.attn_sublayers + config.expert_layers) * (
+        kind == "mixed")
+    # the chunk's 512 queries' scores over a key block: still the loop
+    chunk_scores = re.search(
+        rf"f32\[1,{config.n_heads},512,{KEY_BLOCK}\]", text)
+    assert bool(chunk_scores) == (kind == "mixed")
     assert not re.search(r"bf16\[1024,16,(512|128)\]", text)
 
 
@@ -495,7 +535,7 @@ def _rows_written_whole(args, text):
 
 
 @pytest.mark.parametrize("kind,temporaries", [("decode", 157_404_160),
-                                              ("mixed", 348_711_936)])
+                                              ("mixed", 364_149_760)])
 def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
                                                 temporaries):
     """One expert-parallel rank at the published widths, built as on the
@@ -518,13 +558,17 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     in 512-column blocks) the loop's accumulator and gathered tiles go:
     157,574,656 / 350,972,416; with the packed rotary rows written whole
     (PR 42) the 16 row-at-a-time loops of the write and what they carried
-    go: 157,404,160 / 348,711,936."""
+    go: 157,404,160 / 348,711,936; with the chunk riding the span's first
+    pass (PR 44: ONE layer loop over the chunk's 512 rows and the lanes' 32,
+    the experts' tiles of both one call a layer) the first step's arrays
+    are 544 rows where the chunk's were 512: 364,149,760 the mixed
+    program."""
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("longcat-flash-chat", kind)
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes == temporaries, memory
     _no_row_of_every_expert(config, args, text)
-    _attends_through_the_latent_kernel(config, text)
+    _attends_through_the_latent_kernel(config, text, kind)
     _experts_through_the_grouped_kernel(
         config, text, config.expert_layers * (2 if kind == "mixed" else 1))
     _rows_written_whole(args, text)
@@ -564,7 +608,7 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
     assert memory.temp_size_in_bytes < 128 << 20, memory
     _no_row_of_every_expert(config, args, text)
-    _attends_through_the_latent_kernel(config, text)
+    _attends_through_the_latent_kernel(config, text, kind)
     _experts_through_the_grouped_kernel(
         config, text, config.expert_layers * (2 if kind == "mixed" else 1))
     with_experts = set(re.findall(r"(?:bf16|f32)\[256,[0-9,]+\]", text))
@@ -875,3 +919,52 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     by_stage = _stages_hold(config, args, text)
     assert {"conv", "attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
+
+
+# ---------------------------------------------------------------------------
+# who keeps the chunk a pass of its own: the mixed programs of the models
+# with a state by slot and of the diffusion entry are the parent's
+# ---------------------------------------------------------------------------
+
+# sha256 (16 hex) of the lowered mixed programs at the cells' sizes, a
+# Pallas kernel's serialised body left out (it holds the checkout's paths
+# and the calling function's name), as PR 43 lowers them and PR 44 still
+# does (the whole texts compared once, checkout against checkout).  A PR
+# that means to change one of these programs — the follow-up that lets
+# their chunks ride the first pass too — pins its own.
+UNFUSED_MIXED = {"brumby-14b-base": "dd09e8f25a5d17c5",
+                 "lfm2-24b-a2b": "1caae13d2072dbbe",
+                 "sdar-30b-a3b-chat": "6c71ecda30d19467"}
+
+
+def _lowered(fn, args, sharding, donate_argnums):
+    import re
+
+    text = _lower(fn, args, sharding, donate_argnums).as_text()
+    return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+
+
+@pytest.mark.parametrize("name", sorted(UNFUSED_MIXED))
+def test_unfused_mixed_programs_are_the_parents(one_chip, monkeypatch, name):
+    """``paged_mixed_step`` fuses its first step by ``recurrent is None``
+    alone: a model with a state by slot runs the back-to-back composition,
+    lowered to the very text ``paged_mixed_back_to_back`` gives and to the
+    text it had before the fused step existed; the diffusion entry
+    (``paged_mixed_diffusion_step``) is another program, untouched."""
+    import hashlib
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    if name == "sdar-30b-a3b-chat":
+        _, fn, args = _cell_case(name, "mixed_diffusion")
+        text = _lowered(fn, args, one_chip, (1, 2))
+    else:
+        case = _retention_case if name == "brumby-14b-base" else _conv_case
+        _, fn, args = case("mixed")
+        text = _lowered(fn, args, one_chip, (1, 2, 3))
+        # the case builders call this module's name for it
+        monkeypatch.setitem(globals(), "paged_mixed_step",
+                            paged.paged_mixed_back_to_back)
+        _, fn, args = case("mixed")
+        assert _lowered(fn, args, one_chip, (1, 2, 3)) == text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == UNFUSED_MIXED[name]
