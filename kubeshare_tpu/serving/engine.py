@@ -137,6 +137,7 @@ from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
 from . import paged
+from .packed_args import PackedProgram
 from .paged import (Recurrent, attend_path, experts_path, key_block_entries,
                     mixed_weight_passes, paged_copy_block, paged_decode_loop,
                     paged_decode_span, paged_diffusion_pass,
@@ -860,16 +861,32 @@ class _Slot:
         self.paged_to = 0
 
 
-def _step_program(kind: str, fn, donate_argnums):
-    """``fn`` jitted as ``kubeshare_<kind>_step``: the XLA module is then
+def _program_name(kind: str) -> str:
+    """``kubeshare_<kind>_step``: the XLA module is then
     ``jit_kubeshare_<kind>_step``, and the device plane's ``XLA Modules``
     line tells the engine's programs from everything else on the chip (a
     user's ``jit_step``, pod B's).  The name is in the compile cache's key."""
+    return f"kubeshare_{kind}_step"
+
+
+def _step_program(kind: str, fn, donate_argnums, carried=None):
+    """``fn(params, pool_k, pool_v, *arguments)`` as the step program
+    ``kubeshare_<kind>_step``, called as ``fn`` is: the host arrays among
+    ``arguments`` cross to the device as ONE buffer a call
+    (``packed_args.PackedProgram``; ``carried`` hears what a call took
+    over)."""
+    return PackedProgram(_program_name(kind), fn, donate_argnums, carried)
+
+
+def _block_program(kind: str, fn, donate_argnums):
+    """A single-block pool write (``copy``, ``upload``) jitted under the
+    step programs' names: its arguments are device scalars and slabs, so
+    there is nothing to pack."""
     @functools.wraps(fn)  # keeps the argument names in the HLO
     def step(*args):
         return fn(*args)
 
-    step.__name__ = step.__qualname__ = f"kubeshare_{kind}_step"
+    step.__name__ = step.__qualname__ = _program_name(kind)
     return jax.jit(step, donate_argnums=donate_argnums)
 
 
@@ -1091,6 +1108,12 @@ class ServingEngine:
         # plan kind (:meth:`_weight_passes`; a loop's when it is consumed,
         # with its units)
         self.weight_passes: Dict[str, int] = {}
+        # host arrays the launched programs' calls carried to the device
+        # (one packed buffer a call: packed_args.py) and their bytes;
+        # _launch_carried is the launch in hand's, for its span
+        self.host_arg_transfers = 0
+        self.host_arg_bytes = 0
+        self._launch_carried = [0, 0]
         # device-resident loop counters: launches (fused dispatches)
         # and the span-units those launches actually ran.  Each unit is
         # one decode_span's worth of work and is absorbed into
@@ -1273,6 +1296,10 @@ class ServingEngine:
         sharded = self._sharded
         sharded_prefill = sharded.prefill if sharded is not None else None
         routed = config.routed
+        # every step program hands its call's host arguments over as one
+        # buffer and says so here, for the launch span and the counters
+        step_program = functools.partial(_step_program,
+                                         carried=self._note_carried)
 
         def prefill(w, pk, pv, tables, starts, active, tokens, last_rows,
                     temps, keys):
@@ -1312,7 +1339,7 @@ class ServingEngine:
         # place device-side instead of materializing a second pool (on a
         # fractional-HBM pod a transient 2x cache would blow the cap)
         donated = (1, 2, 10) if self._stateful else (1, 2)
-        self._prefill_step = _step_program("prefill", prefill, donated)
+        self._prefill_step = step_program("prefill", prefill, donated)
 
         def diffusion(w, pk, pv, tables, lengths, active, tokens, masked,
                       open_rows, quota):
@@ -1330,8 +1357,8 @@ class ServingEngine:
                 d_tables, d_lengths, d_active, d_tokens, d_masked, d_open,
                 d_quota, routing=routed)
 
-        self._diffusion_step = _step_program("diffusion", diffusion, (1, 2))
-        self._mixed_diffusion_step = _step_program(
+        self._diffusion_step = step_program("diffusion", diffusion, (1, 2))
+        self._mixed_diffusion_step = step_program(
             "mixed_diffusion", mixed_diffusion, (1, 2))
 
         span = ec.decode_span
@@ -1359,7 +1386,7 @@ class ServingEngine:
                     active, tokens, temps, keys, budgets, routing=routed,
                     recurrent=recurrent, folded=folded)
 
-        self._decode_step = _step_program("decode", decode, donated)
+        self._decode_step = step_program("decode", decode, donated)
 
         def make_loop(k_units):
             # the device-resident multi-step loop: up to K span-units
@@ -1377,7 +1404,7 @@ class ServingEngine:
 
             if sharded is not None:
                 loop = sharded.decode_loop(pick_rows, span, k_units, eos)
-            return _step_program("loop", loop, (1, 2))
+            return step_program("loop", loop, (1, 2))
 
         # one jitted loop program per depth: just the configured K
         # normally; under autotune, EVERY power-of-two depth up to the
@@ -1418,7 +1445,7 @@ class ServingEngine:
                 spec_loop = sharded.spec_loop(
                     pick_rows, k_units, eos, max_order,
                     SPEC_LOOP_REDRAFT, spec_w)
-            return _step_program("spec_loop", spec_loop, (1, 2))
+            return step_program("spec_loop", spec_loop, (1, 2))
 
         # one speculative loop program per warmed depth — exactly the
         # plain loop's depth set, armed only when speculation is on
@@ -1455,7 +1482,7 @@ class ServingEngine:
                     d_budgets, routing=routed, recurrent=recurrent,
                     p_folded=p_folded, p_slot=p_slot, d_folded=d_folded)
 
-        self._mixed_step = _step_program(
+        self._mixed_step = step_program(
             "mixed", mixed, (1, 2, 16) if self._stateful else (1, 2))
 
         def verify(w, pk, pv, tables, lengths, active, tokens, widths,
@@ -1471,7 +1498,7 @@ class ServingEngine:
 
         if sharded is not None:
             verify = sharded.verify_span(pick_rows)
-        self._verify_step = _step_program("verify", verify, (1, 2))
+        self._verify_step = step_program("verify", verify, (1, 2))
 
         def mixed_verify(w, pk, pv, p_table, p_start, p_tokens,
                          p_last_row, p_temp, p_key, d_tables, d_lengths,
@@ -1487,7 +1514,7 @@ class ServingEngine:
 
         if sharded is not None:
             mixed_verify = sharded.mixed_verify_step(pick_rows)
-        self._mixed_verify_step = _step_program(
+        self._mixed_verify_step = step_program(
             "mixed_verify", mixed_verify, (1, 2))
         # the copy-on-write primitive: one block, all layers, K and V —
         # a single static shape, so the cache adds exactly ONE compile.
@@ -1499,7 +1526,7 @@ class ServingEngine:
 
         if sharded is not None:
             copy = sharded.copy_block
-        self._copy_step = _step_program("copy", copy, (0, 1))
+        self._copy_step = _block_program("copy", copy, (0, 1))
 
         # the KV tier's promotion primitive: one block's host payload
         # into a fresh pool block — like the CoW copy, a single static
@@ -1513,7 +1540,7 @@ class ServingEngine:
             # pool's head sharding, so tier promotion and migration
             # unpack are sharding-agnostic host-side
             upload = sharded.upload_block
-        self._upload_step = _step_program("upload", upload, (0, 1))
+        self._upload_step = _block_program("upload", upload, (0, 1))
 
         # the online autotuner (serving/autotune.py): ticked by step()
         # between consume and plan, so _plan_step always reads
@@ -2318,6 +2345,16 @@ class ServingEngine:
             "state by slot).", "counter")
         for kind, passes in sorted(self.weight_passes.items()):
             weight_passes.add({"kind": kind, **plabel}, passes)
+        host_args = MetricFamily(
+            "kubeshare_serving_host_args_total",
+            "Host arrays the launched step programs' calls carried to "
+            "the device: one packed buffer a launch.", "counter")
+        host_args.add(dict(plabel), self.host_arg_transfers)
+        host_arg_bytes = MetricFamily(
+            "kubeshare_serving_host_arg_bytes_total",
+            "Bytes of the host arrays the launched step programs' calls "
+            "carried to the device.", "counter")
+        host_arg_bytes.add(dict(plabel), self.host_arg_bytes)
         loop_units = MetricFamily(
             "kubeshare_serving_loop_units_total",
             "Decode span-units executed inside device-resident loop "
@@ -2703,7 +2740,8 @@ class ServingEngine:
         view_rows.add({"kind": "configured", **plabel},
                       self.view_rows_configured)
         view_rows.add({"kind": "held", **plabel}, self.view_rows_held)
-        return [req, blocks, tokens, dispatches, weight_passes, loop_units,
+        return [req, blocks, tokens, dispatches, weight_passes, host_args,
+                host_arg_bytes, loop_units,
                 moe_assign, moe_touched, moe_tiles, moe_tile_rows, view_rows,
                 diff_passes, diff_rows, diff_tokens, diff_blocks,
                 *retention, spec_loop_units, exit_reason, depth_summary,
@@ -3573,8 +3611,15 @@ class ServingEngine:
                 -(-reach // self._key_block_rows) * self._key_block_rows)
             self.view_rows_configured += (
                 self._table_width * self.engine_config.block_size)
+        self._launch_carried = [0, 0]
         with profiling.span("kubeshare.engine.launch", **attrs) as launch:
             out = fn(*args)
+            # what the program's call carried over (one packed buffer):
+            # known once it has been called
+            host_args, host_bytes = self._launch_carried
+            launch.set(host_args=host_args, host_bytes=host_bytes)
+        self.host_arg_transfers += host_args
+        self.host_arg_bytes += host_bytes
         if plan is not None and plan.prefill_slot is not None:
             result = plan.prefill_slot.result
             result.prefill_chunks += 1
@@ -3608,6 +3653,12 @@ class ServingEngine:
         if plan.kind.startswith("mixed"):
             return 2
         return span if plan.kind == "decode" else 1
+
+    def _note_carried(self, count: int, nbytes: int) -> None:
+        """A step program's call took ``count`` host arrays of ``nbytes``
+        in all over to the device (``packed_args.PackedProgram``)."""
+        self._launch_carried[0] += count
+        self._launch_carried[1] += nbytes
 
     def _count_weight_passes(self, kind: str, passes: int) -> None:
         self.weight_passes[kind] = self.weight_passes.get(kind, 0) + passes
@@ -3819,10 +3870,11 @@ class ServingEngine:
         K*span) the device-loop dispatch.  The key window is sliced
         flat: a K-unit loop consumes exactly the keys K back-to-back
         span dispatches would, at the same emission indices.  They are
-        numpy arrays and stay so: the step program's call carries them
-        to the device (as :meth:`warmup`'s does, so a program has one
-        signature); a ``jnp.asarray`` each was most of the host's time
-        a dispatch."""
+        numpy arrays and stay so: the step program lays them into the
+        one buffer its call carries to the device (``packed_args``; as
+        :meth:`warmup`'s does, so a program has one signature); a
+        ``jnp.asarray`` each was most of the host's time a dispatch,
+        and a transfer each most of the gate's."""
         ec = self.engine_config
         s = ec.num_slots
         steps = ec.decode_span if n_steps is None else n_steps

@@ -50,7 +50,14 @@ and its ``i``, ``reach`` on the launch, ``pod`` on the client's span).
                                and the rest of ``_stages.py``: the table its
                                operations are booked by; W), ``experts`` (a
                                routed engine's alone: ``kernel`` / ``loop``,
-                               what computes the expert layers' tiles; W)
+                               what computes the expert layers' tiles; W),
+                               ``weight_passes`` and, set once the call
+                               has returned, ``host_args`` / ``host_bytes``
+                               (C ``weight_passes{kind}``, ``host_args``,
+                               ``host_arg_bytes``: the passes over the
+                               layers the program made, and the host
+                               arrays its call carried to the device — ONE
+                               packed buffer a planned launch)
 ``kubeshare.engine.device_wait``  M ``_stages.py`` (a launch's device time
                                ends with it); W
 ``kubeshare.engine.routing``   M ``moe.*``, ``step.*routed*``,

@@ -30,6 +30,7 @@ from kubeshare_tpu.models.transformer import (  # noqa: E402
 from kubeshare_tpu.ops.attention import (  # noqa: E402
     _flash_attention, _flash_forward, default_blocks)
 from kubeshare_tpu.serving import paged, stages  # noqa: E402
+from kubeshare_tpu.serving.packed_args import PackedProgram  # noqa: E402
 from kubeshare_tpu.serving.paged import (  # noqa: E402
     KEY_BLOCK, paged_decode_loop, paged_decode_span, paged_decode_step,
     paged_diffusion_pass, paged_mixed_diffusion_step, paged_mixed_step,
@@ -72,6 +73,31 @@ def _lower(fn, args, sharding, donate_argnums=()):
 
 def _compile(fn, args, sharding, donate_argnums=()):
     return _lower(fn, args, sharding, donate_argnums).compile()
+
+
+# (cell, kind, kernel mode) -> the compiled step program: a cell's mixed
+# program compiles in 20-35 s, and two tests read it
+_STEP_PROGRAMS = {}
+
+
+def _compile_step(fn, args, sharding, donate_argnums=(1, 2), key=None):
+    """``fn`` compiled as the engine compiles a step program since PR 45
+    (``engine._step_program``: a ``PackedProgram``): called with the
+    arguments' shapes in ``fn``'s own order, it takes every host argument —
+    every array after the pool that is not donated — as ONE ``uint32``
+    buffer and slices it apart inside.  Kept under ``key`` where one is
+    given."""
+    key = key and (*key, paged._kernel_mode())
+    if key not in _STEP_PROGRAMS:
+        shaped = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), args)
+        compiled = PackedProgram("kubeshare_step", fn,
+                                 donate_argnums).lower(*shaped).compile()
+        if key is None:
+            return compiled
+        _STEP_PROGRAMS[key] = compiled
+    return _STEP_PROGRAMS[key]
 
 
 def _flash_args(b, h, h_kv, s, d):
@@ -194,8 +220,7 @@ def test_serving_program_compiles_and_fits(one_chip, case):
     buffers.  A step that restacks the pool or cuts layer slabs out of it
     needs 1.1-1.3 x BOTH halves of temporaries (PR 25)."""
     fn, args = case()
-    memory = _compile(fn, args, one_chip,
-                      donate_argnums=(1, 2)).memory_analysis()
+    memory = _compile_step(fn, args, one_chip).memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes)
     assert resident < V5E_HBM_BYTES, memory
@@ -262,13 +287,13 @@ def _cell_case(name, kind):
         jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
 
 
-def _compiled_in_place(fn, args, sharding, resident_limit):
+def _compiled_in_place(fn, args, sharding, resident_limit, key=None):
     """``fn`` compiled with its pool donated: it fits, the pool is
     aliased (written in place) and no pool-shaped array is copied.
     Returns (memory analysis, program text)."""
     import re
 
-    compiled = _compile(fn, args, sharding, donate_argnums=(1, 2))
+    compiled = _compile_step(fn, args, sharding, key=key)
     memory = compiled.memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes - memory.alias_size_in_bytes)
@@ -390,7 +415,8 @@ def test_dense_cell_program_attends_by_key_block(one_chip, monkeypatch, name,
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case(name, kind)
-    memory, text = _compiled_in_place(fn, args, one_chip, V5E_HBM_BYTES)
+    memory, text = _compiled_in_place(fn, args, one_chip, V5E_HBM_BYTES,
+                                      key=(name, kind))
     lanes, table_width = args[-7].shape
     _, blocks, h_kv, block_size, d = args[1].shape
     assert table_width * block_size == 4096
@@ -534,8 +560,11 @@ def _rows_written_whole(args, text):
                          text)
 
 
-@pytest.mark.parametrize("kind,temporaries", [("decode", 157_404_160),
-                                              ("mixed", 364_149_760)])
+LCF_DECODE, LCF_MIXED = 157_534_208, 364_600_832
+
+
+@pytest.mark.parametrize("kind,temporaries", [("decode", LCF_DECODE),
+                                              ("mixed", LCF_MIXED)])
 def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
                                                 temporaries):
     """One expert-parallel rank at the published widths, built as on the
@@ -562,10 +591,12 @@ def test_latent_block_program_compiles_and_fits(one_chip, monkeypatch, kind,
     pass (PR 44: ONE layer loop over the chunk's 512 rows and the lanes' 32,
     the experts' tiles of both one call a layer) the first step's arrays
     are 544 rows where the chunk's were 512: 364,149,760 the mixed
-    program."""
+    program; with the host arguments one buffer sliced apart inside
+    (PR 45) 157,534,208 / 364,600,832: 0.13 / 0.45 MB of slices."""
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
     config, fn, args = _cell_case("longcat-flash-chat", kind)
-    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9,
+                                      key=("longcat-flash-chat", kind))
     assert memory.temp_size_in_bytes == temporaries, memory
     _no_row_of_every_expert(config, args, text)
     _attends_through_the_latent_kernel(config, text, kind)
@@ -605,7 +636,8 @@ def test_single_latent_layers_program_compiles_and_fits(one_chip, monkeypatch,
     config, fn, args = _cell_case("joyai-llm-flash", kind)
     assert (config.attn_sublayers, config.expert_layers) == (5, 4)
     assert args[1].shape[0] == 5 and args[2].shape[0] == 3
-    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9,
+                                      key=("joyai-llm-flash", kind))
     assert memory.temp_size_in_bytes < 128 << 20, memory
     _no_row_of_every_expert(config, args, text)
     _attends_through_the_latent_kernel(config, text, kind)
@@ -635,7 +667,8 @@ def _diffusion_case(one_chip, kind):
     assert (config.n_layers, config.expert_layers, config.head_dim) \
         == (6, 6, 128)
     assert args[1].shape == args[2].shape == (6, 16385, 4, 16, 128)
-    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9)
+    memory, text = _compiled_in_place(fn, args, one_chip, 15 * 10 ** 9,
+                                      key=("sdar-30b-a3b-chat", kind))
     assert memory.temp_size_in_bytes < DIFFUSION_TEMPORARIES[kind], memory
     with_experts = set(re.findall(r"(?:bf16|f32)\[128,[0-9,]+\]", text))
     assert {"bf16[128,2048,768]", "bf16[128,768,2048]"} <= with_experts
@@ -786,7 +819,8 @@ def test_retention_program_compiles_and_fits(one_chip, kind):
     gate, states = args[3]
     assert gate.shape == (5, 8, 3252 * 16) and gate.dtype == jnp.float32
     assert len(states) == 5 and states[0].shape == (32, 8, 136, 8320)
-    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2, 3))
+    compiled = _compile_step(fn, args, one_chip, (1, 2, 3),
+                             key=("brumby-14b-base", kind))
     memory = compiled.memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes - memory.alias_size_in_bytes)
@@ -895,7 +929,8 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     assert gate is None and len(states) == 6
     assert all(s.shape == (32, 2, 2048) and s.dtype == jnp.bfloat16
                for s in states)
-    compiled = _compile(fn, args, one_chip, donate_argnums=(1, 2, 3))
+    compiled = _compile_step(fn, args, one_chip, (1, 2, 3),
+                             key=("lfm2-24b-a2b", kind))
     memory = compiled.memory_analysis()
     resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes - memory.alias_size_in_bytes)
@@ -919,6 +954,69 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     by_stage = _stages_hold(config, args, text)
     assert {"conv", "attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
+
+
+# ---------------------------------------------------------------------------
+# one packed host buffer a dispatch: the programs are the parent's but for
+# their entry parameters
+# ---------------------------------------------------------------------------
+
+# cell -> (kind, the PARENT's temporaries, its (attention, experts) kernel
+# calls): PR 44's programs, a bare ``jax.jit`` taking 7-16 small arguments,
+# by this file's chipless compile (PR 45 compiled both sides once)
+PARENTS = {
+    "starcoderbase-1b": ("mixed", 297_772_032, (48, 0)),
+    "starcoder2-3b": ("mixed", 854_657_024, (60, 0)),
+    "longcat-flash-chat": ("mixed", 364_149_760, (16, 8)),
+    "joyai-llm-flash": ("mixed", 63_936_000, (10, 8)),
+    "brumby-14b-base": ("mixed", 918_589_952, (0, 0)),
+    "lfm2-24b-a2b": ("mixed", 73_308_160, (2, 12)),
+    "sdar-30b-a3b-chat": ("diffusion", 10_612_224, (6, 6)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENTS))
+def test_packed_program_is_the_parents_but_for_its_arguments(
+        one_chip, monkeypatch, name):
+    """The six configurations' mixed programs and ``sdar``'s pass as the
+    engine compiles them since PR 45 — every host argument of a dispatch in
+    ONE ``uint32`` buffer, sliced apart by static offsets inside: the
+    program is handed the weights, what is donated (the pool; a model's
+    states by slot) and that buffer, nothing else; the compiler's plan did
+    not change with the entry parameters — nothing the size of the pool or
+    of a state is copied, the Pallas kernels are called as often as in the
+    parent's program, and the temporaries are within 1 MB of the
+    parent's."""
+    import re
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    kind, temporaries, kernel_calls = PARENTS[name]
+    if name in ("brumby-14b-base", "lfm2-24b-a2b"):
+        case = _retention_case if name == "brumby-14b-base" else _conv_case
+        _, fn, args = case(kind)
+        donated = (1, 2, 3)
+    else:
+        _, fn, args = _cell_case(name, kind)
+        donated = (1, 2)
+    compiled = _compile_step(fn, args, one_chip, donated, key=(name, kind))
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    # the entry parameters: the weights, the donated arrays, one buffer
+    words = sum(a.size for a in args[max(donated) + 1:])
+    layout = text.split("entry_computation_layout={(", 1)[1].split(")->")[0]
+    assert layout.count(f"u32[{words}]") == 1
+    handed = len(re.findall(r"\b(?:pred|bf16|f32|s32|u32)\[", layout))
+    assert handed == len(jax.tree.leaves(args[:max(donated) + 1])) + 1
+    # what is donated is written in place, and none of it is copied
+    kept = jax.tree.leaves(args[1:max(donated) + 1])
+    assert memory.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in kept), memory
+    # (the retention block's gate array, 8.3 MB a row of the pool's, is
+    # laid out anew on the way in and out, in the parent's program too)
+    for shape in {",".join(map(str, a.shape)) for a in kept
+                  if a.size * a.dtype.itemsize > 64 << 20}:
+        assert not re.search(rf"\[{shape}\][^ ]* copy\(", text), shape
+    assert _kernel_calls(text) == kernel_calls
+    assert abs(memory.temp_size_in_bytes - temporaries) < 1 << 20, memory
 
 
 # ---------------------------------------------------------------------------
