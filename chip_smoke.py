@@ -96,8 +96,8 @@ class Sizes:
     interpret: bool = False  # Pallas interpret mode (CPU rehearsal only)
 
 
-# The widest serving model the repository names: the GQA decode twin of
-# benchmarks/kernel_bench.py MODEL_SIZES["flagship"].
+# The widest serving model the repository names: the GQA decode twin of the
+# flagship training Transformer (docs/perf.md).
 _FLAGSHIP = dict(d_model=1024, n_layers=8, n_heads=8, d_ff=4096,
                  vocab_size=32000)
 FULL = Sizes(

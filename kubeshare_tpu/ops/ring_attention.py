@@ -229,7 +229,7 @@ def ring_attention(
 # standard flash merge).
 #
 # Which implementation computes each partial is chosen per mask shape from
-# v5e measurements (benchmarks/kernel_bench.py ringstep suite):
+# v5e measurements (docs/perf.md, "Ring attention: hybrid block math"):
 #   - fully-visible blocks: the XLA einsum partial — with nothing to mask,
 #     XLA's fused attention runs near MXU peak (~160 TFLOPs bf16 at shard
 #     2048) and beats the flash kernel's block pipeline (~85 TFLOPs) ~2x;

@@ -490,14 +490,6 @@ class ReplicaFleet:
         self.watchdog_grace = watchdog_grace
         self.replica_failures: Dict[str, int] = {}
         self.salvaged_tokens = 0
-        # denominator of a salvage rate (no reader since the CPU bench
-        # went, PR 29; ROADMAP 3.7): tokens of every
-        # host-resident node a dead replica HELD (salvageable in
-        # principle), whether or not a survivor adopted it
-        self.salvage_candidate_tokens = 0
-        # exact recovery latencies (the histogram buckets coarsen; no
-        # reader since the CPU bench went, PR 29; ROADMAP 3.7)
-        self.recovery_durations: List[float] = []
         self.orphans_readmitted = 0
         self._recovery_counts = [0] * (len(RECOVERY_BUCKETS) + 1)
         self._recovery_sum = 0.0
@@ -820,7 +812,6 @@ class ReplicaFleet:
                               if handle.last_live_at is not None else now))
         _bucket_observe(self._recovery_counts, dur, RECOVERY_BUCKETS)
         self._recovery_sum += dur
-        self.recovery_durations.append(dur)
 
     def _salvage_trie(self, handle: ReplicaHandle) -> int:
         """Crash-time twin of :meth:`_handoff_trie`: the dead replica's
@@ -869,7 +860,6 @@ class ReplicaFleet:
             # the pump, expiries surface as lost chains
             offers: List[Tuple[List[Tuple[str, int]], int]] = []
             for tokens, payload, tenant, ntok in entries:
-                self.salvage_candidate_tokens += ntok
                 body = pack_chain_msg(
                     tenant if isinstance(tenant, str) else "",
                     [(np.asarray(tokens, np.int32), payload)])
@@ -887,7 +877,6 @@ class ReplicaFleet:
             return salvaged
         salvaged = 0
         for tokens, payload, tenant, ntok in entries:
-            self.salvage_candidate_tokens += ntok
             adopted_any = False
             for peer in peers:
                 key = adopt_into(self.shared_tier,

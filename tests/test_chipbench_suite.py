@@ -8,9 +8,9 @@ of stages), collected here
 as ``tests/test_chipbench_contract.py`` collects the contract.  A PR that
 edits ``kubeshare_tpu/serving/`` learns here, not from the driver's
 refusal, what ``chipbench/system.py``, ``trace.py`` or a ``layer_metrics/``
-reader expects of the program.  The whole-window cases of the other two
-files are in ``tests/test_chipbench_twins.py``, so that ``--dist loadfile``
-can give the two to two workers."""
+reader expects of the program.  The whole-window cases of the twins' files
+are in ``tests/test_chipbench_twins.py`` and the two files beside it, so
+that ``--dist loadfile`` can give each to a worker of its own."""
 
 import os
 import sys

@@ -1,5 +1,11 @@
-"""Compute-path tests on the 8-device CPU mesh: attention kernels, ring
-attention vs reference, model forwards/training, mesh shardings."""
+"""Compute-path tests on the 8-device CPU mesh: model forwards/training,
+mesh shardings, and the sequence-parallel (ring, Ulysses) transformers
+against the dense forward.
+
+The rest of the compute path is a file a layer (``tests/test_compute_*.py``:
+attention kernels, ring / zigzag / Ulysses attention, capacity MoE, the
+dense-cache decoders) because a tier-1 worker holds a file for its whole
+length (``--dist loadfile``)."""
 
 import jax
 import jax.numpy as jnp
@@ -16,522 +22,14 @@ from kubeshare_tpu.models import (
     resnet_apply,
     resnet_init,
     transformer_apply,
-    transformer_apply_with_aux,
     transformer_init,
 )
-from kubeshare_tpu.models.transformer import (
-    transformer_activation_spec,
-    transformer_sharding_rules,
-)
-from kubeshare_tpu.ops import attention_reference, flash_attention, ring_attention
-from kubeshare_tpu.ops.ring_attention import ring_attention_sharded
-from kubeshare_tpu.ops.ulysses import ulysses_attention_sharded
+from kubeshare_tpu.models.transformer import transformer_sharding_rules
 from kubeshare_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 from kubeshare_tpu.parallel.mesh import shard_params
-from kubeshare_tpu.parallel.train import TrainState, cross_entropy_loss, make_train_step
+from kubeshare_tpu.parallel.train import cross_entropy_loss, make_train_step
 
-
-def rand(key, *shape):
-    return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32)
-
-
-class TestAttention:
-    def test_flash_matches_reference_interpret(self):
-        q, k, v = (rand(i, 2, 4, 64, 16) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = flash_attention(q, k, v, causal=True, block_q=32,
-                              use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_flash_non_causal(self):
-        q, k, v = (rand(i, 1, 2, 32, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=False)
-        out = flash_attention(q, k, v, causal=False, block_q=16,
-                              use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_flash_gradients(self):
-        q, k, v = (rand(i, 1, 2, 32, 8) for i in range(3))
-
-        def loss_flash(q, k, v):
-            return flash_attention(q, k, v, use_pallas=True, interpret=True,
-                                   block_q=16).sum()
-
-        def loss_ref(q, k, v):
-            return attention_reference(q, k, v).sum()
-
-        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g_flash, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_cpu_auto_fallback(self):
-        q, k, v = (rand(i, 1, 1, 16, 8) for i in range(3))
-        out = flash_attention(q, k, v)  # auto: CPU -> reference
-        ref = attention_reference(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
-
-    def test_default_blocks_by_seq_len(self):
-        """Seq-dependent kernel tiles (v5e sweep, docs/perf.md): larger
-        blocks only at s >= 8192 AND only when they tile — an untiled
-        pick would silently demote the call to the XLA reference."""
-        from kubeshare_tpu.ops.attention import default_blocks
-
-        assert default_blocks(2048) == (512, 1024)
-        assert default_blocks(8192) == (1024, 2048)
-        assert default_blocks(16384) == (1024, 2048)
-        assert default_blocks(9216) == (512, 1024)  # 9216 % 2048 != 0
-
-
-class TestBlockSparseAttention:
-    """Arbitrary [n_qblocks, n_kblocks] masks over the flash kernels
-    (document masking / prefix-LM / strided sparsity): the mask rides in
-    SMEM and masked tiles are skipped in forward AND both backward
-    sweeps."""
-
-    BQ = BK = 16
-
-    def _mask(self, nq, nk, seed=0, density=0.6):
-        rng = np.random.default_rng(seed)
-        mask = (rng.random((nq, nk)) < density).astype(np.int32)
-        mask[0, 0] = 1  # at least one live tile
-        return mask
-
-    def test_matches_reference(self):
-        from kubeshare_tpu.ops.attention import (block_sparse_attention,
-                                                 block_sparse_reference)
-
-        q, k, v = (rand(i, 2, 2, 64, 16) for i in range(3))
-        mask = self._mask(4, 4)
-        ref = block_sparse_reference(q, k, v, jnp.asarray(mask), True,
-                                     self.BQ, self.BK)
-        out = block_sparse_attention(q, k, v, mask, causal=True,
-                                     block_q=self.BQ, block_k=self.BK,
-                                     use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_gradients_match_reference(self):
-        from kubeshare_tpu.ops.attention import (block_sparse_attention,
-                                                 block_sparse_reference)
-
-        q, k, v = (rand(i, 1, 2, 32, 8) for i in range(3))
-        mask = self._mask(2, 2, seed=1, density=0.8)
-
-        def loss_kernel(q, k, v):
-            return (block_sparse_attention(
-                q, k, v, mask, causal=True, block_q=self.BQ,
-                block_k=self.BK, use_pallas=True, interpret=True) ** 2).sum()
-
-        def loss_ref(q, k, v):
-            return (block_sparse_reference(
-                q, k, v, jnp.asarray(mask), True, self.BQ, self.BK) ** 2).sum()
-
-        g_kernel = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g_kernel, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_gqa_heads_share_mask(self):
-        from kubeshare_tpu.ops.attention import (block_sparse_attention,
-                                                 block_sparse_reference)
-
-        q = rand(0, 1, 4, 64, 16)
-        k, v = (rand(i, 1, 2, 64, 16) for i in (1, 2))
-        mask = self._mask(4, 4, seed=2, density=0.7)
-        ref = block_sparse_reference(q, k, v, jnp.asarray(mask), True,
-                                     self.BQ, self.BK)
-        out = block_sparse_attention(q, k, v, mask, causal=True,
-                                     block_q=self.BQ, block_k=self.BK,
-                                     use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_fully_masked_rows_zero(self):
-        from kubeshare_tpu.ops.attention import block_sparse_attention
-
-        q, k, v = (rand(i, 1, 1, 64, 8) for i in range(3))
-        mask = np.ones((4, 4), np.int32)
-        mask[2, :] = 0  # q-block 2 attends nothing
-        out = block_sparse_attention(q, k, v, mask, causal=False,
-                                     block_q=self.BQ, block_k=self.BK,
-                                     use_pallas=True, interpret=True)
-        rows = np.asarray(out)[:, :, 2 * self.BQ:3 * self.BQ, :]
-        assert np.all(rows == 0)
-        assert not np.any(np.isnan(np.asarray(out)))
-
-    def test_mask_shape_validated(self):
-        from kubeshare_tpu.ops.attention import block_sparse_attention
-
-        q, k, v = (rand(i, 1, 1, 64, 8) for i in range(3))
-        with pytest.raises(ValueError, match="block_mask shape"):
-            block_sparse_attention(q, k, v, np.ones((3, 4), np.int32),
-                                   block_q=self.BQ, block_k=self.BK,
-                                   use_pallas=True, interpret=True)
-
-
-class TestRingAttention:
-    def test_matches_reference_over_mesh(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        b, h, s, d = 2, 2, 32, 8  # s=32 across sp=4 -> 8 per device
-        q, k, v = (rand(i, b, h, s, d) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_non_causal(self):
-        mesh = make_mesh(MeshSpec(dp=1, tp=1, sp=8))
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=False)
-        out = ring_attention_sharded(q, k, v, mesh, causal=False,
-                                     batch_axis=None, head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_grads_flow(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-
-        def loss(q):
-            return ring_attention_sharded(q, k, v, mesh, batch_axis=None,
-                                          head_axis=None).sum()
-
-        g = jax.grad(loss)(q)
-        assert np.isfinite(np.asarray(g)).all()
-
-
-class TestZigzagRing:
-    """Load-balanced causal ring (zigzag layout: each device holds one
-    chunk from each end of the sequence, so every off-diagonal ring step
-    is exactly half a block of unmasked work on every device)."""
-
-    def test_permutation_round_trips(self):
-        from kubeshare_tpu.ops.ring_attention import (
-            zigzag_shard, zigzag_unshard)
-
-        x = rand(0, 1, 1, 32, 4)
-        back = zigzag_unshard(zigzag_shard(x, 4), 4)
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(back))
-        # device 0's shard = first and last chunks of the global sequence
-        z = zigzag_shard(x, 4)
-        np.testing.assert_array_equal(np.asarray(z[:, :, :4]),
-                                      np.asarray(x[:, :, :4]))
-        np.testing.assert_array_equal(np.asarray(z[:, :, 4:8]),
-                                      np.asarray(x[:, :, 28:]))
-
-    def test_zigzag_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        b, h, s, d = 2, 2, 32, 8
-        q, k, v = (rand(i, b, h, s, d) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=False, layout="zigzag")
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_zigzag_hybrid_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 2, 2, 64, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=True, interpret=True,
-                                     layout="zigzag")
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_zigzag_gqa_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q = rand(0, 2, 4, 32, 8)
-        k, v = (rand(i, 2, 2, 32, 8) for i in (1, 2))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=False, layout="zigzag")
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_zigzag_grads_match_contiguous_ring(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-
-        def loss(fn_kwargs):
-            def inner(q, k, v):
-                return (ring_attention_sharded(
-                    q, k, v, mesh, causal=True, batch_axis=None,
-                    head_axis=None, **fn_kwargs) ** 2).sum()
-            return inner
-
-        g_ref = jax.grad(loss({"use_flash": False}), argnums=(0, 1, 2))(
-            q, k, v)
-        g_zz = jax.grad(
-            loss({"use_flash": True, "interpret": True,
-                  "layout": "zigzag"}), argnums=(0, 1, 2))(q, k, v)
-        for a, b_ in zip(g_zz, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_zigzag_gqa_grads_match_dense_reference(self):
-        """The hand-scheduled ring backward's grouped dk/dv reduction
-        (query-head groups summing onto shared KV heads) must match dense
-        autodiff."""
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q = rand(0, 2, 4, 32, 8)
-        k, v = (rand(i, 2, 2, 32, 8) for i in (1, 2))
-
-        def dense_loss(q, k, v):
-            return (attention_reference(q, k, v, causal=True) ** 2).sum()
-
-        def zz_loss(q, k, v):
-            return (ring_attention_sharded(
-                q, k, v, mesh, causal=True, batch_axis="dp",
-                head_axis=None, use_flash=True, interpret=True,
-                layout="zigzag") ** 2).sum()
-
-        g_ref = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
-        g_zz = jax.grad(zz_loss, argnums=(0, 1, 2))(q, k, v)
-        for a, b_ in zip(g_zz, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                       rtol=5e-4, atol=5e-4)
-
-    def test_zigzag_positions_cover_sequence(self):
-        from kubeshare_tpu.ops.ring_attention import zigzag_positions
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-
-        def body():
-            return zigzag_positions("sp", 8)
-
-        pos = jax.shard_map(
-            body, mesh=mesh, in_specs=(), out_specs=P("sp"),
-        )()
-        assert sorted(np.asarray(pos).tolist()) == list(range(32))
-
-    def test_windowed_ring_matches_reference(self):
-        """Sliding-window causal attention on the contiguous einsum ring:
-        same band as the dense mask, including windows that cross shard
-        boundaries (w not a multiple of the shard length)."""
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        b, h, s, d = 2, 2, 32, 8
-        q, k, v = (rand(i, b, h, s, d) for i in range(3))
-        for window in (3, 8, 40):  # intra-shard, cross-shard, over-long
-            ref = attention_reference(q, k, v, causal=True, window=window)
-            out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                         batch_axis="dp", head_axis=None,
-                                         window=window)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=2e-4, atol=2e-4,
-                                       err_msg=f"window={window}")
-
-    def test_windowed_ring_grads_match_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-
-        def ring_loss(q, k, v):
-            return (ring_attention_sharded(
-                q, k, v, mesh, causal=True, batch_axis=None, head_axis=None,
-                window=5) ** 2).sum()
-
-        def dense_loss(q, k, v):
-            return (attention_reference(q, k, v, causal=True,
-                                        window=5) ** 2).sum()
-
-        g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-        g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
-        for a, b_ in zip(g_ring, g_dense):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_windowed_ring_steps_math(self):
-        from kubeshare_tpu.ops.ring_attention import windowed_ring_steps
-
-        # window=1: each query sees only itself — no rotation at all
-        assert windowed_ring_steps(1, 8, 8) == 1
-        # a shard's FIRST query reaches window-1 back, so any window > 1
-        # crosses into the previous shard
-        assert windowed_ring_steps(8, 8, 8) == 2
-        # reach-back w-1 <= s_local stays within ONE previous shard
-        assert windowed_ring_steps(9, 8, 8) == 2
-        assert windowed_ring_steps(10, 8, 8) == 3  # 9 back: two shards
-        assert windowed_ring_steps(17, 8, 8) == 3
-        # over-long windows clamp to the full ring
-        assert windowed_ring_steps(1000, 8, 8) == 8
-
-    def test_windowed_ring_comm_scales_with_window(self):
-        """Skip-aware rotation (VERDICT r4 #6): the ring's rotation loop
-        (and with it the K/V ppermute count) must truncate statically to
-        the shards the band reaches — visible as the traced scan length —
-        instead of always walking the whole ring."""
-        import re
-        from kubeshare_tpu.ops.ring_attention import windowed_ring_steps
-
-        mesh = make_mesh(MeshSpec(dp=1, tp=1, sp=8))
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))  # s_local=8
-
-        def scan_lengths(window):
-            jaxpr = str(jax.make_jaxpr(
-                lambda q, k, v: ring_attention_sharded(
-                    q, k, v, mesh, causal=True, batch_axis=None,
-                    head_axis=None, window=window, use_flash=False)
-            )(q, k, v))
-            return [int(m) for m in re.findall(r"length=(\d+)", jaxpr)]
-
-        assert scan_lengths(None) == [7]       # full ring: sp-1 rotations
-        for w in (4, 16, 63):
-            expected = windowed_ring_steps(w, 8, 8) - 1
-            assert scan_lengths(w) == [expected], f"window={w}"
-
-    def test_windowed_ring_rejections(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-        with pytest.raises(ValueError, match="zigzag"):
-            ring_attention_sharded(q, k, v, mesh, causal=True,
-                                   batch_axis=None, head_axis=None,
-                                   layout="zigzag", window=4)
-        with pytest.raises(ValueError, match="einsum ring"):
-            ring_attention_sharded(q, k, v, mesh, causal=True,
-                                   batch_axis=None, head_axis=None,
-                                   use_flash=True, window=4)
-        with pytest.raises(ValueError, match="causal"):
-            ring_attention_sharded(q, k, v, mesh, causal=False,
-                                   batch_axis=None, head_axis=None,
-                                   window=4)
-
-    def test_zigzag_rejects_non_causal(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-        with pytest.raises(ValueError, match="causal"):
-            ring_attention_sharded(q, k, v, mesh, causal=False,
-                                   batch_axis=None, head_axis=None,
-                                   layout="zigzag")
-
-    def test_zigzag_balance_property(self):
-        """The load-balance claim, asserted rather than narrated (VERDICT
-        r3 #5): counting visible (unmasked) q-k pairs from the layout's own
-        position invariant (_zigzag_shard_positions — the function the
-        forward masks, backward, and RoPE all consume), every device does
-        IDENTICAL work at every ring step — exactly half the 2c x 2c block
-        off-diagonal — and per-device totals are exactly 1/sp of global
-        causal work.  Contiguous shards fail the same count."""
-        from kubeshare_tpu.ops.ring_attention import _zigzag_shard_positions
-
-        sp, c = 4, 4
-        pos = {
-            i: np.asarray(_zigzag_shard_positions(i, sp, c))
-            for i in range(sp)
-        }
-
-        def visible(qp, kp):
-            return int((qp[:, None] >= kp[None, :]).sum())
-
-        for t in range(1, sp):  # every off-diagonal ring step
-            works = [visible(pos[i], pos[(i - t) % sp]) for i in range(sp)]
-            assert len(set(works)) == 1, (t, works)
-            assert works[0] == 2 * c * c  # exactly half the block
-
-        diag = [visible(pos[i], pos[i]) for i in range(sp)]
-        assert len(set(diag)) == 1
-        s = 2 * c * sp
-        per_device_total = diag[0] + (sp - 1) * 2 * c * c
-        assert per_device_total * sp == s * (s + 1) // 2
-
-        # contiguous layout: same count is imbalanced at every off-diagonal
-        # step (some devices fully masked, others fully visible)
-        cont = {i: np.arange(i * 2 * c, (i + 1) * 2 * c) for i in range(sp)}
-        for t in range(1, sp):
-            works = {visible(cont[i], cont[(i - t) % sp]) for i in range(sp)}
-            assert len(works) > 1, t
-
-    def test_zigzag_wrapper_counts_traced_calls(self):
-        """The wrapper pays two global permutations per call; repeated
-        calls under one trace (per-layer misuse) must be visible via the
-        traced-call counter (ADVICE r3)."""
-        import importlib
-
-        # ops/__init__ re-exports a function named ring_attention, which
-        # shadows the module for `import ... as` attribute lookup
-        ra = importlib.import_module("kubeshare_tpu.ops.ring_attention")
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-        before = ra.zigzag_traced_calls()
-
-        @jax.jit
-        def two_layers(q, k, v):
-            o = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                       batch_axis=None, head_axis=None,
-                                       use_flash=False, layout="zigzag")
-            return ring_attention_sharded(o, k, v, mesh, causal=True,
-                                          batch_axis=None, head_axis=None,
-                                          use_flash=False, layout="zigzag")
-
-        two_layers(q, k, v)
-        assert ra.zigzag_traced_calls() >= before + 2
-
-
-class TestRingFlashAttention:
-    """Pallas-fused ring (VERDICT r1 #5): the flash kernel computes each
-    ring step's block partial; interpret mode runs the real kernel on CPU."""
-
-    def test_causal_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        b, h, s, d = 2, 2, 32, 8
-        q, k, v = (rand(i, b, h, s, d) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_non_causal_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=1, tp=1, sp=8))
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=False)
-        out = ring_attention_sharded(q, k, v, mesh, causal=False,
-                                     batch_axis=None, head_axis=None,
-                                     use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_matches_einsum_ring(self):
-        mesh = make_mesh(MeshSpec(dp=1, tp=2, sp=4))
-        q, k, v = (rand(i, 1, 2, 32, 8) for i in range(3))
-        einsum_out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                            batch_axis=None, head_axis="tp",
-                                            use_flash=False)
-        flash_out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                           batch_axis=None, head_axis="tp",
-                                           use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(einsum_out),
-                                   np.asarray(flash_out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_grads_match_einsum_ring(self):
-        """The custom-vjp backward (einsum-ring recompute) must produce the
-        einsum path's exact gradients."""
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 1, 16, 4) for i in range(3))
-
-        def loss(fn_kwargs, q, k, v):
-            return (ring_attention_sharded(
-                q, k, v, mesh, batch_axis=None, head_axis=None, **fn_kwargs
-            ) ** 2).sum()
-
-        g_ref = jax.grad(loss, argnums=(1, 2, 3))({"use_flash": False}, q, k, v)
-        g_flash = jax.grad(loss, argnums=(1, 2, 3))(
-            {"use_flash": True, "interpret": True}, q, k, v
-        )
-        for a, b in zip(g_ref, g_flash):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
+from compute_helpers import rand
 
 
 class TestModels:
@@ -793,164 +291,6 @@ class TestRingTransformer:
             transformer_apply(params, jnp.zeros((1, 8), jnp.int32), params_cfg)
 
 
-class TestUlyssesAttention:
-    """All-to-all (Ulysses-style) sequence parallelism (ops/ulysses.py):
-    two all_to_all collectives swap seq-sharding for head-sharding, full
-    local attention, swap back."""
-
-    def test_causal_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        b, h, s, d = 2, 4, 32, 8  # h=4 divisible by sp=4
-        q, k, v = (rand(i, b, h, s, d) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=True,
-                                        batch_axis="dp", head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_non_causal_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=1, tp=1, sp=8))
-        q, k, v = (rand(i, 1, 8, 64, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=False)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=False,
-                                        batch_axis=None, head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_windowed_matches_reference(self):
-        """Sliding-window attention composes with Ulysses (it cannot with
-        the ring — K/V visibility there is ring-position-dependent)."""
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 4, 32, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True, window=8)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=True, window=8,
-                                        batch_axis=None, head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_flash_kernel_body(self):
-        """Interpret mode runs the real Pallas kernel on the swapped
-        (full-sequence) shards."""
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 2, 4, 32, 8) for i in range(3))
-        ref = attention_reference(q, k, v, causal=True)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=True,
-                                        batch_axis="dp", head_axis=None,
-                                        use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_grads_flow(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = (rand(i, 1, 4, 16, 4) for i in range(3))
-
-        def loss(q):
-            return ulysses_attention_sharded(q, k, v, mesh, batch_axis=None,
-                                             head_axis=None).sum()
-
-        g = jax.grad(loss)(q)
-        assert np.isfinite(np.asarray(g)).all()
-        # the collective transposes to the mirrored all_to_all: a reference
-        # gradient check pins the values, not just finiteness
-        ref_g = jax.grad(
-            lambda q: attention_reference(q, k, v, causal=True).sum()
-        )(q)
-        np.testing.assert_allclose(np.asarray(ref_g), np.asarray(g),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_heads_not_divisible_raises(self):
-        mesh = make_mesh(MeshSpec(dp=1, tp=1, sp=8))
-        q, k, v = (rand(i, 1, 4, 32, 8) for i in range(3))  # 4 heads, sp=8
-        with pytest.raises(ValueError, match="divisible"):
-            ulysses_attention_sharded(q, k, v, mesh, batch_axis=None,
-                                      head_axis=None)
-
-    def test_composes_with_tp(self):
-        """Heads split over tp first; the sp swap works on the tp-local
-        head group."""
-        mesh = make_mesh(MeshSpec(dp=1, tp=2, sp=4))
-        q, k, v = (rand(i, 1, 8, 32, 8) for i in range(3))  # 8/tp2 = 4, sp=4
-        ref = attention_reference(q, k, v, causal=True)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=True,
-                                        batch_axis=None, head_axis="tp")
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-
-class TestGQASequenceParallel:
-    """Grouped-query attention through both sequence-parallel paths: K/V
-    stay at their small head width on the wire (ring rotation / all_to_all);
-    only the block math expands per group."""
-
-    def _gqa(self, h=4, h_kv=2, s=32, d=8):
-        q = rand(0, 2, h, s, d)
-        k = rand(1, 2, h_kv, s, d)
-        v = rand(2, 2, h_kv, s, d)
-        return q, k, v
-
-    def test_ring_einsum_gqa_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = self._gqa()
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=False)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_ring_flash_gqa_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = self._gqa()
-        ref = attention_reference(q, k, v, causal=True)
-        out = ring_attention_sharded(q, k, v, mesh, causal=True,
-                                     batch_axis="dp", head_axis=None,
-                                     use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_ring_gqa_grads_match_reference(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = self._gqa(s=16)
-
-        def loss_ring(q, k, v):
-            return ring_attention_sharded(q, k, v, mesh, batch_axis="dp",
-                                          head_axis=None,
-                                          use_flash=False).sum()
-
-        def loss_ref(q, k, v):
-            return attention_reference(q, k, v, causal=True).sum()
-
-        g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g_ring, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_ulysses_gqa_matches_reference(self):
-        mesh = make_mesh(MeshSpec(dp=4, tp=1, sp=2))
-        q, k, v = self._gqa()  # h=4, h_kv=2: both divisible by sp=2
-        ref = attention_reference(q, k, v, causal=True)
-        out = ulysses_attention_sharded(q, k, v, mesh, causal=True,
-                                        batch_axis=None, head_axis=None)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_ulysses_kv_heads_not_divisible_raises(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q, k, v = self._gqa()  # h_kv=2 not divisible by sp=4
-        with pytest.raises(ValueError, match="divisible"):
-            ulysses_attention_sharded(q, k, v, mesh, batch_axis="dp",
-                                      head_axis=None)
-
-    def test_ring_uneven_heads_raises(self):
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        q = rand(0, 2, 3, 32, 8)
-        k = rand(1, 2, 2, 32, 8)
-        with pytest.raises(ValueError, match="multiple"):
-            ring_attention_sharded(q, k, k, mesh, batch_axis="dp",
-                                   head_axis=None, use_flash=False)
-
-
 class TestUlyssesTransformer:
     def test_forward_matches_dense(self):
         from kubeshare_tpu.models.transformer import transformer_apply_ulysses
@@ -1006,947 +346,6 @@ class TestUlyssesTransformer:
             transformer_apply(params, jnp.zeros((1, 8), jnp.int32), cfg)
 
 
-class TestDecoding:
-    def _setup(self):
-        from kubeshare_tpu.models.transformer import TransformerConfig, transformer_init
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        return config, params
-
-    def test_incremental_matches_full_forward(self):
-        # the incremental path explicitly: bulk prefill IS the dense
-        # forward, so comparing it to dense would be a tautology
-        from kubeshare_tpu.models.decoding import (
-            prefill_incremental as prefill)
-
-        config, params = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 10), 0, 64)
-        # cached incremental prefill must equal the dense forward's last step
-        dense = transformer_apply(params, prompt, config)
-        _, last_logits = prefill(params, config, prompt)
-        np.testing.assert_allclose(
-            np.asarray(dense[:, -1]), np.asarray(last_logits),
-            rtol=2e-4, atol=2e-4,
-        )
-
-    def test_gqa_incremental_matches_full_forward(self):
-        """GQA decode: the grouped cached-attention path (KV cache holds
-        n_kv_heads, query heads grouped over it with no materialized
-        repetition) must equal the dense GQA forward."""
-        from kubeshare_tpu.models.decoding import (
-            init_kv_cache, prefill_incremental as prefill)
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
-            d_ff=64, max_seq_len=32, dtype=jnp.float32,
-            attention="reference", positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        # the cache — decode's dominant HBM cost — holds kv heads only
-        assert init_kv_cache(config, 2)["k"].shape == (2, 2, 2, 32, 8)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 10), 0, 64)
-        dense = transformer_apply(params, prompt, config)
-        _, last_logits = prefill(params, config, prompt)
-        np.testing.assert_allclose(
-            np.asarray(dense[:, -1]), np.asarray(last_logits),
-            rtol=2e-4, atol=2e-4,
-        )
-
-    def test_bulk_prefill_matches_incremental(self):
-        """The bulk prefill (one dense forward + bulk cache fill) must
-        produce the same cache and logits as the token-at-a-time oracle —
-        for MHA, GQA, and a MoE config (whose expert buffers prefill pins
-        to the token count so routing stays position/batch-independent)."""
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode, prefill, prefill_incremental)
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        cases = {
-            "mha": dict(),
-            "gqa_rope": dict(n_kv_heads=2, positional="rope"),
-            "moe": dict(moe_every=2, moe_num_experts=4, moe_top_k=2),
-            "windowed": dict(attention_window=6),
-        }
-        for name, extra in cases.items():
-            config = TransformerConfig(
-                vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-                max_seq_len=32, dtype=jnp.float32, attention="reference",
-                **extra)
-            params = transformer_init(jax.random.PRNGKey(0), config)
-            prompt = jax.random.randint(
-                jax.random.PRNGKey(1), (2, 10), 0, 64)
-            cache_b, logits_b = prefill(params, config, prompt)
-            cache_i, logits_i = prefill_incremental(params, config, prompt)
-            np.testing.assert_allclose(
-                np.asarray(logits_b), np.asarray(logits_i),
-                rtol=2e-4, atol=2e-4, err_msg=name)
-            assert int(cache_b["length"]) == int(cache_i["length"]) == 10
-            np.testing.assert_allclose(
-                np.asarray(cache_b["k"]), np.asarray(cache_i["k"]),
-                rtol=2e-4, atol=2e-4, err_msg=name)
-            np.testing.assert_allclose(
-                np.asarray(cache_b["v"]), np.asarray(cache_i["v"]),
-                rtol=2e-4, atol=2e-4, err_msg=name)
-            # and the next decode step computes identical logits from
-            # either cache
-            from kubeshare_tpu.models.decoding import _decode_one
-
-            token = jnp.argmax(logits_b, axis=-1).astype(jnp.int32)
-            step_b, _ = _decode_one(params, config, cache_b, token)
-            step_i, _ = _decode_one(params, config, cache_i, token)
-            np.testing.assert_allclose(
-                np.asarray(step_b), np.asarray(step_i),
-                rtol=2e-4, atol=2e-4, err_msg=name)
-            out = greedy_decode(params, config, prompt, 4)
-            assert out.shape == (2, 4)
-
-    def test_chunked_prefill_matches_bulk(self):
-        """Chunked prefill (O(chunk) activations per step) must produce
-        the same cache and logits as the bulk dense pass — across
-        MHA/GQA/MoE/windowed configs and chunk sizes incl. chunk=1 (which
-        is exactly the incremental path) and chunk=prompt_len."""
-        from kubeshare_tpu.models.decoding import prefill, prefill_chunked
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        cases = {
-            "mha": dict(),
-            "gqa_rope": dict(n_kv_heads=2, positional="rope"),
-            "moe": dict(moe_every=2, moe_num_experts=4, moe_top_k=2),
-            "windowed": dict(attention_window=6),
-        }
-        for name, extra in cases.items():
-            config = TransformerConfig(
-                vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-                max_seq_len=32, dtype=jnp.float32, attention="reference",
-                **extra)
-            params = transformer_init(jax.random.PRNGKey(0), config)
-            prompt = jax.random.randint(
-                jax.random.PRNGKey(1), (2, 12), 0, 64)
-            cache_b, logits_b = prefill(params, config, prompt)
-            for chunk in (1, 4, 12):
-                cache_c, logits_c = prefill_chunked(
-                    params, config, prompt, chunk)
-                np.testing.assert_allclose(
-                    np.asarray(logits_c), np.asarray(logits_b),
-                    rtol=2e-4, atol=2e-4, err_msg=f"{name} chunk={chunk}")
-                np.testing.assert_allclose(
-                    np.asarray(cache_c["k"]), np.asarray(cache_b["k"]),
-                    rtol=2e-4, atol=2e-4, err_msg=f"{name} chunk={chunk}")
-                np.testing.assert_allclose(
-                    np.asarray(cache_c["v"]), np.asarray(cache_b["v"]),
-                    rtol=2e-4, atol=2e-4, err_msg=f"{name} chunk={chunk}")
-                assert int(cache_c["length"]) == 12
-
-    def test_decode_from_chunked_cache_matches_greedy(self):
-        """The serving split — chunked prefill + greedy_decode_with_cache
-        — must emit the same tokens as the one-shot greedy_decode."""
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode, greedy_decode_with_cache, prefill_chunked)
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
-            d_ff=64, max_seq_len=32, dtype=jnp.float32,
-            attention="reference", positional="rope")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 64)
-        one_shot = greedy_decode(params, config, prompt, 8)
-        cache, logits = prefill_chunked(params, config, prompt, 4)
-        split = greedy_decode_with_cache(params, config, cache, logits, 8)
-        np.testing.assert_array_equal(np.asarray(one_shot),
-                                      np.asarray(split))
-        # the split path keeps the one-shot path's loud overflow failure
-        with pytest.raises(ValueError, match="capacity"):
-            greedy_decode_with_cache(params, config, cache, logits, 32)
-        # zero/negative generation lengths fail loudly too (ADVICE r4)
-        with pytest.raises(ValueError, match="max_new_tokens"):
-            greedy_decode_with_cache(params, config, cache, logits, 0)
-
-    def test_jitted_continuation_overflow_caught_with_static_prefill(self):
-        """ADVICE r4 (medium): under jit the cache length is traced, so
-        the capacity bound can only bind through the static
-        ``prefill_length`` — a jitted continuation from a nearly-full
-        cache must fail at trace time, not clamp-overwrite the last
-        slot."""
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode_with_cache, prefill)
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=16, dtype=jnp.float32, attention="reference",
-            positional="rope")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 12), 0, 64)
-        cache, logits = prefill(params, config, prompt)
-
-        # 12 prefilled + 8 > 16: the jitted serving pattern
-        # (examples/serve_fractional.py) with the static prefill length
-        decode_fn = jax.jit(
-            lambda c, lg: greedy_decode_with_cache(
-                params, config, c, lg, 8, prefill_length=12))
-        with pytest.raises(ValueError, match="capacity"):
-            decode_fn(cache, logits)
-        # with headroom the same jit runs
-        ok_fn = jax.jit(
-            lambda c, lg: greedy_decode_with_cache(
-                params, config, c, lg, 4, prefill_length=12))
-        out = ok_fn(cache, logits)
-        assert out.shape == (1, 4)
-        # outside jit the cache's CONCRETE length stays authoritative: an
-        # understated prefill_length must not bypass the real bound
-        with pytest.raises(ValueError, match="capacity"):
-            greedy_decode_with_cache(params, config, cache, logits, 8,
-                                     prefill_length=4)
-
-    def test_sampled_decode_from_cache_matches_one_shot(self):
-        """sample_decode == prefill + sample_decode_with_cache under the
-        same key (the sampled serving split)."""
-        from kubeshare_tpu.models.decoding import (
-            prefill, sample_decode, sample_decode_with_cache)
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 64)
-        rng = jax.random.PRNGKey(7)
-        one_shot = sample_decode(params, config, prompt, rng, 6,
-                                 temperature=0.8, top_k=10)
-        cache, logits = prefill(params, config, prompt)
-        split = sample_decode_with_cache(params, config, cache, logits,
-                                         rng, 6, temperature=0.8, top_k=10)
-        np.testing.assert_array_equal(np.asarray(one_shot),
-                                      np.asarray(split))
-
-    def test_chunked_prefill_ragged_and_chunk_validation(self):
-        """Non-tiling prompts no longer raise: the ragged tail runs as
-        one bucketed (power-of-two) chunk and must match the bulk
-        prefill (tests/test_serving.py locks every remainder); a
-        degenerate chunk still fails loudly."""
-        from kubeshare_tpu.models.decoding import prefill, prefill_chunked
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=1, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 10), 0, 64)
-        cache_b, logits_b = prefill(params, config, prompt)
-        cache_c, logits_c = prefill_chunked(params, config, prompt, 4)
-        np.testing.assert_allclose(
-            np.asarray(logits_c), np.asarray(logits_b),
-            rtol=2e-4, atol=2e-4)
-        assert int(cache_c["length"]) == 10
-        with pytest.raises(ValueError, match="chunk"):
-            prefill_chunked(params, config, prompt, 0)
-
-    def test_gqa_head_count_validated(self):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=8, d_model=24, n_heads=3, n_kv_heads=2, n_layers=1,
-            d_ff=8, max_seq_len=8,
-        )
-        with pytest.raises(ValueError, match="multiple of n_kv_heads"):
-            transformer_init(jax.random.PRNGKey(0), config)
-
-    def test_greedy_decode_jits_and_is_deterministic(self):
-        from kubeshare_tpu.models.decoding import greedy_decode
-
-        config, params = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 4), 0, 64)
-        decode = jax.jit(
-            lambda p, t: greedy_decode(p, config, t, max_new_tokens=8)
-        )
-        out1 = decode(params, prompt)
-        out2 = decode(params, prompt)
-        assert out1.shape == (2, 8)
-        np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-        assert (np.asarray(out1) >= 0).all() and (np.asarray(out1) < 64).all()
-
-    def test_sliding_window_prefill_matches_dense(self):
-        """A windowed model must decode with the same band the dense mask
-        keeps (ADVICE r1: cached path used to attend over full history)."""
-        from dataclasses import replace
-
-        from kubeshare_tpu.models.decoding import (
-            prefill_incremental as prefill)
-
-        config, params = self._setup()
-        config = replace(config, attention_window=4)
-        prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 12), 0, 64)
-        dense = transformer_apply(params, prompt, config)
-        _, last_logits = prefill(params, config, prompt)
-        np.testing.assert_allclose(
-            np.asarray(dense[:, -1]), np.asarray(last_logits),
-            rtol=2e-4, atol=2e-4,
-        )
-        # and it must differ from the un-windowed decode (mask is live)
-        _, full_logits = prefill(params, replace(config, attention_window=None), prompt)
-        assert not np.allclose(np.asarray(last_logits), np.asarray(full_logits))
-
-    def test_overflow_guards(self):
-        from kubeshare_tpu.models.decoding import greedy_decode, prefill
-
-        config, params = self._setup()
-        long_prompt = jnp.zeros((1, 40), jnp.int32)  # > max_seq_len 32
-        with pytest.raises(ValueError):
-            prefill(params, config, long_prompt)
-        with pytest.raises(ValueError):
-            greedy_decode(params, config, jnp.zeros((1, 30), jnp.int32), 10)
-
-
-class TestShardedDecoding:
-    """Multi-chip serving: decode with tensor-parallel-placed parameters.
-    No decode-specific sharding code needed — the params' NamedShardings
-    (transformer_sharding_rules) propagate through the KV-cache scan under
-    jit, XLA inserting the tp collectives; these tests pin that the
-    sharded path is bit-identical to single-device decode."""
-
-    def _setup(self):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_sharding_rules)
-        from kubeshare_tpu.parallel.mesh import shard_params
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        mesh = make_mesh(MeshSpec(dp=2, tp=2, sp=2))
-        placed = shard_params(params, transformer_sharding_rules(), mesh)
-        return config, params, placed
-
-    def test_tp_sharded_greedy_matches_unsharded(self):
-        from kubeshare_tpu.models.decoding import greedy_decode
-
-        config, params, placed = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 4), 0, 64)
-        base = greedy_decode(params, config, prompt, 8)
-        sharded = jax.jit(
-            lambda p, t: greedy_decode(p, config, t, 8))(placed, prompt)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(sharded))
-
-    def test_tp_sharded_sampling_matches_unsharded(self):
-        from kubeshare_tpu.models.decoding import sample_decode
-
-        config, params, placed = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 4), 0, 64)
-        rng = jax.random.PRNGKey(3)
-        base = sample_decode(params, config, prompt, rng, 6,
-                             temperature=0.8, top_k=10)
-        sharded = jax.jit(lambda p, t, r: sample_decode(
-            p, config, t, r, 6, temperature=0.8, top_k=10))(
-                placed, prompt, rng)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(sharded))
-
-    def test_gqa_tp_sharded_greedy_matches_unsharded(self):
-        """The advertised combination — tp-sharded serving WITH a
-        kv_heads-sized cache axis — decoded under placement."""
-        from kubeshare_tpu.models.decoding import greedy_decode
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_sharding_rules)
-        from kubeshare_tpu.parallel.mesh import shard_params
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
-            d_ff=64, max_seq_len=32, dtype=jnp.float32,
-            attention="reference",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        mesh = make_mesh(MeshSpec(dp=2, tp=2, sp=2))
-        placed = shard_params(params, transformer_sharding_rules(), mesh)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 4), 0, 64)
-        base = greedy_decode(params, config, prompt, 8)
-        sharded = jax.jit(
-            lambda p, t: greedy_decode(p, config, t, 8))(placed, prompt)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(sharded))
-
-    def test_undivisible_tp_names_the_parameter(self):
-        """A GQA config whose shrunken wk/wv head axis no longer divides
-        tp must fail with the parameter path and axis named, not
-        device_put's raw divisibility error."""
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_sharding_rules)
-        from kubeshare_tpu.parallel.mesh import shard_params
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_kv_heads=1, n_layers=1,
-            d_ff=64, max_seq_len=32, dtype=jnp.float32,
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        mesh = make_mesh(MeshSpec(dp=2, tp=2, sp=2))
-        with pytest.raises(ValueError, match=r"wk.*axis 1.*tp=2"):
-            shard_params(params, transformer_sharding_rules(), mesh)
-
-
-class TestSpeculativeDecoding:
-    """Draft-model speculation must emit EXACTLY greedy_decode's tokens —
-    the acceptance rule preserves the target's argmax stream regardless
-    of how good or bad the draft is."""
-
-    def _target(self, **extra):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=64, dtype=jnp.float32, attention="reference",
-            **extra)
-        return config, transformer_init(jax.random.PRNGKey(0), config)
-
-    def test_self_draft_matches_greedy(self):
-        """Draft == target: every proposal accepted, output identical."""
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode, speculative_greedy_decode)
-
-        config, params = self._target()
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 64)
-        base = greedy_decode(params, config, prompt, 12)
-        spec = speculative_greedy_decode(
-            params, config, params, config, prompt, 12, draft_len=4)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(spec))
-
-    def test_bad_draft_still_matches_greedy(self):
-        """A differently-initialized (frequently wrong) draft changes only
-        the speed, never the tokens."""
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode, speculative_greedy_decode)
-
-        config, params = self._target(positional="rope", n_kv_heads=2)
-        draft_config = TransformerConfig(
-            vocab_size=64, d_model=16, n_heads=2, n_layers=1, d_ff=32,
-            max_seq_len=64, dtype=jnp.float32, attention="reference")
-        draft_params = transformer_init(jax.random.PRNGKey(9), draft_config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 64)
-        base = greedy_decode(params, config, prompt, 12)
-        for draft_len in (2, 3, 5):
-            spec = speculative_greedy_decode(
-                params, config, draft_params, draft_config, prompt, 12,
-                draft_len=draft_len)
-            np.testing.assert_array_equal(
-                np.asarray(base), np.asarray(spec),
-                err_msg=f"draft_len={draft_len}")
-
-    def test_jits(self):
-        from kubeshare_tpu.models.decoding import speculative_greedy_decode
-
-        config, params = self._target()
-        prompt = jax.random.randint(jax.random.PRNGKey(2), (1, 4), 0, 64)
-        fn = jax.jit(lambda p, t: speculative_greedy_decode(
-            p, config, p, config, t, 8))
-        out1 = fn(params, prompt)
-        out2 = fn(params, prompt)
-        np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-        assert out1.shape == (1, 8)
-
-    def test_validation(self):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-        from kubeshare_tpu.models.decoding import speculative_greedy_decode
-
-        config, params = self._target()
-        prompt = jnp.zeros((1, 4), jnp.int32)
-        with pytest.raises(ValueError, match="draft_len"):
-            speculative_greedy_decode(params, config, params, config,
-                                      prompt, 8, draft_len=1)
-        other_vocab = TransformerConfig(
-            vocab_size=32, d_model=16, n_heads=2, n_layers=1, d_ff=32,
-            max_seq_len=64)
-        other_params = transformer_init(jax.random.PRNGKey(0), other_vocab)
-        with pytest.raises(ValueError, match="vocabular"):
-            speculative_greedy_decode(params, config, other_params,
-                                      other_vocab, prompt, 8)
-        with pytest.raises(ValueError, match="headroom"):
-            speculative_greedy_decode(params, config, params, config,
-                                      prompt, 60)
-
-
-class TestSpeculativeSampling:
-    """Stochastic speculative decoding (VERDICT r4 #5): the rejection-
-    sampling acceptance rule must leave the emitted stream distributed
-    EXACTLY as sample_decode's — locked by an empirical distribution-
-    equivalence test — while a good draft cuts target passes."""
-
-    def _models(self, vocab=16):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=vocab, d_model=16, n_heads=2, n_layers=1, d_ff=32,
-            max_seq_len=32, dtype=jnp.float32, attention="reference")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        draft_config = TransformerConfig(
-            vocab_size=vocab, d_model=8, n_heads=1, n_layers=1, d_ff=16,
-            max_seq_len=32, dtype=jnp.float32, attention="reference")
-        draft_params = transformer_init(jax.random.PRNGKey(7), draft_config)
-        return config, params, draft_config, draft_params
-
-    def test_distribution_matches_sample_decode(self):
-        """Empirical per-position token distributions of the speculative
-        sampler and the plain sampler must agree within sampling noise
-        (N=1500 lanes; TV tolerance sized ~3x the expected noise — a
-        wrong acceptance ratio or residual shifts TV far more)."""
-        from kubeshare_tpu.models.decoding import (
-            sample_decode, speculative_sample_decode)
-
-        config, params, dconfig, dparams = self._models()
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 4), 0, 16)
-        n, steps = 1500, 3
-        keys = jax.random.split(jax.random.PRNGKey(42), n)
-
-        plain = jax.jit(jax.vmap(
-            lambda k: sample_decode(params, config, prompt, k, steps,
-                                    temperature=0.9, top_k=12)))(keys)
-        spec = jax.jit(jax.vmap(
-            lambda k: speculative_sample_decode(
-                params, config, dparams, dconfig, prompt, k, steps,
-                draft_len=3, temperature=0.9, top_k=12)))(keys)
-        plain = np.asarray(plain)[:, 0, :]  # [n, steps]
-        spec = np.asarray(spec)[:, 0, :]
-        for pos in range(steps):
-            h_plain = np.bincount(plain[:, pos], minlength=16) / n
-            h_spec = np.bincount(spec[:, pos], minlength=16) / n
-            tv = 0.5 * np.abs(h_plain - h_spec).sum()
-            assert tv < 0.12, (
-                f"position {pos}: TV distance {tv:.3f} between plain and "
-                f"speculative sampling (plain {h_plain}, spec {h_spec})")
-
-    def test_self_draft_accepts_every_proposal(self):
-        """Draft == target makes the acceptance ratio exactly 1: every
-        round emits draft_len tokens, so the target-pass count hits the
-        theoretical floor ceil((max_new - 1) / draft_len)."""
-        from kubeshare_tpu.models.decoding import speculative_sample_decode
-
-        config, params, _, _ = self._models()
-        prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 4), 0, 16)
-        out, stats = speculative_sample_decode(
-            params, config, params, config, prompt,
-            jax.random.PRNGKey(3), 12, draft_len=3, return_stats=True)
-        assert out.shape == (2, 12)
-        assert int(stats["rounds"]) == 4  # ceil(11 / 3)
-        # the greedy variant exposes the same stat (benchmarks report
-        # measured tokens-per-target-pass rather than assuming accept=1)
-        from kubeshare_tpu.models.decoding import speculative_greedy_decode
-
-        gout, gstats = speculative_greedy_decode(
-            params, config, params, config, prompt, 12, draft_len=3,
-            return_stats=True)
-        assert gout.shape == (2, 12)
-        assert int(gstats["rounds"]) == 4
-
-    def test_temperature_zero_delegates_to_greedy(self):
-        from kubeshare_tpu.models.decoding import (
-            greedy_decode, speculative_sample_decode)
-
-        config, params, dconfig, dparams = self._models()
-        prompt = jax.random.randint(jax.random.PRNGKey(4), (1, 4), 0, 16)
-        spec = speculative_sample_decode(
-            params, config, dparams, dconfig, prompt,
-            jax.random.PRNGKey(5), 8, temperature=0.0)
-        base = greedy_decode(params, config, prompt, 8)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(spec))
-
-    def test_deterministic_under_same_key(self):
-        from kubeshare_tpu.models.decoding import speculative_sample_decode
-
-        config, params, dconfig, dparams = self._models()
-        prompt = jax.random.randint(jax.random.PRNGKey(6), (2, 4), 0, 16)
-        fn = jax.jit(lambda k: speculative_sample_decode(
-            params, config, dparams, dconfig, prompt, k, 10, draft_len=4,
-            top_p=0.95))
-        k = jax.random.PRNGKey(8)
-        np.testing.assert_array_equal(np.asarray(fn(k)), np.asarray(fn(k)))
-
-    def test_validation(self):
-        from kubeshare_tpu.models.decoding import speculative_sample_decode
-
-        config, params, dconfig, dparams = self._models()
-        prompt = jnp.zeros((1, 4), jnp.int32)
-        rng = jax.random.PRNGKey(0)
-        with pytest.raises(ValueError, match="max_new_tokens"):
-            speculative_sample_decode(params, config, dparams, dconfig,
-                                      prompt, rng, 0)
-        with pytest.raises(ValueError, match="draft_len"):
-            speculative_sample_decode(params, config, dparams, dconfig,
-                                      prompt, rng, 8, draft_len=1)
-        with pytest.raises(ValueError, match="temperature"):
-            speculative_sample_decode(params, config, dparams, dconfig,
-                                      prompt, rng, 8, temperature=-1.0)
-
-
-class TestSampledDecoding:
-    _setup = TestDecoding._setup
-
-    def test_temperature_zero_is_greedy(self):
-        from kubeshare_tpu.models.decoding import greedy_decode, sample_decode
-
-        config, params = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 4), 0, 64)
-        greedy = greedy_decode(params, config, prompt, max_new_tokens=8)
-        sampled = sample_decode(params, config, prompt,
-                                jax.random.PRNGKey(7), 8, temperature=0.0)
-        np.testing.assert_array_equal(np.asarray(greedy), np.asarray(sampled))
-
-    def test_top_k_one_is_greedy(self):
-        from kubeshare_tpu.models.decoding import greedy_decode, sample_decode
-
-        config, params = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 4), 0, 64)
-        greedy = greedy_decode(params, config, prompt, max_new_tokens=6)
-        sampled = sample_decode(params, config, prompt,
-                                jax.random.PRNGKey(9), 6, temperature=1.0,
-                                top_k=1)
-        np.testing.assert_array_equal(np.asarray(greedy), np.asarray(sampled))
-
-    def test_jit_deterministic_under_same_key(self):
-        from kubeshare_tpu.models.decoding import sample_decode
-
-        config, params = self._setup()
-        prompt = jax.random.randint(jax.random.PRNGKey(4), (2, 4), 0, 64)
-        decode = jax.jit(lambda p, t, r: sample_decode(
-            p, config, t, r, 8, temperature=0.8, top_k=10, top_p=0.9))
-        out1 = decode(params, prompt, jax.random.PRNGKey(5))
-        out2 = decode(params, prompt, jax.random.PRNGKey(5))
-        np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-        assert out1.shape == (2, 8)
-        assert (np.asarray(out1) >= 0).all() and (np.asarray(out1) < 64).all()
-        # a different key must be able to produce a different sequence
-        out3 = decode(params, prompt, jax.random.PRNGKey(6))
-        assert not np.array_equal(np.asarray(out1), np.asarray(out3))
-
-    def test_filter_logits_top_k(self):
-        from kubeshare_tpu.models.decoding import _filter_logits
-
-        logits = jnp.asarray([[1.0, 5.0, 3.0, 2.0]])
-        out = np.asarray(_filter_logits(logits, top_k=2, top_p=None))
-        assert np.isfinite(out[0, 1]) and np.isfinite(out[0, 2])
-        assert np.isneginf(out[0, 0]) and np.isneginf(out[0, 3])
-        # top_k >= vocab keeps everything (explicit clamp, ADVICE r2)
-        out = np.asarray(_filter_logits(logits, top_k=100, top_p=None))
-        assert np.isfinite(out).all()
-
-    def test_filter_logits_top_p(self):
-        from kubeshare_tpu.models.decoding import _filter_logits
-
-        # softmax of [2, 1, 0, -10] ~= [0.70, 0.26, 0.095, ~0]: top_p=0.5
-        # keeps only the first (its mass alone reaches 0.5)
-        logits = jnp.asarray([[2.0, 1.0, 0.0, -10.0]])
-        out = np.asarray(_filter_logits(logits, top_k=None, top_p=0.5))
-        assert np.isfinite(out[0, 0])
-        assert np.isneginf(out[0, 1:]).all()
-        # top_p=1.0 keeps everything
-        out = np.asarray(_filter_logits(logits, top_k=None, top_p=1.0))
-        assert np.isfinite(out).all()
-
-    def test_argument_validation(self):
-        from kubeshare_tpu.models.decoding import _filter_logits, sample_decode
-
-        config, params = self._setup()
-        with pytest.raises(ValueError):
-            sample_decode(params, config, jnp.zeros((1, 4), jnp.int32),
-                          jax.random.PRNGKey(0), 8, temperature=-1.0)
-        with pytest.raises(ValueError):
-            sample_decode(params, config, jnp.zeros((1, 30), jnp.int32),
-                          jax.random.PRNGKey(0), 10)
-        with pytest.raises(ValueError):
-            _filter_logits(jnp.zeros((1, 4)), top_k=0, top_p=None)
-        with pytest.raises(ValueError):
-            _filter_logits(jnp.zeros((1, 4)), top_k=None, top_p=1.5)
-
-
-class TestFlashKTiling:
-    def test_multiple_k_blocks(self):
-        from kubeshare_tpu.ops.attention import _flash_forward
-
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))
-        for causal in (True, False):
-            ref = attention_reference(q, k, v, causal)
-            out, lse = _flash_forward(q, k, v, causal, block_q=16,
-                                      interpret=True, block_k=16)
-            assert lse.shape == q.shape[:3] + (1,)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_k_tiling_gradients(self):
-        q, k, v = (rand(i, 1, 1, 32, 8) for i in range(3))
-
-        def loss(q, k, v):
-            return flash_attention(q, k, v, block_q=8, use_pallas=True,
-                                   interpret=True).sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(
-            lambda q, k, v: attention_reference(q, k, v).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-
-class TestFlashBackwardKernels:
-    def test_grads_multi_block_causal_and_not(self):
-        q, k, v = (rand(i, 2, 2, 64, 8) for i in range(3))
-        for causal in (True, False):
-            def loss(q, k, v):
-                return (flash_attention(q, k, v, causal=causal, block_q=16,
-                                        use_pallas=True, interpret=True) ** 2).sum()
-
-            def loss_ref(q, k, v):
-                return (attention_reference(q, k, v, causal) ** 2).sum()
-
-            g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-            for a, b in zip(g, g_ref):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           rtol=2e-4, atol=2e-4)
-
-    def test_value_and_grad_through_training_loss(self):
-        # end-to-end: attention inside a toy loss with value_and_grad
-        q, k, v = (rand(i, 1, 2, 32, 8) for i in range(3))
-        targets = rand(9, 1, 2, 32, 8)
-
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, block_q=8, use_pallas=True,
-                                  interpret=True)
-            return jnp.mean((out - targets) ** 2)
-
-        (val, grads) = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-        assert np.isfinite(float(val))
-        for g in grads:
-            assert np.isfinite(np.asarray(g)).all()
-
-
-class TestFlashBackwardFallback:
-    def test_non_tiling_seq_uses_reference_grads(self):
-        # s=320 tiles the forward blocks (bq=64, bk=min(1024,320)=320) but
-        # not the backward defaults (256/512): must fall back, not truncate
-        q, k, v = (rand(i, 1, 2, 320, 8) for i in range(3))
-
-        def loss(q, k, v):
-            return flash_attention(q, k, v, block_q=64, use_pallas=True,
-                                   interpret=True).sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(
-            lambda q, k, v: attention_reference(q, k, v).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g, g_ref):
-            assert np.isfinite(np.asarray(a)).all()
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
-
-
-class TestSlidingWindowAttention:
-    def test_window_matches_reference(self):
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))
-        for window in (8, 16, 64):
-            ref = attention_reference(q, k, v, causal=True, window=window)
-            out = flash_attention(q, k, v, block_q=16, use_pallas=True,
-                                  interpret=True, window=window)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_window_gradients(self):
-        q, k, v = (rand(i, 1, 1, 32, 8) for i in range(3))
-
-        def loss(q, k, v):
-            return flash_attention(q, k, v, block_q=8, use_pallas=True,
-                                   interpret=True, window=8).sum()
-
-        def loss_ref(q, k, v):
-            return attention_reference(q, k, v, True, window=8).sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_window_equals_full_causal(self):
-        # window >= seq is exactly causal attention
-        q, k, v = (rand(i, 1, 1, 32, 8) for i in range(3))
-        full = attention_reference(q, k, v, causal=True)
-        windowed = flash_attention(q, k, v, block_q=8, use_pallas=True,
-                                   interpret=True, window=32)
-        np.testing.assert_allclose(np.asarray(full), np.asarray(windowed),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_window_with_multiple_k_blocks(self):
-        # force several K blocks so the band-skip clause actually runs
-        from kubeshare_tpu.ops.attention import _flash_forward
-
-        q, k, v = (rand(i, 1, 2, 64, 8) for i in range(3))
-        for window in (8, 24, 40):
-            ref = attention_reference(q, k, v, causal=True, window=window)
-            out, _ = _flash_forward(q, k, v, True, 16, True, block_k=16,
-                                    window=window)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_window_backward_multiple_blocks(self):
-        # s=1024 -> bwd blocks 256/512: several blocks in both sweeps
-        q, k, v = (rand(i, 1, 1, 1024, 8) for i in range(3))
-
-        def loss(q, k, v):
-            return flash_attention(q, k, v, use_pallas=True, interpret=True,
-                                   window=300).sum()
-
-        def loss_ref(q, k, v):
-            return attention_reference(q, k, v, True, window=300).sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-3, atol=1e-3)
-
-    def test_invalid_window_rejected(self):
-        q = rand(0, 1, 1, 16, 8)
-        with pytest.raises(ValueError):
-            flash_attention(q, q, q, window=0)
-        with pytest.raises(ValueError):
-            attention_reference(q, q, q, window=-5)
-
-
-class TestGQA:
-    def test_gqa_matches_repeated_reference(self):
-        q = rand(0, 1, 8, 64, 16)
-        k = rand(1, 1, 2, 64, 16)  # 2 kv heads, group of 4
-        v = rand(2, 1, 2, 64, 16)
-        k_full = jnp.repeat(k, 4, axis=1)
-        v_full = jnp.repeat(v, 4, axis=1)
-        ref = attention_reference(q, k_full, v_full, causal=True)
-        out = flash_attention(q, k, v, block_q=16, use_pallas=True,
-                              interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_gqa_gradients(self):
-        q = rand(0, 1, 4, 32, 8)
-        k = rand(1, 1, 2, 32, 8)
-        v = rand(2, 1, 2, 32, 8)
-
-        def loss(q, k, v):
-            return flash_attention(q, k, v, block_q=8, use_pallas=True,
-                                   interpret=True).sum()
-
-        def loss_ref(q, k, v):
-            return attention_reference(
-                q, jnp.repeat(k, 2, axis=1), jnp.repeat(v, 2, axis=1)
-            ).sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        # reference grads for grouped kv: sum over the repeat
-        gq_ref, gk_full, gv_full = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        np.testing.assert_allclose(np.asarray(g[0]), np.asarray(gq_ref),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(g[1]), np.asarray(gk_full),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(g[2]), np.asarray(gv_full),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_bad_head_ratio_rejected(self):
-        q = rand(0, 1, 6, 16, 8)
-        k = rand(1, 1, 4, 16, 8)
-        with pytest.raises(ValueError):
-            flash_attention(q, k, k, block_q=8, use_pallas=True, interpret=True)
-
-
-class TestRope:
-    def test_rope_shapes_and_rotation_identity(self):
-        from kubeshare_tpu.ops.rope import apply_rope, rope_positions
-
-        x = rand(0, 2, 4, 16, 8)
-        out = apply_rope(x, rope_positions(16))
-        assert out.shape == x.shape
-        # position 0 is the identity rotation
-        np.testing.assert_allclose(np.asarray(out[:, :, 0]),
-                                   np.asarray(x[:, :, 0]), rtol=1e-5)
-        # rotation preserves pair norms
-        def pair_norms(a):
-            a1, a2 = np.split(np.asarray(a, np.float64), 2, axis=-1)
-            return a1**2 + a2**2
-        np.testing.assert_allclose(pair_norms(out), pair_norms(x), rtol=1e-4)
-
-    def test_rope_relative_shift_invariance(self):
-        from kubeshare_tpu.ops.rope import apply_rope, rope_positions
-
-        # attention scores depend only on relative positions
-        q = rand(0, 1, 1, 8, 8)
-        k = rand(1, 1, 1, 8, 8)
-        def scores(offset):
-            pos = rope_positions(8, offset)
-            qr, kr = apply_rope(q, pos), apply_rope(k, pos)
-            return np.asarray(jnp.einsum("bhqd,bhkd->bhqk", qr, kr))
-        np.testing.assert_allclose(scores(0), scores(17), rtol=1e-4, atol=1e-5)
-
-    def test_rope_transformer_and_decode_consistent(self):
-        from kubeshare_tpu.models.decoding import (
-            prefill_incremental as prefill)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 10), 0, 64)
-        dense = transformer_apply(params, prompt, config)
-        _, last_logits = prefill(params, config, prompt)
-        np.testing.assert_allclose(np.asarray(dense[:, -1]),
-                                   np.asarray(last_logits),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_rope_ring_matches_dense(self):
-        from kubeshare_tpu.models.transformer import transformer_apply_ring
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=64, dtype=jnp.float32, attention="reference",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
-        dense = transformer_apply(params, tokens, config)
-        ring = transformer_apply_ring(params, tokens, config, mesh)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(ring),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_rope_config_validation_and_no_pos_table(self):
-        config = TransformerConfig(
-            vocab_size=16, d_model=16, n_heads=2, n_layers=1, d_ff=16,
-            max_seq_len=16, dtype=jnp.float32, attention="reference",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        assert "pos_embed" not in params  # no dead table under rope
-        bad = TransformerConfig(
-            vocab_size=16, d_model=16, n_heads=2, n_layers=1, d_ff=16,
-            max_seq_len=16, dtype=jnp.float32, positional="Rotary",
-        )
-        with pytest.raises(ValueError):
-            transformer_init(jax.random.PRNGKey(0), bad)
-
-
 class TestRemat:
     def test_remat_grads_match(self):
         base = dict(vocab_size=32, d_model=16, n_heads=2, n_layers=2,
@@ -1965,239 +364,3 @@ class TestRemat:
         for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_remat)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
-
-
-class TestMoEFlagship:
-    """MoE layers inside the flagship Transformer (config.moe_every)."""
-
-    def _config(self, **kw):
-        kw.setdefault("moe_every", 2)
-        kw.setdefault("moe_num_experts", 4)
-        kw.setdefault("moe_capacity_factor", 8.0)  # ample: no token drops
-        kw.setdefault("attention", "reference")
-        return TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=4, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, **kw)
-
-    def test_init_places_moe_layers(self):
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        kinds = ["moe" if "moe" in l else "mlp" for l in params["layers"]]
-        assert kinds == ["mlp", "moe", "mlp", "moe"]
-        assert params["layers"][1]["moe"]["w_in"].shape == (4, 32, 64)
-
-    def test_forward_and_aux(self):
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
-        logits = transformer_apply(params, tokens, config)
-        assert logits.shape == (2, 16, 64)
-        assert np.isfinite(np.asarray(logits)).all()
-        logits2, aux = transformer_apply_with_aux(params, tokens, config)
-        np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits2))
-        assert float(aux) > 0.0  # two MoE layers contribute load-balance loss
-
-    def test_router_gets_gradients_through_aux(self):
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 64)
-
-        def loss(p):
-            logits, aux = transformer_apply_with_aux(p, tokens, config)
-            targets = jnp.zeros(tokens.shape, jnp.int32)
-            return cross_entropy_loss(logits, targets) + 0.01 * aux
-
-        grads = jax.grad(loss)(params)
-        g_router = np.asarray(grads["layers"][1]["moe"]["router"])
-        assert np.isfinite(g_router).all()
-        assert np.abs(g_router).sum() > 0
-
-    def test_decode_matches_full_forward(self):
-        from kubeshare_tpu.models.decoding import (
-            prefill_incremental as prefill)
-
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 10), 0, 64)
-        dense = transformer_apply(params, prompt, config)
-        _, last_logits = prefill(params, config, prompt)
-        np.testing.assert_allclose(
-            np.asarray(dense[:, -1]), np.asarray(last_logits),
-            rtol=2e-4, atol=2e-4)
-
-    def test_sampled_decode_runs(self):
-        from kubeshare_tpu.models.decoding import sample_decode
-
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        prompt = jnp.zeros((1, 4), jnp.int32)
-        toks = sample_decode(params, config, prompt, jax.random.PRNGKey(5),
-                             6, temperature=0.8, top_k=8)
-        assert toks.shape == (1, 6)
-
-    def test_sharding_rules_place_experts_on_tp(self):
-        from kubeshare_tpu.models.transformer import transformer_sharding_rules
-        from kubeshare_tpu.parallel.mesh import shard_params
-
-        config = self._config()
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        mesh = make_mesh(MeshSpec(dp=2, tp=2, sp=2))
-        placed = shard_params(params, transformer_sharding_rules(), mesh)
-        moe = placed["layers"][1]["moe"]
-        assert moe["w_in"].sharding.spec == P("tp", None, None)
-        assert moe["w_out"].sharding.spec == P("tp", None, None)
-        assert moe["router"].sharding.spec == P()
-        # tp-sharded forward still matches unsharded
-        tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 8), 0, 64)
-        base = transformer_apply(params, tokens, config)
-        sharded = jax.jit(
-            lambda p, t: transformer_apply(p, t, config))(placed, tokens)
-        np.testing.assert_allclose(np.asarray(base), np.asarray(sharded),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_sp_entries_accept_token_choice_moe(self):
-        """Round 4: the standalone sp entries route MoE per shard
-        (TestMoESequenceParallel locks dense equivalence); only
-        expert-choice routing — whole-batch by construction — is
-        rejected there."""
-        from dataclasses import replace
-
-        from kubeshare_tpu.models.transformer import transformer_apply_ring
-
-        config = self._config(attention="ring")
-        params = transformer_init(jax.random.PRNGKey(0), self._config())
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        out = transformer_apply_ring(params, jnp.zeros((2, 8), jnp.int32),
-                                     config, mesh)
-        assert np.isfinite(np.asarray(out)).all()
-        ec = replace(config, moe_routing="experts_choose")
-        with pytest.raises(ValueError, match="whole-batch"):
-            transformer_apply_ring(params, jnp.zeros((2, 8), jnp.int32),
-                                   ec, mesh)
-
-    @pytest.mark.parametrize("attention", ["reference", "ring"])
-    def test_pipelined_paths_reject_moe(self, attention):
-        """Both pipelined branches (dense AND sp-in-stage) must refuse MoE
-        configs — the stage body would otherwise silently run MoE layers
-        with default routing hyperparameters and drop the aux loss."""
-        from jax.sharding import Mesh
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply_pipelined, transformer_train_1f1b)
-
-        config = self._config(attention=attention, moe_every=1,
-                              positional="rope")
-        params = transformer_init(jax.random.PRNGKey(0), self._config())
-        shape = (2, 2) if attention == "ring" else (2,)
-        axes = ("pp", "sp") if attention == "ring" else ("pp",)
-        mesh = Mesh(np.array(jax.devices()[:4]).reshape(*shape)
-                    if attention == "ring"
-                    else np.array(jax.devices()[:2]).reshape(2), axes)
-        tokens = jnp.zeros((2, 8), jnp.int32)
-        with pytest.raises(ValueError, match="MoE"):
-            transformer_apply_pipelined(params, tokens, config, mesh)
-        with pytest.raises(ValueError, match="MoE"):
-            transformer_train_1f1b(params, tokens, tokens, config, mesh)
-
-    def test_top2_forward_grads_and_decode_parity(self):
-        """The flagship wired for GShard-style top-2 (config.moe_top_k=2):
-        forward + grads finite, and incremental decode matches the dense
-        forward — the dispatch/combine paths must agree for k>1 too."""
-        from kubeshare_tpu.models.decoding import prefill
-
-        config = self._config(moe_top_k=2)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 12), 0, 64)
-        logits, aux = transformer_apply_with_aux(params, tokens, config)
-        assert np.isfinite(np.asarray(logits)).all()
-        assert float(aux) > 0.0
-
-        def loss(p):
-            lg, ax = transformer_apply_with_aux(p, tokens, config)
-            return cross_entropy_loss(lg, jnp.zeros_like(tokens)) + 0.01 * ax
-
-        grads = jax.grad(loss)(params)
-        for li in (1, 3):
-            g = np.asarray(grads["layers"][li]["moe"]["w_in"])
-            assert np.isfinite(g).all() and np.abs(g).sum() > 0
-
-        dense = transformer_apply(params, tokens, config)
-        _, last_logits = prefill(params, config, tokens)
-        np.testing.assert_allclose(
-            np.asarray(dense[:, -1]), np.asarray(last_logits),
-            rtol=2e-4, atol=2e-4)
-
-    def test_experts_choose_flagship_trains_but_refuses_decode(self):
-        """moe_routing='experts_choose': training works (grads finite,
-        zero aux), incremental decode raises — expert choices depend on
-        the whole sequence and cannot be replayed token-by-token."""
-        from kubeshare_tpu.models.decoding import prefill
-
-        config = self._config(moe_routing="experts_choose",
-                              moe_capacity_factor=2.0)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 12), 0, 64)
-        logits, aux = transformer_apply_with_aux(params, tokens, config)
-        assert np.isfinite(np.asarray(logits)).all()
-        assert float(aux) == 0.0
-
-        grads = jax.grad(lambda p: cross_entropy_loss(
-            transformer_apply(p, tokens, config), tokens))(params)
-        g = np.asarray(grads["layers"][1]["moe"]["w_in"])
-        assert np.isfinite(g).all() and np.abs(g).sum() > 0
-
-        with pytest.raises(ValueError, match="expert-choice"):
-            prefill(params, config, tokens)
-
-    def test_decode_batch_independent_at_default_capacity(self):
-        """Batched incremental decode must equal per-row decode even at the
-        default capacity_factor (1.25): the decode path pins capacity to the
-        per-step token count, so expert collisions between batch rows can
-        never drop a row's token (ADVICE r2, decoding.py)."""
-        from kubeshare_tpu.models.decoding import prefill
-
-        config = self._config(moe_capacity_factor=1.25)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        # batch 4 over 4 experts: some step almost surely routes two rows
-        # to the same expert, which the old factor-derived capacity dropped
-        prompt = jax.random.randint(jax.random.PRNGKey(7), (4, 8), 0, 64)
-        _, batched = prefill(params, config, prompt)
-        for row in range(prompt.shape[0]):
-            _, single = prefill(params, config, prompt[row:row + 1])
-            np.testing.assert_allclose(
-                np.asarray(batched[row:row + 1]), np.asarray(single),
-                rtol=2e-4, atol=2e-4)
-
-
-class TestMoECapacity:
-    def test_capacity_rounds_up(self):
-        """capacity = ceil(cf*n/e), not floor (ADVICE r2, moe.py): route all
-        5 tokens to expert 0 with cf=1.0, e=4 -> capacity must be 2, so
-        exactly 2 token rows survive (floor kept only 1)."""
-        from kubeshare_tpu.ops.moe import MoEConfig, moe_apply, moe_init
-
-        config = MoEConfig(d_model=8, d_ff=16, num_experts=4,
-                           capacity_factor=1.0)
-        params = dict(moe_init(jax.random.PRNGKey(0), config))
-        router = np.zeros((8, 4), np.float32)
-        router[:, 0] = 100.0  # positive-sum tokens all argmax to expert 0
-        params["router"] = jnp.asarray(router)
-        x = 0.1 + jnp.abs(
-            jax.random.normal(jax.random.PRNGKey(1), (1, 5, 8), jnp.float32))
-        out, _ = moe_apply(params, x, config)
-        kept_rows = np.abs(np.asarray(out[0])).sum(axis=-1) > 0
-        assert kept_rows.sum() == 2
-
-    def test_capacity_override_keeps_all_tokens(self):
-        from kubeshare_tpu.ops.moe import MoEConfig, moe_apply, moe_init
-
-        config = MoEConfig(d_model=8, d_ff=16, num_experts=4,
-                           capacity_factor=1.0)
-        params = moe_init(jax.random.PRNGKey(0), config)
-        x = jax.random.normal(jax.random.PRNGKey(2), (2, 6, 8), jnp.float32)
-        ample = moe_apply(params, x, config, capacity=12)[0]
-        huge_cf = moe_apply(
-            params, x,
-            MoEConfig(d_model=8, d_ff=16, num_experts=4,
-                      capacity_factor=100.0))[0]
-        np.testing.assert_allclose(np.asarray(ample), np.asarray(huge_cf),
-                                   rtol=1e-6, atol=1e-6)

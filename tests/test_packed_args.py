@@ -72,7 +72,7 @@ def _sdar():
 
 
 def _speculative():
-    from test_serving import _cyclic_params, _small_config
+    from serving_helpers import _cyclic_params, _small_config
 
     config = _small_config()
     return (config, _cyclic_params(config),

@@ -1,4 +1,9 @@
-"""Expert parallelism (MoE) and pipeline parallelism tests on the CPU mesh."""
+"""Expert parallelism (MoE) and pipeline parallelism tests on the CPU mesh.
+
+The transformer on these schedules is in files of its own because a tier-1
+worker holds a file for its whole length (``--dist loadfile``):
+``tests/test_parallel_pipelined.py``, ``test_parallel_pp_sp.py`` and
+``test_parallel_moe_sp.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -140,8 +145,8 @@ class TestMoE:
                 p, x, MoEConfig(dispatch=dispatch, **config_kwargs))
             return jnp.mean(out ** 2) + 0.01 * aux
 
-        g_s = jax.grad(lambda p: loss(p, "scatter"))(params)
-        g_e = jax.grad(lambda p: loss(p, "einsum"))(params)
+        g_s = jax.jit(jax.grad(lambda p: loss(p, "scatter")))(params)
+        g_e = jax.jit(jax.grad(lambda p: loss(p, "einsum")))(params)
         for name in ("router", "w_in", "w_out"):
             np.testing.assert_allclose(np.asarray(g_s[name]),
                                        np.asarray(g_e[name]),
@@ -300,7 +305,7 @@ class TestPipeline:
             return pipeline_apply(params, x, stage_fn, mesh,
                                   num_microbatches=2).sum()
 
-        grads = jax.grad(loss)(stacked)
+        grads = jax.jit(jax.grad(loss))(stacked)
         assert np.isfinite(np.asarray(grads)).all()
         assert np.abs(np.asarray(grads)).sum() > 0
 
@@ -349,7 +354,7 @@ class TestPipeline1F1B:
             micro_y = y.reshape(M, -1, y.shape[-1])
             return jax.vmap(loss_fn)(micro_out, micro_y).mean()
 
-        loss_ref, grads_ref = jax.value_and_grad(gpipe_loss)(stacked)
+        loss_ref, grads_ref = jax.jit(jax.value_and_grad(gpipe_loss))(stacked)
         np.testing.assert_allclose(float(loss_1f1b), float(loss_ref),
                                    rtol=1e-5, atol=1e-6)
         for key in ("w", "b"):
@@ -382,7 +387,7 @@ class TestPipeline1F1B:
             micro_y = y.reshape(M, -1, d)
             return jax.vmap(loss_fn)(micro_out, micro_y).mean()
 
-        loss_ref, grads_ref = jax.value_and_grad(gpipe_loss)(stacked)
+        loss_ref, grads_ref = jax.jit(jax.value_and_grad(gpipe_loss))(stacked)
         np.testing.assert_allclose(float(loss), float(loss_ref),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(grads["w"]),
@@ -432,442 +437,3 @@ class TestPipeline1F1B:
         # the same static size both times.  Allow 2x slack for XLA temps
         # that legitimately scale with total batch (I/O staging etc.).
         assert large <= 2 * max(small, 1), (small, large)
-
-
-class TestPipelinedTransformer:
-    def test_matches_dense_forward(self):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig,
-            transformer_apply,
-            transformer_apply_pipelined,
-            transformer_init,
-        )
-
-        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("pp",))
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=4, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-        dense = transformer_apply(params, tokens, config)
-        piped = transformer_apply_pipelined(params, tokens, config, mesh,
-                                            num_microbatches=2)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(piped),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_pipelined_grads_flow(self):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig,
-            transformer_apply_pipelined,
-            transformer_init,
-        )
-
-        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("pp",))
-        config = TransformerConfig(
-            vocab_size=32, d_model=16, n_heads=2, n_layers=2, d_ff=32,
-            max_seq_len=16, dtype=jnp.float32, attention="reference",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jnp.ones((2, 8), jnp.int32)
-
-        def loss(params):
-            return transformer_apply_pipelined(
-                params, tokens, config, mesh, num_microbatches=2).sum()
-
-        grads = jax.grad(loss)(params)
-        flat = jax.tree.leaves(grads)
-        assert all(np.isfinite(np.asarray(g)).all() for g in flat)
-        assert sum(float(np.abs(np.asarray(g)).sum()) for g in flat) > 0
-
-
-class TestTransformerTrain1F1B:
-    """transformer_train_1f1b: the FULL flagship training step under the
-    1F1B schedule — loss and grads for every parameter (embedding,
-    positional, all layers, final norm, lm_head) must be gradient-
-    equivalent to autodiff over the dense forward."""
-
-    @staticmethod
-    def _reference(params, tokens, targets, config):
-        from kubeshare_tpu.models.transformer import transformer_apply
-        from kubeshare_tpu.parallel.train import cross_entropy_loss
-
-        def loss(p):
-            return cross_entropy_loss(
-                transformer_apply(p, tokens, config), targets)
-
-        return jax.value_and_grad(loss)(params)
-
-    @pytest.mark.parametrize("positional", ["learned", "rope"])
-    def test_matches_dense_autodiff(self, positional):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_train_1f1b)
-
-        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("pp",))
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=4, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-            positional=positional,
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-        targets = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, 64)
-
-        loss, grads = transformer_train_1f1b(
-            params, tokens, targets, config, mesh, num_microbatches=2)
-        loss_ref, grads_ref = self._reference(params, tokens, targets, config)
-
-        np.testing.assert_allclose(float(loss), float(loss_ref),
-                                   rtol=1e-5, atol=1e-6)
-        flat, flat_ref = jax.tree.leaves(grads), jax.tree.leaves(grads_ref)
-        assert len(flat) == len(flat_ref)
-        for g, g_ref in zip(flat, flat_ref):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                                       rtol=2e-4, atol=2e-5)
-
-    def test_1f1b_sp_ring_matches_dense_autodiff(self):
-        """1F1B x sp with ring attention in-stage — the flagship schedule:
-        gradients still match dense autodiff, every param included."""
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_train_1f1b)
-
-        pp, sp = 2, 2
-        mesh = Mesh(np.array(jax.devices()[:pp * sp]).reshape(pp, sp),
-                    ("pp", "sp"))
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=4, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="ring",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, 64)
-        targets = jax.random.randint(jax.random.PRNGKey(4), (4, 16), 0, 64)
-
-        loss, grads = transformer_train_1f1b(
-            params, tokens, targets, config, mesh, num_microbatches=2)
-        dense_config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=4, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, attention="reference",
-            positional="rope",
-        )
-        loss_ref, grads_ref = self._reference(
-            params, tokens, targets, dense_config)
-
-        np.testing.assert_allclose(float(loss), float(loss_ref),
-                                   rtol=1e-5, atol=1e-6)
-        for g, g_ref in zip(jax.tree.leaves(grads),
-                            jax.tree.leaves(grads_ref)):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                                       rtol=5e-4, atol=5e-5)
-
-    def test_1f1b_sp_ulysses_runs(self):
-        """Ulysses all-to-all in-stage under 1F1B: finite loss + grads."""
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init, transformer_train_1f1b)
-
-        pp, sp = 2, 2
-        mesh = Mesh(np.array(jax.devices()[:pp * sp]).reshape(pp, sp),
-                    ("pp", "sp"))
-        config = TransformerConfig(
-            vocab_size=32, d_model=16, n_heads=2, n_layers=2, d_ff=32,
-            max_seq_len=16, dtype=jnp.float32, attention="ulysses",
-            positional="rope",
-        )
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jnp.ones((2, 8), jnp.int32)
-
-        loss, grads = transformer_train_1f1b(
-            params, tokens, tokens, config, mesh, num_microbatches=2)
-        assert np.isfinite(float(loss))
-        flat = jax.tree.leaves(grads)
-        assert all(np.isfinite(np.asarray(g)).all() for g in flat)
-        assert sum(float(np.abs(np.asarray(g)).sum()) for g in flat) > 0
-
-
-class TestPipelineSequenceParallel:
-    """pp x sp composition: sequence-parallel attention (ring / Ulysses)
-    running INSIDE pipeline stages — activations flow sequence-sharded,
-    microbatches hop stages over pp, attention collectives run over sp."""
-
-    def _mesh(self, pp=2, sp=4):
-        devices = np.array(jax.devices()[:pp * sp]).reshape(pp, sp)
-        return Mesh(devices, ("pp", "sp"))
-
-    def _config(self, attention, **kw):
-        from kubeshare_tpu.models.transformer import TransformerConfig
-
-        return TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=64, dtype=jnp.float32, attention=attention,
-            positional="rope", **kw)
-
-    def _check_matches_dense(self, attention, **kw):
-        from dataclasses import replace
-
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply, transformer_apply_pipelined, transformer_init)
-
-        mesh = self._mesh()
-        config = self._config(attention, **kw)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 64)
-        dense = transformer_apply(
-            params, tokens, replace(config, attention="reference"))
-        piped = transformer_apply_pipelined(
-            params, tokens, config, mesh, num_microbatches=2)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(piped),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_ring_in_pipeline_matches_dense(self):
-        self._check_matches_dense("ring")
-
-    def test_ulysses_in_pipeline_matches_dense(self):
-        self._check_matches_dense("ulysses")
-
-    def test_windowed_ulysses_in_pipeline(self):
-        self._check_matches_dense("ulysses", attention_window=8)
-
-    def test_moe_still_rejected_on_pipelined_path(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply_pipelined, transformer_init)
-
-        mesh = self._mesh()
-        config = self._config("ring", moe_every=2, moe_num_experts=4)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jnp.zeros((4, 32), jnp.int32)
-        with pytest.raises(ValueError, match="MoE"):
-            transformer_apply_pipelined(params, tokens, config, mesh,
-                                        num_microbatches=2)
-
-
-    def test_windowed_ring_in_pipeline(self):
-        """Sliding-window attention through the in-stage einsum ring
-        (round 4: the ring path composes with windows now)."""
-        self._check_matches_dense("ring", attention_window=8)
-
-    def test_grads_flow_through_pp_sp(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply_pipelined, transformer_init)
-
-        mesh = self._mesh()
-        config = self._config("ring")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jnp.ones((4, 32), jnp.int32)
-        grads = jax.grad(lambda p: transformer_apply_pipelined(
-            p, tokens, config, mesh, num_microbatches=2).sum())(params)
-        flat = jax.tree_util.tree_leaves(grads)
-        assert all(np.isfinite(np.asarray(g)).all() for g in flat)
-        assert any(np.abs(np.asarray(g)).sum() > 0 for g in flat)
-
-    def test_missing_sp_axis_raises(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply_pipelined, transformer_init)
-
-        devices = np.array(jax.devices()[:2]).reshape(2)
-        mesh = Mesh(devices, ("pp",))
-        config = self._config("ring")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        with pytest.raises(ValueError, match="mesh axis"):
-            transformer_apply_pipelined(params, jnp.ones((2, 16), jnp.int32),
-                                        config, mesh)
-
-    def test_activation_spec_rejects_pp(self):
-        mesh = self._mesh()
-        stage_params = {"w": jnp.zeros((2, 4, 4))}
-        with pytest.raises(ValueError, match="must not shard"):
-            pipeline_apply(stage_params, jnp.zeros((4, 8, 4)),
-                           lambda p, x: x, mesh, 2,
-                           activation_spec=P("pp", None, None))
-
-
-    def test_ring_flash_in_pipeline_matches_dense(self):
-        """The Pallas-fused ring body (interpret mode) inside pipeline
-        stages — the pp x sp kernel path."""
-        from dataclasses import replace
-
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply, transformer_apply_pipelined, transformer_init)
-
-        mesh = self._mesh()
-        config = self._config("ring")
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 32), 0, 64)
-        dense = transformer_apply(
-            params, tokens, replace(config, attention="reference"))
-        piped = transformer_apply_pipelined(
-            params, tokens, config, mesh, num_microbatches=2,
-            use_flash=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(piped),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_1f1b_composes_with_sp(self):
-        """1F1B x sp: ring attention inside the stage body, losses pmean'd
-        and param grads psum'd over sp — gradient-equivalent to autodiff
-        over the sp-composed GPipe path."""
-        from kubeshare_tpu.ops.ring_attention import ring_attention
-        from kubeshare_tpu.parallel.pipeline import (
-            pipeline_apply, pipeline_train_1f1b, stack_stage_params)
-
-        pp, sp = 2, 4
-        devices = np.array(jax.devices()[:pp * sp]).reshape(pp, sp)
-        mesh = Mesh(devices, ("pp", "sp"))
-        d = 8
-        rng = jax.random.PRNGKey(0)
-        stacked = stack_stage_params([
-            {"w": jax.random.normal(jax.random.fold_in(rng, s), (d, d)) * 0.3}
-            for s in range(pp)
-        ])
-        x = jax.random.normal(jax.random.fold_in(rng, 10), (4, 32, d))
-        y = jax.random.normal(jax.random.fold_in(rng, 11), (4, 32, d))
-        spec = P(None, "sp", None)
-
-        def stage_fn(params, xin):
-            # toy attention stage: single head over the sequence shard
-            h = (xin @ params["w"])[:, None]  # [mb, 1, s_local, d]
-            att = ring_attention(h, h, h, axis_name="sp", causal=True)
-            return xin + att[:, 0]
-
-        def loss_fn(out, target):
-            return jnp.mean((out - target.astype(out.dtype)) ** 2)
-
-        loss_1f1b, grads_1f1b = pipeline_train_1f1b(
-            stacked, x, y, stage_fn, loss_fn, mesh, num_microbatches=2,
-            activation_spec=spec, target_spec=spec)
-
-        def gpipe_loss(params):
-            out = pipeline_apply(params, x, stage_fn, mesh, 2,
-                                 activation_spec=spec)
-            return jnp.mean((out.astype(jnp.float32) - y) ** 2)
-
-        loss_ref, grads_ref = jax.value_and_grad(gpipe_loss)(stacked)
-        np.testing.assert_allclose(float(loss_1f1b), float(loss_ref),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(grads_1f1b["w"]),
-                                   np.asarray(grads_ref["w"]),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_1f1b_sp_with_token_targets(self):
-        """Default target spec truncates the activation spec to y's rank
-        ([batch, seq] int targets vs [batch, seq, d] activations)."""
-        from kubeshare_tpu.parallel.pipeline import (
-            pipeline_train_1f1b, stack_stage_params)
-
-        pp, sp = 2, 2
-        devices = np.array(jax.devices()[:pp * sp]).reshape(pp, sp)
-        mesh = Mesh(devices, ("pp", "sp"))
-        d, vocab = 8, 16
-        rng = jax.random.PRNGKey(0)
-        stacked = stack_stage_params([
-            {"w": jax.random.normal(jax.random.fold_in(rng, s), (d, d)) * 0.3}
-            for s in range(pp)
-        ])
-        x = jax.random.normal(jax.random.fold_in(rng, 5), (4, 8, d))
-        y = jax.random.randint(jax.random.fold_in(rng, 6), (4, 8), 0, vocab)
-        proj = jax.random.normal(jax.random.fold_in(rng, 7), (d, vocab))
-
-        def stage_fn(params, xin):
-            return xin + jax.nn.gelu(xin @ params["w"])
-
-        def loss_fn(out, target):
-            logits = out @ proj.astype(out.dtype)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-            onehot = jax.nn.one_hot(target, vocab)
-            return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
-
-        loss, grads = pipeline_train_1f1b(
-            stacked, x, y, stage_fn, loss_fn, mesh, num_microbatches=2,
-            activation_spec=P(None, "sp", None))
-        assert np.isfinite(float(loss))
-        assert np.isfinite(np.asarray(grads["w"])).all()
-
-
-class TestMoESequenceParallel:
-    """MoE layers on the standalone ring/ulysses entries (round 4):
-    routing is per-token, so each sequence shard routes locally with
-    shard-derived expert buffers; at no-drop capacities the output must
-    equal the dense entry exactly."""
-
-    def _setup(self, **extra):
-        from kubeshare_tpu.models.transformer import (
-            TransformerConfig, transformer_init)
-
-        config = TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq_len=64, dtype=jnp.float32, attention="reference",
-            moe_every=2, moe_num_experts=4, moe_top_k=2,
-            # generous capacity: no drops on either the global (dense) or
-            # the per-shard derivation, so outputs are exactly comparable
-            moe_capacity_factor=4.0, **extra)
-        params = transformer_init(jax.random.PRNGKey(0), config)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
-        return config, params, tokens
-
-    def test_moe_ring_matches_dense(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply, transformer_apply_ring)
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config, params, tokens = self._setup()
-        dense = transformer_apply(params, tokens, config)
-        ring = transformer_apply_ring(params, tokens, config, mesh)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(ring),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_moe_ulysses_matches_dense_with_aux(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply, transformer_apply_ulysses)
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config, params, tokens = self._setup()
-        dense = transformer_apply(params, tokens, config)
-        out, aux = transformer_apply_ulysses(params, tokens, config, mesh,
-                                             with_aux=True)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
-        # the sp-mean aux estimator is a usable load-balancing signal
-        assert np.isfinite(float(aux)) and float(aux) > 0
-
-    def test_moe_zigzag_ring_matches_dense(self):
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply, transformer_apply_ring)
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config, params, tokens = self._setup(positional="rope")
-        dense = transformer_apply(params, tokens, config)
-        ring = transformer_apply_ring(params, tokens, config, mesh,
-                                      layout="zigzag", use_flash=False)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(ring),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_experts_choose_rejected_on_sp_entries(self):
-        """Expert-choice routing is whole-batch routing — a sequence
-        shard cannot route it locally (per-shard selection materially
-        diverges from the dense entry), so the sp entries refuse it."""
-        from kubeshare_tpu.models.transformer import (
-            transformer_apply_ring, transformer_init)
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config, params, tokens = self._setup()
-        from dataclasses import replace
-
-        ec = replace(config, moe_routing="experts_choose")
-        ec_params = transformer_init(jax.random.PRNGKey(0), ec)
-        with pytest.raises(ValueError, match="whole-batch"):
-            transformer_apply_ring(ec_params, tokens, ec, mesh)
-
-    def test_moe_ring_grads_flow(self):
-        from kubeshare_tpu.models.transformer import transformer_apply_ring
-        from kubeshare_tpu.parallel.train import cross_entropy_loss
-
-        mesh = make_mesh(MeshSpec(dp=2, tp=1, sp=4))
-        config, params, tokens = self._setup()
-
-        def loss(p):
-            logits, aux = transformer_apply_ring(
-                p, tokens, config, mesh, with_aux=True)
-            return cross_entropy_loss(logits, tokens) + 0.01 * aux
-
-        grads = jax.grad(loss)(params)
-        g = np.asarray(grads["layers"][1]["moe"]["w_in"])
-        assert np.isfinite(g).all() and np.abs(g).sum() > 0

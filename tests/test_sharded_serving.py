@@ -8,7 +8,7 @@ round-trips, and speculation — with zero recompiles after warmup.  Plus
 the strict-mesh satellite: ``MeshSpec.resolve`` rejects degenerate
 specs loudly and ``serving_mesh`` builds the serving preset.
 
-Workload geometries deliberately mirror tests/test_serving.py's (same
+Workload geometries deliberately mirror tests/test_serving*.py's (same
 prompts, same PRNG seeds, same engine shapes) so the single-device
 references hit the persistent compile cache instead of compiling anew.
 """
