@@ -143,6 +143,21 @@ class TransformerConfig:
     conv_taps: int = 0
     # what the renormalised weights' sum is kept off zero by
     router_renormalise_eps: float = 1e-20
+    # two more attention KINDS a layer may name beside "attention" (the
+    # block's: every earlier row, rotated): "global" sees every earlier row
+    # and rotates NOTHING (no position enters it), "window" sees row j from
+    # row i iff 0 <= i - j < attention_window and rotates.  A model that
+    # names "window" caches BY KIND: the "attention"/"global" layers' rows
+    # in one pool, the "window" layers' in another whose pages behind the
+    # window go back while the request runs (serving/kv_blocks.py).  Three
+    # switches of the 'gqa_moe' layer, each as every earlier configuration
+    # has it by default: the RMSNorm over each head's q and k; what the
+    # router reads, "post_attention" (norm2's output, as the experts) or
+    # "layer_input" (the residual stream entering the layer, before norm1
+    # and the attention); and the experts' gate activation, "silu" | "relu"
+    qk_norm: bool = True
+    router_input: str = "post_attention"
+    expert_activation: str = "silu"
 
     def __post_init__(self) -> None:
         if self.layer_operators is not None:
@@ -162,8 +177,16 @@ class TransformerConfig:
         """Attention sub-layers, each with a cache row of its own: the
         layers of the KV pool."""
         if self.layer_operators is not None:
-            return self.layer_operators.count("attention")
+            return sum(POOL_OF[name] != "conv"
+                       for name in self.layer_operators)
         return _LATENT_SUBLAYERS.get(self.block, 1) * self.n_layers
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that name the "window" kind: the layers of the cache's
+        second pool (0: the model caches under one table a lane)."""
+        return (0 if self.layer_operators is None
+                else self.layer_operators.count("window"))
 
     @property
     def conv_layers(self) -> int:
@@ -174,12 +197,13 @@ class TransformerConfig:
 
     def operator_index(self, layer_idx: int) -> Tuple[str, int]:
         """Layer ``layer_idx``'s operator and its place among the layers
-        of that operator: an attention layer's pool layer, a convolution's
-        state."""
+        that keep what it keeps (``POOL_OF``): an attention layer's layer of
+        its kind's pool, a convolution's state."""
         if self.layer_operators is None:
             return "attention", layer_idx
         name = self.layer_operators[layer_idx]
-        return name, self.layer_operators[:layer_idx].count(name)
+        return name, sum(POOL_OF[other] == POOL_OF[name]
+                         for other in self.layer_operators[:layer_idx])
 
     @property
     def expert_layers(self) -> int:
@@ -236,8 +260,11 @@ def _check_routed(config: TransformerConfig) -> None:
             raise ValueError(
                 f"block {config.block!r} needs {name} >= 1, got "
                 f"{getattr(config, name)}")
-    if config.moe_every is not None or config.attention_window is not None \
-            or config.positional != "rope":
+    # a window is a layer KIND's ('gqa_moe' layers that name "window":
+    # _check_operators), never the whole block's
+    if config.moe_every is not None or config.positional != "rope" or (
+            config.attention_window is not None
+            and not config.window_layers):
         raise ValueError(
             f"block {config.block!r} takes neither moe_every nor "
             f"attention_window, and positional='rope' (it has no learned "
@@ -280,6 +307,10 @@ def _check_gqa_moe(config: TransformerConfig) -> None:
             f"last layer is an expert layer) with d_ff >= 1 the dense "
             f"width, got {config.first_dense_layers} and {config.d_ff}")
     _check_operators(config)
+    if not config.qk_norm and config.layer_operators is None:
+        raise ValueError(
+            "qk_norm=False is served where the layers name their operator "
+            "(the projections held as matrices: retention_qkv)")
     b, steps = config.diffusion_block, config.diffusion_steps
     if b < 0 or (b == 0 and (steps or config.mask_token)):
         raise ValueError(
@@ -296,7 +327,12 @@ def _check_gqa_moe(config: TransformerConfig) -> None:
             f"{config.vocab_size} ids")
 
 
-OPERATORS = ("attention", "conv")
+# an operator a layer may name -> what keeps its rows: the pool of the
+# full kind (every row of a request, under one table), the pool of the
+# window kind (the rows a window still reaches), or a state by slot
+POOL_OF = {"attention": "full", "global": "full", "window": "window",
+           "conv": "conv"}
+OPERATORS = tuple(POOL_OF)
 
 
 def _check_operators(config: TransformerConfig) -> None:
@@ -311,10 +347,20 @@ def _check_operators(config: TransformerConfig) -> None:
         raise ValueError(
             f"layer_operators names one of {OPERATORS} a layer, "
             f"{config.n_layers} in all, got {ops!r}")
-    if "attention" not in ops:
+    if not any(POOL_OF[name] == "full" for name in ops):
         raise ValueError(
-            "layer_operators names no 'attention' layer: the paged pool "
-            "would hold nothing")
+            "layer_operators names no 'attention' or 'global' layer: the "
+            "paged pool would hold nothing")
+    window = config.attention_window
+    if ("window" in ops) != (window is not None) or (
+            window is not None and window < 1):
+        raise ValueError(
+            f"attention_window must be >= 1 where a layer's operator is "
+            f"'window' and None where none is, got {window}")
+    if "window" in ops and "conv" in ops:
+        raise ValueError(
+            "layer_operators names both 'window' and 'conv': a cache by "
+            "layer kind beside a state by slot is not served")
     if ("conv" in ops) != (taps >= 2):
         raise ValueError(
             f"conv_taps must be >= 2 where a layer's operator is 'conv' "
@@ -322,7 +368,8 @@ def _check_operators(config: TransformerConfig) -> None:
     if config.diffusion_block:
         raise ValueError(
             "layer_operators is served under the causal mask only: a "
-            "convolution's state has no block to see both ways")
+            "convolution's state has no block to see both ways, and a "
+            "window cuts through one")
 
 
 def _check_retention(config: TransformerConfig) -> None:
@@ -342,8 +389,19 @@ def _check_retention(config: TransformerConfig) -> None:
 
 
 def _check_block(config: TransformerConfig) -> None:
+    if config.router_input not in ("post_attention", "layer_input") \
+            or config.expert_activation not in ("silu", "relu"):
+        raise ValueError(
+            f"router_input must be 'post_attention' or 'layer_input' and "
+            f"expert_activation 'silu' or 'relu', got "
+            f"{config.router_input!r} and {config.expert_activation!r}")
     if config.block == "gqa_moe":
         return _check_gqa_moe(config)
+    if not config.qk_norm or config.router_input != "post_attention" \
+            or config.expert_activation != "silu":
+        raise ValueError(
+            f"qk_norm, router_input and expert_activation are block "
+            f"'gqa_moe''s; block {config.block!r} takes none of them")
     if config.block not in ("dense", "retention") and not config.latent:
         raise ValueError(
             f"block must be 'dense', 'gqa_moe', 'retention', "
@@ -482,9 +540,10 @@ def _gqa_moe_layer_init(keys, config: TransformerConfig, dense,
         layer["attn"] = {"wq": dense(next(keys), heads(h), d),
                          "wk": dense(next(keys), heads(h_kv), d),
                          "wv": dense(next(keys), heads(h_kv), d),
-                         "wo": dense(next(keys), (h, hd, d), h * hd),
-                         "q_norm": {"scale": jnp.ones((hd,))},
-                         "k_norm": {"scale": jnp.ones((hd,))}}
+                         "wo": dense(next(keys), (h, hd, d), h * hd)}
+        if config.qk_norm:
+            layer["attn"].update(q_norm={"scale": jnp.ones((hd,))},
+                                 k_norm={"scale": jnp.ones((hd,))})
     if layer_idx < config.first_dense_layers:
         f = config.d_ff
         layer["ffn"] = {"w_gate": dense(next(keys), (d, f), d),
@@ -725,7 +784,11 @@ def attend_key_blocks(view_block, block_rows: int, scores_of, context_of,
     ``positions[b, i]`` and sees rows at or before it (and, with a
     ``window``, fewer than ``window`` rows back).  Only the blocks that
     hold a row some query may see are asked for — up to the largest of
-    ``positions`` — and the softmax is carried across them in float32
+    ``positions`` and, under a ``window``, from the block that holds the
+    first row the step's earliest query still reaches (``min(positions) -
+    window + 1``: a 512-row chunk at row 12,288 under a window of 4,096
+    walks 9 blocks of 512, not 25) — and the softmax is carried across them
+    in float32
     (running maximum, running sum, rescaled context), so a step's
     attention costs what its lanes hold, not what a lane may hold.
     Returns the normalised context, float32: the numbers of the whole
@@ -765,8 +828,10 @@ def attend_key_blocks(view_block, block_rows: int, scores_of, context_of,
     # block can be wholly masked for a query, and -inf - (-inf) is NaN:
     # there the maximum starts from a finite floor no score reaches.
     floor = -jnp.inf if window is None else jnp.finfo(f32).min / 2
+    first = 0 if window is None else jnp.maximum(
+        jnp.min(positions) - window + 1, 0) // block_rows
     _, total, ctx = jax.lax.fori_loop(
-        0, jnp.max(positions) // block_rows + 1, step,
+        first, jnp.max(positions) // block_rows + 1, step,
         (jnp.full(lead, floor, f32), jnp.zeros(lead, f32),
          jnp.zeros((*lead, width), f32)))
     return ctx / total[..., None]
@@ -844,11 +909,23 @@ def gated_ffn(ffn, y, dtype):
     return hidden @ ffn["w_down"].astype(dtype)
 
 
-def routed_experts(moe, config: TransformerConfig, y, live=None):
+def _router_law(config: TransformerConfig) -> Dict:
+    """The router's law as ops/moe.py takes it."""
+    return dict(top_k=config.router_top_k,
+                scale=config.routed_scaling_factor,
+                scoring=config.router_scoring,
+                renormalise=config.router_renormalise,
+                renormalise_eps=config.router_renormalise_eps)
+
+
+def routed_experts(moe, config: TransformerConfig, y, live=None,
+                   choices=None):
     """A layer's routed experts over ``y`` [B, C, d] -> (out [B, C, d],
     routing counts int32[6]: see ops/moe.py).  A row that ``live``
     [B, C] says is dead (an idle lane, a chunk's padding) chooses
-    nothing.  The tiles run as one Pallas kernel where the backend the
+    nothing.  ``choices``: the rows' experts and weights where the router
+    read something else than ``y`` (ops/moe.py ``route``; None: it reads
+    ``y``).  The tiles run as one Pallas kernel where the backend the
     program is being built for can run one and the experts' shapes fit
     it (ops/moe.py ``expert_path``; ``serving.paged._kernel_mode`` is
     the one place that says how a kernel can run, the attention's and
@@ -859,14 +936,11 @@ def routed_experts(moe, config: TransformerConfig, y, live=None):
     b, c, d = y.shape
     out, counts = routed_experts_apply(
         moe, y.reshape(b * c, d),
-        n_routed=config.n_routed_experts, top_k=config.router_top_k,
-        scale=config.routed_scaling_factor,
+        n_routed=config.n_routed_experts,
         first_held=config.first_expert_held,
-        scoring=config.router_scoring,
-        renormalise=config.router_renormalise,
-        renormalise_eps=config.router_renormalise_eps,
         live=None if live is None else live.reshape(b * c),
-        kernel_mode=_kernel_mode())
+        kernel_mode=_kernel_mode(), choices=choices,
+        activation=config.expert_activation, **_router_law(config))
     return out.reshape(b, c, d), counts
 
 
@@ -977,14 +1051,17 @@ def attend_reach(config: TransformerConfig, positions):
 
 
 @jax.named_scope("attention")
-def gqa_qkv(attn, y, positions, config: TransformerConfig):
+def gqa_qkv(attn, y, positions, config: TransformerConfig,
+            rotate: bool = True):
     """A 'gqa_moe' layer's projections of ``y`` [B, C, d] at ``positions``
     [B, C]: ``q`` [B, H, C, hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k
     normed a head and then rotated (split halves).  Weights held as
     matrices ``[d, heads x hd]`` (a model whose layers name their operator)
-    go through :func:`retention_qkv`, the same numbers."""
+    go through :func:`retention_qkv`, the same numbers — and only there
+    can a model leave the norms out (``qk_norm``) and a layer the rotation
+    (``rotate``: the "global" kind's)."""
     if attn["wq"].ndim == 2:
-        return retention_qkv(attn, y, positions, config)
+        return retention_qkv(attn, y, positions, config, rotate)
     dtype, eps = config.dtype, config.norm_eps
     q = jnp.einsum("bsd,dhk->bhsk", y, attn["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bhsk", y, attn["wk"].astype(dtype))
@@ -1007,25 +1084,33 @@ def gqa_moe_layers(params, x, config: TransformerConfig, attend_row,
     callers differ, as in :func:`latent_layers`: the unpaged forward attends
     its own rows and convolves from zeros, a cached step writes the row and
     attends the lane's view, reads the lane's state and leaves the new one.
-    Returns (x, routing counts int32[6] summed over the expert layers:
-    ops/moe.py)."""
-    from ..ops.moe import ROUTING_COUNTS
+    ``attend_row`` also hears the operator the layer names ("attention",
+    "global", "window": what it rotates, what it sees and which pool keeps
+    its rows).  Returns (x, routing counts int32[6] summed over the expert
+    layers: ops/moe.py)."""
+    from ..ops.moe import ROUTING_COUNTS, route
 
     dtype, eps = config.dtype, config.norm_eps
     counts = jnp.zeros((len(ROUTING_COUNTS),), jnp.int32)
+    early = config.router_input == "layer_input"
     for i, layer in enumerate(params["layers"]):
         operator, index = config.operator_index(i)
+        # a router that reads the layer's input chooses before the operator
+        choices = (route(layer["moe"], x.reshape(-1, x.shape[-1]),
+                         **_router_law(config))
+                   if early and "moe" in layer else None)
         y = _rms_norm(x, layer["norm1"]["scale"], eps)
         if operator == "conv":
             x = x + conv_row(index, layer["conv"], y)
         else:
-            o = attend_row(index, layer["attn"], y).astype(dtype)
+            o = attend_row(index, layer["attn"], y, operator).astype(dtype)
             with jax.named_scope("attention"):
                 x = x + jnp.einsum("bhsk,hkd->bsd", o,
                                    layer["attn"]["wo"].astype(dtype))
         y = _rms_norm(x, layer["norm2"]["scale"], eps)
         if "moe" in layer:
-            out, layer_counts = routed_experts(layer["moe"], config, y, live)
+            out, layer_counts = routed_experts(layer["moe"], config, y, live,
+                                               choices)
             x = x + out
             counts = counts + layer_counts
         else:
@@ -1046,14 +1131,19 @@ def _gqa_moe_forward(params, tokens, config: TransformerConfig,
     positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (b, seq))
     seen = jnp.arange(seq)[None, None, :] \
         <= attend_reach(config, positions)[:, :, None]  # [B, C, S]
+    # a "window" layer's mask: fewer than attention_window rows back
+    near = seen if config.attention_window is None else seen & (
+        positions[:, :, None] - jnp.arange(seq)[None, None, :]
+        < config.attention_window)
     group = config.n_heads // config.kv_heads
 
-    def attend(_, attn, y):
-        q, k, v = gqa_qkv(attn, y, positions, config)
+    def attend(_, attn, y, operator):
+        q, k, v = gqa_qkv(attn, y, positions, config, operator != "global")
         qg = q.reshape(b, config.kv_heads, group, seq, config.head_dim)
         scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k).astype(
             jnp.float32) * config.head_dim ** -0.5
-        scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
+        mask = near if operator == "window" else seen
+        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
         return jnp.einsum("bhgqk,bhkd->bhgqd", probs, v).reshape(q.shape)
 
@@ -1079,11 +1169,13 @@ def _gqa_moe_forward(params, tokens, config: TransformerConfig,
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("attention")
-def retention_qkv(attn, y, positions, config: TransformerConfig):
+def retention_qkv(attn, y, positions, config: TransformerConfig,
+                  rotate: bool = True):
     """A 'retention' layer's projections of ``y`` [B, C, d] at
     ``positions`` [B, C], as :func:`gqa_qkv` gives them — ``q`` [B, H, C,
-    hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k normed a head and then
-    rotated (split halves) — from weights held as matrices."""
+    hd], ``k`` and ``v`` [B, H_kv, C, hd], q and k normed a head (unless the
+    model has no such norm) and then rotated (split halves; unless the
+    layer rotates nothing) — from weights held as matrices."""
     dtype, eps = config.dtype, config.norm_eps
     b, c, _ = y.shape
 
@@ -1092,9 +1184,12 @@ def retention_qkv(attn, y, positions, config: TransformerConfig):
         return out.reshape(b, c, -1, config.head_dim).transpose(0, 2, 1, 3)
 
     q, k, v = heads(attn["wq"]), heads(attn["wk"]), heads(attn["wv"])
-    with jax.named_scope("qk_norm"):
-        q = _rms_norm(q, attn["q_norm"]["scale"], eps)
-        k = _rms_norm(k, attn["k_norm"]["scale"], eps)
+    if config.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, attn["q_norm"]["scale"], eps)
+            k = _rms_norm(k, attn["k_norm"]["scale"], eps)
+    if not rotate:
+        return q, k, v
     return (apply_rope(q, positions, theta=config.rope_theta),
             apply_rope(k, positions, theta=config.rope_theta), v)
 

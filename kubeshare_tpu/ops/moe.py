@@ -336,9 +336,14 @@ def expert_path(moe: Dict, n: int, kernel_mode: Optional[str]) -> str:
     return "kernel" if kernel_mode and expert_kernel_fits(moe, n) else "loop"
 
 
+# the experts' gate activation a configuration may name
+# (TransformerConfig.expert_activation), the kernel's and the loop's alike
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _experts_kernel(expert_ref, rows_ref, n_tiles_ref, token_ref, x_ref,
                     gate_ref, wg_ref, wu_ref, wd_ref, o_ref, tile_ref,
-                    result_ref, *, dtype):
+                    result_ref, *, dtype, activation="silu"):
     step, block = pl.program_id(0), pl.program_id(1)
     tile = tile_ref.shape[0]
     f32 = jnp.float32
@@ -373,7 +378,7 @@ def _experts_kernel(expert_ref, rows_ref, n_tiles_ref, token_ref, x_ref,
 
         # the loop's mathematics, rounded where its program on the chip
         # rounds: the products accumulated in float32 and rounded to the
-        # rows' dtype (a dot's result), SiLU and the product of the two
+        # rows' dtype (a dot's result), the activation and the product of the two
         # in float32 (the vector unit has no narrower arithmetic, and
         # XLA's fused body keeps float32 there too), the hidden rounded
         # once; the down product and the weight in float32
@@ -384,7 +389,8 @@ def _experts_kernel(expert_ref, rows_ref, n_tiles_ref, token_ref, x_ref,
                            preferred_element_type=f32)
             return wide.astype(dtype).astype(f32)
 
-        hidden = (jax.nn.silu(product(wg_ref)) * product(wu_ref)).astype(dtype)
+        hidden = (ACTIVATIONS[activation](product(wg_ref))
+                  * product(wu_ref)).astype(dtype)
         part = gate_ref[...] * jnp.dot(hidden, wd_ref[...].astype(dtype),
                                        preferred_element_type=f32)
 
@@ -404,11 +410,12 @@ def _experts_kernel(expert_ref, rows_ref, n_tiles_ref, token_ref, x_ref,
             each_row(add)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "dtype", "interpret"),
+@functools.partial(jax.jit, static_argnames=("tile", "dtype", "interpret",
+                                             "activation"),
                    inline=True)
 def grouped_experts(x, slot_token, slot_gate, tile_expert, tile_rows, n_tiles,
                     w_gate, w_up, w_down, *, tile: int, dtype,
-                    interpret: bool = False):
+                    interpret: bool = False, activation: str = "silu"):
     """Every live tile's expert over its rows, added up a row, as ONE
     Pallas TPU kernel.  ``x`` [n, d] float32 (values of ``dtype``, the
     rows' own: a row of 32-bit words can be picked by its index);
@@ -417,8 +424,9 @@ def grouped_experts(x, slot_token, slot_gate, tile_expert, tile_rows, n_tiles,
     0), a tile's ``tile`` slots side by side; ``tile_expert`` /
     ``tile_rows`` [slots / tile] the expert and the live rows of each
     tile, ``n_tiles`` how many tiles are live.  Returns [n, d] float32:
-    row t the sum over its slots s of ``slot_gate[s] * (silu(x_t Wg) *
-    (x_t Wu)) Wd`` under the slot's tile's expert, in tile order.
+    row t the sum over its slots s of ``slot_gate[s] * (act(x_t Wg) *
+    (x_t Wu)) Wd`` under the slot's tile's expert, in tile order
+    (``activation``: "silu" or "relu", ``ACTIVATIONS``).
 
     A grid over tiles, and over blocks of the expert's width where two
     copies of the whole expert do not fit fast memory
@@ -459,7 +467,8 @@ def grouped_experts(x, slot_token, slot_gate, tile_expert, tile_rows, n_tiles,
 
     ints = lambda a: a.astype(jnp.int32)
     return pl.pallas_call(
-        functools.partial(_experts_kernel, dtype=dtype),
+        functools.partial(_experts_kernel, dtype=dtype,
+                          activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(slots // tile, blocks),
@@ -515,6 +524,25 @@ def router_choices(logits: jax.Array, bias: Optional[jax.Array], *,
     return gate * scale, index
 
 
+def _route(moe: Dict, y: jax.Array, **law) -> Tuple[jax.Array, jax.Array]:
+    """The router of an expert layer over the rows it reads, ``y`` [n, d]:
+    its float32 outputs (``moe["router"]`` [d, outputs], with ``moe["bias"]``
+    where the model has a choice bias) turned into each row's choices by
+    ``law`` (:func:`router_choices`' keywords) -> (``gate``, ``index``),
+    both [n, top_k]."""
+    # bf16 x bf16 products are exact in float32, so this IS the
+    # float32 product of the values the block holds
+    logits = jnp.dot(y, moe["router"].astype(y.dtype),
+                     preferred_element_type=jnp.float32)
+    return router_choices(logits, moe.get("bias"), **law)
+
+
+# the router called apart from its experts (a layer whose router reads the
+# layer's input chooses before the attention), under the scopes it has
+# inside :func:`routed_experts_apply`: stage `experts` keeps it
+route = jax.named_scope("experts")(jax.named_scope("router")(_route))
+
+
 @jax.named_scope("experts")
 def routed_experts_apply(
     moe: Dict,
@@ -529,13 +557,17 @@ def routed_experts_apply(
     renormalise_eps: float = 1e-20,
     live: Optional[jax.Array] = None,
     kernel_mode: Optional[str] = None,
+    choices: Optional[Tuple[jax.Array, jax.Array]] = None,
+    activation: str = "silu",
 ) -> Tuple[jax.Array, jax.Array]:
     """The part of a routed expert layer that THIS device computes.
 
     ``y`` [n, d].  The router (``moe["router"]`` [d, n_routed + n_zero],
     with ``moe["bias"]`` [n_routed + n_zero] where the model has a choice
     bias) keeps every output, and :func:`router_choices` turns a row's
-    float32 outputs into its ``top_k`` choices and their weights.
+    float32 outputs into its ``top_k`` choices and their weights
+    (:func:`_route`; ``choices``, where the router read other rows than
+    ``y`` — the layer's input, say — are what it made of those).
     Experts ``>= n_routed`` are zero-compute: they return their input,
     so all of a token's identity choices are ONE weighted add.  Of the
     routed experts this device holds ``moe["w_gate"].shape[0]`` from
@@ -572,15 +604,12 @@ def routed_experts_apply(
     """
     n, d = y.shape
     e_held = moe["w_gate"].shape[0]
-    with jax.named_scope("router"):
-        # bf16 x bf16 products are exact in float32, so this IS the
-        # float32 product of the values the block holds
-        logits = jnp.dot(y, moe["router"].astype(y.dtype),
-                         preferred_element_type=jnp.float32)
-        gate, index = router_choices(
-            logits, moe.get("bias"), top_k=top_k, scale=scale,
-            scoring=scoring, renormalise=renormalise,
-            renormalise_eps=renormalise_eps)  # [n, k]
+    if choices is None:
+        with jax.named_scope("router"):
+            choices = _route(moe, y, top_k=top_k, scale=scale,
+                             scoring=scoring, renormalise=renormalise,
+                             renormalise_eps=renormalise_eps)
+    gate, index = choices  # [n, k]
     chose = jnp.ones((n, 1), bool) if live is None else live[:, None]
     local = index - first_held
     held = chose & (local >= 0) & (local < e_held) & (index < n_routed)
@@ -625,7 +654,8 @@ def routed_experts_apply(
         out = out + grouped_experts(
             y32, jnp.minimum(slot_token, n - 1), slot_gate, tile_expert,
             tile_rows, n_tiles, moe["w_gate"], moe["w_up"], moe["w_down"],
-            tile=tile, dtype=dtype, interpret=kernel_mode == "interpret")
+            tile=tile, dtype=dtype, interpret=kernel_mode == "interpret",
+            activation=activation)
     else:
         rows_of = jnp.concatenate([y, jnp.zeros((1, d), dtype)])  # n: pad
 
@@ -636,7 +666,7 @@ def routed_experts_apply(
             rows = rows_of[token]  # [tile, d]; pad slots read the zero row
             pick = lambda w: jax.lax.dynamic_index_in_dim(
                 w, expert, keepdims=False).astype(dtype)
-            hidden = jax.nn.silu(rows @ pick(moe["w_gate"])) \
+            hidden = ACTIVATIONS[activation](rows @ pick(moe["w_gate"])) \
                 * (rows @ pick(moe["w_up"]))
             result = jnp.dot(hidden, pick(moe["w_down"]),
                              preferred_element_type=jnp.float32)

@@ -124,14 +124,16 @@ def _walk_pages(tables_ref, positions_ref, next_ref, sems, copies, *, lanes,
     bs = copies[0][1].shape[3]
     rows = pages * bs
 
-    def live(lane):
-        # block 0 is the scratch block: no live lane's view starts there
-        return tables_ref[lane * width] != 0
-
     def first_page(lane):
         if window is None:
             return 0
         return jnp.maximum(positions_ref[lane] - window + 1, 0) // bs
+
+    def live(lane):
+        # block 0 is the scratch block: no live lane's view starts there —
+        # its view under a window, whose earlier entries may have been
+        # handed back (a released entry points at the scratch block)
+        return tables_ref[lane * width + first_page(lane)] != 0
 
     def groups_of(lane, block):
         """Compute block ``block`` of ``lane``: where its first page's
@@ -325,7 +327,7 @@ def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
     head are one query group of the kernel, ``[lanes, h_kv, (h / h_kv) x
     C, d]`` (32 rows a KV head at ``sdar-30b-a3b-chat``: two whole bf16
     tiles), padded to whole tiles here and cut off after.  A lane whose
-    table starts at the scratch block is idle and reads zeros.  ``scale``
+    table starts (under a ``window``: whose window starts) at the scratch block is idle and reads zeros.  ``scale``
     is what the scores are multiplied by (None: ``d ** -0.5``; heads
     narrower than the pool's row, laid into a part of a row of zeros, say
     their own: ``kv_blocks.KVRowLayout`` ``heads_paired``).
@@ -338,6 +340,8 @@ def paged_decode_attention(q, pool_k, pool_v, layer_idx, tables, positions,
     lanes, h, rows, d = q.shape
     h_kv, bs = pool_k.shape[2], pool_k.shape[3]
     group = h // h_kv * rows
+    # a group that is no whole tile (7 query heads a KV head, 12) is padded
+    # here, inside the call, and cut off after it
     tile = sublanes(q.dtype)
     padded = -(-group // tile) * tile
     q = jnp.pad(q.reshape(lanes, h_kv, group, d),

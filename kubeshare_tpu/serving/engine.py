@@ -131,8 +131,8 @@ from .autotune import AnalyticPolicy, AutoTuner
 from .drafter import NGramDrafter
 from .kv_blocks import (BlockAllocator, BlockExhausted, QuotaExceeded,
                         init_conv_states, init_paged_pool,
-                        init_retention_states,
-                        kv_row_layout)
+                        init_retention_states, kind_blocks,
+                        kv_row_layout, window_reserve_rows)
 from .kv_tier import (DiskTier, HostTier, LRUTierPolicy, QoSTierPolicy,
                       WireCorruption, pack_block, unpack_block,
                       wire_block_bytes)
@@ -527,8 +527,37 @@ def _config_rows(ec: EngineConfig, config: TransformerConfig,
         ("autotune=True (the tuner re-slices chunks and arms the loops)",
          ec.autotune),
     ) if asked]
+    # what cannot hold a cache by layer kind (a table, a pool and an
+    # allocator a kind; a window kind's pages go back while the request
+    # runs): everything that takes a lane's ONE chain of pages for its
+    # whole context.  The prefix index and its copy-on-write matches are
+    # not in the list: such an engine keeps no index (as one with a state
+    # by slot), whatever prefix_cache says
+    no_kinds = [name for name, asked in (
+        ("speculative=True (the draft-verify programs write and roll back "
+         "one table's rows)", ec.speculative),
+        ("steps_per_launch > 1 (the device-resident loops run past the "
+         "dispatch whose end hands a window's pages back)",
+         ec.steps_per_launch > 1),
+        ("mesh_spec (serving/sharded.py's twins shard one pool's KV-head "
+         "axis)", ec.mesh_spec is not None),
+        ("host_tier_bytes (serving/kv_tier.py packs a page of every "
+         "layer; a window layer's is gone)", ec.host_tier_bytes is not None),
+        ("a shared host tier (serving/kv_tier.py, serving/fabric.py)",
+         shared_host_tier is not None),
+        (f"pool_role={ec.pool_role!r} (serving/disagg.py migrates one "
+         f"chain of pages a request)", ec.pool_role != "both"),
+        ("autotune=True (the tuner arms the loops and re-slices chunks "
+         "past what a window lane is funded for)", ec.autotune),
+    ) if asked]
     key_block = paged.KEY_BLOCK
     return [
+        (bool(layout.window_layers) and bool(no_kinds),
+         f"this model caches BY LAYER KIND ({layout.window_layers} of its "
+         f"{layout.layers} attention layers keep a window of "
+         f"{config.attention_window} rows in a pool of their own, under a "
+         f"table and an allocator of their own), which is not served yet "
+         f"by: {'; '.join(no_kinds)}"),
         (stateful and bool(no_state),
          f"block {config.block!r} serves a state a lane BY SLOT beside its "
          f"paged rows ("
@@ -812,7 +841,7 @@ class _Slot:
         "prompt", "plan", "max_new", "temperature", "first_key",
         "step_keys", "result", "tenant", "emitted_prefix",
         "last_token_at", "drafter", "draft_width", "accept_rate",
-        "block", "folded", "paged_to",
+        "block", "folded", "paged_to", "window_blocks", "window_to",
     )
 
     def __init__(self, idx: int, table_width: int) -> None:
@@ -859,6 +888,13 @@ class _Slot:
         # the table entries it has been given pages for so far
         self.folded = 0
         self.paged_to = 0
+        # a lane of a cache by layer kind: the window kind's pages it
+        # holds, in the order of the entries they fill of that kind's table
+        # (the second half of ``table``) — the ``len(window_blocks)``
+        # entries that end at ``window_to``; the entries behind have been
+        # handed back, those ahead are not drawn yet
+        self.window_blocks: List[int] = []
+        self.window_to = 0
 
 
 def _program_name(kind: str) -> str:
@@ -939,10 +975,36 @@ class ServingEngine:
         self.model_config = config
         self.engine_config = ec
         self.guard = guard
+        # the warmed prefill-chunk bucket universe — warmup compiles
+        # exactly this set, and the autotuner's fused-budget envelope
+        # is confined to it (a tuned budget can only select among
+        # already-compiled shapes)
+        self._warmed_widths = _warmed_prefill_widths(
+            ec, _bucket_floor(ec, config))
+        # a cache BY LAYER KIND (a layer names the "window" kind): a pool,
+        # a table and an allocator a kind.  ``num_blocks`` stays the
+        # pool's bytes in blocks of every layer's row, divided between the
+        # kinds by what the engine can see (kv_blocks.kind_blocks); a lane
+        # is funded for ``_window_pages`` pages of the window kind whatever
+        # its length, and that kind's pages behind the window go back after
+        # every dispatch (:meth:`_observe_kinds`)
+        layout = kv_row_layout(config)
+        self._kinds = layout.window_layers > 0
+        kinds = None
+        self._window_pages = 0
+        if self._kinds:
+            dispatch_rows = max(self._warmed_widths | {ec.decode_span})
+            kinds = kind_blocks(
+                layout, ec.num_blocks, ec.block_size, ec.max_request_len,
+                dispatch_rows, config.attention_window)
+            self._window_pages = window_reserve_rows(
+                config.attention_window, ec.block_size,
+                dispatch_rows) // ec.block_size
         self.pool = init_paged_pool(
             config, ec.num_blocks, ec.block_size,
             kv_sharding=(self._sharded.kv_sharding
-                         if self._sharded is not None else None))
+                         if self._sharded is not None else None),
+            kinds=kinds)
         # a 'retention' block: the lanes' recurrent states, BY SLOT and
         # beside the pool (kv_blocks.init_retention_states); the pool
         # holds a lane's unfolded rows only.  A model whose layers name
@@ -964,9 +1026,11 @@ class ServingEngine:
                        if self._retention else
                        init_conv_states(config, ec.num_slots)
                        if self._conv else None)
+        # nor does a cache by layer kind: a match would need the window
+        # layers' pages of the matched rows, which went back long ago
         self.prefix_index = (PrefixIndex(ec.block_size)
                              if ec.prefix_cache and not self._stateful
-                             else None)
+                             and not self._kinds else None)
         # the tenant registry must exist before the tier policy (the
         # QoS-aware policy reads class membership from it)
         self.tenants = tenants or TenantRegistry.default()
@@ -998,14 +1062,19 @@ class ServingEngine:
             self.host_tier = shared_host_tier
             self.prefix_index.host_drop = self.host_tier.forget
         self.allocator = BlockAllocator(
-            ec.num_blocks, ec.block_size,
+            self.pool.num_blocks, ec.block_size,
             evictor=(self._evict_blocks if self.prefix_index is not None
                      else None))
+        self.window_allocator = (
+            BlockAllocator(self.pool.kind_num_blocks[1], ec.block_size)
+            if self._kinds else None)
         self._table_width = -(-ec.max_request_len // ec.block_size)
+        # entries of a lane's table: a table a kind, side by side
+        self._table_entries = self._table_width * (1 + self._kinds)
         # view rows a step of the blockwise attention takes (paged.py)
         self._key_block_rows = ec.block_size * key_block_entries(
             self._table_width, ec.block_size)
-        self._slots = [_Slot(i, self._table_width)
+        self._slots = [_Slot(i, self._table_entries)
                        for i in range(ec.num_slots)]
         # mixed-batching scheduler state: the effective fused-chunk
         # budget, the prefill round-robin pointer (a many-chunk prompt
@@ -1020,12 +1089,6 @@ class ServingEngine:
         # beside it, a routed block's dispatch: (its counts array in a
         # list, the rows and the expert-layer passes it carried)
         self._routing_inflight = ([], 0, 0)
-        # the warmed prefill-chunk bucket universe — warmup compiles
-        # exactly this set, and the autotuner's fused-budget envelope
-        # is confined to it (a tuned budget can only select among
-        # already-compiled shapes)
-        self._warmed_widths = _warmed_prefill_widths(
-            ec, _bucket_floor(ec, config))
         # the rows a 'retention' lane is funded for: its unfolded tail
         # through the widest step, whatever the request's length
         self._tail_rows = (
@@ -1202,6 +1265,15 @@ class ServingEngine:
         # row 0 (they read zeros, whatever the slot held)
         self.conv_state_reads = 0
         self.conv_state_resets = 0
+        # a cache by layer kind: pages by kind and event — reserved at
+        # admission, returned at a request's end (or its preemption) and,
+        # the window kind's, released behind the window and drawn for the
+        # rows ahead while the request runs
+        self.kv_kind_blocks: Dict[Tuple[str, str], int] = {
+            (kind, event): 0 for kind, events in (
+                ("full", ("reserved", "returned")),
+                ("window", ("reserved", "released", "drawn", "returned")))
+            for event in events} if self._kinds else {}
         # how far the step programs' attention had to go: summed over
         # planned dispatches, the furthest lane's rows rounded up to key
         # blocks (what the key-block loop runs over), the view's whole
@@ -1655,6 +1727,14 @@ class ServingEngine:
                 f"pool only has {self.allocator.num_blocks - 1} — it can "
                 f"NEVER be admitted (grow num_blocks or shrink the request)"
             )
+        if self._kinds and min(needed, self._window_pages) \
+                > self.window_allocator.num_blocks - 1:
+            raise BlockExhausted(
+                f"request {request.rid!r} needs "
+                f"{min(needed, self._window_pages)} blocks of the window "
+                f"kind but its pool only has "
+                f"{self.window_allocator.num_blocks - 1} — it can NEVER be "
+                f"admitted (grow num_blocks or shrink the request)")
         if spec.kv_block_quota is not None and needed > spec.kv_block_quota:
             raise QuotaExceeded(
                 f"request {request.rid!r} needs {needed} blocks but "
@@ -2073,7 +2153,7 @@ class ServingEngine:
             _, pk, pv, *rest = self._warm(
                 "prefill", (width,), self._prefill_step,
                 self.params, self.pool.k, self.pool.v,
-                np.zeros((1, self._table_width), np.int32),
+                np.zeros((1, self._table_entries), np.int32),
                 one, np.zeros((1,), bool),
                 np.zeros((1, width), np.int32), one,
                 np.zeros((1,), np.float32),
@@ -2089,11 +2169,11 @@ class ServingEngine:
                 _, _, pk, pv, *rest = self._warm(
                     "mixed", (width,), self._mixed_step,
                     self.params, self.pool.k, self.pool.v,
-                    np.zeros((1, self._table_width), np.int32), one,
+                    np.zeros((1, self._table_entries), np.int32), one,
                     np.zeros((1, width), np.int32), one,
                     np.zeros((1,), np.float32),
                     np.zeros((1, 2), np.uint32),
-                    np.zeros((s, self._table_width), np.int32),
+                    np.zeros((s, self._table_entries), np.int32),
                     zeros_s, np.zeros((s,), bool), zeros_s,
                     np.zeros((s,), np.float32),
                     np.zeros((s, ec.decode_span, 2), np.uint32),
@@ -2107,11 +2187,11 @@ class ServingEngine:
                             "mixed_verify", (width, 1 + k),
                             self._mixed_verify_step,
                             self.params, self.pool.k, self.pool.v,
-                            np.zeros((1, self._table_width), np.int32),
+                            np.zeros((1, self._table_entries), np.int32),
                             one, np.zeros((1, width), np.int32), one,
                             np.zeros((1,), np.float32),
                             np.zeros((1, 2), np.uint32),
-                            np.zeros((s, self._table_width), np.int32),
+                            np.zeros((s, self._table_entries), np.int32),
                             zeros_s, np.zeros((s,), bool),
                             np.full((s, 1 + k), -1, np.int32),
                             np.ones((s,), np.int32),
@@ -2122,7 +2202,7 @@ class ServingEngine:
             _, pk, pv, *rest = self._warm(
                 "decode", (), self._decode_step,
                 self.params, self.pool.k, self.pool.v,
-                np.zeros((s, self._table_width), np.int32),
+                np.zeros((s, self._table_entries), np.int32),
                 zeros_s, np.zeros((s,), bool), zeros_s,
                 np.zeros((s,), np.float32),
                 np.zeros((s, ec.decode_span, 2), np.uint32), zeros_s,
@@ -2137,7 +2217,7 @@ class ServingEngine:
             _, _, pk, pv = self._warm(
                 "loop", (k_depth,), loop_step,
                 self.params, self.pool.k, self.pool.v,
-                np.zeros((s, self._table_width), np.int32),
+                np.zeros((s, self._table_entries), np.int32),
                 zeros_s, np.zeros((s,), bool), zeros_s,
                 np.zeros((s,), np.float32),
                 np.zeros((s, k_depth * ec.decode_span, 2), np.uint32),
@@ -2154,7 +2234,7 @@ class ServingEngine:
             _, _, _, _, _, pk, pv = self._warm(
                 "spec_loop", (k_depth,), spec_step,
                 self.params, self.pool.k, self.pool.v,
-                np.zeros((s, self._table_width), np.int32),
+                np.zeros((s, self._table_entries), np.int32),
                 zeros_s, np.zeros((s,), bool), zeros_s,
                 np.zeros((s,), np.float32),
                 np.zeros((s, k_depth * w, 2), np.uint32),
@@ -2179,7 +2259,7 @@ class ServingEngine:
                 _, _, pk, pv = self._warm(
                     "verify", (1 + k,), self._verify_step,
                     self.params, self.pool.k, self.pool.v,
-                    np.zeros((s, self._table_width), np.int32),
+                    np.zeros((s, self._table_entries), np.int32),
                     zeros_s, np.zeros((s,), bool),
                     np.full((s, 1 + k), -1, np.int32),
                     np.ones((s,), np.int32),
@@ -2226,8 +2306,8 @@ class ServingEngine:
         ec = self.engine_config
         s, b = ec.num_slots, self._diffusion
         one = np.zeros((1,), np.int32)
-        table = np.zeros((1, self._table_width), np.int32)
-        lanes = (np.zeros((s, self._table_width), np.int32),
+        table = np.zeros((1, self._table_entries), np.int32)
+        lanes = (np.zeros((s, self._table_entries), np.int32),
                  np.zeros((s,), np.int32), np.zeros((s,), bool),
                  np.zeros((s, b), np.int32), np.zeros((s, b), bool),
                  np.zeros((s, b), bool), np.zeros((s,), np.int32))
@@ -2702,6 +2782,18 @@ class ServingEngine:
             family = MetricFamily(
                 f"kubeshare_serving_conv_{name}_total", said, "counter")
             family.add(dict(plabel), value)
+            retention.append(family)
+        if self.kv_kind_blocks:
+            family = MetricFamily(
+                "kubeshare_serving_kv_kind_blocks_total",
+                "Pages of a cache by layer kind, by kind (full: every row "
+                "of a request under one table; window: the rows a window "
+                "layer still reaches) and event: reserved at admission, "
+                "returned at a request's end or preemption and, the window "
+                "kind's, released behind the window and drawn for the rows "
+                "ahead while the request runs.", "counter")
+            for (kind, event), value in self.kv_kind_blocks.items():
+                family.add({**plabel, "kind": kind, "event": event}, value)
             retention.append(family)
         diff_passes = MetricFamily(
             "kubeshare_serving_diffusion_passes_total",
@@ -3231,6 +3323,18 @@ class ServingEngine:
                     needed, pending.rid, tenant=spec.name,
                     quota=spec.kv_block_quota,
                     evict_tenants_first=evict_first)
+                if self._kinds:
+                    # the window kind, as explicit and as up-front: what a
+                    # lane of it can ever hold at once, or the request's
+                    # pages if those are fewer (a tenant's quota counts
+                    # the full kind's pages alone)
+                    try:
+                        near = self.window_allocator.reserve(
+                            min(needed, self._window_pages), pending.rid,
+                            tenant=spec.name)
+                    except BlockExhausted:
+                        self.allocator.reclaim(blocks)
+                        raise
                 # host payloads are deserialized (and crc-checked) here,
                 # BEFORE any of them uploads: a corrupt block is dropped
                 # from tier + trie and the whole admission retries COLD —
@@ -3287,6 +3391,12 @@ class ServingEngine:
         slot.table[: len(slot.blocks)] = slot.blocks
         slot.length = 0
         slot.folded, slot.paged_to = 0, len(slot.blocks)
+        if self._kinds:
+            first = self._table_width
+            slot.table[first:first + len(near)] = near
+            slot.window_blocks, slot.window_to = list(near), len(near)
+            self.kv_kind_blocks["full", "reserved"] += len(blocks)
+            self.kv_kind_blocks["window", "reserved"] += len(near)
         if n_promote or (hit is not None and hit.host_cow is not None):
             # PROMOTION: host payloads back into fresh device blocks.
             # Each upload is one warmed compiled shape dispatched
@@ -3488,6 +3598,9 @@ class ServingEngine:
         # reservation that needs only a few blocks shaves the cached
         # chain instead of wiping it, so the resume still hits
         self.allocator.reclaim(slot.blocks[::-1])
+        # both kinds' pages are dropped whole: the resumed request
+        # prefills prompt + generated from row 0
+        self._return_window(slot)
         remaining = slot.max_new - done
         plan, cover = self._prefill_plan(resume_prompt.size)
         rows = max(cover, self._request_rows(resume_prompt.size, remaining))
@@ -3521,6 +3634,16 @@ class ServingEngine:
             self.preemptions.get(slot.tenant, 0) + 1
         slot._clear()
         slot.state = "free"
+
+    def _return_window(self, slot: _Slot) -> None:
+        """A cache by layer kind: ``slot``'s request is over here (done, or
+        preempted), and the window kind's pages it still holds go back with
+        the full kind's."""
+        if not self._kinds:
+            return
+        self.window_allocator.reclaim(slot.window_blocks[::-1])
+        self.kv_kind_blocks["full", "returned"] += len(slot.blocks)
+        self.kv_kind_blocks["window", "returned"] += len(slot.window_blocks)
 
     def _dispatch(self, fn, *args):
         """Every device burst charges through the guard when one is
@@ -3590,6 +3713,11 @@ class ServingEngine:
                      "chunk": plan.chunk[1] if plan.chunk else 0,
                      "attend": self._attend_of(plan),
                      "program": self._program_of(plan)}
+            if self._kinds:
+                # what a window layer reads of the lanes' rows
+                window = self.model_config.attention_window
+                attrs["window_rows"] = sum(
+                    min(s.length, window) for s in plan.decode_slots)
             passes = self._weight_passes(plan)
             if passes is not None:
                 attrs["weight_passes"] = passes
@@ -3676,10 +3804,14 @@ class ServingEngine:
             query_rows = plan.chunk[1]
         config = self.model_config
         # the width of a head AS THE POOL HOLDS IT: narrower heads lie
-        # paired in a row (kv_blocks.KVRowLayout heads_paired)
-        return attend_path(config.block, query_rows, self._table_width,
-                           self.pool.k, self.pool.v, self.pool.k.shape[-1],
-                           config.diffusion_block)
+        # paired in a row (kv_blocks.KVRowLayout heads_paired).  A cache by
+        # layer kind: each kind's own arrays, and both paths ("full+window")
+        # where they differ
+        paths = [attend_path(config.block, query_rows, self._table_width,
+                             k, v, k.shape[-1], config.diffusion_block)
+                 for k, v in zip(jax.tree_util.tree_leaves(self.pool.k),
+                                 jax.tree_util.tree_leaves(self.pool.v))]
+        return "+".join(dict.fromkeys(paths))
 
     def _report_slow_dispatch(self, entered: float, start: float,
                               launch: profiling.span,
@@ -3761,9 +3893,10 @@ class ServingEngine:
             segment = np.pad(segment, (0, width - segment.size))
         # a 'retention' lane's table changes while the request lives (a
         # fold zeroes the entries behind it in .consume, which does not
-        # wait for a chunk that yields no token): the dispatch gets a copy
-        # of it, not a view the backend may still be reading
-        table = slot.table[None].copy() if self._retention \
+        # wait for a chunk that yields no token; so does a window kind's):
+        # the dispatch gets a copy of it, not a view the backend may still
+        # be reading
+        table = slot.table[None].copy() if self._retention or self._kinds \
             else slot.table[None]
         return (final, table,
                 np.asarray([start], np.int32),
@@ -3878,7 +4011,7 @@ class ServingEngine:
         ec = self.engine_config
         s = ec.num_slots
         steps = ec.decode_span if n_steps is None else n_steps
-        tables = np.zeros((s, self._table_width), np.int32)
+        tables = np.zeros((s, self._table_entries), np.int32)
         lengths = np.zeros((s,), np.int32)
         active = np.zeros((s,), bool)
         tokens = np.zeros((s,), np.int32)
@@ -3951,7 +4084,7 @@ class ServingEngine:
         # the fused pick at the final chunk's last-real-row logits IS
         # the first token; read when consumed (one step later), with
         # a routed block's counts
-        if final or counts or self._stateful:
+        if final or counts or self._stateful or self._kinds:
             self._inflight = ("diffusion" if self._diffusion else "span",
                               None, (slot, picked) if final else None)
 
@@ -4010,7 +4143,7 @@ class ServingEngine:
         ec = self.engine_config
         s = ec.num_slots
         n_keys = k_depth * (1 + ec.draft_len)
-        tables = np.zeros((s, self._table_width), np.int32)
+        tables = np.zeros((s, self._table_entries), np.int32)
         lengths = np.zeros((s,), np.int32)
         active = np.zeros((s,), bool)
         tokens = np.zeros((s,), np.int32)
@@ -4209,7 +4342,7 @@ class ServingEngine:
         block's known tokens, which rows are still masked, which of them
         may be committed, and how many this pass commits."""
         s, b = self.engine_config.num_slots, self._diffusion
-        tables = np.zeros((s, self._table_width), np.int32)
+        tables = np.zeros((s, self._table_entries), np.int32)
         lengths = np.zeros((s,), np.int32)
         active = np.zeros((s,), bool)
         tokens = np.zeros((s, b), np.int32)
@@ -4274,7 +4407,7 @@ class ServingEngine:
         the schedule stays aligned with the non-speculative stream by
         construction."""
         s = self.engine_config.num_slots
-        tables = np.zeros((s, self._table_width), np.int32)
+        tables = np.zeros((s, self._table_entries), np.int32)
         lengths = np.zeros((s,), np.int32)
         active = np.zeros((s,), bool)
         tokens = np.full((s, width), -1, np.int32)
@@ -4434,7 +4567,56 @@ class ServingEngine:
             self._observe_retention()
         if self._conv_inflight is not None:
             self._observe_conv()
+        if self._kinds:
+            self._observe_kinds()
         return True
+
+    def _observe_kinds(self) -> None:
+        """One dispatch of a cache by layer kind, after its tokens are
+        accepted: a lane's next dispatch starts at row ``n`` (its next
+        chunk's first row, or its length) and its earliest query reaches
+        back to ``n - window + 1``, so the window kind's pages wholly
+        behind that row go back to that kind's allocator — the entries
+        point at the scratch block from then on, and a table keeps its
+        absolute page indices — and the lane draws the pages its next rows
+        need: never more than the ``_window_pages`` it was admitted with,
+        fewer as the request nears its end; it draws no more than it has
+        just released, so the draw cannot fail.  Then the counters and the
+        ``kubeshare.engine.kv_kinds`` span (its attributes are what a
+        trace's reader can reach)."""
+        bs = self.engine_config.block_size
+        window = self.model_config.attention_window
+        base = self._table_width
+        released = drawn = context_rows = 0
+        for slot in self._slots:
+            if slot.state == "free":
+                continue
+            rows = slot.plan[0][0] if slot.plan else slot.length
+            context_rows += rows
+            first = max(rows - window + 1, 0) // bs
+            held_from = slot.window_to - len(slot.window_blocks)
+            if first > held_from:
+                behind = slot.window_blocks[:first - held_from]
+                del slot.window_blocks[:first - held_from]
+                slot.table[base + held_from:base + first] = 0
+                self.window_allocator.reclaim(behind)
+                released += len(behind)
+            want = min(len(slot.blocks), first + self._window_pages)
+            ahead = want - slot.window_to
+            if ahead > 0:
+                near = self.window_allocator.reserve(
+                    ahead, slot.rid, tenant=slot.tenant)
+                slot.table[base + slot.window_to:base + want] = near
+                slot.window_blocks += near
+                slot.window_to = want
+                drawn += ahead
+        with profiling.span(
+                "kubeshare.engine.kv_kinds", released=released, drawn=drawn,
+                live_full=self.allocator.blocks_in_use,
+                live_window=self.window_allocator.blocks_in_use,
+                context_rows=context_rows):
+            self.kv_kind_blocks["window", "released"] += released
+            self.kv_kind_blocks["window", "drawn"] += drawn
 
     def _observe_retention(self) -> None:
         """One 'retention' dispatch's bookkeeping, after its tokens are
@@ -4994,6 +5176,7 @@ class ServingEngine:
         # tail-first reclaim: see _preempt — eviction shaves chains
         # from the deepest block, preserving the shared head
         self.allocator.reclaim(slot.blocks[::-1])
+        self._return_window(slot)
         self.requests_finished += 1
         slot._clear()
         slot.state = "free"

@@ -97,6 +97,13 @@ class KVRowLayout:
       loop of one update a row, 4.5-5.0 us each on a v5e against 0.09 us
       a whole row (PERF.md, PR 42).
 
+    A model whose layers name the "window" attention kind
+    (``TransformerConfig.layer_operators``) caches BY LAYER KIND:
+    ``window_layers`` of the ``layers`` keep their rows in a pool of their
+    own (:func:`init_paged_pool`), under a table and an allocator of their
+    own, and the pages a window no longer reaches go back while the request
+    runs.  The row is the same in both kinds.
+
     Every step program writes rows through ``paged._write_rows`` and
     reads them through ``paged._layer_views`` whatever the layout; what
     packs a block for another tier or another pool (``kv_tier``,
@@ -114,11 +121,21 @@ class KVRowLayout:
     gate_heads: int = 0
     # KV heads that share one array row ("kv_heads" only; 1: a row a head)
     heads_paired: int = 1
+    # of ``layers``, those of the window kind ("kv_heads" only; 0: one kind)
+    window_layers: int = 0
 
     @property
     def v_layers(self) -> int:
         """Layers of the V array: ``v_packed`` pool layers share one."""
         return -(-self.layers // self.v_packed)
+
+    @property
+    def kind_layers(self) -> Tuple[int, ...]:
+        """The layers of each kind's pool, the full kind first: one entry
+        where the model caches under one table a lane."""
+        if not self.window_layers:
+            return (self.layers,)
+        return (self.layers - self.window_layers, self.window_layers)
 
     def values_per_row(self) -> int:
         """Values the pool holds a token, over all pool layers (a spare
@@ -146,7 +163,7 @@ def kv_row_layout(config: TransformerConfig) -> KVRowLayout:
     return KVRowLayout(
         "kv_heads", config.attn_sublayers, row, row,
         gate_heads=config.kv_heads if config.block == "retention" else 0,
-        heads_paired=paired)
+        heads_paired=paired, window_layers=config.window_layers)
 
 
 LANES = 128  # values a TPU vector register holds across
@@ -167,6 +184,12 @@ def heads_paired(config: TransformerConfig) -> int:
 def require_kv_heads(config: TransformerConfig, who: str) -> None:
     """Raise for a cache row ``who`` cannot serve yet."""
     layout = kv_row_layout(config)
+    if layout.window_layers:
+        raise ValueError(
+            f"{who} serves one pool under one table a lane; this model "
+            f"caches BY LAYER KIND ({layout.window_layers} of its "
+            f"{layout.layers} attention layers keep a window's rows in a "
+            f"pool of their own)")
     if layout.kind != "kv_heads":
         raise ValueError(
             f"{who} serves the 'kv_heads' row layout only (a K and a V "
@@ -186,6 +209,12 @@ class PagedKVPool:
     — one cache row per (block, offset) pair; a slot's virtual position
     ``p`` lives at block ``table[p // block_size]``, offset
     ``p % block_size``.
+
+    A model that caches by layer kind (:class:`KVRowLayout`
+    ``window_layers``) holds an array a KIND, ``k`` and ``v`` each a tuple
+    (full, window): the same row, each kind's own layers and its own count
+    of blocks (:func:`kind_blocks`), block 0 of each its scratch block.
+    Every step program takes and returns the tuples as it takes the arrays.
     """
 
     k: jnp.ndarray
@@ -198,19 +227,29 @@ class PagedKVPool:
     gate: Optional[jnp.ndarray] = None
 
     @property
+    def by_kind(self) -> bool:
+        return isinstance(self.k, tuple)
+
+    @property
     def num_blocks(self) -> int:
-        return self.k.shape[1]
+        """Blocks of the pool (the full kind's, where it holds two)."""
+        return jax.tree_util.tree_leaves(self.k)[0].shape[1]
+
+    @property
+    def kind_num_blocks(self) -> Tuple[int, ...]:
+        """Blocks of each kind's pool, scratch block 0 included."""
+        return tuple(x.shape[1] for x in jax.tree_util.tree_leaves(self.k))
 
     def arrays(self) -> Tuple[jnp.ndarray, ...]:
         """The pool's device arrays, in the order every step program
         takes and returns them."""
-        return tuple(x for x in (self.k, self.v, self.gate)
-                     if x is not None)
+        return tuple(jax.tree_util.tree_leaves((self.k, self.v, self.gate)))
 
     def bytes_per_block(self) -> int:
         """HBM cost of one block (K and V and, where the block has one,
         the log gate; all layers) — the allocation granularity the
-        serving docs size against."""
+        serving docs size against (a pool under one table a lane: a pool by
+        layer kind has a block a kind)."""
         return sum(x.size * x.dtype.itemsize
                    for x in self.arrays()) // self.num_blocks
 
@@ -258,12 +297,64 @@ def chain_token_runs(tokens, block_size: int) -> List[List[int]]:
             for i in range(0, len(toks), block_size)]
 
 
+def window_reserve_rows(window: int, block_size: int,
+                        dispatch_rows: int) -> int:
+    """Rows a lane of the window kind is funded for, whatever its request's
+    length: a dispatch that adds ``a`` rows from row ``n`` on attends rows
+    ``n - window + 1 .. n + a - 1``, ``window + a - 1`` of them, which
+    whole pages cover with one more where neither end lies on a page's
+    edge."""
+    pages = -(-(window + dispatch_rows - 1) // block_size) + 1
+    return pages * block_size
+
+
+def kind_blocks(layout: KVRowLayout, num_blocks: int, block_size: int,
+                max_request_len: int, dispatch_rows: int,
+                window: int) -> Tuple[int, ...]:
+    """How a pool by layer kind divides ``num_blocks`` blocks of EVERY
+    layer's row between its kinds: (N_full, N_window), each with its own
+    scratch block 0, holding the same bytes —
+
+        g x N_full + w x N_window = (g + w) x num_blocks
+
+    with ``g`` full and ``w`` window layers of one row width.  The law: both
+    kinds fund the same number of worst-case lanes, a lane holding
+    ``max_request_len`` rows of the full kind and
+    :func:`window_reserve_rows` of the window kind (what admission reserves
+    of each).  ``N_window`` is moved to the nearest count where ``w x
+    (num_blocks - N_window)`` divides by ``g``, so ``N_full`` is whole and
+    no byte is left over."""
+    g, w = layout.kind_layers
+    full = -(-max_request_len // block_size)
+    near = min(full, window_reserve_rows(window, block_size, dispatch_rows)
+               // block_size)
+    lanes = (g + w) * (num_blocks - 1) / (g * full + w * near)
+    # the nearest count to the law's that leaves both kinds two blocks (a
+    # scratch block and one more) and N_full whole: within g of it
+    most = num_blocks + (num_blocks - 2) * g // w
+    target = max(2, min(int(1 + lanes * near), most))
+    fits = [n for delta in range(g + 1) for n in (target - delta,
+                                                  target + delta)
+            if 2 <= n <= most and w * (num_blocks - n) % g == 0]
+    if not fits:
+        raise ValueError(
+            f"num_blocks {num_blocks} cannot be divided between "
+            f"{g} full and {w} window layers (each kind needs its scratch "
+            f"block and one more)")
+    n_window = fits[0]
+    n_full = num_blocks + w * (num_blocks - n_window) // g
+    return n_full, n_window
+
+
 def init_paged_pool(
     config: TransformerConfig, num_blocks: int, block_size: int,
-    kv_sharding=None,
+    kv_sharding=None, kinds: Optional[Tuple[int, ...]] = None,
 ) -> PagedKVPool:
     """Allocate the static block pool (block 0 is the scratch block, so
-    ``num_blocks - 1`` are allocatable).
+    ``num_blocks - 1`` are allocatable).  ``kinds``: the blocks of each
+    kind's pool where the model caches by layer kind (:func:`kind_blocks`,
+    which the engine asks with what it serves; the bytes are ``num_blocks``
+    blocks of every layer's row whatever the division).
 
     ``kv_sharding``: optional ``jax.sharding.Sharding`` the buffers are
     committed to — the sharded serving context passes a
@@ -280,6 +371,19 @@ def init_paged_pool(
             f"got {num_blocks}"
         )
     layout = kv_row_layout(config)
+    if layout.window_layers:
+        if kv_sharding is not None:
+            raise ValueError("a pool by layer kind takes no kv_sharding")
+        if kinds is None:
+            # no engine says what its requests and dispatches may be: as
+            # for requests of max_seq_len rows and dispatches of a page
+            kinds = kind_blocks(layout, num_blocks, block_size,
+                                config.max_seq_len, block_size,
+                                config.attention_window)
+        k, v = (tuple(jnp.zeros((layers, blocks) + shape[1:], config.dtype)
+                      for layers, blocks in zip(layout.kind_layers, kinds))
+                for shape in layout.block_shapes(block_size))
+        return PagedKVPool(k=k, v=v, block_size=block_size)
     k, v = (jnp.zeros(shape[:1] + (num_blocks,) + shape[1:], config.dtype)
             for shape in layout.block_shapes(block_size))
     if kv_sharding is not None:

@@ -340,6 +340,12 @@ def _layer_views(pool_k, pool_v, layer_idx, tables,
 KEY_BLOCK = 512  # view rows a step of the blockwise attention takes
 
 
+def _page_rows(pool_k) -> int:
+    """Rows a page of the pool holds (a pool by layer kind is an array a
+    kind, ``kv_blocks.PagedKVPool``: their pages hold the same rows)."""
+    return jax.tree_util.tree_leaves(pool_k)[0].shape[3]
+
+
 def key_block_entries(table_width: int, block_size: int) -> int:
     """Table entries a key block: the most that divide the table's width
     and hold no more than ``KEY_BLOCK`` rows."""
@@ -662,6 +668,39 @@ def _paired_context(o, paired: int, kv_heads: int):
                      axis=2).reshape(b, h, c, wide // paired)
 
 
+def _tables_by_kind(tables, positions, blk, kinds: int, page_rows: int):
+    """A step's tables and write targets, a kind of a pool by layer kind
+    at a time -> (tables of each kind, ``blk`` of each kind).  A lane's
+    table holds its kinds' tables side by side (``[B, kinds x T]``, the full
+    kind first: ``kv_blocks``), so every program takes one table a lane
+    whatever the pool, and the steps' own ``blk`` — looked up in the first
+    ``T`` entries — is the full kind's.  A further kind's is looked up here
+    in its own entries, at the same ``positions``, and lands in that kind's
+    scratch block wherever the step sent the full kind's there (an idle
+    lane, a dead row).  ``tables`` may be a fused step's row groups
+    (:func:`_attend_rows`).  One kind: what came in."""
+    if kinds == 1:
+        return [tables], [blk]
+    fused = isinstance(tables, tuple)
+    groups = tables if fused else (RowGroup(tables, positions),)
+    width = groups[0].tables.shape[1] // kinds
+    out_tables, out_blk = [], []
+    for kind in range(kinds):
+        parts = tuple(
+            RowGroup(g.tables[:, kind * width:(kind + 1) * width],
+                     g.positions) for g in groups)
+        out_tables.append(parts if fused else parts[0].tables)
+        if kind == 0:
+            out_blk.append(blk)
+            continue
+        own = [jnp.take_along_axis(g.tables, g.positions // page_rows,
+                                   axis=1) for g in parts]
+        own = (jnp.concatenate([o.reshape(-1) for o in own])[None] if fused
+               else own[0])
+        out_blk.append(jnp.where(blk != 0, own, 0))
+    return out_tables, out_blk
+
+
 def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
                     tables, positions, blk, off, x, live, carried=None):
     """The 'gqa_moe' block's layers (``transformer.gqa_moe_layers`` puts
@@ -694,32 +733,49 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     holds — and leaves ``B * u`` of the lane's last ``conv_taps - 1`` LIVE
     rows (``ops/short_conv.py``); a lane with no live row (an idle lane, a
     slot between two chunks of its prompt) keeps what it held.  The new
-    states come back as the fifth result."""
+    states come back as the fifth result.
+
+    Where the model caches BY LAYER KIND (a layer names "window":
+    ``kv_blocks.PagedKVPool``) ``pool_k`` and ``pool_v`` are an array a
+    kind and a lane's table its kinds' tables side by side
+    (:func:`_tables_by_kind`): a layer writes and attends its OWN kind's
+    arrays through its own kind's table — the "window" kind under
+    ``attention_window`` (the paged kernel walks from the window's first
+    page, the key-block loop from its first block), the full kind as
+    ever — and a "global" layer rotates nothing."""
     # a fused step's groups bring their own positions, which ARE their
     # reach: generation by diffusion has a mixed entry point of its own
     reach = attend_reach(config, positions)
     dtype = config.dtype
     h_kv = config.kv_heads
-    paired = h_kv // pool_k.shape[2]
+    by_kind = isinstance(pool_k, tuple)
+    pools = list(zip(pool_k, pool_v)) if by_kind else [(pool_k, pool_v)]
+    kind_tables, kind_blk = _tables_by_kind(tables, positions, blk,
+                                            len(pools), _page_rows(pool_k))
+    paired = h_kv // pools[0][0].shape[2]
     scale = config.head_dim ** -0.5
 
-    def attend(row, attn, y):
-        nonlocal pool_k, pool_v
-        q, k, v = gqa_qkv(attn, y, positions, config)
+    def attend(row, attn, y, operator):
+        # the layer's kind: its pool and table, its window, its rotation
+        kind = int(operator == "window")
+        window = config.attention_window if kind else None
+        pk, pv = pools[kind]
+        q, k, v = gqa_qkv(attn, y, positions, config, operator != "global")
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         if paired > 1:
             with jax.named_scope("attention"):
                 q = _paired_queries(q, paired, h_kv)
-                k = k.reshape(*k.shape[:2], *pool_k.shape[2::2])
-                v = v.reshape(*v.shape[:2], *pool_v.shape[2::2])
-        pool_k, pool_v = _write_rows(pool_k, pool_v, row, blk, off, k, v)
+                k = k.reshape(*k.shape[:2], *pk.shape[2::2])
+                v = v.reshape(*v.shape[:2], *pv.shape[2::2])
+        pk, pv = pools[kind] = _write_rows(pk, pv, row, kind_blk[kind], off,
+                                           k, v)
 
         def view(tables, reach, q):
-            return _attend_view(q, pool_k, pool_v, row, tables, reach, None,
+            return _attend_view(q, pk, pv, row, tables, reach, window,
                                 config.diffusion_block, scale)
 
         with jax.named_scope("attention"):
-            o = _attend_rows(tables, reach, view, q)
+            o = _attend_rows(kind_tables[kind], reach, view, q)
             return o if paired == 1 else _paired_context(o, paired, h_kv)
 
     # no convolution, nothing carried: `conv` is never called
@@ -742,6 +798,7 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
 
     x, counts = gqa_moe_layers(params, x, config, attend, live, conv)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
+    pool_k, pool_v = zip(*pools) if by_kind else pools[0]
     return (x, pool_k, pool_v, counts,
             Recurrent(None, tuple(states)) if states else None)
 
@@ -924,7 +981,7 @@ def _prefill_rows(params, config: TransformerConfig, pool_k, pool_v, tables,
     pool and the routing counts, before any head."""
     dtype = config.dtype
     chunk = tokens.shape[1]
-    bs = pool_k.shape[3]
+    bs = _page_rows(pool_k)
     positions = starts[:, None] + jnp.arange(chunk)[None, :]  # [P, C]
     blk = jnp.take_along_axis(tables, positions // bs, axis=1)  # [P, C]
     blk = jnp.where(active[:, None], blk, 0)
@@ -1043,7 +1100,7 @@ def paged_decode_step(
     states shifted by the step's row, an idle lane's as they were.
     """
     dtype = config.dtype
-    bs = pool_k.shape[3]
+    bs = _page_rows(pool_k)
     positions = lengths  # [S]
     # each slot's write target; inactive lanes land in scratch block 0
     blk = jnp.take_along_axis(
@@ -1664,7 +1721,7 @@ def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
     each chunk's ``p_last_row`` — pool_k, pool_v, the routing counts or
     None): the head runs over those rows alone."""
     dtype = config.dtype
-    bs = pool_k.shape[3]
+    bs = _page_rows(pool_k)
     lanes, width = p_tokens.shape
     p_positions = p_start[:, None] + jnp.arange(width)[None, :]  # [P, W]
     groups = (RowGroup(p_table, p_positions),

@@ -328,7 +328,8 @@ def _stages_hold(config, args, text):
 
     table = stages.instruction_stages(text)
     fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", text))
-    blocks = str(args[1].shape[1])  # an axis only the pool's views have
+    # an axis only the pool's views have (a pool by kind: one a kind)
+    blocks = {str(a.shape[1]) for a in jax.tree.leaves(args[1])}
     stacked = {f"{config.held_experts},{a},{b}"
                for a, b in ((config.d_model, config.expert_d_ff),
                             (config.expert_d_ff, config.d_model))} \
@@ -359,7 +360,7 @@ def _stages_hold(config, args, text):
             _DTYPE_BYTES.get(dtype, 4) * math.prod(map(int, dims.split(",")))
             for dtype, dims in re.findall(r"\b([a-z]+[0-9]*)\[([0-9,]+)\]",
                                           shape)
-            if blocks not in dims.split(","))
+            if not blocks & set(dims.split(",")))
         by_stage[table[name]] = by_stage.get(table[name], 0) + size
     assert kernels > 0 and set(by_stage) <= set(stages.STAGES)
     assert by_stage.get("unscoped", 0) < 0.1 * sum(by_stage.values()), \
@@ -954,6 +955,111 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     by_stage = _stages_hold(config, args, text)
     assert {"conv", "attention", "ffn", "experts", "kv_write", "head"} \
         <= set(by_stage)
+
+
+# temporaries of `smallthinker-21ba3b-instruct`'s two programs as first
+# compiled (PR 47): 147,692,032 B the span alone, 305,668,096 B beside a
+# 512-row chunk
+KINDS_TEMPORARIES = {"decode": 192 << 20, "mixed": 400 << 20}
+
+
+def _kinds_case(kind):
+    """The decode span or the mixed program of
+    ``smallthinker-21ba3b-instruct``'s first pipeline stage at its published
+    widths, as shapes only, compiled as the engine compiles them: the pool
+    BY LAYER KIND (an array a kind, K and V) donated, a lane's tables side by
+    side."""
+    import json
+
+    from kubeshare_tpu.serving.kv_blocks import (init_paged_pool,
+                                                 kind_blocks, kv_row_layout)
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "smallthinker-21ba3b-instruct.json")) as f:
+        config_file = json.load(f)
+    tc = dict(config_file["transformer_config"])
+    tc["dtype"] = jnp.dtype(tc["dtype"])
+    config = TransformerConfig(**tc)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, config.dtype),
+        jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), config)))
+    e = config_file["engine"]
+    s, t = e["num_slots"], e["max_request_len"] // e["block_size"]
+    num_blocks = e["pool_bytes"] // (16384 * e["block_size"]) + 1
+    kinds = kind_blocks(kv_row_layout(config), num_blocks, e["block_size"],
+                        e["max_request_len"], e["prefill_chunk"],
+                        config.attention_window)
+    pool_k, pool_v = jax.eval_shape(
+        lambda: (lambda pool: (pool.k, pool.v))(init_paged_pool(
+            config, num_blocks, e["block_size"], kinds=kinds)))
+    span = 4
+    lanes = (_i32(s, 2 * t), _i32(s), jax.ShapeDtypeStruct((s,), bool),
+             _i32(s), jax.ShapeDtypeStruct((s,), jnp.float32),
+             jax.ShapeDtypeStruct((s, span, 2), jnp.uint32), _i32(s))
+    if kind == "decode":
+        fn = lambda w, pk, pv, *rest: paged_decode_span(
+            w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+        return config, fn, (params, pool_k, pool_v, *lanes)
+    fn = lambda w, pk, pv, *rest: paged_mixed_step(
+        w, config, _greedy_pick, span, None, pk, pv, *rest, routing=True)
+    return config, fn, (
+        params, pool_k, pool_v, _i32(1, 2 * t), _i32(1),
+        _i32(1, e["prefill_chunk"]), _i32(1),
+        jax.ShapeDtypeStruct((1,), jnp.float32),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32), *lanes)
+
+
+@pytest.mark.parametrize("kind", list(KINDS_TEMPORARIES))
+def test_cache_by_kind_program_compiles_and_fits(one_chip, monkeypatch, kind):
+    """The first pipeline stage of ``smallthinker-21ba3b-instruct`` at the
+    published widths — two full layers that rotate nothing and six under a
+    4,096-row window, GQA 28 to 4 at head width 128 (a query group of 7,
+    padded to a tile inside the kernel's call), 64 ReLU-gated experts top 6
+    behind a router on the layer's input, the whole vocabulary — built as on
+    the chip over a 4 GiB pool BY LAYER KIND: 35,492 blocks of the 2 full
+    layers' rows and 10,016 of the 6 window layers', the bytes of 16,385
+    blocks of every layer's row.  12.4 / 12.5 GB resident (7.93 GB of
+    weights, the pool); everything donated is written in place and no
+    program copies or re-lays either kind's pool; the decode lanes' one
+    query row attends through the paged kernel in BOTH kinds (one call an
+    attention layer a pass), the experts' tiles through the grouped kernel;
+    no key block of every lane's table entries is gathered; and the
+    program's own table of stages leaves no more unscoped than the other
+    routed cells' (the two kinds and the early router add no stage)."""
+    import re
+
+    monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
+    config, fn, args = _kinds_case(kind)
+    assert (config.attn_sublayers, config.window_layers,
+            config.expert_layers) == (8, 6, 8)
+    assert [a.shape for a in args[1]] == [a.shape for a in args[2]] == [
+        (2, 35492, 4, 16, 128), (6, 10016, 4, 16, 128)]
+    compiled = _compile_step(fn, args, one_chip,
+                             key=("smallthinker-21ba3b-instruct", kind))
+    memory = compiled.memory_analysis()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 12.2e9 < resident < 12.7e9 < 0.9 * V5E_HBM_BYTES, memory
+    assert memory.temp_size_in_bytes < KINDS_TEMPORARIES[kind], memory
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(args[1:3]))
+    assert donated == 16385 * 16 * 16384 == (1 << 32) + 16 * 16384
+    assert memory.alias_size_in_bytes >= donated, memory
+    text = compiled.as_text()
+    for shape in ("2,35492,4,16,128", "6,10016,4,16,128", "35492,4,16,128",
+                  "10016,4,16,128"):
+        assert not re.search(rf"bf16\[{shape}\][^ ]* copy\(", text), shape
+    passes = 2 if kind == "mixed" else 1
+    assert _kernel_calls(text) == (config.attn_sublayers * passes,
+                                   config.expert_layers * passes)
+    # no key block of every lane's table entries is gathered for the lanes
+    assert not re.search(r"bf16\[1024,4,16,128\]", text)
+    with_experts = set(re.findall(r"(?:bf16|f32)\[64,[0-9,]+\]", text))
+    assert {"bf16[64,2560,768]", "bf16[64,768,2560]"} <= with_experts
+    by_stage = _stages_hold(config, args, text)
+    assert {"attention", "experts", "kv_write", "head"} <= set(by_stage)
+    assert not {"conv", "ffn", "retention"} & set(by_stage)
 
 
 # ---------------------------------------------------------------------------

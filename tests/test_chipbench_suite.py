@@ -24,6 +24,7 @@ from chipbench.tests.test_spans import *  # noqa: E402,F401,F403
 from chipbench.tests.test_tiles import *  # noqa: E402,F401,F403
 from chipbench.tests import test_conv_readers as _conv  # noqa: E402
 from chipbench.tests import test_diffusion_readers as _diffusion  # noqa: E402
+from chipbench.tests import test_kinds_readers as _kinds  # noqa: E402
 from chipbench.tests import test_retention_readers as _retention  # noqa: E402
 from chipbench.tests import test_stages as _stages  # noqa: E402
 
@@ -77,6 +78,14 @@ test_conv_spans_that_lack_what_a_reader_reads_give_nothing = \
 test_a_program_without_the_conv_span_gives_nothing = \
     _conv.test_a_program_without_the_span_gives_nothing
 
+# ... and the readers' of a cache by layer kind (the same three)
+test_kinds_readers_over_spans_with_the_attributes = \
+    _kinds.test_readers_over_spans_with_the_attributes
+test_kinds_spans_that_lack_what_a_reader_reads_give_nothing = \
+    _kinds.test_spans_that_lack_what_a_reader_reads_give_nothing
+test_a_program_without_the_kinds_span_gives_nothing = \
+    _kinds.test_a_program_without_the_span_gives_nothing
+
 # the readers of device time by stage, under names of their own too
 test_stages_a_while_keeps_what_its_body_does_not_cover_and_names_collide = \
     _stages.test_a_while_keeps_what_its_body_does_not_cover_and_names_collide
@@ -97,8 +106,11 @@ def test_every_stage_metric_has_its_file_and_its_cells():
     ``per_layer`` over exactly the cells of that day; a configuration of
     another block has since appended its cell to the stages it has and four
     metrics after them (PR 41: a `benchmark` PR's to repair there, PERF.md
-    section 7, item 11), and another its cell and two more (PR 43, with a
-    second dense backlog cell).  The same statements, over the cells of today: PR
+    section 7, item 11), another its cell and two more (PR 43, with a
+    second dense backlog cell), and a third its cell — to the stages it has
+    and not to the kernel's share, which counts a held row as read in every
+    layer — and four metrics that are no stage's (PR 47).  The same
+    statements, over the cells of today: PR
     38's 14 in their order before anything later, a file each that says
     what its entry says, and each over the cells whose programs have the
     stage."""
@@ -113,8 +125,13 @@ def test_every_stage_metric_has_its_file_and_its_cells():
         RETENTION_STAGE, "step.retention_hbm_roofline.backlog",
         "retention.state_bytes_share.backlog",
         "retention.tail_rows_per_lane.backlog",  # appended since (PR 41)
-        CONV_STAGE, "step.conv_roofline.backlog"]  # ... and since (PR 43)
+        CONV_STAGE, "step.conv_roofline.backlog",  # ... and since (PR 43)
+        "step.mixed_kinds_routed_hbm_roofline.backlog",
+        "step.attend_kinds_kernel_hbm_roofline.backlog",
+        "kv.window_read_share.backlog",
+        "kv.pool_bytes_per_context_row.backlog"]  # ... and since (PR 47)
     conv = {"lfm2-pp5.gen.topics"}  # routed too, behind convolutions
+    kinds = {"smallthinker-pp7.gen.longmix"}  # routed, a cache by kind
     routed = {"lcf-ep32.gen.topics", "joyai-pp8.gen.topics",
               "sdar-pp8.gen.topics"} | conv
     dense = {"sc2-3b.gen.backlog", "scb-1b.gen.backlog"}
@@ -134,11 +151,11 @@ def test_every_stage_metric_has_its_file_and_its_cells():
         elif ".conv." in name:
             assert cells == conv
         elif "experts" in name:
-            assert cells == routed
-        elif "attend_kernel" in name:
+            assert cells == routed | kinds
+        elif "attend_kernel" in name:  # every layer reads every held row
             assert cells == routed | dense
         elif ".ffn." in name:  # `sdar`'s every feed-forward is the experts'
             assert cells == routed - {"sdar-pp8.gen.topics"} | dense \
                 | retention
         else:
-            assert cells == routed | dense | retention
+            assert cells == routed | dense | retention | kinds

@@ -244,9 +244,9 @@ def test_the_block_has_the_published_shape(model):
 
 @pytest.mark.parametrize("changes,said", [
     (dict(layer_operators=OPERATORS[:4]), "one of .* a layer, 5 in all"),
-    (dict(layer_operators=["conv", "window"] * 2 + ["attention"]),
+    (dict(layer_operators=["conv", "linear"] * 2 + ["attention"]),
      "one of"),
-    (dict(layer_operators=["conv"] * 5), "no 'attention' layer"),
+    (dict(layer_operators=["conv"] * 5), "names no 'attention'"),
     (dict(conv_taps=0), "conv_taps must be >= 2"),
     (dict(conv_taps=1), "conv_taps must be >= 2"),
     (dict(layer_operators=["attention"] * 5), "and 0 where none is"),
