@@ -25,6 +25,39 @@ import jax
 import jax.numpy as jnp
 
 
+def conv_gates(conv: Dict, y: jax.Array, dtype
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The operator's projections of ``y`` [..., d], a row's own whatever
+    rows stand beside it: (``C`` the output's gate, ``g = B * u`` what the
+    filter runs over), each [..., d]."""
+    with jax.named_scope("short_conv"):
+        d = y.shape[-1]
+        bcu = y @ conv["w_in"].astype(dtype)
+        gate_b, gate_c, u = (bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:])
+        return gate_c, gate_b * u
+
+
+def conv_filter(conv: Dict, g: jax.Array, state: jax.Array, dtype
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The filter over ``g`` [B, C, d], lane by lane, behind ``state``
+    [B, L - 1, d] -> (``c`` [B, C, d] in ``dtype``, ``window`` [B, L - 1 + C,
+    d] = ``[state | g]``): the one part of the operator in which a row
+    sees other rows."""
+    with jax.named_scope("short_conv"):
+        window = jnp.concatenate([state.astype(dtype), g], axis=1)
+        taps = conv["filter"].astype(jnp.float32)
+        rows = g.shape[1]
+        c = sum(taps[j] * window[:, j:j + rows].astype(jnp.float32)
+                for j in range(taps.shape[0]))
+        return c.astype(dtype), window
+
+
+def conv_out(conv: Dict, gate_c: jax.Array, c: jax.Array, dtype) -> jax.Array:
+    """The gated output projection ``(C * c) W_out``, a row's own."""
+    with jax.named_scope("short_conv"):
+        return (gate_c * c) @ conv["w_out"].astype(dtype)
+
+
 def short_conv(conv: Dict, y: jax.Array, state: jax.Array, dtype
                ) -> Tuple[jax.Array, jax.Array]:
     """The operator over ``y`` [B, C, d] whose lanes' earlier rows left
@@ -34,18 +67,13 @@ def short_conv(conv: Dict, y: jax.Array, state: jax.Array, dtype
     j`` back) and ``w_out`` [d, d].  Returns (out [B, C, d], ``window``
     [B, L - 1 + C, d] = ``[state | g]``: the state after n of the chunk's
     rows is ``window[:, n : n + L - 1]``, so after none of them it is the
-    state that came in)."""
-    with jax.named_scope("short_conv"):
-        d = y.shape[-1]
-        bcu = y @ conv["w_in"].astype(dtype)
-        gate_b, gate_c, u = (bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:])
-        g = gate_b * u
-        window = jnp.concatenate([state.astype(dtype), g], axis=1)
-        taps = conv["filter"].astype(jnp.float32)
-        rows = g.shape[1]
-        c = sum(taps[j] * window[:, j:j + rows].astype(jnp.float32)
-                for j in range(taps.shape[0]))
-        return (gate_c * c.astype(dtype)) @ conv["w_out"].astype(dtype), window
+    state that came in).  Its three parts (:func:`conv_gates`,
+    :func:`conv_filter`, :func:`conv_out`) one after another: a step whose
+    rows are several groups of lanes side by side runs the first and the
+    last once over all of them and the filter a group at a time."""
+    gate_c, g = conv_gates(conv, y, dtype)
+    c, window = conv_filter(conv, g, state, dtype)
+    return conv_out(conv, gate_c, c, dtype), window
 
 
 def state_after(window: jax.Array, rows_done: jax.Array, taps: int

@@ -36,7 +36,8 @@ schedules at TOKEN granularity instead:
   The chunk rides the span's first pass over the weights (its rows and
   the lanes' first rows through one layer loop: ``decode_span`` passes a
   dispatch, ``weight_passes`` on the launch span; a model with a state
-  by slot keeps the chunk a pass of its own).  Streams are token for
+  by slot rides it too, its state phase a group of lanes at a time as the
+  attention).  Streams are token for
   token those of ``mixed=False`` (the two sides write disjoint blocks,
   and a row's math is the split entry points' — hard-asserted by the
   tests);
@@ -1017,9 +1018,9 @@ class ServingEngine:
         self._conv = config.conv_layers > 0
         self._stateful = _carries_state(config)
         # a mixed dispatch's chunk rides the span's first pass over the
-        # layer stack unless a state by slot (or the sharded context's own
-        # composition) keeps it a pass of its own
-        self._mixed_fused = not self._stateful and self._sharded is None
+        # layer stack — a model's with a state by slot too — unless the
+        # sharded context's own composition keeps it a pass of its own
+        self._mixed_fused = self._sharded is None
         self._mixed_passes = mixed_weight_passes(ec.decode_span,
                                                  not self._mixed_fused)
         self.states = (init_retention_states(config, ec.num_slots)
@@ -1435,6 +1436,22 @@ class ServingEngine:
 
         span = ec.decode_span
         eos = ec.eos_token
+        # the step over the lanes has the same shapes in the decode program
+        # and in every mixed program: a model with a state by slot, whose
+        # fused mixed programs are dearer to trace than the compositions
+        # they replaced, traces it ONCE an engine and replays it into each
+        # (inline: what a program computes is what it was; only the order
+        # of the scan's closed-over operands in its text moves, which is
+        # why the other models' programs are left to the letter).  A
+        # function of this engine's own, so that no other engine's trace
+        # (made under another test's patched module) is ever taken for it
+        def decode_step(*args, **kwargs):
+            return paged.paged_decode_step(*args, **kwargs)
+
+        decode_once = jax.jit(
+            decode_step, static_argnums=(1,),
+            static_argnames=("routing", "grow"),
+            inline=True) if self._stateful else None
 
         def decode(w, pk, pv, tables, lengths, active, tokens, temps,
                    keys, budgets):
@@ -1446,7 +1463,8 @@ class ServingEngine:
             # collectives live inside the program.
             return paged_decode_span(
                 w, cfg, pick_rows, span, eos, pk, pv, tables, lengths,
-                active, tokens, temps, keys, budgets, routing=routed)
+                active, tokens, temps, keys, budgets, routing=routed,
+                decode_step=decode_once)
 
         if sharded is not None:
             decode = sharded.decode_span(pick_rows, span, eos)
@@ -1456,7 +1474,8 @@ class ServingEngine:
                 return paged_decode_span(
                     w, cfg, pick_rows, span, eos, pk, pv, tables, lengths,
                     active, tokens, temps, keys, budgets, routing=routed,
-                    recurrent=recurrent, folded=folded)
+                    recurrent=recurrent, folded=folded,
+                    decode_step=decode_once)
 
         self._decode_step = step_program("decode", decode, donated)
 
@@ -1538,7 +1557,7 @@ class ServingEngine:
                 w, cfg, pick_rows, span, eos, pk, pv, p_table, p_start,
                 p_tokens, p_last_row, p_temp, p_key, d_tables,
                 d_lengths, d_active, d_tokens, d_temps, d_keys,
-                d_budgets, routing=routed)
+                d_budgets, routing=routed, decode_step=decode_once)
 
         if sharded is not None:
             mixed = sharded.mixed_step(pick_rows, span, eos)
@@ -1552,7 +1571,8 @@ class ServingEngine:
                     p_tokens, p_last_row, p_temp, p_key, d_tables,
                     d_lengths, d_active, d_tokens, d_temps, d_keys,
                     d_budgets, routing=routed, recurrent=recurrent,
-                    p_folded=p_folded, p_slot=p_slot, d_folded=d_folded)
+                    p_folded=p_folded, p_slot=p_slot, d_folded=d_folded,
+                    decode_step=decode_once)
 
         self._mixed_step = step_program(
             "mixed", mixed, (1, 2, 16) if self._stateful else (1, 2))
@@ -2421,8 +2441,8 @@ class ServingEngine:
             "made, by plan kind: decode_span a decode dispatch, 1 a "
             "prefill chunk, decode_span a mixed dispatch whose chunk "
             "rides the span's first pass and decode_span + 1 one that "
-            "runs the chunk and the span back to back (a model with a "
-            "state by slot).", "counter")
+            "runs the chunk and the span back to back (the sharded "
+            "context).", "counter")
         for kind, passes in sorted(self.weight_passes.items()):
             weight_passes.add({"kind": kind, **plabel}, passes)
         host_args = MetricFamily(

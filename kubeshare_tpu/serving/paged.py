@@ -79,10 +79,13 @@ points:
   every decode lane's writable blocks (shared prefix blocks are read-only
   to both — divergence is copied-on-write before any append), so both
   groups' rows are written before either attends and neither reads a row
-  the other writes.  A model with a state by slot still runs the two
-  entry points above back to back (:func:`paged_mixed_back_to_back`:
-  prefill first, then the span), op for op the split dispatches' math —
-  the composition the fused step is held to, token for token;
+  the other writes.  A state by slot parts the rows where the attention
+  does: the chunk's rows cross the filling slot's state in order, each
+  lane's one row its own slot's, and a 'retention' block's folds stay the
+  program's last phase.  The two entry points above back to back
+  (:func:`paged_mixed_back_to_back`: prefill first, then the span), op for
+  op the split dispatches' math, are the composition the fused step is
+  held to, token for token — no engine of one chip runs it;
 - :func:`paged_verify_span`: the speculative draft-verify dispatch —
   one width-W chunk scores every lane's self-drafted tokens at once,
   picks what sequential decoding would emit at each position (each
@@ -134,7 +137,8 @@ from ..ops.paged_attention import (kernel_fits, latent_kernel_fits,
                                    paged_decode_attention,
                                    paged_latent_decode_attention)
 from ..ops.rope import apply_rope
-from ..ops.short_conv import short_conv, state_after
+from ..ops.short_conv import (conv_filter, conv_gates, conv_out,
+                              state_after)
 from .drafter import ngram_propose_rows
 
 
@@ -469,10 +473,41 @@ def _attend_view_blocks(q, pool_k, pool_v, layer_idx, tables, positions,
 class RowGroup(NamedTuple):
     """One group of a fused step's rows (:func:`_mixed_first_step`): ``B``
     lanes of ``C`` rows each, lane-major along the step's one row axis,
-    which attend ``tables`` [B, T] at ``positions`` [B, C]."""
+    which attend ``tables`` [B, T] at ``positions`` [B, C].  Where the model
+    carries a state BY SLOT (:class:`Recurrent`) a group also says whose
+    states its rows cross: ``live`` [B, C] its real rows, ``slots`` [B] its
+    lanes' slots (None: lane b IS slot b, the decode lanes), and — a
+    'retention' block — ``folded`` [B] its lanes' fold points and ``grow``
+    the rows a tail of its may gain before the next fold."""
 
     tables: jax.Array
     positions: jax.Array
+    live: Optional[jax.Array] = None
+    folded: Optional[jax.Array] = None
+    slots: Optional[jax.Array] = None
+    grow: int = 0
+
+
+def _by_group(groups, fn, *rows):
+    """``fn(group, *rows)`` for every group of a fused step's rows: each
+    group's rows are cut out of ``rows`` [1, H, R, .], laid out as its lanes
+    hold them [B, H, C, .], and the results [B, H, C, .] go back side by
+    side [1, H, R, .].  ``groups``: anything a group that has ``positions``
+    [B, C]."""
+    out, lo = [], 0
+    for group in groups:
+        b, c = group.positions.shape
+
+        def lanes_of(q, lo=lo):  # [1, H, R, w] -> [B, H, C, w]
+            h, w = q.shape[1], q.shape[3]
+            return q[0, :, lo:lo + b * c].reshape(h, b, c, w).transpose(
+                1, 0, 2, 3)
+
+        o = fn(group, *map(lanes_of, rows))
+        out.append(o.transpose(1, 0, 2, 3).reshape(
+            1, o.shape[1], b * c, o.shape[3]))
+        lo += b * c
+    return jnp.concatenate(out, axis=2)
 
 
 def _attend_rows(tables, positions, attend, *queries):
@@ -484,27 +519,30 @@ def _attend_rows(tables, positions, attend, *queries):
     ``tables``: its rows are ONE lane [1, R] of all the groups' rows side by
     side, so everything that is a row's own (the norms, the projections,
     rope by the row's position, the row's write, the feed-forward) runs
-    once over all of them, and only here do the groups part: each group's
-    rows are cut out of ``queries`` [1, H, R, .], laid out as its lanes
-    hold them and attended over its own tables by whatever
-    :func:`attend_path` chooses for its width, and the contexts go back
-    side by side."""
+    once over all of them, and only here do the groups part
+    (:func:`_by_group`): each group's rows are attended over its own tables
+    by whatever :func:`attend_path` chooses for its width, and the contexts
+    go back side by side.  (A state by slot parts them the same way:
+    :func:`_lane_groups`.)"""
     if not isinstance(tables, tuple):
         return attend(tables, positions, *queries)
-    out, lo = [], 0
-    for group in tables:
-        b, c = group.positions.shape
+    return _by_group(
+        tables, lambda group, *q: attend(group.tables, group.positions, *q),
+        *queries)
 
-        def lanes_of(q, lo=lo):  # [1, H, R, w] -> [B, H, C, w]
-            h, w = q.shape[1], q.shape[3]
-            return q[0, :, lo:lo + b * c].reshape(h, b, c, w).transpose(
-                1, 0, 2, 3)
 
-        o = attend(group.tables, group.positions, *map(lanes_of, queries))
-        out.append(o.transpose(1, 0, 2, 3).reshape(
-            1, o.shape[1], b * c, o.shape[3]))
-        lo += b * c
-    return jnp.concatenate(out, axis=2)
+def _lane_groups(tables, positions, live, carried):
+    """Whose states a step's rows cross -> (the :class:`Recurrent`, the
+    step's groups of lanes).  A fused step's groups bring their own
+    ``live``, ``folded``, ``slots`` and ``grow`` (``carried`` = the
+    :class:`Recurrent` alone); any other step's rows are one group, which
+    ``carried`` = (:class:`Recurrent`, ``folded``, ``slots``, ``grow``)
+    describes."""
+    if isinstance(tables, tuple):
+        return carried[0], tables
+    recurrent, folded, slots, grow = carried
+    return recurrent, (RowGroup(tables, positions, live, folded, slots,
+                                grow),)
 
 
 @jax.named_scope("mlp")
@@ -687,8 +725,8 @@ def _tables_by_kind(tables, positions, blk, kinds: int, page_rows: int):
     out_tables, out_blk = [], []
     for kind in range(kinds):
         parts = tuple(
-            RowGroup(g.tables[:, kind * width:(kind + 1) * width],
-                     g.positions) for g in groups)
+            g._replace(tables=g.tables[:, kind * width:(kind + 1) * width])
+            for g in groups)
         out_tables.append(parts if fused else parts[0].tables)
         if kind == 0:
             out_blk.append(blk)
@@ -733,7 +771,10 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
     holds — and leaves ``B * u`` of the lane's last ``conv_taps - 1`` LIVE
     rows (``ops/short_conv.py``); a lane with no live row (an idle lane, a
     slot between two chunks of its prompt) keeps what it held.  The new
-    states come back as the fifth result.
+    states come back as the fifth result.  In a fused step the projections
+    and the gates run once over all the rows and only the filter over the
+    state runs a group of lanes at a time (:func:`_lane_groups`), so the
+    experts behind it see ONE grouping of every live row.
 
     Where the model caches BY LAYER KIND (a layer names "window":
     ``kv_blocks.PagedKVPool``) ``pool_k`` and ``pool_v`` are an array a
@@ -779,22 +820,37 @@ def _gqa_moe_layers(params, config: TransformerConfig, pool_k, pool_v,
             return o if paired == 1 else _paired_context(o, paired, h_kv)
 
     # no convolution, nothing carried: `conv` is never called
-    recurrent, _, slots, _ = carried or (Recurrent(None, ()), None, None,
-                                         None)
+    recurrent, groups = _lane_groups(
+        tables, positions, live,
+        carried or (Recurrent(None, ()), None, None, 0))
     states = list(recurrent.states)
-    rows_done = jnp.sum(live, axis=1, dtype=jnp.int32)  # live rows lead
-    fresh = live[:, 0] & (positions[:, 0] == 0)
 
     def conv(idx, weights, y):
-        with jax.named_scope("conv_state"):
-            state = states[idx] if slots is None else states[idx][slots]
-            state = jnp.where(fresh[:, None, None], 0, state)
-        out, window = short_conv(weights, y, state, dtype)
-        new = state_after(window, rows_done, config.conv_taps)
-        with jax.named_scope("conv_state"):
-            states[idx] = (new if slots is None
-                           else states[idx].at[slots].set(new))
-        return out
+        # the projections once over every row, the filter a group of lanes
+        # at a time: each reads its slots' states as the array now stands
+        # and leaves the new ones there, so the decode lanes' idle lane that
+        # IS the filling slot keeps what the chunk's group has just left
+        gate_c, g = conv_gates(weights, y, dtype)
+
+        def filtered(group, g):  # the group's lanes' rows, [B, 1, C, d]
+            slots = group.slots
+            rows_done = jnp.sum(group.live, axis=1,
+                                dtype=jnp.int32)  # live rows lead
+            fresh = group.live[:, 0] & (group.positions[:, 0] == 0)
+            with jax.named_scope("conv_state"):
+                state = states[idx] if slots is None else states[idx][slots]
+                state = jnp.where(fresh[:, None, None], 0, state)
+            c, window = conv_filter(weights, g[:, 0], state, dtype)
+            new = state_after(window, rows_done, config.conv_taps)
+            with jax.named_scope("conv_state"):
+                states[idx] = (new if slots is None
+                               else states[idx].at[slots].set(new))
+            return c[:, None]
+
+        g = g[:, None]  # as :func:`_by_group` cuts rows: one head of them
+        c = (_by_group(groups, filtered, g) if isinstance(tables, tuple)
+             else filtered(groups[0], g))
+        return conv_out(weights, gate_c, c[:, 0], dtype)
 
     x, counts = gqa_moe_layers(params, x, config, attend, live, conv)
     counts = jnp.concatenate([counts, jnp.sum(live, dtype=jnp.int32)[None]])
@@ -813,7 +869,10 @@ class Recurrent(NamedTuple):
     states (``kv_blocks.init_retention_states``).  A model whose layers
     name the short convolution: ``gate`` None and ``states`` an array a
     convolution layer ``[slots, conv_taps - 1, d_model]``
-    (``kv_blocks.init_conv_states``)."""
+    (``kv_blocks.init_conv_states``).  A step's rows cross the states a
+    group of lanes at a time (:func:`_lane_groups`): one group in a chunk or
+    a decode step, the chunk's lane and the decode lanes in a mixed
+    dispatch's fused first step (:func:`_mixed_first_step`)."""
 
     gate: Optional[jax.Array]
     states: Tuple[jax.Array, ...]
@@ -830,6 +889,22 @@ def tail_pages(rows: int, block_size: int) -> int:
     return -(-(KEY_BLOCK + rows) // block_size)
 
 
+class _Tail(NamedTuple):
+    """What every 'retention' layer of a step reads of one group of lanes
+    (:func:`_retention_layers`): ``window`` [B, Wp] the pages from each
+    lane's fold point on, ``columns`` [B, W] their rows as columns of the
+    gate array, ``q_row`` [B, C] each query's row of the window (a dead
+    query: -1), ``has_state`` [B] the lanes that have folded anything, and
+    the group's ``positions`` and ``slots``."""
+
+    positions: jax.Array
+    slots: Optional[jax.Array]
+    window: jax.Array
+    columns: jax.Array
+    q_row: jax.Array
+    has_state: jax.Array
+
+
 def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
                       tables, positions, blk, off, x, live, carried):
     """The 'retention' block's layers (``transformer.retention_layers`` puts
@@ -839,31 +914,43 @@ def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
     multiple of ``KEY_BLOCK``) into the state of slot ``slots[b]`` (None:
     lane b IS slot b, the decode lanes) and holds the rows from there on in
     its pages; ``grow`` bounds the rows a tail gains before its next fold
-    (the chunk's width, the span's steps).
+    (the chunk's width, the span's steps).  A fused step's ``tables`` are
+    its row groups, which bring their own (:func:`_lane_groups`).
 
-    A layer writes its rows' K, V (:func:`_write_rows`) and log gate, then
-    reads ONE window of pages a lane, from the fold point on — at most a
-    key block and ``grow`` rows, whatever the request's length: the gate's
+    A layer writes its rows' K, V (:func:`_write_rows`) and log gate — once
+    over all the step's rows — then reads, a group of lanes at a time, ONE
+    window of pages a lane, from the fold point on — at most a key block
+    and ``grow`` rows, whatever the request's length: the gate's
     running log is the window's running sum, the unfolded rows weigh in by
     their squared scores (``ops.retention.tail_sums``, a running sum, no
     softmax), everything before them by ``phi(q)`` against the state
-    (``state_sums``), which a dispatch whose lanes have folded nothing
+    (``state_sums``), which a group whose lanes have folded nothing
     never reads.  Nothing here writes a state: a fold is the step
     program's last phase (:func:`fold_lanes`).  Returns no routing counts
     and, fifth, the :class:`Recurrent` with the updated gate array."""
-    recurrent, folded, slots, grow = carried
+    recurrent, groups = _lane_groups(tables, positions, live, carried)
     gate, states = recurrent
     bs = pool_k.shape[3]
     dtype = config.dtype
-    pages = tail_pages(grow, bs)
-    first = (folded // bs)[:, None] + jnp.arange(pages)[None, :]
-    window = jnp.take_along_axis(
-        tables, jnp.minimum(first, tables.shape[1] - 1), axis=1)  # [B, Wp]
-    # the window's rows as columns of the gate array, [B, W]
-    columns = (window[:, :, None] * bs
-               + jnp.arange(bs)[None, None, :]).reshape(window.shape[0], -1)
-    q_row = jnp.where(live, positions - folded[:, None], -1)  # [B, C]
-    has_state = (folded > 0) & jnp.any(live, axis=1)
+
+    def tail(group):
+        pages = tail_pages(group.grow, bs)
+        folded = group.folded
+        first = (folded // bs)[:, None] + jnp.arange(pages)[None, :]
+        window = jnp.take_along_axis(
+            group.tables, jnp.minimum(first, group.tables.shape[1] - 1),
+            axis=1)  # [B, Wp]
+        # the window's rows as columns of the gate array, [B, W]
+        columns = (window[:, :, None] * bs
+                   + jnp.arange(bs)[None, None, :]).reshape(
+                       window.shape[0], -1)
+        q_row = jnp.where(group.live, group.positions - folded[:, None],
+                          -1)  # [B, C]
+        has_state = (folded > 0) & jnp.any(group.live, axis=1)
+        return _Tail(group.positions, group.slots, window, columns, q_row,
+                     has_state)
+
+    tails = [tail(group) for group in groups]
 
     def attend(layer_idx, attn, y):
         nonlocal pool_k, pool_v, gate
@@ -874,23 +961,33 @@ def _retention_layers(params, config: TransformerConfig, pool_k, pool_v,
             k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
         with jax.named_scope("kv_write"):
             gate = gate.at[layer_idx, :, blk * bs + off].set(a)
-        with jax.named_scope("retention_tail"):
-            k_win, v_win = _layer_views(pool_k, pool_v, layer_idx, window)
-            cum_win = jnp.cumsum(gate[layer_idx, :, columns], axis=1)
-            cum_q = jnp.take_along_axis(
-                cum_win, jnp.maximum(q_row, 0)[:, :, None], axis=1)
-        tail = tail_sums(q, k_win, v_win, cum_q, cum_win, q_row, dtype)
 
-        def from_state():
-            with jax.named_scope("retention_state"):
-                lane_states = (states[layer_idx] if slots is None
-                               else states[layer_idx][slots])
-            return state_sums(q, lane_states, cum_q, has_state)
+        def sums(tail, q):  # of one group's lanes
+            with jax.named_scope("retention_tail"):
+                k_win, v_win = _layer_views(pool_k, pool_v, layer_idx,
+                                            tail.window)
+                cum_win = jnp.cumsum(gate[layer_idx, :, tail.columns],
+                                     axis=1)
+                cum_q = jnp.take_along_axis(
+                    cum_win, jnp.maximum(tail.q_row, 0)[:, :, None], axis=1)
+            unfolded = tail_sums(q, k_win, v_win, cum_q, cum_win,
+                                 tail.q_row, dtype)
 
-        state = jax.lax.cond(
-            jnp.any(has_state), from_state,
-            lambda: (jnp.zeros_like(tail[0]), jnp.zeros_like(tail[1])))
-        return retention_output(tail, state, dtype)
+            def from_state():
+                with jax.named_scope("retention_state"):
+                    lane_states = (states[layer_idx] if tail.slots is None
+                                   else states[layer_idx][tail.slots])
+                return state_sums(q, lane_states, cum_q, tail.has_state)
+
+            state = jax.lax.cond(
+                jnp.any(tail.has_state), from_state,
+                lambda: (jnp.zeros_like(unfolded[0]),
+                         jnp.zeros_like(unfolded[1])))
+            return retention_output(unfolded, state, dtype)
+
+        if isinstance(tables, tuple):
+            return _by_group(tails, sums, q)
+        return sums(tails[0], q)
 
     x = retention_layers(params, x, config, attend)
     return x, pool_k, pool_v, None, Recurrent(gate, states)
@@ -1142,6 +1239,7 @@ def paged_decode_span(
     routing: bool = False,
     recurrent: Optional[Recurrent] = None,
     folded=None,
+    decode_step=None,
 ) -> Tuple[jax.Array, ...]:
     """Advance every active lane up to ``span`` tokens in ONE dispatch.
 
@@ -1164,12 +1262,43 @@ def paged_decode_span(
     block once the span is done folds it in the program's last phase
     (:func:`fold_lanes`).  The short convolutions' states shift a row a
     step.
+
+    ``decode_step`` stands in for :func:`paged_decode_step` in the scan's
+    body (the same signature): an engine hands every span it builds ONE
+    ``jax.jit(paged_decode_step, inline=True)``, so the step over the lanes
+    — the same shapes in the decode program and in every mixed program — is
+    traced once for all of them and replayed into each (nothing of it is
+    left in the lowered text: the program is what it was).
     """
+    emitted, pk, pv, recurrent, lens, counts = _span_steps(
+        params, config, pick_fn, span, eos, pool_k, pool_v, tables, lengths,
+        active, tokens, temps, keys, budgets, routing, recurrent, folded,
+        grow=span, decode_step=decode_step)
+    if recurrent is not None:
+        recurrent = _settled(config, pk, pv, recurrent, tables, folded,
+                             lens, active)
+    return _step_outputs(routing, counts, recurrent, emitted, pk, pv)
+
+
+def _span_steps(params, config: TransformerConfig, pick_fn, steps: int, eos,
+                pool_k, pool_v, tables, lengths, active, tokens, temps, keys,
+                budgets, routing: bool, recurrent, folded, grow: int,
+                decode_step=None):
+    """The scan of :func:`paged_decode_span` — ``steps`` step-identical
+    :func:`paged_decode_step` iterations with the pick between them — before
+    the program's last phase: a whole span's, or the steps a mixed dispatch's
+    fused first step leaves (:func:`paged_mixed_step`; ``grow`` is the rows a
+    'retention' tail gains over the WHOLE dispatch, whatever share of it
+    this scan is).  Returns (emitted [steps, S], pool_k, pool_v, the
+    :class:`Recurrent` or None, the lanes' lengths after the last step, the
+    routing counts summed over the steps or None)."""
+    step = decode_step or paged_decode_step
+
     def body(carry, i):
         pk, pv, rec, lens, toks, alive, *counts = carry
-        logits, pk, pv, *rest = paged_decode_step(
+        logits, pk, pv, *rest = step(
             params, config, pk, pv, tables, lens, alive, toks,
-            routing=routing, recurrent=rec, folded=folded, grow=span)
+            routing=routing, recurrent=rec, folded=folded, grow=grow)
         if rec is not None:
             rec = rest.pop()
         with jax.named_scope("sample"):
@@ -1184,12 +1313,8 @@ def paged_decode_span(
     carry = (pool_k, pool_v, recurrent, lengths, tokens, active,
              *([jnp.zeros((N_STEP_COUNTS,), jnp.int32)] if routing else []))
     (pk, pv, recurrent, lens, _, _, *counts), emitted = jax.lax.scan(
-        body, carry, jnp.arange(span))
-    if recurrent is not None:
-        recurrent = _settled(config, pk, pv, recurrent, tables, folded,
-                             lens, active)
-    return _step_outputs(routing, counts[0] if counts else None, recurrent,
-                         emitted, pk, pv)
+        body, carry, jnp.arange(steps))
+    return emitted, pk, pv, recurrent, lens, counts[0] if counts else None
 
 
 def _decode_loop_impl(
@@ -1702,7 +1827,9 @@ def paged_mixed_verify_step(
 
 def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
                       p_table, p_start, p_tokens, p_last_row, d_tables,
-                      d_lengths, d_active, d_tokens):
+                      d_lengths, d_active, d_tokens, recurrent=None,
+                      p_folded=None, p_slot=None, d_folded=None,
+                      span: int = 1):
     """A mixed dispatch's fused first step: the chunk's ``W`` rows and every
     decode lane's row of the span's step 0, ``W + S`` rows through ONE
     layer loop — one pass over the weights where the chunk and the step
@@ -1717,15 +1844,30 @@ def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
     can run.  Both groups' K/V rows are written before either attends: the
     filling slot is no decode lane, the groups write disjoint blocks and
     share read-only prefix blocks only, so neither reads a row the other
-    writes.  Returns (logits [S + P, vocab] float32 — the lanes' rows, then
+    writes.
+
+    A state BY SLOT (``recurrent``) parts the rows exactly there too: the
+    chunk's rows read and leave the state of slot ``p_slot`` crossing them
+    in order (a 'retention' chunk: from its fold point ``p_folded``, a tail
+    that grows by the chunk's width), the lanes' one row each reads and
+    leaves the state of its own slot (lane s IS slot s; a 'retention' lane
+    from ``d_folded[s]``, and its tail's window is as wide as the whole
+    dispatch needs — ``span`` rows more, as in every step of the scan that
+    follows, so the dispatch reads one width of window and the folds stay
+    where they were: after the span's last step).  The filling slot is no
+    decode lane, so the two groups' states are disjoint as their blocks
+    are.  A chunk of such a model pads FORWARD: its padding's rows land in
+    the scratch block.
+
+    Returns (logits [S + P, vocab] float32 — the lanes' rows, then
     each chunk's ``p_last_row`` — pool_k, pool_v, the routing counts or
-    None): the head runs over those rows alone."""
+    None, the :class:`Recurrent` or None): the head runs over those rows
+    alone."""
     dtype = config.dtype
     bs = _page_rows(pool_k)
     lanes, width = p_tokens.shape
     p_positions = p_start[:, None] + jnp.arange(width)[None, :]  # [P, W]
-    groups = (RowGroup(p_table, p_positions),
-              RowGroup(d_tables, d_lengths[:, None]))
+    d_positions = d_lengths[:, None]  # [S, 1]
 
     def side_by_side(chunk, steps):  # [P, W], [S] -> [1, P * W + S]
         return jnp.concatenate([chunk.reshape(-1), steps])[None, :]
@@ -1741,11 +1883,19 @@ def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
     if config.positional != "rope":
         x = x + params["pos_embed"][positions].astype(dtype)
     # a chunk's rows after its last real one are padding
-    live = side_by_side(
-        jnp.arange(width)[None, :] <= p_last_row[:, None], d_active)
-    x, pool_k, pool_v, counts, _ = _run_layers(
+    p_live = jnp.arange(width)[None, :] <= p_last_row[:, None]
+    live = side_by_side(p_live, d_active)
+    if recurrent is not None:
+        # past the prompt's last row: into the scratch block, not a page
+        blk = jnp.where(live, blk, 0)
+    groups = (RowGroup(p_table, p_positions, p_live, p_folded, p_slot, width),
+              RowGroup(d_tables, d_positions, d_active[:, None], d_folded,
+                       None, span))
+    # the groups say whose states the rows cross: nothing else is carried
+    carried = None if recurrent is None else (recurrent,)
+    x, pool_k, pool_v, counts, recurrent = _run_layers(
         params, config, pool_k, pool_v, groups, positions, blk,
-        positions % bs, x, live)
+        positions % bs, x, live, carried=carried)
 
     with jax.named_scope("lm_head"):
         last = jnp.arange(lanes) * width + p_last_row
@@ -1754,13 +1904,14 @@ def _mixed_first_step(params, config: TransformerConfig, pool_k, pool_v,
                             config.norm_eps)
         logits = (head_in
                   @ params["lm_head"].astype(dtype)).astype(jnp.float32)
-    return logits, pool_k, pool_v, counts
+    return logits, pool_k, pool_v, counts, recurrent
 
 
 def mixed_weight_passes(span: int, back_to_back: bool) -> int:
     """The passes over the layer stack a mixed dispatch makes: the span's
     steps, and one more where the chunk has a pass of its own
-    (``back_to_back``: a model with a state by slot)."""
+    (``back_to_back``: :func:`paged_mixed_back_to_back`, and the sharded
+    context's own composition)."""
     return span + 1 if back_to_back else span
 
 
@@ -1790,6 +1941,7 @@ def paged_mixed_step(
     p_folded=None,
     p_slot=None,
     d_folded=None,
+    decode_step=None,
 ) -> Tuple[jax.Array, ...]:
     """One fused mixed dispatch: a bounded prefill chunk for ONE
     filling slot + a full decode span for every active decode lane.
@@ -1813,45 +1965,58 @@ def paged_mixed_step(
     first-token pick comes from the same head pass.  A row's values are
     what the split programs give up to the rounding of a sum over a batch
     of another height; the row positions, the masks, the keys consumed and
-    what is written where are the same.  A model with a state by slot
-    (``recurrent``) keeps the back-to-back composition
-    (:func:`paged_mixed_back_to_back`): a chunk's state crosses its rows in
-    order while a lane's is by slot.  Returns
-    (p_picked [1], emitted [span, S], pool_k, pool_v); ``p_picked`` is
-    meaningful only when the chunk is the prompt's final one (the
+    what is written where are the same.
+
+    A model with a state by slot (``recurrent``, the chunk's slot
+    ``p_slot`` and, a 'retention' block's, the fold points ``p_folded`` /
+    ``d_folded``) rides the same first step: its state phase runs a group
+    of lanes at a time as the attention does, and the :class:`Recurrent`
+    goes on through the scan and comes back last.  A 'retention' block's
+    folds are the program's last phase, after the span's last step: the
+    decode lanes' that are due (by the lanes that went IN active, as the
+    span folds them), then the chunk's — the filling slot is no decode
+    lane, so the order between them is free and each is what
+    :func:`paged_mixed_back_to_back` folds, row for row.
+
+    Returns (p_picked [1], emitted [span, S], pool_k, pool_v); ``p_picked``
+    is meaningful only when the chunk is the prompt's final one (the
     fused first-token pick, same as the standalone prefill step); with
-    ``routing`` the dispatch's routing counts come last.
+    ``routing`` the dispatch's routing counts come after, and the
+    :class:`Recurrent` last.  ``decode_step``: the scan's step, as
+    :func:`paged_decode_span` takes it.
     """
-    if recurrent is not None:
-        return paged_mixed_back_to_back(
-            params, config, pick_fn, span, eos, pool_k, pool_v, p_table,
-            p_start, p_tokens, p_last_row, p_temp, p_key, d_tables,
-            d_lengths, d_active, d_tokens, d_temps, d_keys, d_budgets,
-            routing=routing, recurrent=recurrent, p_folded=p_folded,
-            p_slot=p_slot, d_folded=d_folded)
-    logits, pk, pv, counts = _mixed_first_step(
+    logits, pk, pv, counts, recurrent = _mixed_first_step(
         params, config, pool_k, pool_v, p_table, p_start, p_tokens,
-        p_last_row, d_tables, d_lengths, d_active, d_tokens)
+        p_last_row, d_tables, d_lengths, d_active, d_tokens, recurrent,
+        p_folded, p_slot, d_folded, span)
     s = d_tokens.shape[0]
     with jax.named_scope("sample"):
         p_picked = pick_fn(logits[s:], p_temp, p_key)
         first = pick_fn(logits[:s], d_temps, d_keys[:, 0])
     emitted = first[None]
+    # step 0's end, as paged_decode_span's body leaves it
+    cont = d_active & (1 < d_budgets)
+    if eos is not None:
+        cont = cont & (first != eos)
+    lens = d_lengths + d_active.astype(jnp.int32)
     if span > 1:
-        # step 0's end, as paged_decode_span's body leaves it; the scan's
-        # step j is the span's step j + 1: its budgets are one emission
-        # short
-        cont = d_active & (1 < d_budgets)
-        if eos is not None:
-            cont = cont & (first != eos)
-        rest, pk, pv, *more = paged_decode_span(
-            params, config, pick_fn, span - 1, eos, pk, pv, d_tables,
-            d_lengths + d_active.astype(jnp.int32), cont, first, d_temps,
-            d_keys[:, 1:], d_budgets - 1, routing=routing)
+        # the scan's step j is the span's step j + 1: its budgets are one
+        # emission short
+        rest, pk, pv, recurrent, lens, more = _span_steps(
+            params, config, pick_fn, span - 1, eos, pk, pv, d_tables, lens,
+            cont, first, d_temps, d_keys[:, 1:], d_budgets - 1, routing,
+            recurrent, d_folded, grow=span, decode_step=decode_step)
         emitted = jnp.concatenate([emitted, rest])
         if routing:
-            counts = counts + more[0]
-    return _step_outputs(routing, counts, None, p_picked, emitted, pk, pv)
+            counts = counts + more
+    if recurrent is not None:
+        recurrent = _settled(config, pk, pv, recurrent, d_tables, d_folded,
+                             lens, d_active)
+        recurrent = _settled(
+            config, pk, pv, recurrent, p_table, p_folded,
+            p_start + p_last_row + 1, jnp.ones_like(p_start, bool), p_slot)
+    return _step_outputs(routing, counts, recurrent, p_picked, emitted, pk,
+                         pv)
 
 
 def paged_mixed_back_to_back(
@@ -1888,13 +2053,13 @@ def paged_mixed_back_to_back(
     dispatches' op for op: the prefill lane writes only its own (fresh or
     CoW-private) blocks, every decode lane writes only its own current
     block, and the prefill-then-decode order inside the program matches
-    the split scheduler's dispatch order.  What a model with a state by
-    slot runs: its :class:`Recurrent` goes through the chunk (a
-    'retention' block's fold with it) and then the span (and its lanes'
-    folds) — the chunk's slot is no lane of the span, so the span leaves
-    what the chunk wrote there alone — and comes back last, after the
-    chunk's and the span's routing counts, summed.  And what the fused
-    step is held to, token for token (``tests/test_mixed_fused.py``)."""
+    the split scheduler's dispatch order.  A model's :class:`Recurrent`
+    goes through the chunk (a 'retention' block's fold with it) and then
+    the span (and its lanes' folds) — the chunk's slot is no lane of the
+    span, so the span leaves what the chunk wrote there alone — and comes
+    back last, after the chunk's and the span's routing counts, summed.
+    No engine dispatches this: it is the REFERENCE the fused step is held
+    to, token for token, state for state (``tests/test_mixed_fused.py``)."""
     p_logits, pk, pv, *p_counts = paged_prefill_step(
         params, config, pool_k, pool_v, p_table, p_start,
         jnp.ones_like(p_start, bool), p_tokens, p_last_row,

@@ -276,7 +276,7 @@ def test_a_mixed_dispatch_over_both_kinds_gives_the_references_logits(
     d_table[width:width + 7] = 0
     tables = np.zeros((2, 2 * width), np.int32)
     tables[1] = d_table
-    logits, pk, pv, _ = paged._mixed_first_step(
+    logits, pk, pv, *_ = paged._mixed_first_step(
         params, config, pk, pv, jnp.asarray(p_table[None]),
         jnp.asarray([48]), jnp.asarray(chunk_tokens[None, 48:64]),
         jnp.asarray([15]), jnp.asarray(tables), jnp.asarray([0, 80]),
@@ -493,7 +493,10 @@ _BASE = dict(vocab_size=512, d_model=64, n_heads=4, n_kv_heads=2, n_layers=4,
              expert_d_ff=32)
 # sha256 of the lowered text (``jax.jit(fn).lower(...).as_text()``) of the
 # mixed program of each, by the PARENT of the PR that brought the kinds
-# (c2c52be, this installation: jax 0.9.0)
+# (c2c52be, this installation: jax 0.9.0).  The "lfm2" one was re-pinned by
+# PR 48 (on 719ea7b, where it still read d12cf1ad...9ad7002), which MEANT to
+# change that program: a state by slot rides the fused first step, and the
+# kinds' branches are as dead in the new text as they were in the old
 LIKE = {
     "sdar": (dict(_BASE, diffusion_block=4, diffusion_steps=4,
                   mask_token=511),
@@ -502,7 +505,7 @@ LIKE = {
                   router_renormalise_eps=1e-6, first_dense_layers=1,
                   layer_operators=("conv", "conv", "attention", "conv"),
                   conv_taps=3),
-             "d12cf1adbd43ad3d4c3957cd3b5d484c0b035654758ff89d668b9a5ad9ad7002"),
+             "53599c068e5c5d1af74ef589c9f902e940ffb3624ffabd6866d63764c8f43e77"),
 }
 
 

@@ -744,8 +744,9 @@ def test_latent_program_off_the_chip_gathers_key_blocks(one_chip, name):
 # ---------------------------------------------------------------------------
 
 # temporaries of `brumby-14b-base`'s two programs as first accepted (PR 41):
-# 552,131,072 B the span alone, 918,589,952 B beside a 512-row chunk
-RETENTION_TEMPORARIES = {"decode": 700 << 20, "mixed": 1100 << 20}
+# 552,131,072 B the span alone, 918,589,952 B beside a 512-row chunk; the
+# chunk on the span's first pass (PR 48): 666,886,144 B
+RETENTION_TEMPORARIES = {"decode": 700 << 20, "mixed": 800 << 20}
 
 
 def _retention_case(kind):
@@ -850,7 +851,8 @@ def test_retention_program_compiles_and_fits(one_chip, kind):
 # ---------------------------------------------------------------------------
 
 # temporaries of `lfm2-24b-a2b`'s two programs as first compiled (PR 43):
-# 31,684,608 B the span alone, 73,308,160 B beside a 512-row chunk
+# 31,684,608 B the span alone, 73,308,160 B beside a 512-row chunk; the
+# chunk on the span's first pass (PR 48): 88,963,584 B
 CONV_TEMPORARIES = {"decode": 48 << 20, "mixed": 112 << 20}
 
 
@@ -911,14 +913,17 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     whole vocabulary — built as on the chip with 32 lanes' convolution
     states (an array a layer, 256 KB each) beside a 1 GiB pool of the TWO
     attention layers' rows: 9.4 GB resident (8.32 GB of weights, the pool)
-    and 32 / 73 MB of temporaries.  The pool's minor dimension is 128 (two
+    and 32 / 89 MB of temporaries.  The pool's minor dimension is 128 (two
     64-wide heads side by side), everything donated is written in place
     and NO program copies or re-lays the pool; the decode lanes' one query
     row attends through the paged kernel (one call an attention layer: the
     query laid into its head's half of a row of zeros), the experts' tiles
     through the grouped kernel (one call an expert layer a pass); the rows
     are written whole (no loop of row updates in ``kv_write``); and the
-    program's own table of stages has ``conv`` beside the others."""
+    program's own table of stages has ``conv`` beside the others.  The
+    mixed program is its fused first step and the scan's body: the lanes'
+    kernel and the experts' are called in each, and the first step's experts
+    see the chunk's 512 rows and the lanes' 32 as ONE grouping."""
     import re
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
@@ -945,8 +950,11 @@ def test_conv_hybrid_program_compiles_and_fits(one_chip, monkeypatch, kind):
     for shape in ("2,16385,4,16,128", "16385,4,16,128", "2,16385,16,128"):
         assert not re.search(rf"bf16\[{shape}\][^ ]* copy\(", text), shape
     passes = 2 if kind == "mixed" else 1
-    assert _kernel_calls(text) == (config.attn_sublayers,
+    assert _kernel_calls(text) == (config.attn_sublayers * passes,
                                    config.expert_layers * passes)
+    grouped = set(re.findall(r"f32\[(\d+),2048\][^\n]*custom_call_target="
+                             r'"tpu_custom_call"', text))
+    assert grouped == ({"544", "32"} if kind == "mixed" else {"32"})
     # no key block of every lane's table entries is gathered for the lanes
     assert not re.search(r"bf16\[1024,4,16,128\]", text)
     _rows_written_whole(args, text)
@@ -1069,14 +1077,16 @@ def test_cache_by_kind_program_compiles_and_fits(one_chip, monkeypatch, kind):
 
 # cell -> (kind, the PARENT's temporaries, its (attention, experts) kernel
 # calls): PR 44's programs, a bare ``jax.jit`` taking 7-16 small arguments,
-# by this file's chipless compile (PR 45 compiled both sides once)
+# by this file's chipless compile (PR 45 compiled both sides once); the two
+# cells with a state by slot as PR 48's fused first step left them (their
+# parents': 918,589,952 and (0, 0); 73,308,160 and (2, 12))
 PARENTS = {
     "starcoderbase-1b": ("mixed", 297_772_032, (48, 0)),
     "starcoder2-3b": ("mixed", 854_657_024, (60, 0)),
     "longcat-flash-chat": ("mixed", 364_149_760, (16, 8)),
     "joyai-llm-flash": ("mixed", 63_936_000, (10, 8)),
-    "brumby-14b-base": ("mixed", 918_589_952, (0, 0)),
-    "lfm2-24b-a2b": ("mixed", 73_308_160, (2, 12)),
+    "brumby-14b-base": ("mixed", 666_886_144, (0, 0)),
+    "lfm2-24b-a2b": ("mixed", 88_963_584, (4, 12)),
     "sdar-30b-a3b-chat": ("diffusion", 10_612_224, (6, 6)),
 }
 
@@ -1126,19 +1136,19 @@ def test_packed_program_is_the_parents_but_for_its_arguments(
 
 
 # ---------------------------------------------------------------------------
-# who keeps the chunk a pass of its own: the mixed programs of the models
-# with a state by slot and of the diffusion entry are the parent's
+# who keeps the chunk a pass of its own: the diffusion entry alone, whose
+# program is the parent's; a state by slot rides the first pass (PR 48)
 # ---------------------------------------------------------------------------
 
-# sha256 (16 hex) of the lowered mixed programs at the cells' sizes, a
-# Pallas kernel's serialised body left out (it holds the checkout's paths
-# and the calling function's name), as PR 43 lowers them and PR 44 still
-# does (the whole texts compared once, checkout against checkout).  A PR
-# that means to change one of these programs — the follow-up that lets
-# their chunks ride the first pass too — pins its own.
-UNFUSED_MIXED = {"brumby-14b-base": "dd09e8f25a5d17c5",
-                 "lfm2-24b-a2b": "1caae13d2072dbbe",
-                 "sdar-30b-a3b-chat": "6c71ecda30d19467"}
+# sha256 (16 hex) of the lowered mixed program at the cell's size, a Pallas
+# kernel's serialised body left out (it holds the checkout's paths and the
+# calling function's name), as PR 43 lowers it and every PR since (the whole
+# texts compared once, checkout against checkout).  A PR that means to
+# change this program pins its own.
+UNFUSED_MIXED = {"sdar-30b-a3b-chat": "6c71ecda30d19467"}
+# the cells whose mixed program was the back-to-back composition until PR 48
+FUSED_STATEFUL = {"brumby-14b-base": "bf16[544,17408]",
+                  "lfm2-24b-a2b": "bf16[544,11776]"}
 
 
 def _lowered(fn, args, sharding, donate_argnums):
@@ -1148,27 +1158,48 @@ def _lowered(fn, args, sharding, donate_argnums):
     return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
 
 
-@pytest.mark.parametrize("name", sorted(UNFUSED_MIXED))
+@pytest.mark.parametrize("name", sorted({**UNFUSED_MIXED, **FUSED_STATEFUL}))
 def test_unfused_mixed_programs_are_the_parents(one_chip, monkeypatch, name):
-    """``paged_mixed_step`` fuses its first step by ``recurrent is None``
-    alone: a model with a state by slot runs the back-to-back composition,
-    lowered to the very text ``paged_mixed_back_to_back`` gives and to the
-    text it had before the fused step existed; the diffusion entry
-    (``paged_mixed_diffusion_step``) is another program, untouched."""
+    """The diffusion entry (``paged_mixed_diffusion_step``) is another
+    program than ``paged_mixed_step``, untouched: lowered to the text it had
+    before the fused step existed.  The two cells with a state by slot ran
+    the back-to-back composition until PR 48; their chunk now rides the
+    span's first pass: the program is no longer the composition's, its
+    feed-forward runs over the chunk's 512 rows and the lanes' 32 side by
+    side, it compiles for the described v5e and fits under 14.4 GB
+    (``gpu_mem`` 0.9 of the chip), everything donated is written in place,
+    and nothing the size of the pool or of a state is copied."""
     import hashlib
+    import re
 
     monkeypatch.setattr(paged, "_kernel_mode", lambda: "compiled")
-    if name == "sdar-30b-a3b-chat":
+    if name in UNFUSED_MIXED:
         _, fn, args = _cell_case(name, "mixed_diffusion")
         text = _lowered(fn, args, one_chip, (1, 2))
-    else:
-        case = _retention_case if name == "brumby-14b-base" else _conv_case
-        _, fn, args = case("mixed")
-        text = _lowered(fn, args, one_chip, (1, 2, 3))
-        # the case builders call this module's name for it
-        monkeypatch.setitem(globals(), "paged_mixed_step",
-                            paged.paged_mixed_back_to_back)
-        _, fn, args = case("mixed")
-        assert _lowered(fn, args, one_chip, (1, 2, 3)) == text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == UNFUSED_MIXED[name]
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+            == UNFUSED_MIXED[name]
+        return
+    case = _retention_case if name == "brumby-14b-base" else _conv_case
+    _, fn, args = case("mixed")
+    fused = _lowered(fn, args, one_chip, (1, 2, 3))
+    compiled = _compile_step(fn, args, one_chip, (1, 2, 3),
+                             key=(name, "mixed"))
+    # the case builders call this module's name for it
+    monkeypatch.setitem(globals(), "paged_mixed_step",
+                        paged.paged_mixed_back_to_back)
+    _, fn, args = case("mixed")
+    assert _lowered(fn, args, one_chip, (1, 2, 3)) != fused
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    resident = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"{name} mixed, fused: resident {resident:,} B, temporaries "
+          f"{memory.temp_size_in_bytes:,} B")
+    assert resident < 14.4e9, memory
+    kept = jax.tree.leaves(args[1:4])
+    assert memory.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in kept), memory
+    for shape in {",".join(map(str, a.shape)) for a in kept
+                  if a.size * a.dtype.itemsize > 64 << 20}:
+        assert not re.search(rf"\[{shape}\][^ ]* copy\(", text), shape
+    # the feed-forward runs over both groups' rows side by side
+    assert FUSED_STATEFUL[name] in text
